@@ -799,6 +799,16 @@ class ECBackendMixin:
         await self._reply_osd(conn, msg, M.MOSDECSubOpWriteBatchReply(
             results=results))
 
+    async def _serve_ec_read(self, conn: Connection,
+                             msg: M.MOSDECSubOpRead) -> None:
+        """``_handle_ec_read`` as a task of its own (see ``_dispatch``).
+        A reply that cannot be delivered is the requester's sub-op
+        timeout, as it was when the read loop raised it."""
+        try:
+            await self._handle_ec_read(conn, msg)
+        except (ConnectionError, OSError, RuntimeError):
+            self.perf.inc("osd_dispatch_errors")
+
     async def _handle_ec_read(self, conn: Connection,
                               msg: M.MOSDECSubOpRead) -> None:
         if self._sub_op_expired(msg):

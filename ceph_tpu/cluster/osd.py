@@ -680,7 +680,15 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
                           SimpleNamespace(src=msg.src, shard=shard))
             return True
         if isinstance(msg, M.MOSDECSubOpRead):
-            await self._handle_ec_read(conn, msg)
+            # served OFF the connection's read loop: the reply carries a
+            # whole shard range, and a reader that waits for its own
+            # reply to drain has stopped reading.  Two OSDs answering
+            # each other's sub-reads (a degraded k2m1 pool at 4 MiB x 16
+            # in flight: 2 MiB replies both ways) then fill both sockets
+            # and sit there until the sub-op timeout turns the client's
+            # read into EIO
+            self._track(asyncio.get_event_loop().create_task(
+                self._serve_ec_read(conn, msg)))
             return True
         if isinstance(msg, M.MOSDECSubOpReadReply):
             self._ack(msg.reqid, msg.result, msg)

@@ -151,7 +151,8 @@ class PGInfo:
 
 
 def choose_authoritative(infos: Dict[int, PGInfo],
-                         require_rollback: bool = False) -> int:
+                         require_rollback: bool = False,
+                         decodable: int = 0) -> int:
     """Authoritative-log election (reference find_best_info).
 
     Replicated pools: max last_update wins (a write present anywhere may
@@ -163,13 +164,26 @@ def choose_authoritative(infos: Dict[int, PGInfo],
     some shards only, unreconstructable if fewer than k have it — is
     ROLLED BACK rather than blessed.  Members below the watermark are
     stale rejoiners, excluded so acked writes can never be rolled back
-    (the reference excludes them via last_epoch_started)."""
+    (the reference excludes them via last_epoch_started).
+
+    The watermark alone cannot exclude a member with NO history (a
+    daemon revived on an empty store): it lives on the primary and
+    trails by one write on the replicas, so while a PG has taken a
+    single write every survivor still reports ZERO — and when the empty
+    member is the returning primary it would elect itself and order the
+    ACKED write rewound everywhere.  So while at least ``decodable``
+    (the codec's k) members hold history, the history-less ones are not
+    candidates: the head those members share can be decoded, and rolling
+    an un-acked write forward is as legal as rolling it back."""
     if not require_rollback:
         return min(infos,
                    key=lambda o: (tuple(-x for x in infos[o].last_update), o))
     committed = max(i.last_complete for i in infos.values())
     candidates = {o: i for o, i in infos.items()
                   if i.last_update >= committed}
+    holders = {o: i for o, i in candidates.items() if i.last_update > ZERO}
+    if decodable and len(holders) >= decodable:
+        candidates = holders
     if not candidates:
         # infos raced in-flight commits (a member's watermark moved
         # after another snapshotted): no member's log covers the

@@ -195,7 +195,9 @@ class RecoveryMixin:
             inventories[osd] = reply.objects or {}
 
         auth = pglog.choose_authoritative(
-            infos, require_rollback=pool.is_erasure())
+            infos, require_rollback=pool.is_erasure(),
+            decodable=self._codec(pool).get_data_chunk_count()
+            if pool.is_erasure() else 0)
         auth_head = infos[auth].last_update
         if auth_head < st.last_complete:
             # STALE ROUND (round 12): in-flight ack waits advanced our

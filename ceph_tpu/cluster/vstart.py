@@ -581,4 +581,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--osds", type=int, default=3)
     args = ap.parse_args()
+    from ceph_tpu.utils import compile_cache
+
+    compile_cache.enable()
     asyncio.run(_main(args.osds))

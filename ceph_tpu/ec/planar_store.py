@@ -37,26 +37,37 @@ LAYOUT_PLANAR = "planar8"
 # planar gate requires unit % 8 == 0)
 QUANTUM = 8
 
-_SHIFTS = np.arange(8, dtype=np.uint8)
-_WEIGHTS = (1 << np.arange(8)).astype(np.uint32)
+def _transpose8(x: np.ndarray) -> np.ndarray:
+    """8x8 bit-matrix transpose of every uint64 (bit 8r+c <-> bit 8c+r):
+    three masked swaps of ever larger blocks, a handful of whole-array
+    ops — the (.., 8, 8) {0,1} expansion it replaces cost ~0.3 s of
+    event-loop time per 4 MiB object at the read egress."""
+    u = np.uint64
+    t = (x ^ (x >> u(7))) & u(0x00AA00AA00AA00AA)
+    x = x ^ t ^ (t << u(7))
+    t = (x ^ (x >> u(14))) & u(0x0000CCCC0000CCCC)
+    x = x ^ t ^ (t << u(14))
+    t = (x ^ (x >> u(28))) & u(0x00000000F0F0F0F0)
+    return x ^ t ^ (t << u(28))
 
 
 def rows_to_planes(rows: np.ndarray) -> np.ndarray:
     """(c, L) uint8 byte rows -> (c*8, L/8) packed bit-planes.
 
     Host-numpy mirror of the jitted ``gf8.bytes_to_planar`` (same
-    formula, same LSB-first packing) so the CPU-backend steady state
-    never touches the device runtime for a layout change."""
+    LSB-first packing, bit-exact) so the CPU-backend steady state
+    never touches the device runtime for a layout change: each group of
+    8 source bytes is an 8x8 bit matrix (byte u, bit t) whose transpose
+    is the group's 8 plane bytes (plane t, bit u)."""
     rows = np.ascontiguousarray(rows, dtype=np.uint8)
     c, l = rows.shape
     if l % 8:
         raise ValueError(f"row length {l} not a multiple of 8")
     nb = l // 8
-    d = rows.reshape(c, nb, 8)                                # (c, i, u)
-    bits = (d[:, None, :, :] >> _SHIFTS[None, :, None, None]) & 1
-    planes = (bits.astype(np.uint32)
-              * _WEIGHTS[None, None, None, :]).sum(axis=3)    # (c, t, i)
-    return planes.reshape(c * 8, nb).astype(np.uint8)
+    x = _transpose8(rows.reshape(c, nb, 8).view("<u8"))       # (c, i, 1)
+    return np.ascontiguousarray(
+        x.view(np.uint8).reshape(c, nb, 8).transpose(0, 2, 1)
+    ).reshape(c * 8, nb)
 
 
 def planes_to_rows(planes: np.ndarray) -> np.ndarray:
@@ -64,11 +75,9 @@ def planes_to_rows(planes: np.ndarray) -> np.ndarray:
     planes = np.ascontiguousarray(planes, dtype=np.uint8)
     c8, nb = planes.shape
     c = c8 // 8
-    p = planes.reshape(c, 8, nb)                              # (c, t, i)
-    bits = (p[:, :, :, None] >> _SHIFTS[None, None, None, :]) & 1
-    by = (bits.astype(np.uint32)
-          * _WEIGHTS[None, :, None, None]).sum(axis=1)        # (c, i, u)
-    return by.reshape(c, nb * 8).astype(np.uint8)
+    x = np.ascontiguousarray(
+        planes.reshape(c, 8, nb).transpose(0, 2, 1)).view("<u8")  # (c, i, 1)
+    return _transpose8(x).view(np.uint8).reshape(c, nb * 8)
 
 
 # -- single-shard blob views (the store/wire serialization) -----------------

@@ -95,8 +95,8 @@ async def ramp(spec: LoadSpec, seed: int,
 
 
 def next_round() -> int:
-    """Artifact numbering follows the existing BENCH/LOAD trajectory
-    (the run_tpu_checks convention)."""
+    """Artifact numbering follows the existing BENCH/LOAD trajectory:
+    one past the highest round on disk."""
     rounds = [0]
     for pat in ("BENCH_r*.json", "LOAD_r*.json"):
         for path in glob.glob(os.path.join(_REPO, pat)):

@@ -22,18 +22,9 @@ from ceph_tpu.ops import gf8
 
 def make_mesh(n_devices: int | None = None, shard_axis: int | None = None) -> Mesh:
     """Build a ('data', 'shard') mesh over the first n devices."""
-    try:
-        devices = jax.devices()
-    except RuntimeError:
-        # default platform failed to initialize entirely (e.g. a libtpu
-        # version skew): the virtual CPU mesh is still usable
-        devices = jax.devices("cpu")
+    devices = jax.devices()
     if n_devices is None:
         n_devices = len(devices)
-    if len(devices) < n_devices:
-        # default platform too small (e.g. one real TPU): fall back to the
-        # virtual CPU mesh (xla_force_host_platform_device_count)
-        devices = jax.devices("cpu")
     if len(devices) < n_devices:
         raise ValueError(
             f"need {n_devices} devices, have {len(devices)}"

@@ -26,6 +26,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main() -> int:
+    from ceph_tpu.utils import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     sub.add_parser("list", help="list built-in scenarios")

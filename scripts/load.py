@@ -84,6 +84,9 @@ def _with_tmpdir(spec_store, fn):
 
 
 def main() -> int:
+    from ceph_tpu.utils import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     sub.add_parser("list", help="list built-in load specs and soaks")

@@ -183,6 +183,9 @@ async def _attribute(args) -> int:
 
 
 def main(argv=None) -> int:
+    from ceph_tpu.utils import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
     p = sub.add_parser("convert",

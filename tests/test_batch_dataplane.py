@@ -425,3 +425,76 @@ def test_coalesced_concurrent_appends_apply_exactly_once():
             await cluster.stop()
 
     run(scenario())
+
+
+@contention_retry()
+def test_device_branches_serve_write_read_recover(monkeypatch):
+    """The TPU product path's control flow, on CPU: with the host GF
+    engine switched off, a cluster's write, read, degraded read, recovery
+    and read-after-recovery run the device branches of
+    ``encode/decode/reencode_planes_multi`` (pad to a bucket,
+    ``to_planar``, ``gf8.planar_matmul``, read back) — which no other
+    tier-1 test reaches, because every codec ``planar_at_rest_ok`` admits
+    also satisfies ``_host_engine_ok`` on a CPU backend.  This is
+    ``chip_smoke.py``'s phase 3 at tiny size, through the same code."""
+    import chip_smoke
+    from ceph_tpu.ec import stripe
+
+    monkeypatch.setattr(stripe, "_host_engine_ok", lambda codec: False)
+    report = run(chip_smoke.serve_ec_objects(
+        seed=7, n_objects=4, object_size=64 << 10, in_flight=4))
+    grew = report["counters"]
+    assert grew.get("planar_matmul_calls", 0) > 0
+    assert grew.get("ec_coalesced_ticks", 0) > 0
+    assert grew.get("ec_coalesced_read_ticks", 0) > 0, \
+        "no degraded read decoded a missing data shard"
+    assert grew.get("ec_coalesced_reencode_ticks", 0) > 0, \
+        "recovery rebuilt nothing through reencode_planes_multi"
+    assert "ec_host_matmul_calls" not in grew
+    assert "ec_host_planar_matmul_calls" not in grew
+    assert report["shards_rebuilt"] > 0
+    assert report["placement_engine"] == "scalar"
+    # on CPU the planar matmul takes the XLA route (one stack group per
+    # call); the smoke's own check demands the Pallas kernel, so here it
+    # must refuse
+    with pytest.raises(AssertionError, match="stack-group"):
+        chip_smoke.check_device_did_the_work(report)
+
+
+@contention_retry()
+def test_degraded_reads_of_4mib_objects_are_answered():
+    """Degraded reads at the rados-bench object size: two surviving OSDs
+    of a k2m1 pool answer each other's sub-reads with 2 MiB frames, 16
+    reads in flight.  While the sub-read was served inside the
+    connection's read loop, both readers waited for their own replies to
+    drain, both sockets filled, and client reads came back EIO ("only 1
+    of 2 shard ranges") after the sub-op timeout — what stopped
+    chip_smoke.py's degraded pass.  At this size the stall was frequent,
+    not certain (it was certain at the smoke's 85 stored objects, which
+    tier-1 cannot afford); the test also keeps 4 MiB objects, which no
+    other tier-1 test writes, on the read egress and decode paths."""
+    async def scenario():
+        cluster = await start_cluster(3)
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create(
+                "big", "erasure", pg_num=8,
+                ec_profile={"plugin": "jerasure",
+                            "technique": "reed_sol_van",
+                            "k": "2", "m": "1"})
+            io = client.ioctx(pool)
+            rng = np.random.default_rng(3)
+            objs = {f"big_{i}": rng.integers(0, 256, 4 << 20,
+                                            dtype=np.uint8).tobytes()
+                    for i in range(16)}
+            await asyncio.gather(*(io.write_full(n, d, timeout=120)
+                                   for n, d in objs.items()))
+            await cluster.kill_osd(2)
+            await cluster.wait_down(2)
+            got = await asyncio.gather(*(io.read(n, timeout=120)
+                                         for n in objs))
+            assert all(g == d for g, d in zip(got, objs.values()))
+        finally:
+            await cluster.stop()
+
+    run(scenario())

@@ -382,3 +382,21 @@ def test_attribution_books_planar_convert_stage():
 
     assert stage_for("planar_ingest") == "planar_convert"
     assert stage_for("planar_egress") == "planar_convert"
+
+
+def test_host_layout_helpers_match_the_jitted_layout():
+    """rows_to_planes / planes_to_rows (8x8 bit transposes on uint64
+    words) against the jitted gf8.bytes_to_planar they mirror, bit for
+    bit, ragged row counts and the empty row included."""
+    import jax.numpy as jnp
+
+    from ceph_tpu.ops import gf8
+
+    r = _rng(19)
+    for c, nbytes in ((1, 8), (2, 64), (3, 8 * 1237), (12, 4096), (2, 0)):
+        rows = r.integers(0, 256, (c, nbytes), dtype=np.uint8)
+        planes = planar_store.rows_to_planes(rows)
+        assert planes.shape == (c * 8, nbytes // 8)
+        assert np.array_equal(
+            planes, np.asarray(gf8.bytes_to_planar(jnp.asarray(rows))))
+        assert np.array_equal(planar_store.planes_to_rows(planes), rows)

@@ -264,3 +264,66 @@ def test_thrash_primaries_mid_ec_write():
     v = run(run_scenario(builtin_scenarios()["thrash-ec-midwrite"], 11))
     assert v.passed, v.failures
     assert v.counters.get("daemon_restarts") == 3
+
+
+def _info(lu, lc=pglog.ZERO):
+    return pglog.PGInfo(last_update=lu, last_complete=lc)
+
+
+@pytest.mark.parametrize("infos,k,want", [
+    # an empty returning primary (osd 2) must not outvote two survivors
+    # whose watermark still trails the one acked write they both hold
+    ({0: _info((6, 1)), 1: _info((6, 1)), 2: _info(pglog.ZERO)}, 2, 0),
+    # fewer than k holders: the first write reached one shard only and
+    # cannot be decoded — the history-less member wins, it rolls back
+    ({0: _info((6, 1)), 1: _info(pglog.ZERO), 2: _info(pglog.ZERO)}, 2, 1),
+    # nobody has history: a new PG, any member will do
+    ({0: _info(pglog.ZERO), 1: _info(pglog.ZERO)}, 2, 0),
+    # among holders the rule is unchanged: MIN last_update at or above
+    # the watermark, so the un-acked (6, 3) on osd 0 rolls back
+    ({0: _info((6, 3), (6, 2)), 1: _info((6, 2), (6, 2)),
+      2: _info(pglog.ZERO)}, 2, 1),
+    # the guard is EC's: without `decodable` the old election stands
+    ({0: _info((6, 1)), 1: _info((6, 1)), 2: _info(pglog.ZERO)}, 0, 2),
+])
+def test_ec_election_skips_members_without_history(infos, k, want):
+    assert pglog.choose_authoritative(
+        infos, require_rollback=True, decodable=k) == want
+
+
+@contention_retry()
+def test_acked_single_write_survives_an_empty_primary_bounce():
+    """One write per PG leaves every replica's watermark at ZERO (it
+    rides the NEXT write).  Kill the primary, revive it on an empty
+    store before the interim primary's peering round has rolled the
+    watermark forward: the returning primary used to elect its own empty
+    log and order the acked write rewound on both survivors (ENOENT on
+    an acknowledged object; found by PR 23's chip bring-up)."""
+    async def scenario():
+        cluster = await start_cluster(3)
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create(
+                "bounce", "erasure", pg_num=8,
+                ec_profile={"plugin": "jerasure",
+                            "technique": "reed_sol_van",
+                            "k": "2", "m": "1"})
+            io = client.ioctx(pool)
+            names = [f"o{i}" for i in range(12)]
+            for n in names:
+                await io.write_full(n, n.encode() * 4096, timeout=60)
+            await cluster.kill_osd(2)
+            await cluster.wait_down(2)
+            await cluster.revive_osd(2)
+            # let the returning primary's peering rounds run their course
+            # before judging: the rewind it used to order lands then
+            deadline = asyncio.get_event_loop().time() + 30
+            while cluster.mon._health_data()["status"] != "HEALTH_OK":
+                assert asyncio.get_event_loop().time() < deadline
+                await asyncio.sleep(0.1)
+            for n in names:
+                assert await io.read(n, timeout=60) == n.encode() * 4096
+        finally:
+            await cluster.stop()
+
+    asyncio.run(scenario())

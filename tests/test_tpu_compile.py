@@ -1,0 +1,151 @@
+"""The served path's device programs, compiled for a TPU v5e that is
+described and not attached, plus the Pallas planar kernel bit-exact in
+interpret mode.
+
+The chip's compiler is installed on the CPU dev host, so a kernel it
+would refuse (tiling, VMEM budget, an unsupported reshape) is caught by
+tier-1 at no chip time.  A compile that passes is not a chip run: what
+the kernels compute on the device is `chip_smoke.py`'s business.
+
+The topology is described INSIDE a module-scoped fixture, never at
+import: only one process may hold libtpu, and every xdist worker imports
+every test file.  Keep all such compiles in this one file.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ceph_tpu.ec import matrices  # noqa: E402
+from ceph_tpu.ops import gf8, gf8_pallas  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (rw, kw) of the bit-matrix: k8m4 encode, k2m1 encode, k8 two-erasure
+# decode (2 wanted chunks from 8 survivors)
+_PALLAS_WIDTHS = [
+    pytest.param(32, 64, id="k8m4_encode"),
+    pytest.param(8, 16, id="k2m1_encode"),
+    pytest.param(16, 64, id="k8_decode_e2"),
+]
+
+
+@pytest.mark.parametrize("rw,kw", _PALLAS_WIDTHS)
+def test_planar_pallas_kernel_compiles_for_v5e(one_chip, rw, kw):
+    g = gf8_pallas.stack_groups(kw)
+    npk = 64 * gf8_pallas._TILE_P           # 128 Ki packed columns
+    compiled = gf8_pallas._planar_tiled.lower(
+        _shape((rw, kw), jnp.uint8, one_chip),
+        _shape((kw, npk), jnp.uint8, one_chip), rw, kw, g).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_planar_matmul_xla_compiles_for_v5e(one_chip):
+    compiled = gf8.planar_matmul_xla.lower(
+        _shape((32, 64), jnp.uint8, one_chip),
+        _shape((64, 8 * gf8_pallas._TILE_P), jnp.uint8, one_chip)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_batch_to_planes_compiles_for_v5e(one_chip):
+    from ceph_tpu.ec.planar import _batch_to_planes_bitpack
+
+    _batch_to_planes_bitpack.lower(
+        _shape((128, 8, 4096), jnp.uint8, one_chip), 8).compile()
+
+
+def test_crc32c_batch_compiles_for_v5e(one_chip):
+    from ceph_tpu.ops.crc32c import _crc32c_batch_jit
+
+    n, block = 4096, 4096
+    _crc32c_batch_jit().lower(
+        _shape((32, 8 * block), jnp.uint8, one_chip),
+        _shape((n, block), jnp.uint8, one_chip),
+        _shape((), jnp.uint32, one_chip)).compile()
+
+
+def test_crush_rule_compiles_for_v5e(one_chip):
+    """One rack of the three-level map (256 OSDs); the 10k-OSD map takes
+    ~20 s to compile and belongs to chip_smoke.py."""
+    from ceph_tpu.crush.mapper import TensorMapper
+    from ceph_tpu.crush.types import build_three_level
+
+    cmap, rule = build_three_level(n_racks=1, hosts_per_rack=16,
+                                   osds_per_host=16, numrep=3)
+    mapper = TensorMapper(cmap, chunk=1 << 14)
+    fn, tensors = mapper.compiled_rule(rule, 3)
+    t_shapes = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), tensors)
+    fn.lower(_shape((mapper.chunk,), jnp.uint32, one_chip),
+             _shape((cmap.max_devices,), jnp.uint32, one_chip),
+             t_shapes).compile()
+
+
+# (k, m, packed columns): g = 2, 8 and 4 stack groups; the middle case
+# spans two grid steps
+@pytest.mark.parametrize("k,m,npk", [(8, 4, gf8_pallas._TILE_P),
+                                     (2, 1, 2 * gf8_pallas._TILE_P),
+                                     (4, 2, gf8_pallas._TILE_P)])
+def test_planar_kernel_interpret_matches_xla(k, m, npk):
+    """`_planar_kernel` (unpack, K-stacked dot, pack) in Pallas interpret
+    mode, bit for bit against planar_matmul_xla.  Needs no topology."""
+    from jax.experimental import pallas as pl
+    import functools
+
+    rng = np.random.default_rng(k * 16 + m)
+    bitmat = np.asarray(gf8.expand_bitmatrix(matrices.isa_rs_matrix(k, m)))
+    rw, kw = bitmat.shape
+    g = gf8_pallas.stack_groups(kw)
+    planes = rng.integers(0, 256, (kw, npk), dtype=np.uint8)
+    stacked = np.kron(np.eye(g, dtype=np.int8), bitmat.astype(np.int8))
+    tp = gf8_pallas._TILE_P
+    got = pl.pallas_call(
+        functools.partial(gf8_pallas._planar_kernel, g=g, rw=rw),
+        out_shape=jax.ShapeDtypeStruct((rw, npk), jnp.uint8),
+        grid=(npk // tp,),
+        in_specs=[pl.BlockSpec((rw * g, kw * g), lambda i: (0, 0)),
+                  pl.BlockSpec((kw, tp), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((rw, tp), lambda i: (0, i)),
+        interpret=True,
+    )(jnp.asarray(stacked), jnp.asarray(planes))
+    want = gf8.planar_matmul_xla(jnp.asarray(bitmat), jnp.asarray(planes))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
