@@ -27,14 +27,33 @@ from __future__ import annotations
 import asyncio
 from typing import Dict, List, Tuple
 
+from ceph_tpu.cluster.optracker import CURRENT_OP
+from ceph_tpu.trace import tick as ticktrace
+
 
 class _Req:
-    __slots__ = ("data", "want_crc", "fut")
+    __slots__ = ("data", "want_crc", "fut", "op_id")
 
     def __init__(self, data, want_crc: bool, fut: asyncio.Future):
         self.data = data
         self.want_crc = want_crc
         self.fut = fut
+        # the OpTracker id of the op that parked this request: the tick
+        # that serves it names it ("the span that caused it")
+        op = CURRENT_OP.get()
+        self.op_id = None if op is None else op.seq
+
+
+async def _compute_tick(osd, name: str, batch: List[_Req], fn, *args):
+    """One coalesced device tick through the shared ``OSD._compute`` seam,
+    recorded (trace/tick.py): opened here on the loop, run on the worker
+    thread, closed when this coroutine resumes."""
+    tick = ticktrace.TICKS.open(name, f"osd.{osd.osd_id}",
+                                [r.op_id for r in batch])
+    try:
+        return await osd._compute(fn, *args, tick=tick)
+    finally:
+        tick.close()
 
 
 class SubWriteBatcher:
@@ -310,7 +329,7 @@ class ReadBatcher:
         and the decode runs in the plane domain end to end
         (``decode_planes_multi``) — the assemble's planes->bytes hop is
         the read's ONE sanctioned egress conversion."""
-        from ceph_tpu.cluster.optracker import CURRENT_OP, mark_current
+        from ceph_tpu.cluster.optracker import mark_current
 
         if all(s in shards for s in range(sinfo.k)):
             # every data shard present: the "decode" is a pure host
@@ -462,19 +481,23 @@ class ReadBatcher:
                 else stripemod.decode_stripes_multi
 
             def compute(reqs):
-                return osd._compute(fn, codec, sinfo, reqs)
+                return _compute_tick(osd, "decode_tick", reqs, fn, codec,
+                                     sinfo, [r.data for r in reqs])
         elif mode == "reencode":
             fn = stripemod.reencode_planes_multi if key[4] \
                 else stripemod.reencode_stripes_multi
 
             def compute(reqs):
-                return osd._compute(fn, codec, sinfo, reqs)
+                return _compute_tick(osd, "reencode_tick", reqs, fn, codec,
+                                     sinfo, [r.data for r in reqs])
         elif mode == "verify_planar":
             def compute(reqs):
-                return osd._compute(self._verify_planar_multi, reqs)
+                return osd._compute(self._verify_planar_multi,
+                                    [r.data for r in reqs])
         else:
             def compute(reqs):
-                return osd._compute(self._verify_multi, reqs)
+                return osd._compute(self._verify_multi,
+                                    [r.data for r in reqs])
         batch: List[_Req] = []
         try:
             while not osd._stopped:
@@ -486,7 +509,7 @@ class ReadBatcher:
                 self._pending[key] = pending[cap:]
                 t0 = osd.clock.monotonic()
                 try:
-                    results = await compute([r.data for r in batch])
+                    results = await compute(batch)
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
@@ -502,7 +525,7 @@ class ReadBatcher:
                             if r.fut.done():
                                 continue
                             try:
-                                [res] = await compute([r.data])
+                                [res] = await compute([r])
                                 r.fut.set_result(
                                     (res, (t0, osd.clock.monotonic(), 1)))
                             except asyncio.CancelledError:
@@ -617,9 +640,9 @@ class EncodeBatcher:
                 osd._chaos_point("tick_mid_encode")
                 t0 = osd.clock.monotonic()
                 try:
-                    results = await osd._compute(
-                        encode_fn, codec, sinfo,
-                        [r.data for r in batch],
+                    results = await _compute_tick(
+                        osd, ticktrace.ENCODE_TICK, batch, encode_fn,
+                        codec, sinfo, [r.data for r in batch],
                         [r.want_crc for r in batch])
                 except asyncio.CancelledError:
                     raise

@@ -1043,6 +1043,16 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
                       "per-stage wall-time breakdown over completed ops "
                       "(args: match=<desc substring>, measured_wall_s)")
 
+        def _dump_ticks(cmd):
+            from ceph_tpu.trace.tick import TICKS
+
+            a = {**cmd, **cmd.get("args", {})}
+            return TICKS.dump(f"osd.{self.osd_id}", int(a.get("n", 20)))
+
+        asok.register("dump_ticks", _dump_ticks,
+                      "this daemon's newest coalesced device ticks, each "
+                      "a root span tiled by its host phases (args: n)")
+
         def _trace_dump(cmd):
             a = {**cmd, **cmd.get("args", {})}
             tid = a.get("trace_id")
@@ -1124,12 +1134,18 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
 
     # -------------------------------------------------------------- helpers
 
-    async def _compute(self, fn, *args):
+    async def _compute(self, fn, *args, tick=None):
         """Run codec compute (encode/decode, possibly a first-call jit
         compile) off the event loop.  Blocking the loop here starves
         heartbeat replies and triggers false failure reports — the reference
         isolates heartbeats on dedicated messengers for the same reason
-        (src/ceph_osd.cc:459-486 creates 4 hb messengers)."""
+        (src/ceph_osd.cc:459-486 creates 4 hb messengers).  ``tick`` (a
+        batcher's open ``trace.tick.Tick``) runs the work as that tick's:
+        it stamps the thread's start and return and is the one the
+        phases inside ``fn`` land on."""
+        if tick is not None:
+            return await asyncio.get_event_loop().run_in_executor(
+                None, tick.run, fn, *args)
         return await asyncio.get_event_loop().run_in_executor(
             None, lambda: fn(*args))
 

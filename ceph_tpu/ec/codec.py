@@ -32,6 +32,7 @@ from ceph_tpu.ec.base import ErasureCode
 from ceph_tpu.ec.interface import ECError
 from ceph_tpu.ec.table_cache import DecodeTableCache
 from ceph_tpu.ops import gf8, gfw
+from ceph_tpu.trace import tick as ticktrace
 from ceph_tpu.utils.perf import KERNELS
 
 
@@ -332,6 +333,8 @@ class MatrixCodec(ErasureCode):
         """(B, k-or-n, S) byte batch -> device PlanarBatch (one convert)."""
         from ceph_tpu.ec.planar import PlanarBatch
 
+        # the host->device copy of the batch + the ingest program
+        ticktrace.device_calls(2)
         return PlanarBatch.from_batch(batch, w=self.w)
 
     def encode_planar(self, pb) -> "PlanarBatch":
@@ -339,6 +342,7 @@ class MatrixCodec(ErasureCode):
         chunks.  No expansion, no pack: one matmul on packed planes."""
         from ceph_tpu.ops import gf8
 
+        ticktrace.device_calls()        # the planar matmul program
         return pb.with_planes(
             gf8.planar_matmul(self.engine._enc_bitmat, pb.planes), self.m)
 
@@ -561,6 +565,7 @@ class BitmatrixCodec(MatrixCodec):
     def to_planar(self, batch):
         from ceph_tpu.ec.planar import PlanarBatch
 
+        ticktrace.device_calls(2)       # as MatrixCodec.to_planar
         batch = jnp.asarray(batch)
         self._check_layout(int(batch.shape[2]))
         return PlanarBatch.from_batch(batch, w=self.w, layout="packet",
@@ -569,6 +574,7 @@ class BitmatrixCodec(MatrixCodec):
     def encode_planar(self, pb):
         m01 = self._encode_bits()
         lane = _lane_expand(m01.tobytes(), m01.shape)
+        ticktrace.device_calls()        # the packet-rows matmul program
         return pb.with_planes(_planar_rows_matmul(lane, pb.planes), self.m)
 
     def decode_planar(self, erasures, pb, want=None):

@@ -42,6 +42,9 @@ from ceph_tpu.ops.profiling import record_planar_convert
 # jitted layout transforms (batch <-> planes), one dispatch each way
 # ---------------------------------------------------------------------------
 
+# The name is read: the device trace shows this program as
+# ``jit__batch_to_planes_bitpack`` (PERF.md's device time by program;
+# pinned by tests/test_tick_trace.py).  Do not rename.
 @functools.partial(jax.jit, static_argnums=1)
 def _batch_to_planes_bitpack(batch, w: int):
     """(B, c, S) bytes -> (c*w, B*S/w) packed planes (shard-major cols)."""

@@ -738,6 +738,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
     from ceph_tpu.ec import planar_store as pstore
     from ceph_tpu.ops.crc32c import crc32c_planar_rows
     from ceph_tpu.ops.profiling import record_planar_at_rest
+    from ceph_tpu.trace import tick as ticktrace
     from ceph_tpu.utils.perf import KERNELS
 
     k = sinfo.k
@@ -757,55 +758,70 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
         return out
     KERNELS.inc("ec_coalesced_ticks")
     KERNELS.inc("ec_coalesced_ops", len(datas))
-    batch = np.zeros((total, k, unit), dtype=np.uint8)
-    pad = 0
-    ofs = 0
-    for d, ns in zip(datas, counts):
-        if ns == 0:
-            continue
-        flat = batch[ofs:ofs + ns].reshape(ns * k * unit)
-        flat[: len(d)] = np.frombuffer(d, dtype=np.uint8)
-        pad += ns * sinfo.stripe_width - len(d)
-        ofs += ns
-    if _host_engine_ok(codec):
-        KERNELS.inc("ec_stripe_pad_bytes", pad)
-        # THE sanctioned ingest: client bytes -> planes, once per tick
-        record_planar_at_rest("ingest", total * k * unit)
+    host = _host_engine_ok(codec)
+    bb = total if host else _bucket(total)
+    # the phases below land on the tick open on this thread (the
+    # batcher's; trace/tick.py) and are no-ops outside one
+    ticktrace.annotate(total, bb, sum(len(d) for d in datas))
+    with ticktrace.phase("fill"):
+        batch = np.zeros((total, k, unit), dtype=np.uint8)
+        pad = 0
+        ofs = 0
+        for d, ns in zip(datas, counts):
+            if ns == 0:
+                continue
+            flat = batch[ofs:ofs + ns].reshape(ns * k * unit)
+            flat[: len(d)] = np.frombuffer(d, dtype=np.uint8)
+            pad += ns * sinfo.stripe_width - len(d)
+            ofs += ns
+        if bb != total:
+            batch = np.concatenate(
+                [batch, np.zeros((bb - total, k, unit), dtype=np.uint8)])
+    KERNELS.inc("ec_stripe_pad_bytes", pad + (bb - total) * k * unit)
+    # THE sanctioned ingest: client bytes -> planes, once per tick
+    record_planar_at_rest("ingest", total * k * unit)
+    if host:
         rows = np.ascontiguousarray(
             batch.transpose(1, 0, 2).reshape(k, total * unit))
         data_planes = pstore.rows_to_planes(rows)
         all_planes = np.vstack(
             [data_planes, _parity_planes_for(codec, data_planes)])
     else:
-        bb = _bucket(total)
-        if bb != total:
-            batch = np.concatenate(
-                [batch, np.zeros((bb - total, k, unit), dtype=np.uint8)])
-        KERNELS.inc("ec_stripe_pad_bytes", pad + (bb - total) * k * unit)
-        record_planar_at_rest("ingest", total * k * unit)
-        pb = codec.to_planar(batch)
-        parity_pb = codec.encode_planar(pb)
-        all_planes = np.vstack([np.asarray(pb.planes),
-                                np.asarray(parity_pb.planes)])
+        with ticktrace.phase("to_planar"):
+            pb = codec.to_planar(batch)
+        with ticktrace.phase("encode_dispatch"):
+            parity_pb = codec.encode_planar(pb)
+        # each readback blocks until the device is done, then copies
+        # device -> host; a device call apiece
+        with ticktrace.phase("readback"):
+            ticktrace.device_calls()
+            data_planes = np.asarray(pb.planes)
+        with ticktrace.phase("readback"):
+            ticktrace.device_calls()
+            parity_planes = np.asarray(parity_pb.planes)
+        with ticktrace.phase("slice"):
+            all_planes = np.vstack([data_planes, parity_planes])
     # per-op at-rest planes slice straight out of the coalesced plane
     # matrix: op columns are contiguous (unit % 8 == 0), shard s is
     # plane rows s*8..s*8+8 — no conversion, no transpose of payload
     crc_groups: Dict[int, List] = {}
-    c0 = 0
-    for i, ns in enumerate(counts):
-        cw = ns * unit // 8
-        op_planes = np.ascontiguousarray(
-            all_planes[:, c0:c0 + cw]).reshape(n, 8, cw)
-        c0 += cw
-        out[i] = (op_planes, None)
-        if want_crcs[i]:
-            crc_groups.setdefault(cw, []).append((i, op_planes))
+    with ticktrace.phase("slice"):
+        c0 = 0
+        for i, ns in enumerate(counts):
+            cw = ns * unit // 8
+            op_planes = np.ascontiguousarray(
+                all_planes[:, c0:c0 + cw]).reshape(n, 8, cw)
+            c0 += cw
+            out[i] = (op_planes, None)
+            if want_crcs[i]:
+                crc_groups.setdefault(cw, []).append((i, op_planes))
     # one planar crc dispatch per shard length group (planar row view:
     # bit-identical to the byte anchor's crc32c_rows)
     for _cw, group in crc_groups.items():
-        stacked = np.concatenate(
-            [p.reshape(n * 8, -1) for _i, p in group], axis=0)
-        crcs = crc32c_planar_rows(stacked)
+        with ticktrace.phase("crc"):
+            stacked = np.concatenate(
+                [p.reshape(n * 8, -1) for _i, p in group], axis=0)
+            crcs = crc32c_planar_rows(stacked)
         for gi, (i, p) in enumerate(group):
             out[i] = (out[i][0], crcs[gi * n:(gi + 1) * n])
     return out

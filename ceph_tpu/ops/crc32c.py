@@ -412,6 +412,11 @@ def crc32c_planar_rows(planes, seed: int = 0xFFFFFFFF):
                 _batch_jit = _crc32c_batch_jit()
             import jax.numpy as jnp
 
+            from ceph_tpu.trace import tick as ticktrace
+
+            # host->device copy of the blobs, the crc program, readback;
+            # booked on the encode tick open on this thread, if any
+            ticktrace.device_calls(3)
             bitmat = _planar_message_bitmat_dev(length)
             const = np.uint32(crc32c_zeros(seed, length))
             blobs = jnp.asarray(arr.reshape(g, length))

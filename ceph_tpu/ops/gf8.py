@@ -277,7 +277,7 @@ def planar_matmul(bitmat, planes):
 
     planes = jnp.asarray(planes)
     use_pallas = gf8_pallas.planar_available()
-    record_planar_matmul(tuple(bitmat.shape), int(np.prod(planes.shape)),
+    record_planar_matmul(int(np.prod(planes.shape)),
                          gf8_pallas.stack_groups(int(bitmat.shape[1]))
                          if use_pallas else 1)
     if use_pallas:

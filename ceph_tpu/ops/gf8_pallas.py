@@ -189,6 +189,10 @@ def _planar_kernel(bm_ref, p_ref, o_ref, *, g: int, rw: int):
     o_ref[:] = out.astype(jnp.uint8)
 
 
+# The name is read: the XLA module is ``jit_`` + ``__name__``, and
+# benchmark/layer_metrics/planar_roofline.write.json and
+# trace/gapjoin.py match ``jit__planar_tiled`` in the device trace
+# (pinned by tests/test_tick_trace.py).  Do not rename.
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def _planar_tiled(bitmat, planes, rw: int, kw: int, g: int):
     from jax.experimental import pallas as pl

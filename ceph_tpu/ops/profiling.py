@@ -25,28 +25,18 @@ from typing import Optional
 from ceph_tpu.utils.perf import KERNELS
 
 
-def record_planar_matmul(bitmat_shape, payload_bytes: int,
-                         groups: int = 1) -> None:
+def record_planar_matmul(payload_bytes: int, groups: int = 1) -> None:
     """Device-kernel telemetry for the bit-planar GF(2) matmul path.
 
     Counts invocations and payload bytes separately from the byte-path
     ``ec_matmul`` counters so a perf dump shows how much traffic rides the
-    new layout, records the K-stacking factor, and accounts the MXU
-    shape-padding waste of the STACKED matrix: a block-diagonal g-stack
-    occupies (g*rw, g*kw) tiles of which only g*rw*kw entries are useful —
-    the gap between that and the 128-multiple tile grid is throughput the
-    shape still leaves on the floor (zero when g*kw == 128 exactly).
+    new layout, and records the K-stacking factor (``planar_stack_groups``
+    / ``planar_matmul_calls`` > 1 says the Pallas kernel ran, not the XLA
+    route).
     """
-    rw, kw = int(bitmat_shape[0]), int(bitmat_shape[1])
     KERNELS.inc("planar_matmul_calls")
     KERNELS.inc("planar_matmul_bytes", int(payload_bytes))
     KERNELS.inc("planar_stack_groups", int(groups))
-    srw, skw = rw * groups, kw * groups
-    tiles = (-(-srw // 128) * 128) * (-(-skw // 128) * 128)
-    useful = groups * rw * kw
-    if useful:
-        KERNELS.inc("planar_mxu_pad_bytes",
-                    int(payload_bytes * (tiles - useful) / useful))
 
 
 def record_planar_convert(direction: str, payload_bytes: int) -> None:

@@ -14,6 +14,10 @@ model (BENCH_NOTES) is untouched.
 - ``perfetto``    chrome://tracing / Perfetto JSON export.
 - ``flight``      graft-blackbox per-daemon flight-recorder rings.
 - ``postmortem``  triggered POSTMORTEM_* bundles + breach attribution.
+- ``tick``        one span per coalesced device tick with its host
+                  phases, on the device trace's clock (always on).
+- ``gapjoin``     device idle gaps cut by host cause: ticks laid over a
+                  device trace's events.
 """
 
 from ceph_tpu.trace.span import (  # noqa: F401

@@ -173,6 +173,7 @@ def test_read_batcher_verify_and_fault_isolation():
     class _FakeOSD:
         config = Config(osd_batch_tick_ops=16)
         perf = PerfCounters("t")
+        osd_id = 0
         _stopped = False
 
         class clock:
@@ -182,7 +183,7 @@ def test_read_batcher_verify_and_fault_isolation():
 
                 return time.monotonic()
 
-        async def _compute(self, fn, *args):
+        async def _compute(self, fn, *args, tick=None):
             return fn(*args)
 
         def _track(self, task):
