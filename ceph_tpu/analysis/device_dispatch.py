@@ -45,7 +45,7 @@ DEVICE_CALLS = frozenset({
     "encode_stripes", "decode_stripes", "reencode_stripes",
     "encode_stripes_multi", "crc32c_batch", "crc32c_rows",
     "encode_planes_multi", "decode_planes_multi",
-    "reencode_planes_multi", "crc32c_planar_rows",
+    "reencode_planes_multi", "crc32c_planar_rows", "planar_chunk_crcs",
 })
 
 # the one sanctioned per-op dispatch seam: the tick coalescer

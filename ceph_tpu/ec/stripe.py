@@ -736,7 +736,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
     domain and shard bytes are never materialized.
     """
     from ceph_tpu.ec import planar_store as pstore
-    from ceph_tpu.ops.crc32c import crc32c_planar_rows
+    from ceph_tpu.ops import crc32c as crcmod
     from ceph_tpu.ops.profiling import record_planar_at_rest
     from ceph_tpu.trace import tick as ticktrace
     from ceph_tpu.utils.perf import KERNELS
@@ -753,7 +753,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
         for i in range(len(datas)):
             planes = np.zeros((n, 8, 0), dtype=np.uint8)
             out[i] = (planes,
-                      crc32c_planar_rows(planes.reshape(n * 8, 0))
+                      crcmod.crc32c_planar_rows(planes.reshape(n * 8, 0))
                       if want_crcs[i] else None)
         return out
     KERNELS.inc("ec_coalesced_ticks")
@@ -780,6 +780,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
     KERNELS.inc("ec_stripe_pad_bytes", pad + (bb - total) * k * unit)
     # THE sanctioned ingest: client bytes -> planes, once per tick
     record_planar_at_rest("ingest", total * k * unit)
+    op_crcs: Dict[int, List[int]] = {}
     if host:
         rows = np.ascontiguousarray(
             batch.transpose(1, 0, 2).reshape(k, total * unit))
@@ -791,6 +792,17 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
             pb = codec.to_planar(batch)
         with ticktrace.phase("encode_dispatch"):
             parity_pb = codec.encode_planar(pb)
+        # The shard crcs come from the planes while the device holds
+        # them: per-chunk crcs, launched behind the encode and before
+        # the first blocking readback, so the device runs ingest ->
+        # encode -> crc back to back.  Chosen by what the batch says of
+        # itself; any other layout keeps the host crc below.
+        chunk_crcs = None
+        if any(want_crcs) and pb.layout == "bitpack" and pb.w == 8 \
+                and pb.chunk_size <= crcmod._PLANAR_DEV_MAX:
+            with ticktrace.phase("crc"):
+                chunk_crcs = crcmod.planar_chunk_crcs(
+                    (pb.planes, parity_pb.planes), unit)
         # each readback blocks until the device is done, then copies
         # device -> host; a device call apiece
         with ticktrace.phase("readback"):
@@ -799,6 +811,12 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
         with ticktrace.phase("readback"):
             ticktrace.device_calls()
             parity_planes = np.asarray(parity_pb.planes)
+        if chunk_crcs is not None:
+            # (n, bb) words, on their way since the device had them
+            with ticktrace.phase("crc"):
+                ticktrace.device_calls()
+                op_crcs = _fold_op_crcs(np.asarray(chunk_crcs), counts,
+                                        want_crcs, unit)
         with ticktrace.phase("slice"):
             all_planes = np.vstack([data_planes, parity_planes])
     # per-op at-rest planes slice straight out of the coalesced plane
@@ -812,18 +830,44 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
             op_planes = np.ascontiguousarray(
                 all_planes[:, c0:c0 + cw]).reshape(n, 8, cw)
             c0 += cw
-            out[i] = (op_planes, None)
-            if want_crcs[i]:
+            out[i] = (op_planes, op_crcs.get(i))
+            if want_crcs[i] and i not in op_crcs:
                 crc_groups.setdefault(cw, []).append((i, op_planes))
-    # one planar crc dispatch per shard length group (planar row view:
-    # bit-identical to the byte anchor's crc32c_rows)
+    # host crc: one planar pass per shard length group (planar row
+    # view: bit-identical to the byte anchor's crc32c_rows)
     for _cw, group in crc_groups.items():
         with ticktrace.phase("crc"):
             stacked = np.concatenate(
                 [p.reshape(n * 8, -1) for _i, p in group], axis=0)
-            crcs = crc32c_planar_rows(stacked)
+            crcs = crcmod.crc32c_planar_rows(stacked)
         for gi, (i, p) in enumerate(group):
-            out[i] = (out[i][0], crcs[gi * n:(gi + 1) * n])
+            out[i] = (p, crcs[gi * n:(gi + 1) * n])
+    return out
+
+
+def _fold_op_crcs(chunk_crcs: np.ndarray, counts, want_crcs,
+                  unit: int) -> Dict[int, List[int]]:
+    """A tick's (n, bb) zero-seeded chunk crcs -> {op: its n shard
+    ``ceph_crc32c(~0, byte_view)`` values}.  Op i owns the ``counts[i]``
+    columns after those of the ops before it (the bucket's padding
+    stripes come last and are nobody's); ops of one length fold
+    together."""
+    from ceph_tpu.ops.crc32c import fold_chunk_crcs
+
+    n = chunk_crcs.shape[0]
+    groups: Dict[int, List[Tuple[int, int]]] = {}
+    c0 = 0
+    for i, ns in enumerate(counts):
+        if want_crcs[i]:
+            groups.setdefault(ns, []).append((i, c0))
+        c0 += ns
+    out: Dict[int, List[int]] = {}
+    for ns, members in groups.items():
+        crcs = fold_chunk_crcs(
+            np.concatenate([chunk_crcs[:, c:c + ns] for _i, c in members]),
+            unit)
+        for gi, (i, _c) in enumerate(members):
+            out[i] = [int(c) for c in crcs[gi * n:(gi + 1) * n]]
     return out
 
 

@@ -250,26 +250,43 @@ def _matvec_rows(m: np.ndarray, v: np.ndarray) -> np.ndarray:
     return np.bitwise_xor.reduce(sel, axis=1).astype(np.uint32)
 
 
-def _fold_blocks(cs2d: np.ndarray, lane: int) -> np.ndarray:
-    """(R, nb) per-block crcs (each seeded 0) -> (R,) ``update(0, row)``
-    via a pairwise zero-extension tree: log2(nb) vectorized rounds
-    instead of nb sequential folds.  Left-padding with zero crcs is the
-    identity (leading zero bytes of a zero-seeded crc stay zero)."""
-    r, nb = cs2d.shape
-    pow2 = 1 << max(0, nb - 1).bit_length() if nb > 1 else 1
-    if pow2 != nb:
-        cs2d = np.concatenate(
-            [np.zeros((r, pow2 - nb), np.uint32), cs2d], axis=1)
-        nb = pow2
-    span = 1
-    while nb > 1:
-        ext = _zeros_mat(lane * span)
-        left = np.ascontiguousarray(cs2d[:, 0::2]).reshape(-1)
-        right = np.ascontiguousarray(cs2d[:, 1::2]).reshape(-1)
-        cs2d = (_matvec_rows(ext, left) ^ right).reshape(r, nb // 2)
-        nb //= 2
-        span *= 2
-    return cs2d[:, 0]
+@functools.lru_cache(maxsize=32)
+def _fold_words(nb: int, unit: int) -> np.ndarray:
+    """The join of ``nb`` (a power of two) zero-seeded chunk crcs as ONE
+    GF(2) map, (nb*32,) uint32: word 32*j + b is the crc of a row whose
+    only set bit is bit b of chunk j's crc, i.e. ``A^((nb-1-j)*unit)``
+    applied to ``1 << b``.  A chunk's weight depends only on its
+    distance from the END of the row, so the join of any shorter run is
+    this map's last words.  Built by doubling: the first half of twice
+    the length is this length's words carried over ``p*unit`` more zero
+    bytes."""
+    words = _identity()
+    p = 1
+    while p < nb:
+        words = np.concatenate(
+            [_matvec_rows(_zeros_mat(p * unit), words), words])
+        p *= 2
+    return words
+
+
+def fold_chunk_crcs(chunks: np.ndarray, unit: int,
+                    seed: int = 0xFFFFFFFF) -> np.ndarray:
+    """(R, nb) zero-seeded crcs of each row's consecutive ``unit``-byte
+    chunks -> (R,) uint32 ``ceph_crc32c(seed, row)``.  Linearity:
+    ``update(s, a||b) = A^len(b)(update(s, a)) ^ update(0, b)``, so a
+    row's crc from zero is the XOR of one cached word per set bit of its
+    chunk crcs, and ``update(seed, row) = update(seed, 0^L) ^
+    update(0, row)``.  Four numpy calls however long the row: on a tick
+    thread each call is a chance to lose the GIL to the event loop."""
+    chunks = np.ascontiguousarray(chunks, dtype="<u4")
+    r, nb = chunks.shape
+    head = np.uint32(crc32c_zeros(seed, nb * unit))
+    if nb == 0:
+        return np.full(r, head, dtype=np.uint32)
+    bits = np.unpackbits(chunks.view(np.uint8), axis=1, bitorder="little")
+    words = _fold_words(1 << (nb - 1).bit_length(), unit)[-32 * nb:]
+    return np.bitwise_xor.reduce(
+        np.where(bits.view(bool), words, np.uint32(0)), axis=1) ^ head
 
 
 _HOST_LANE = 512
@@ -326,10 +343,7 @@ def crc32c_rows(rows, seed: int = 0xFFFFFFFF, block: int = 4096):
         nb = length // lane
         cs = np.asarray(crc32c_batch(arr.reshape(r * nb, lane),
                                      seed=0)).reshape(r, nb)
-    folded = _fold_blocks(cs, lane)
-    # update(seed, row) = update(seed, 0^L) ^ update(0, row)
-    head = np.uint32(crc32c_zeros(seed, length))
-    return [int(c) for c in (folded ^ head)]
+    return [int(c) for c in fold_chunk_crcs(cs, lane, seed)]
 
 
 # ---------------------------------------------------------------------------
@@ -353,19 +367,24 @@ def crc32c_rows(rows, seed: int = 0xFFFFFFFF, block: int = 4096):
 _PLANAR_DEV_MAX = 1 << 15
 
 
-@functools.lru_cache(maxsize=16)
-def _planar_message_bitmat_dev(length: int):
-    """Device copy of ``_message_bitmat(length)`` column-permuted so it
-    applies directly to a plane-group BLOB (8 rows of length/8 packed
-    bytes, row-major): blob bit 8*(t*cols+i)+u is D-bit 8*(8i+u)+t."""
-    import jax.numpy as jnp
-
+def _planar_message_bitmat(length: int) -> np.ndarray:
+    """``_message_bitmat(length)`` column-permuted so it applies directly
+    to a plane-group BLOB (8 rows of length/8 packed bytes, row-major):
+    blob bit 8*(t*cols+i)+u is D-bit 8*(8i+u)+t."""
     cols = length // 8
     base = _message_bitmat(length)
     t, i, u = np.meshgrid(np.arange(8), np.arange(cols), np.arange(8),
                           indexing="ij")
     src = (8 * (8 * i + u) + t).reshape(-1)
-    return jnp.asarray(base[:, src])
+    return base[:, src]
+
+
+@functools.lru_cache(maxsize=16)
+def _planar_message_bitmat_dev(length: int):
+    """Device copy of ``_planar_message_bitmat(length)``."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(_planar_message_bitmat(length))
 
 
 def _planar_spread(planes: np.ndarray) -> np.ndarray:
@@ -427,3 +446,94 @@ def crc32c_planar_rows(planes, seed: int = 0xFFFFFFFF):
     folded = np.bitwise_xor.reduce(parts, axis=1)
     head = np.uint32(crc32c_zeros(seed, length))
     return [int(c) for c in (folded ^ head)]
+
+
+# ---------------------------------------------------------------------------
+# Chunk crcs of planes that ARE on the device: the encode tick's shard crcs
+# ---------------------------------------------------------------------------
+#
+# A coalesced encode tick (ec/stripe.py::encode_planes_multi, device
+# branch) holds its data and parity planes on the device as (c*8,
+# bb*unit/8) matrices: the bytes of shard s, stripe j are the columns
+# [j*unit/8, (j+1)*unit/8) of plane rows s*8..s*8+8, one plane-group blob
+# of ``unit`` bytes.  One program takes the zero-seeded crc of every such
+# blob ((n, bb) words come back), and ``fold_chunk_crcs`` joins an op's
+# run of them on the host.  Its shape depends on the bucket alone, never
+# on where an op begins.
+
+# unpacked {0,1} bytes the program may hold at once: it walks the bucket
+# in groups of stripes sized to this, not all of it (8x the planes)
+_CHUNK_TEMP_BYTES = 32 << 20
+
+
+@functools.lru_cache(maxsize=16)
+def _chunk_bitmat_dev(unit: int):
+    """``_planar_message_bitmat(unit)`` as the (8*unit, 32) int8 right-hand
+    side of the chunk program, rows bit-major (row u*unit + p is bit u of
+    blob byte p): the program lays a blob's 8 bit positions side by side
+    and never interleaves them.  1 MiB at 4 KiB, cached on the device."""
+    import jax.numpy as jnp
+
+    mat = _planar_message_bitmat(unit).reshape(32, unit, 8)
+    return jnp.asarray(mat.transpose(2, 1, 0).reshape(8 * unit, 32),
+                       dtype=jnp.int8)
+
+
+@functools.lru_cache(maxsize=1)
+def _chunk_crcs_jit():
+    """Build the jitted chunk program lazily (jax import stays optional)."""
+    import jax
+    import jax.numpy as jnp
+
+    # The name is read: the device trace shows this program as
+    # ``jit__chunk_crcs_planes``.  It must not contain ``jit__planar_tiled``
+    # (planar_roofline.write sums the device time of the programs so named
+    # against the encode matmul's bytes; pinned by tests/test_tick_trace.py).
+    @functools.partial(jax.jit, static_argnums=2)
+    def _chunk_crcs_planes(bitmat, planes, unit: int):
+        cols = unit // 8
+        bb = planes[0].shape[1] // cols
+        n = sum(p.shape[0] for p in planes) // 8
+        gs = max(1, min(bb, _CHUNK_TEMP_BYTES // (n * 8 * unit)))
+        gs = 1 << (gs.bit_length() - 1)         # bb is a power of two
+        groups = jnp.concatenate(
+            [p.reshape(-1, 8, bb // gs, gs, cols).transpose(2, 0, 3, 1, 4)
+             .reshape(bb // gs, -1, gs, unit) for p in planes],
+            axis=1)                             # (G, n, gs, unit) blobs
+        weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+
+        def group(g):                           # (n, gs, unit) blobs
+            b = g.reshape(n * gs, unit)
+            bits = jnp.concatenate(
+                [(b >> jnp.uint8(u)) & jnp.uint8(1) for u in range(8)],
+                axis=1).astype(jnp.int8)        # (n*gs, 8*unit), bit-major
+            acc = jax.lax.dot_general(
+                bits, bitmat, dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)
+            return jnp.sum((acc & 1).astype(jnp.uint32) * weights,
+                           axis=1, dtype=jnp.uint32).reshape(n, gs)
+
+        out = jax.lax.map(group, groups)                     # (G, n, gs)
+        return out.transpose(1, 0, 2).reshape(n, bb)
+
+    return _chunk_crcs_planes
+
+
+def planar_chunk_crcs(planes, unit: int):
+    """Device plane matrices ``[(c_i*8, bb*unit/8), ...]`` over one column
+    axis -> the DEVICE (sum c_i, bb) uint32 array whose [s, j] is
+    ``ceph_crc32c(0, bytes of shard s in stripe j)``.  Launched, not
+    waited for, and its words set off for the host as soon as the device
+    has them: the caller's ``np.asarray`` finds them there, and folds
+    each op's columns with ``fold_chunk_crcs``."""
+    from ceph_tpu.trace import tick as ticktrace
+    from ceph_tpu.utils.perf import KERNELS
+
+    planes = tuple(planes)
+    KERNELS.inc("crc32c_planar_calls")
+    KERNELS.inc("crc32c_planar_bytes",
+                sum(int(p.shape[0]) * int(p.shape[1]) for p in planes))
+    ticktrace.device_calls()            # the chunk program
+    words = _chunk_crcs_jit()(_chunk_bitmat_dev(unit), planes, unit)
+    words.copy_to_host_async()
+    return words

@@ -17,7 +17,11 @@ cut into host phases at the lines of ``ec/stripe.py`` where the work is:
     readback       each blocking np.asarray: waits for the device, then
                    device->host
     slice          np.vstack + the contiguous copy per op
-    crc            every crc32c_planar_rows group (and which path it took)
+    crc            what exists only because of the shard crcs.  Device
+                   path: the chunk-crc program's launch (behind the
+                   encode, before the first readback), then the readback
+                   of its words + the per-op fold.  Host path: every
+                   crc32c_planar_rows group.  A dumped span says which
     wake           fn returns on the thread -> the drain loop resumes
     other          what the phases leave of the thread's wall
 
@@ -66,7 +70,9 @@ _PHASE_COUNTERS = {
     "readback": ("ec_tick_readback_ns", "blocking readbacks (wait for "
                  "the device + device->host)"),
     "slice": ("ec_tick_slice_ns", "vstack + per-op contiguous copies"),
-    "crc": ("ec_tick_crc_ns", "crc32c over the ops' plane groups"),
+    "crc": ("ec_tick_crc_ns", "the shard crcs: device program launch, "
+            "readback of its words and fold, or crc32c over the ops' "
+            "plane groups on the host"),
 }
 # the phases a Tick can record, by index into its flat array
 PHASES = tuple(_PHASE_COUNTERS)
@@ -82,6 +88,8 @@ _OTHER_COUNTERS = (
      "thread running (process-wide)"),
     ("ec_tick_multi_active_ns", "ns", "wall time with >= 2 encode tick "
      "threads running (process-wide)"),
+    ("ec_tick_crc_device_ticks", "ticks", "encode ticks whose shard crcs "
+     "all came from a device program"),
 )
 
 
@@ -220,15 +228,23 @@ class Tick:
         return out
 
     def device_window(self) -> Optional[Tuple[int, int]]:
-        """[to_planar start, last readback end]: where this tick's
-        device work has to lie.  None for a tick that recorded neither."""
+        """[to_planar start, end of the last readback, the crc words'
+        included]: where this tick's device work has to lie.  None for a
+        tick that recorded neither."""
         lo = hi = None
-        for name, t0, t1, _calls in self.phases():
+        for name, t0, t1, calls in self.phases():
             if name == "to_planar" and lo is None:
                 lo = t0
-            elif name == "readback":
+            elif name == "readback" or (name == "crc" and calls):
                 hi = t1
         return None if lo is None or hi is None else (lo, hi)
+
+    def crc_on_device(self) -> bool:
+        """Did every ``crc`` phase of this tick make a device call (and
+        was there one)?"""
+        crc = [calls for name, _t0, _t1, calls in self.phases()
+               if name == "crc"]
+        return bool(crc) and all(crc)
 
     def dump(self) -> List[Dict]:
         """Root + children as ``Span.dump()`` dicts (one trace)."""
@@ -303,6 +319,8 @@ class TickLog:
         for phase, (counter, _desc) in _PHASE_COUNTERS.items():
             inc(counter, spent.get(phase, 0))
         inc("ec_tick_device_calls", tick.calls)
+        if tick.crc_on_device():
+            inc("ec_tick_crc_device_ticks")
 
     def dump(self, daemon: str, n: int = 20) -> Dict[str, List[Dict]]:
         """``daemon``'s newest ``n`` ticks, oldest first, each one trace

@@ -316,6 +316,9 @@ def check_device_did_the_work(report: dict) -> None:
     if c.get("planar_stack_groups", 0) / calls <= 1:
         raise AssertionError("mean stack-group factor <= 1: the XLA planar "
                              "path took the calls, not the Pallas kernel")
+    if c.get("ec_tick_crc_device_ticks", 0) < 1:
+        raise AssertionError("no encode tick took its shard crcs from the "
+                             "device chunk-crc program")
 
 
 def phase_cluster(seed: int, **size) -> None:
@@ -323,9 +326,13 @@ def phase_cluster(seed: int, **size) -> None:
 
     report = asyncio.run(serve_ec_objects(seed, **size))
     say(phase="cluster", **report,
-        crc32c_engine="host google_crc32c (hardware instruction): the "
-        "served path's crcs do not run on the chip; only crc32c_batch in "
-        "phase 2 does" if crc32c._gcrc is not None else "device/numpy",
+        crc32c_engine="writes: every encode tick's shard crcs come from "
+        "the chunk-crc program over the planes the tick holds on the chip, "
+        "folded per shard on the host (ec_tick_crc_device_ticks); read "
+        "verification, scrub and partial overwrites crc planes that live "
+        "on the host, on the host: "
+        + ("google_crc32c (hardware instruction)"
+           if crc32c._gcrc is not None else "numpy table loop"),
         placement_note="the pool has fewer than osd_map_batch_min_pgs PGs, "
         "so placement ran on the scalar CRUSH chain; the CRUSH kernel ran "
         "in phase 2 only")

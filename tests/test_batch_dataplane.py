@@ -446,6 +446,10 @@ def test_device_branches_serve_write_read_recover(monkeypatch):
     grew = report["counters"]
     assert grew.get("planar_matmul_calls", 0) > 0
     assert grew.get("ec_coalesced_ticks", 0) > 0
+    # every served write took its shard crcs from the chunk-crc program,
+    # and the verified reads below re-derived them on the host
+    assert grew.get("ec_tick_crc_device_ticks", 0) \
+        == grew["ec_coalesced_ticks"]
     assert grew.get("ec_coalesced_read_ticks", 0) > 0, \
         "no degraded read decoded a missing data shard"
     assert grew.get("ec_coalesced_reencode_ticks", 0) > 0, \

@@ -56,7 +56,8 @@ def test_multichip_phase_on_virtual_devices(capsys):
 
 
 _PALLAS_RAN = {"planar_matmul_calls": 10, "planar_matmul_bytes": 1 << 20,
-               "planar_stack_groups": 80, "ec_coalesced_ticks": 3}
+               "planar_stack_groups": 80, "ec_coalesced_ticks": 3,
+               "ec_tick_crc_device_ticks": 3}
 
 
 @pytest.mark.parametrize("change,complaint", [
@@ -67,6 +68,7 @@ _PALLAS_RAN = {"planar_matmul_calls": 10, "planar_matmul_bytes": 1 << 20,
     ({"ec_host_planar_matmul_calls": 2}, "host GF engine"),
     ({"ec_coalesced_ticks": 0}, "no coalesced"),
     ({"planar_stack_groups": 10}, "stack-group"),
+    ({"ec_tick_crc_device_ticks": 0}, "chunk-crc program"),
 ])
 def test_counter_check_tells_the_engines_apart(change, complaint):
     report = {"counters": {**_PALLAS_RAN, **change},

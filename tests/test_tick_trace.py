@@ -30,11 +30,12 @@ SLICE = os.path.join(ROOT, "benchmark", "tests", "data",
                      "k2m1_write_slice.xplane.pb.gz")
 
 N_WRITES = 12
-# read off the code, device branch, crc on the host (google_crc32c): the
-# host->device copy of the batch and the ingest program
-# (codec.to_planar), the planar matmul (codec.encode_planar), and the
-# two readbacks (ec/stripe.py)
-DEVICE_CALLS_PER_TICK = 2 + 1 + 2
+# read off the code, device branch: the host->device copy of the batch
+# and the ingest program (codec.to_planar), the planar matmul
+# (codec.encode_planar), the chunk-crc program
+# (ops/crc32c.planar_chunk_crcs), the two plane readbacks and the
+# readback of the crc words (ec/stripe.py)
+DEVICE_CALLS_PER_TICK = 2 + 1 + 1 + 2 + 1
 
 
 @pytest.fixture(scope="module")
@@ -128,7 +129,8 @@ def test_tick_root_says_what_it_encoded(burst):
 
 def test_phases_in_order_and_tiling_the_root(burst):
     want = ["executor_wait", "fill", "to_planar", "encode_dispatch",
-            "readback", "readback", "slice", "slice", "crc", "wake"]
+            "crc", "readback", "readback", "crc", "slice", "slice",
+            "wake"]
     for t in burst["ticks"]:
         segs = t.segments()
         assert [s[0] for s in segs if s[0] != "other"] == want
@@ -149,7 +151,10 @@ def test_device_calls_per_tick_equal_the_count_read_off_the_code(burst):
             by_phase[name] = by_phase.get(name, 0) + calls
         assert by_phase == {"fill": 0, "to_planar": 2,
                             "encode_dispatch": 1, "readback": 2,
-                            "slice": 0, "crc": 0}
+                            "slice": 0, "crc": 2}
+        # the launch, then the words' readback: one call each
+        assert [calls for name, _a, _b, calls in t.phases()
+                if name == "crc"] == [1, 1]
     assert burst["grew"]["ec_tick_device_calls"] \
         == DEVICE_CALLS_PER_TICK * len(burst["ticks"])
 
@@ -167,6 +172,9 @@ def test_counters_are_fed_once_per_tick_from_the_record(burst):
     assert sum(grew[c] for c in phases) <= wall
     assert grew["ec_tick_to_planar_ns"] == sum(
         t.phase_ns()["to_planar"] for t in ticks)
+    # every tick's crcs came from the device program
+    assert grew["ec_tick_crc_device_ticks"] == len(ticks)
+    assert all(t.crc_on_device() for t in ticks)
     # occupancy: never more than the wall between the first thread's
     # start and the last one's return, and two or more is a part of any
     span = max(t.t[2] for t in ticks) - min(t.t[1] for t in ticks)
@@ -221,8 +229,8 @@ def test_a_dumped_tick_is_a_span_tree_the_exporters_take(burst):
     kids = root["children"]
     assert kids[0]["name"] == "executor_wait" and kids[-1]["name"] == "wake"
     assert sum(k["meta"]["dur_ns"] for k in kids) == root["meta"]["dur_ns"]
-    assert [k for k in kids if k["name"] == "crc"][0]["meta"]["path"] \
-        == "host"
+    assert [k["meta"]["path"] for k in kids if k["name"] == "crc"] \
+        == ["device", "device"]
     doc = perfetto.chrome_trace_from_spans(spans)
     slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert len(slices) == len(spans)
@@ -254,6 +262,47 @@ def test_outside_a_tick_the_phase_is_one_shared_noop():
     assert grew["ec_coalesced_ticks"] == 1
     assert not any(k.startswith("ec_tick_") for k in grew)
     assert len(ticktrace.TICKS.ring) == n0
+
+
+@pytest.mark.parametrize("branch", ["device", "host"])
+def test_crc_device_ticks_counter_and_window(monkeypatch, branch):
+    """One encode tick run by hand on each branch of
+    ``encode_planes_multi``: ``ec_tick_crc_device_ticks`` grows by one
+    where the device program made the crcs and by nothing on the host
+    branch, and the device window reaches past the plane readbacks to
+    the readback of the crc words."""
+    from ceph_tpu.ec import factory, stripe
+    from ceph_tpu.ec.stripe import StripeInfo, encode_planes_multi
+
+    if branch == "device":
+        monkeypatch.setattr(stripe, "_host_engine_ok", lambda codec: False)
+    codec = factory({"plugin": "jerasure", "technique": "reed_sol_van",
+                     "k": "2", "m": "1"})
+    counters = PerfCounters("synthetic")
+    log = ticktrace.TickLog(keep=4, counters=counters)
+    tick = log.open(ticktrace.ENCODE_TICK, "osd.0", (1, 2))
+    datas = [bytes(range(256)) * 64, b"y" * 8192]        # 2 + 1 stripes
+    out = tick.run(encode_planes_multi, codec, StripeInfo(2, 4096), datas,
+                   [True, True])
+    tick.close()
+    assert all(len(crcs) == 3 for _planes, crcs in out)
+    got = counters.dump()["synthetic"]
+    crc = [(t0, t1, calls) for name, t0, t1, calls in tick.phases()
+           if name == "crc"]
+    if branch == "host":
+        assert got["ec_tick_crc_device_ticks"] == 0
+        assert not tick.crc_on_device() and tick.device_window() is None
+        assert [c for _a, _b, c in crc] == [0, 0]   # two shard lengths
+        assert tick.dump()[0]["meta"]["device_calls"] == 0
+        return
+    assert got["ec_tick_crc_device_ticks"] == 1 and tick.crc_on_device()
+    assert got["ec_tick_device_calls"] == DEVICE_CALLS_PER_TICK
+    readbacks = [t1 for name, _t0, t1, _c in tick.phases()
+                 if name == "readback"]
+    lo, hi = tick.device_window()
+    # launched before the first readback, read after the last
+    assert crc[0][1] <= min(readbacks) <= max(readbacks) <= crc[1][0]
+    assert hi == crc[1][1] and lo < crc[0][0]
 
 
 # --------------------------------------------- injected clock: occupancy
@@ -358,12 +407,14 @@ def test_tick_counters_are_declared_with_unit_and_description():
     schema = KERNELS.dump_schema()["device_kernels"]
     names = [c for c, _d in ticktrace._PHASE_COUNTERS.values()] + [
         "ec_tick_wall_ns", "ec_tick_handoff_ns", "ec_tick_any_active_ns",
-        "ec_tick_multi_active_ns", "ec_tick_device_calls"]
-    assert len(set(names)) == 11
+        "ec_tick_multi_active_ns", "ec_tick_device_calls",
+        "ec_tick_crc_device_ticks"]
+    assert len(set(names)) == 12
+    units = {"ec_tick_device_calls": "calls",
+             "ec_tick_crc_device_ticks": "ticks"}
     for name in names:
         assert schema[name]["type"] == "u64"
-        assert schema[name]["unit"] == \
-            ("calls" if name == "ec_tick_device_calls" else "ns")
+        assert schema[name]["unit"] == units.get(name, "ns")
         assert schema[name]["description"]
 
 
@@ -600,6 +651,8 @@ NINE = {
     "dispatches_per_tick.write": 5.0,
     "tick_overlap_share.write": 60.0,
 }
+# PR 27's, read the same way: every tick's crcs came from the device
+TICK_READERS = {**NINE, "tick_crc_device_share.write": 100.0}
 
 
 def _benchmark_cells():
@@ -615,7 +668,7 @@ def test_every_cell_loads_and_the_nine_tick_readers_read(cell_name):
     from benchmark.harness.loader import load_cell
 
     cell = load_cell(cell_name)
-    assert set(NINE) <= set(cell.per_layer)
+    assert set(TICK_READERS) <= set(cell.per_layer)
     ticks = 200
     wall = 500_000_000 * ticks
     counters = {
@@ -629,20 +682,21 @@ def test_every_cell_loads_and_the_nine_tick_readers_read(cell_name):
         "ec_tick_crc_ns": wall * 0.25,
         "ec_tick_handoff_ns": 2_000_000 * ticks,
         "ec_tick_device_calls": 5 * ticks,
+        "ec_tick_crc_device_ticks": ticks,
         "ec_tick_any_active_ns": 40_000_000_000,
         "ec_tick_multi_active_ns": 24_000_000_000,
     }
     readings = layers.Readings(
         config=cell.config, device_kind="TPU v5 lite", attribution={},
         counters=counters, slice_counters={}, trace=None)
-    for name, want in NINE.items():
+    for name, want in TICK_READERS.items():
         got = layers.read_metric(name, cell.per_layer[name], readings)
         assert got == pytest.approx(want), name
     # a program without the counters (the parent commit): nothing raises
     bare = layers.Readings(
         config=cell.config, device_kind="TPU v5 lite", attribution={},
         counters={}, slice_counters={}, trace=None)
-    for name in NINE:
+    for name in TICK_READERS:
         assert layers.read_metric(name, cell.per_layer[name], bare) is None
 
 
@@ -650,12 +704,13 @@ def test_the_nine_entries_agree_with_their_files():
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         spec = json.load(f)
     entries = {m["name"]: m for m in spec["per_layer"]}
-    assert list(entries)[-9:] == list(NINE)
+    assert list(entries)[-10:] == list(TICK_READERS)
     declared = set(KERNELS.dump()["device_kernels"])
-    for name in NINE:
+    for name in TICK_READERS:
         entry = entries[name]
         assert entry["source"] == "program_counter"
-        assert entry["moves"] == "write_MBps" and entry["better"] == "lower"
+        assert entry["moves"] == "write_MBps"
+        assert entry["better"] == ("lower" if name in NINE else "higher")
         assert entry["workloads"] == ["k2m1_write_4m_t16",
                                       "k2m1_write_64k_t16"]
         path = os.path.join(ROOT, "benchmark", "layer_metrics",
@@ -676,9 +731,14 @@ def test_program_names_the_benchmark_matches_are_pinned():
     ``planar_roofline.write.json`` matches ``jit__planar_tiled`` and
     ``trace/gapjoin.py`` counts the same events."""
     from ceph_tpu.ec import planar
-    from ceph_tpu.ops import gf8_pallas
+    from ceph_tpu.ops import crc32c, gf8_pallas
 
     assert gf8_pallas._planar_tiled.__name__ == "_planar_tiled"
+    # the chunk-crc program is another program: its device time is not
+    # the encode matmul's (planar_roofline.write matches by substring)
+    crc_program = "jit_" + crc32c._chunk_crcs_jit().__name__
+    assert crc_program == "jit__chunk_crcs_planes"
+    assert "jit__planar_tiled" not in crc_program
     assert planar._batch_to_planes_bitpack.__name__ \
         == "_batch_to_planes_bitpack"
     path = os.path.join(ROOT, "benchmark", "layer_metrics",

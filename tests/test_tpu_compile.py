@@ -103,6 +103,23 @@ def test_crc32c_batch_compiles_for_v5e(one_chip):
         _shape((), jnp.uint32, one_chip)).compile()
 
 
+# the encode tick's largest buckets: 8 x 4 MiB objects (48 MiB of planes)
+@pytest.mark.parametrize("k,m,bb", [pytest.param(2, 1, 4096, id="k2m1"),
+                                    pytest.param(4, 2, 2048, id="k4m2")])
+def test_chunk_crcs_program_compiles_for_v5e(one_chip, k, m, bb):
+    from ceph_tpu.ops.crc32c import _chunk_crcs_jit
+
+    unit = 4096
+    compiled = _chunk_crcs_jit().lower(
+        _shape((8 * unit, 32), jnp.int8, one_chip),
+        (_shape((k * 8, bb * unit // 8), jnp.uint8, one_chip),
+         _shape((m * 8, bb * unit // 8), jnp.uint8, one_chip)),
+        unit).compile()
+    # the bucket is walked in groups of stripes: one loop, one matmul
+    text = compiled.as_text()
+    assert " while(" in text and "convolution(" in text
+
+
 def test_crush_rule_compiles_for_v5e(one_chip):
     """One rack of the three-level map (256 OSDs); the 10k-OSD map takes
     ~20 s to compile and belongs to chip_smoke.py."""
