@@ -27,8 +27,12 @@ class MOSDBoot(Message):
 
 @dataclass
 class MOSDFailure(Message):
+    """``alive`` withdraws the reporter's earlier report: the peer
+    answered again (reference MOSDFailure FLAG_ALIVE)."""
+
     failed_osd: int = -1
     reporter: int = -1
+    alive: bool = False
 
 
 @dataclass

@@ -57,6 +57,9 @@ _SID = itertools.count(1)
 # in few syscalls (TCP_NODELAY is asyncio's default already).
 _STREAM_LIMIT = 4 << 20
 _SOCK_BUF = 2 << 20
+# how long a closing endpoint waits for its transport to flush before it
+# aborts it (Connection.close, Messenger.shutdown)
+_CLOSE_WAIT_S = 1.0
 
 
 def _tune_socket(writer) -> None:
@@ -234,12 +237,21 @@ class Connection:
                 raise
 
     async def close(self) -> None:
+        """A close that returns: a graceful close first flushes what the
+        transport still buffers, and a peer that has stopped reading
+        (a throttled or wedged read loop behind megabytes of frames)
+        never lets it finish — ``wait_closed`` then waits forever, and
+        with it every ``shutdown`` up to ``Cluster.stop``.  After
+        ``_CLOSE_WAIT_S`` the transport is aborted: the bytes a closing
+        endpoint still owed were void anyway."""
         self.closed = True
         try:
             self.writer.close()
-            await self.writer.wait_closed()
-        except (ConnectionError, OSError, RuntimeError,
-                asyncio.TimeoutError):
+            await asyncio.wait_for(self.writer.wait_closed(),
+                                   _CLOSE_WAIT_S)
+        except asyncio.TimeoutError:
+            self.writer.transport.abort()
+        except (ConnectionError, OSError, RuntimeError):
             pass  # best-effort close of an already-dying transport
 
 
@@ -396,6 +408,9 @@ class Messenger:
         self.dispatchers: List[Dispatcher] = []
         self._server: Optional[asyncio.base_events.Server] = None
         self._out: Dict[Addr, Connection] = {}
+        # the heartbeat lane: one more connection per peer, which carries
+        # pings and their replies and nothing else (send_heartbeat)
+        self._hb_out: Dict[Addr, Connection] = {}
         self._sessions: Dict[Addr, _Session] = {}
         self._accepted: List[Connection] = []
         # live-task registry: completed tasks self-discard, or a chaos
@@ -620,12 +635,17 @@ class Messenger:
             self._auth_waiters.pop(id(conn), None)
             await conn.close()
 
-    async def connect(self, addr: Addr) -> Connection:
+    async def connect(self, addr: Addr, lane: Optional[
+            Dict[Addr, Connection]] = None) -> Connection:
+        """The live connection to ``addr`` in ``lane`` (the data lane
+        ``_out`` unless the heartbeat lane is named), opened on demand."""
+        if lane is None:
+            lane = self._out
         if self.chaos is not None:
             # asymmetric partition: OUR connects to that peer fail like
             # a blackholed TCP connect; their path to us is untouched
             self.chaos.check_connect(addr)
-        conn = self._out.get(tuple(addr))
+        conn = lane.get(tuple(addr))
         if conn is not None and not conn.closed:
             return conn
         reader, writer = await asyncio.open_connection(
@@ -642,10 +662,25 @@ class Messenger:
             await conn.send(_MsgAuth(authorizer=authmod.make_authorizer(
                 self.auth.ticket_blob, self.auth.session_key)))
             conn.session_key = self.auth.session_key
-        self._out[tuple(addr)] = conn
+        lane[tuple(addr)] = conn
         task = asyncio.get_event_loop().create_task(self._read_loop(conn))
         self._track(task)
         return conn
+
+    async def send_heartbeat(self, msg: Message, addr: Addr) -> None:
+        """Send a ping on the heartbeat lane: a connection of its own per
+        peer, so that neither the ping nor its reply (which the peer
+        writes back on the connection the ping came in on) ever waits
+        behind megabyte data frames in a socket buffer, behind the data
+        lane's session lock and drain, or behind the inline dispatch of
+        queued sub-writes in the peer's read loop (reference: the OSD's
+        dedicated hb_front/hb_back messengers, src/ceph_osd.cc).  No
+        session and no replay: a lost ping is not worth resending, the
+        next one asks the same question.  A peer that listens no more
+        raises ``ConnectionRefusedError``: evidence of a dead daemon that
+        needs no grace (reference osd_fast_fail_on_connection_refused)."""
+        conn = await self.connect(tuple(addr), self._hb_out)
+        await conn.send(msg)
 
     async def send_message(self, msg: Message, addr: Addr) -> None:
         """Session send: ordered at-least-once with reconnect + replay of
@@ -875,8 +910,10 @@ class Messenger:
             self.config.remove_observer(self._chaos_observer)
         if self._server:
             self._server.close()
-        for conn in list(self._out.values()) + list(self._accepted):
-            await conn.close()
+        # together, not in turn: each close is bounded (_CLOSE_WAIT_S),
+        # and so is their sum
+        await asyncio.gather(*(conn.close() for conn in (
+            *self._out.values(), *self._hb_out.values(), *self._accepted)))
         # cancel + drain reader/handler tasks BEFORE wait_closed: since
         # py3.12 wait_closed() awaits every connection handler, and a
         # handler blocked in its read loop only exits via EOF or cancel
@@ -888,4 +925,9 @@ class Messenger:
             # results are void by definition
             await asyncio.gather(*pending, return_exceptions=True)  # graftlint: ignore[swallowed-async-error]
         if self._server:
-            await self._server.wait_closed()
+            try:
+                await asyncio.wait_for(self._server.wait_closed(),
+                                       _CLOSE_WAIT_S)
+            except asyncio.TimeoutError:
+                pass  # a handler that outlives its cancel must not
+                # hold the daemon's stop

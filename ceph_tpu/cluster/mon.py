@@ -1042,9 +1042,18 @@ class Monitor(Dispatcher):
         if osd < 0 or osd >= m.max_osd or not m.osd_up[osd]:
             return
         reporters = self.failure_reports.setdefault(osd, set())
+        if msg.alive:
+            # the peer answered its reporter again: a withdrawn report
+            # must not wait here to pair up with a later stray one
+            reporters.discard(msg.reporter)
+            return
         reporters.add(msg.reporter)
-        # can_mark_down analog: enough distinct reporters
-        if len(reporters) < self.config.mon_osd_min_down_reporters:
+        # can_mark_down analog: enough distinct reporters, and never
+        # more than there are OSDs up to make a report
+        others = sum(1 for o in range(m.max_osd)
+                     if m.osd_up[o] and o != osd)
+        if len(reporters) < min(self.config.mon_osd_min_down_reporters,
+                                max(1, others)):
             return
         self._propose("down", osd)
         window = self.config.mon_osd_failure_coalesce
@@ -1748,8 +1757,16 @@ class Monitor(Dispatcher):
         """Down-out + beacon-staleness tick (reference OSDMonitor tick:
         auto-out and mark-down of osds whose beacons went silent)."""
         while True:
-            await asyncio.sleep(self.config.mon_tick_interval)
+            interval = self.config.mon_tick_interval
+            slept = self.clock.monotonic()
+            await asyncio.sleep(interval)
             now = self.clock.monotonic()
+            # our own stall is no evidence against an OSD: while this
+            # loop did not run, beacons sat unread in our socket buffers
+            stalled = now - slept - interval
+            if stalled > interval:
+                for osd in self.last_beacon:
+                    self.last_beacon[osd] += stalled
             self._note_health()
             async with self._map_mutex:
                 inc = self._new_inc()

@@ -432,6 +432,48 @@ class Objecter(Dispatcher):
         else:
             await self.messenger.send_message(msg, addr)
 
+    async def _await_reply(self, fut, pgid, primary: int, addr: Tuple,
+                           deadline: float):
+        """Wait for the reply to an op that was handed to the session to
+        ``addr``.  The op deadline is the only bound while the attempt
+        STANDS: the target is still the PG's primary, up at the same
+        address in our map, and the connection that carried the op (the
+        one its reply comes back on) is alive — the op is then queued or
+        running there, and sending it again only queues a second copy of
+        its payload behind it: at 4 MiB x 16 in flight one slow stage
+        became a retry storm (reference Objecter: resend on map change
+        or session reset, never on a timer).  Every
+        ``osd_client_op_timeout + 2`` s (the OSD's own replica-ack
+        timeout, outwaited) the map is refreshed and the attempt is
+        looked at again; one that no longer stands raises
+        ``TimeoutError`` and the caller retargets.  Never past the op
+        deadline: an ack past the deadline must not reach the caller as
+        success."""
+        loop = asyncio.get_event_loop()
+        carried = self.messenger._out.get(addr)
+        while True:
+            look = min(self.config.osd_client_op_timeout + 2.0,
+                       max(0.05, deadline - loop.time()))
+            try:
+                # shielded: a look that times out must not cancel the
+                # future the reply will resolve
+                return await asyncio.wait_for(asyncio.shield(fut),
+                                              timeout=look)
+            except asyncio.TimeoutError:
+                if loop.time() >= deadline:
+                    raise
+            try:
+                await self._refresh_map()
+            except asyncio.TimeoutError:
+                pass
+            m = self.osdmap
+            if carried is None or carried.closed or \
+                    self.messenger._out.get(addr) is not carried or \
+                    self._target_osd(pgid) != primary or \
+                    not m.osd_up[primary] or \
+                    tuple(m.osd_addrs.get(primary) or ()) != addr:
+                raise asyncio.TimeoutError
+
     async def _op_submit_attempts(self, pool_id, oid, ops, deadline,
                                   wall_deadline, explicit_pgid, trace_id,
                                   trace_events, root, snapc, snapid):
@@ -470,13 +512,8 @@ class Objecter(Dispatcher):
                     msg.trace["span"] = root.span_id
                 try:
                     await self._send_op(msg, tuple(addr))
-                    # outwait the OSD's own replica-ack timeout (abandoning
-                    # in parallel just queues a duplicate op behind the PG
-                    # lock), but never past the op deadline — an ack past
-                    # the deadline must not reach the caller as success
-                    attempt = min(self.config.osd_client_op_timeout + 2.0,
-                                  max(0.05, deadline - loop.time()))
-                    reply = await asyncio.wait_for(fut, timeout=attempt)
+                    reply = await self._await_reply(
+                        fut, pgid, primary, tuple(addr), deadline)
                     if getattr(reply, "throttled", False):
                         # explicit admission pushback: shrink the window
                         # (multiplicative decrease), pause a jittered

@@ -34,7 +34,7 @@ from ceph_tpu.cluster.store import MemStore, ObjectStore, Transaction
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.ops import crc32c as crcmod
 from ceph_tpu.osdmap.osdmap import OSDMap, PGid, PGPool
-from ceph_tpu.utils import Config, PerfCounters
+from ceph_tpu.utils import KERNELS, Config, PerfCounters
 from ceph_tpu.cluster.backend_ec import ECBackendMixin
 from ceph_tpu.cluster.tiering import TieringMixin
 from ceph_tpu.cluster.backend_replicated import ReplicatedBackendMixin
@@ -112,7 +112,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         self.pgs: Dict[PGid, PGState] = {}
         # per-daemon counter registry: own counters + the process-wide
         # device-kernel counters, all served by one 'perf dump'
-        from ceph_tpu.utils import KERNELS, PerfCountersCollection
+        from ceph_tpu.utils import PerfCountersCollection
 
         self.perfcoll = PerfCountersCollection()
         self.perf = self.perfcoll.create(f"osd.{osd_id}")
@@ -206,7 +206,10 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         self._peering_rng = _chaos_stream(
             self.config.chaos_seed, f"peering:osd.{osd_id}") \
             if self.config.chaos_seed else None
-        self._hb_last: Dict[int, float] = {}
+        # peer -> stamp of the oldest ping sent and not yet answered, on
+        # this daemon's clock (reference HeartbeatInfo::ping_history): a
+        # peer is judged by pings it was sent, never by our own silence
+        self._hb_unanswered: Dict[int, float] = {}
         self._reported: Set[int] = set()
         # dmClock op scheduling (reference mClockClientQueue plugged into
         # ShardedOpWQ): enabled by osd_op_queue=mclock; ops enqueue per
@@ -728,7 +731,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         if isinstance(msg, M.MPing):
             if msg.reply:
                 if msg.src is not None:
-                    self._hb_last[msg.src.num] = self.clock.monotonic()
+                    await self._hb_reply(msg.src.num, msg.stamp)
             else:
                 await conn.send(M.MPing(stamp=msg.stamp, reply=True))
             return True
@@ -1136,10 +1139,13 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
 
     async def _compute(self, fn, *args, tick=None):
         """Run codec compute (encode/decode, possibly a first-call jit
-        compile) off the event loop.  Blocking the loop here starves
-        heartbeat replies and triggers false failure reports — the reference
-        isolates heartbeats on dedicated messengers for the same reason
-        (src/ceph_osd.cc:459-486 creates 4 hb messengers).  ``tick`` (a
+        compile) off the event loop: blocking the loop here stalls every
+        daemon of the process.  Failure detection does not hang on it:
+        heartbeats have a lane of their own
+        (``Messenger.send_heartbeat``; the reference's hb messengers,
+        src/ceph_osd.cc:459-486), a peer is judged by pings it was sent
+        and time counts against it only while this daemon's own loop
+        ran (``_heartbeat_loop``).  ``tick`` (a
         batcher's open ``trace.tick.Tick``) runs the work as that tick's:
         it stamps the thread's start and return and is the one the
         phases inside ``fn`` land on."""
@@ -1489,15 +1495,62 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
 
     # ------------------------------------------------------------ heartbeat
 
+    async def _hb_reply(self, osd: int, stamp: float) -> None:
+        """A ping reply: the lane delivers in order, so the reply that
+        echoes ``stamp`` answers every ping up to it.  Feeds the margin
+        counters the benchmark's ``hb_*`` metrics read."""
+        rtt = self.clock.monotonic() - stamp
+        KERNELS.inc("osd_hb_replies")
+        KERNELS.inc("osd_hb_rtt_ns", int(rtt * 1e9))
+        if rtt > self.config.osd_heartbeat_grace / 2:
+            KERNELS.inc("osd_hb_late_replies")
+        oldest = self._hb_unanswered.get(osd)
+        if oldest is not None and stamp >= oldest:
+            del self._hb_unanswered[osd]
+        if osd in self._reported:
+            # it answers after all: withdraw the report, so that it
+            # cannot pair up with a later stray one at the mon
+            self._reported.discard(osd)
+            await self._mon_send(M.MOSDFailure(
+                failed_osd=osd, reporter=self.osd_id, alive=True))
+
+    async def _report_failure(self, osd: int, age: float,
+                              why: str) -> None:
+        self._reported.add(osd)
+        if self.flight:
+            # a false report must explain itself in the black box: how
+            # old the unanswered ping was against the grace in force,
+            # and how long this reporter's own loop had been stalling
+            lag = self.loopmon.lag_report()
+            self.flight.record(
+                "failure_report", peer=osd, why=why, age=round(age, 6),
+                grace=self.config.osd_heartbeat_grace,
+                loop_lag_window_max=None if lag is None
+                else round(lag[1], 6))
+        if await self._mon_send(M.MOSDFailure(
+                failed_osd=osd, reporter=self.osd_id)):
+            self.perf.inc("osd_failure_reports")
+            KERNELS.inc("osd_hb_failure_reports")
+
     async def _heartbeat_loop(self) -> None:
         while not self._stopped:
-            await asyncio.sleep(self.config.osd_heartbeat_interval)
+            interval = self.config.osd_heartbeat_interval
+            slept = self.clock.monotonic()
+            await asyncio.sleep(interval)
             m = self.osdmap
             if m is None:
                 continue
             # the chaos-skewable per-daemon clock: a skewed OSD judges
             # peer heartbeat staleness from ITS OWN view of time
             now = self.clock.monotonic()
+            # our own stall is no evidence against a peer: while this
+            # loop did not run, replies sat unread in our socket buffers.
+            # Time counts against an unanswered ping only while we were
+            # there to read the answer.
+            stalled = now - slept - interval
+            if stalled > interval:
+                for osd in self._hb_unanswered:
+                    self._hb_unanswered[osd] += stalled
             # beacon to the mon (reference MOSDBeacon): lets the mon mark
             # us down even when no peer reporters survive; never let a
             # transport hiccup kill the heartbeat task.  The beacon also
@@ -1584,24 +1637,30 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
             for osd, addr in list(m.osd_addrs.items()):
                 if osd == self.osd_id or not m.osd_up[osd]:
                     continue
+                refused = False
                 try:
-                    await self.messenger.send_message(
+                    await self.messenger.send_heartbeat(
                         M.MPing(stamp=now), addr)
-                except (ConnectionError, OSError):
+                except ConnectionRefusedError:
+                    # nothing listens where the map says the peer is: a
+                    # dead daemon, known at once and without a grace
+                    # (reference osd_fast_fail_on_connection_refused)
+                    refused = True
+                except (ConnectionError, OSError, RuntimeError):
+                    # unreachable some other way (a partition, a reset):
+                    # the ping counts as sent, and the grace decides
                     pass
-                last = self._hb_last.get(osd)
-                if last is not None and \
-                        now - last > self.config.osd_heartbeat_grace and \
-                        osd not in self._reported:
-                    self._reported.add(osd)
-                    if await self._mon_send(M.MOSDFailure(
-                            failed_osd=osd, reporter=self.osd_id)):
-                        self.perf.inc("osd_failure_reports")
-                elif last is None:
-                    self._hb_last[osd] = now
-            # once the monitor marks a reported peer down, forget it so a
-            # future reboot is tracked afresh
-            for osd in list(self._reported):
-                if not m.osd_up[osd]:
-                    self._reported.discard(osd)
-                    self._hb_last.pop(osd, None)
+                if osd in self._reported:
+                    continue
+                age = now - self._hb_unanswered.setdefault(osd, now)
+                if refused or age > self.config.osd_heartbeat_grace:
+                    await self._report_failure(
+                        osd, age, "refused" if refused else "grace")
+            # once the monitor marks a peer down, forget it so a future
+            # reboot is tracked afresh: an old unanswered ping must not
+            # count against the next incarnation
+            for osd in list(self._hb_unanswered):
+                if osd >= m.max_osd or not m.osd_up[osd]:
+                    del self._hb_unanswered[osd]
+            self._reported = {o for o in self._reported
+                              if o < m.max_osd and m.osd_up[o]}

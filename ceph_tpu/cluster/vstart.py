@@ -465,14 +465,25 @@ class Cluster:
 
 
 def _fast_config() -> Config:
-    """Test-speed timings (the vstart analog of ceph.conf overrides)."""
+    """The product configuration of a vstart cluster: what the benchmark,
+    ``chip_smoke.py`` and the tests serve from (the vstart analog of
+    ceph.conf overrides).  Recovery, op and tick timings are fast; the
+    failure timings are a deployment's, in upstream's proportions at a
+    tenth of its scale, because every daemon of the cluster shares one
+    event loop with the data frames: a grace must outlast the worst lag
+    a loaded loop shows, two OSDs must agree before a third is marked
+    down, and a down OSD is not marked out (and its PGs remapped) inside
+    anybody's measured window.  A daemon that is really dead is found at
+    once all the same: its peers' pings are refused (osd.py,
+    ``_heartbeat_loop``).  A test that needs a grace to expire within a
+    second, or an OSD marked out, sets those values itself."""
     return Config(
-        osd_heartbeat_interval=0.1,
-        osd_heartbeat_grace=1.5,
+        osd_heartbeat_interval=0.5,     # upstream 6 s
+        osd_heartbeat_grace=10.0,       # upstream 20 s
         mon_tick_interval=0.1,
-        mon_osd_down_out_interval=2.0,
-        mon_osd_min_down_reporters=1,
-        mon_osd_beacon_grace=1.5,
+        mon_osd_down_out_interval=600.0,    # upstream's own
+        mon_osd_min_down_reporters=2,       # upstream's own
+        mon_osd_beacon_grace=30.0,      # upstream mon_osd_report_timeout 900 s
         osd_recovery_delay_start=0.05,
         osd_client_op_timeout=5.0,
         # XLA first-compiles of codec shapes can take tens of seconds on a

@@ -252,8 +252,9 @@ async def serve_ec_objects(seed: int, n_objects: int = 64,
         await read_all("degraded_read")
 
         await cluster.revive_osd(victim)
-        # down past mon_osd_down_out_interval it was marked out, and a
-        # boot does not mark it in again: the operator's `ceph osd in`
+        # had it been down past mon_osd_down_out_interval (600 s in the
+        # product configuration) it would be marked out, and a boot does
+        # not mark it in again: the operator's `ceph osd in`, a no-op here
         await client.objecter.mon_command({"prefix": "osd in", "id": victim})
         await timed("recover_to_health_ok",
                     _wait_health_ok(client, recover_deadline_s))

@@ -252,6 +252,10 @@ def test_health_err_transition_triggers_one_bundle(tmp_path):
         cfg.set("blackbox_enabled", 1)
         cfg.set("blackbox_dir", str(tmp_path))
         cfg.set("mon_health_history", 8)
+        # the last OSD to die has no peer left to report it: only the
+        # mon's beacon grace marks it down, and the product
+        # configuration's 30 s (PR 28) is this test's whole deadline
+        cfg.set("mon_osd_beacon_grace", 1.5)
         cluster = await start_cluster(2, config=cfg)
         try:
             await cluster.client()  # collection rides a live session

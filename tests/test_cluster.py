@@ -149,7 +149,14 @@ def test_down_out_rebalance_and_recovery():
     """Down OSD is auto-outed by the mon tick; replicated PGs remap and the
     new acting set is backfilled by primary-driven recovery."""
     async def scenario():
-        cluster = await start_cluster(4, osds_per_host=1)
+        # the product configuration marks nothing out inside a test's
+        # lifetime (600 s, PR 28): this test is about the auto-out, and
+        # sets its own interval
+        from ceph_tpu.cluster.vstart import _fast_config
+
+        cfg = _fast_config()
+        cfg.mon_osd_down_out_interval = 2.0
+        cluster = await start_cluster(4, osds_per_host=1, config=cfg)
         try:
             client = await cluster.client()
             pool = await client.pool_create("repl", "replicated",

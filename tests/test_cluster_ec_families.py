@@ -56,6 +56,9 @@ def test_shec_pool_parity_shard_loss_recovers():
     out, reference ErasureCodeShec.cc:526-756)."""
     async def scenario():
         cfg = _fast_config()
+        # the re-protection below needs the dead holder marked OUT: the
+        # product configuration's 600 s (PR 28) is not a test's
+        cfg.mon_osd_down_out_interval = 2.0
         # 8 osds for 7 shards: a replacement member must exist after the
         # parity holder dies, or CRUSH can never fill the hole
         cluster = await start_cluster(8, config=cfg)

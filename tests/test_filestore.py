@@ -99,6 +99,10 @@ def test_cluster_full_restart_zero_pushes(tmp_path):
 
         cfg = _fast_config()
         cfg.mon_osd_down_out_interval = 120.0
+        # every OSD stops at once: nobody is left to report, only the
+        # mon's beacon grace marks them down, and the product
+        # configuration's 30 s (PR 28) outlasts wait_down
+        cfg.mon_osd_beacon_grace = 1.5
 
         def factory(osd_id):
             return FileStore(str(tmp_path / f"osd{osd_id}"))

@@ -1,0 +1,612 @@
+"""The jerasure k=4 m=2 pool on 8 OSDs (``benchmark/configs/
+rados_k4m2_8osd.json``) against a plain reference, at small size on the
+CPU with the device branches forced, and what lets it serve without a
+stall: failure detection that survives a stalled loop, a stop that is
+bounded, the heartbeat counters and the two metric files that read them.
+
+The reference: for what is served, name -> payload; for what is stored,
+a scalar GF(2^8) Vandermonde encode written here from the field's
+polynomial alone (no table, matrix or code of ``ceph_tpu``), with the
+coding matrix of the golden vectors, and first held to the independent
+C oracle's chunks itself.  Every comparison is exact.
+
+Each cluster scenario runs under a bound of its own (``bounded``): a
+regression fails, it does not hang the suite.
+"""
+
+import asyncio
+import json
+import os
+import time
+
+import numpy as np
+import pytest
+
+from ceph_tpu.cluster import messages as M
+from ceph_tpu.cluster.vstart import _fast_config, start_cluster
+from ceph_tpu.ops import crc32c as crcmod
+from ceph_tpu.utils import KERNELS
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmark", "configs",
+                       "rados_k4m2_8osd.json"), encoding="utf-8") as _f:
+    DEPLOYMENT = json.load(_f)
+K, MM, UNIT = DEPLOYMENT["k"], DEPLOYMENT["m"], DEPLOYMENT["stripe_unit"]
+SIZES = {"4k": 4096, "64k+1": 65537, "1m": 1 << 20}
+CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16")
+
+
+def bounded(coro, seconds):
+    async def _run():
+        return await asyncio.wait_for(coro, seconds)
+    return asyncio.run(_run())
+
+
+def counters():
+    return dict(KERNELS.dump()["device_kernels"])
+
+
+# ------------------------------------------------------ the plain reference
+
+def _gf_mul_tables():
+    """256 x 256 products in GF(2^8) modulo x^8+x^4+x^3+x^2+1 (0x11d),
+    by shift and reduce."""
+    table = np.zeros((256, 256), dtype=np.uint8)
+    for a in range(256):
+        for b in range(256):
+            x, y, p = a, b, 0
+            while y:
+                if y & 1:
+                    p ^= x
+                x <<= 1
+                if x & 0x100:
+                    x ^= 0x11d
+                y >>= 1
+            table[a, b] = p
+    return table
+
+
+def _golden_case():
+    with open(os.path.join(ROOT, "tests", "golden", "ec_golden.jsonl"),
+              encoding="utf-8") as f:
+        for line in f:
+            case = json.loads(line)
+            if (case["plugin"], case["technique"], case["k"], case["m"],
+                    case.get("w", 8), case["packetsize"]) == \
+                    ("jerasure", "reed_sol_van", K, MM, 8, 0):
+                return case
+    raise AssertionError("no golden vector for reed_sol_van k=4 m=2")
+
+
+class Reference:
+    def __init__(self):
+        self.case = _golden_case()
+        self.matrix = np.array(self.case["matrix"],
+                               dtype=np.uint8).reshape(MM, K)
+        self.mul = _gf_mul_tables()
+
+    def parity(self, chunks):
+        """chunks: (k, n) bytes -> (m, n) parity bytes, scalar products
+        looked up per byte and XORed."""
+        out = np.zeros((MM, chunks.shape[1]), dtype=np.uint8)
+        for j in range(MM):
+            for c in range(K):
+                out[j] ^= self.mul[self.matrix[j, c]][chunks[c]]
+        return out
+
+    def shards(self, payload: bytes):
+        """The k+m shards as stored: the object zero-padded to whole
+        stripes of k x UNIT, shard i = chunk i of every stripe in turn."""
+        width = K * UNIT
+        stripes = -(-len(payload) // width)
+        padded = np.zeros(stripes * width, dtype=np.uint8)
+        padded[:len(payload)] = np.frombuffer(payload, dtype=np.uint8)
+        data = padded.reshape(stripes, K, UNIT).transpose(1, 0, 2) \
+            .reshape(K, stripes * UNIT)
+        return [bytes(r) for r in data] + \
+            [bytes(r) for r in self.parity(data)]
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return Reference()
+
+
+def test_reference_agrees_with_the_independent_oracle(reference):
+    """The scalar encode above against gen.c's chunks for this profile:
+    the reference is itself held to something outside the repo's code."""
+    from test_ec_golden import _fnv1a64, _lcg_bytes
+
+    case = reference.case
+    payload = _lcg_bytes(case["seed"], case["object_size"])
+    n = case["chunk_size"]
+    data = np.frombuffer(payload, dtype=np.uint8).reshape(K, n)
+    chunks = [bytes(r) for r in data] + \
+        [bytes(r) for r in reference.parity(data)]
+    for got, want in zip(chunks, case["chunks"]):
+        assert _fnv1a64(got) == want["fnv1a64"]
+        assert got[:16].hex() == want["head"]
+
+
+# ---------------------------------------------- served against the reference
+
+async def _serve():
+    """One cluster, everything the cases below look at."""
+    cluster = await start_cluster(DEPLOYMENT["osds"])
+    out = {"stored": {}, "reads": {}, "holders": {}}
+    try:
+        client = await cluster.client()
+        pool = await client.pool_create(
+            "k4m2", DEPLOYMENT["pool_type"], pg_num=DEPLOYMENT["pg_num"],
+            ec_profile=dict(DEPLOYMENT["ec_profile"]))
+        io = client.ioctx(pool)
+        rng = np.random.default_rng(28)
+        payloads = {
+            f"{label}_{i}": rng.integers(0, 256, size,
+                                         dtype=np.uint8).tobytes()
+            for label, size in SIZES.items() for i in range(3)}
+        out["payloads"] = payloads
+        before = counters()
+        await asyncio.gather(*(io.write_full(n, d, timeout=120)
+                               for n, d in payloads.items()))
+        out["write_grew"] = {k: v - before.get(k, 0)
+                             for k, v in counters().items()
+                             if isinstance(v, (int, float))}
+
+        def acting(name):
+            pgid = client.objecter.object_pgid(pool, name)
+            return pgid, client.objecter.osdmap.pg_to_up_acting_osds(
+                pgid)[2]
+
+        for name in payloads:
+            pgid, holders = acting(name)
+            out["holders"][name] = list(holders)
+            coll = f"pg_{pgid.pool}_{pgid.seed}"
+            rows = []
+            for shard, osd in enumerate(holders):
+                store = cluster.osds[osd].store
+                rows.append((
+                    int(store.getattr(coll, name, "shard")), shard,
+                    bytes(store.read(coll, name)),
+                    int(store.getattr(coll, name, "hinfo_crc"))))
+            out["stored"][name] = rows
+
+        async def read_all(state):
+            got = await asyncio.gather(*(io.read(n, timeout=120)
+                                         for n in payloads))
+            out["reads"][state] = dict(zip(payloads, got))
+
+        await read_all("healthy")
+        # two holders of DATA shards of one 1 MiB object: with the first
+        # down its reads decode from one parity row, with both down from
+        # the two (m = 2: what k2m1 cannot show)
+        _, holders = acting("1m_0")
+        victims = [holders[1], holders[2]]
+        before = counters()
+        await cluster.kill_osd(victims[0])
+        await cluster.wait_down(victims[0])
+        await read_all("one_down")
+        await cluster.kill_osd(victims[1])
+        await cluster.wait_down(victims[1])
+        await read_all("two_down")
+        out["decode_ticks"] = counters().get(
+            "ec_coalesced_read_ticks", 0) - before.get(
+            "ec_coalesced_read_ticks", 0)
+        out["two_down_objects"] = sum(
+            1 for n in payloads
+            if len(set(out["holders"][n]) & set(victims)) == 2)
+    finally:
+        await cluster.stop()
+    return out
+
+
+@pytest.fixture(scope="module")
+def served():
+    from ceph_tpu.ec import stripe
+
+    sound = stripe._host_engine_ok
+    stripe._host_engine_ok = lambda codec: False    # the device branches
+    before = counters()
+    try:
+        out = bounded(_serve(), 420)
+    finally:
+        stripe._host_engine_ok = sound
+    out["host_engine_calls"] = sum(
+        counters().get(c, 0) - before.get(c, 0)
+        for c in ("ec_host_matmul_calls", "ec_host_planar_matmul_calls"))
+    return out
+
+
+@pytest.mark.parametrize("label", list(SIZES))
+def test_stored_shards_equal_the_scalar_reference(served, reference, label):
+    """Every stored shard, parity above all, is the reference's bytes,
+    on the holder the acting set names for it."""
+    for name, payload in served["payloads"].items():
+        if not name.startswith(label + "_"):
+            continue
+        want = reference.shards(payload)
+        rows = served["stored"][name]
+        assert len(rows) == K + MM
+        for shard_attr, shard, stored, _crc in rows:
+            assert shard_attr == shard
+            assert stored == want[shard], (name, shard)
+
+
+@pytest.mark.parametrize("label", list(SIZES))
+def test_stored_shard_crcs_equal_crc32c_of_the_reference(served, reference,
+                                                         label):
+    """The crcs the device's chunk-crc program made and the host folded
+    are crc32c of the reference's shard bytes."""
+    for name, payload in served["payloads"].items():
+        if not name.startswith(label + "_"):
+            continue
+        want = reference.shards(payload)
+        for _attr, shard, _stored, crc in served["stored"][name]:
+            assert crc == crcmod.crc32c(0xFFFFFFFF, want[shard]), \
+                    (name, shard)
+
+
+@pytest.mark.parametrize("state", ["healthy", "one_down", "two_down"])
+def test_reads_equal_the_payload(served, state):
+    got = served["reads"][state]
+    for name, payload in served["payloads"].items():
+        assert got[name] == payload, (state, name)
+
+
+def test_the_device_engine_served_and_decoded(served):
+    grew = served["write_grew"]
+    assert grew.get("planar_matmul_calls", 0) > 0
+    assert grew.get("ec_coalesced_ticks", 0) > 0
+    assert grew.get("ec_tick_crc_device_ticks", 0) \
+        == grew["ec_coalesced_ticks"]
+    assert served["decode_ticks"] > 0
+    assert served["two_down_objects"] >= 1
+    assert served["host_engine_calls"] == 0
+    # heartbeats were answered while it served, on their own lane
+    assert grew.get("osd_hb_replies", 0) > 0
+    assert grew.get("osd_hb_rtt_ns", 0) > 0
+    assert grew.get("osd_hb_failure_reports", 0) == 0
+
+
+# ------------------------------------------------- failure detection, stop
+
+def _stall(seconds):
+    # a deliberate loop stall: the duration IS the stimulus
+    # graftlint: ignore[asyncio-blocking] graftlint: ignore[fixed-sleep-in-tests]
+    time.sleep(seconds)
+
+
+def _mon_perf(cluster, name):
+    return next(iter(cluster.mon.perf.dump().values())).get(name, 0)
+
+
+def test_no_false_down_when_the_loop_stalls_past_the_old_grace():
+    """The product configuration under loop stalls of 2 s (the old grace
+    was 1.5 s, one reporter, and a beacon grace of 1.5 s at the mon):
+    nobody is reported, nobody is marked down, the map does not move."""
+    async def scenario():
+        cluster = await start_cluster(4)
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create("p", "replicated", pg_num=4,
+                                            size=3)
+            io = client.ioctx(pool)
+            await io.write_full("o", b"x" * 4096)
+            # the pool's log lines land with the mon's next ticks: the
+            # epoch is read once it has stood still for half a second
+            epoch, since = cluster.mon.osdmap.epoch, time.monotonic()
+            while time.monotonic() - since < 0.5:
+                await asyncio.sleep(0.05)
+                if cluster.mon.osdmap.epoch != epoch:
+                    epoch, since = cluster.mon.osdmap.epoch, \
+                        time.monotonic()
+            before = counters()
+            for _ in range(3):
+                _stall(2.0)
+                # the heartbeat loops and the mon's tick run between the
+                # stalls; then three heartbeat rounds more: a false
+                # report would be made and counted inside them
+                # graftlint: ignore[fixed-sleep-in-tests]
+                await asyncio.sleep(0.3)
+            # graftlint: ignore[fixed-sleep-in-tests]
+            await asyncio.sleep(1.5)
+            assert await io.read("o") == b"x" * 4096
+            assert _mon_perf(cluster, "mon_osd_marked_down") == 0
+            assert cluster.mon.osdmap.epoch == epoch
+            assert all(cluster.mon.osdmap.osd_up[:4])
+            for o, osd in cluster.osds.items():
+                assert osd.perf.dump()[f"osd.{o}"].get(
+                    "osd_failure_reports", 0) == 0
+            grew = counters()
+            assert grew.get("osd_hb_failure_reports", 0) \
+                == before.get("osd_hb_failure_reports", 0)
+            # and heartbeats went on being answered
+            assert grew.get("osd_hb_replies", 0) \
+                > before.get("osd_hb_replies", 0)
+        finally:
+            await cluster.stop()
+
+    bounded(scenario(), 90)
+
+
+def test_a_killed_osd_is_marked_down_by_its_peers_at_once():
+    """Really dead: its peers' pings are refused, two of them say so,
+    and the mon marks it down inside the benchmark's ``wait_down``
+    (20 s) with room: no grace has to run out."""
+    async def scenario():
+        cluster = await start_cluster(DEPLOYMENT["osds"])
+        try:
+            before = counters()
+            t0 = time.monotonic()
+            await cluster.kill_osd(5)
+            await cluster.wait_down(5, timeout=20.0)
+            took = time.monotonic() - t0
+            cfg = cluster.config
+            assert took < min(cfg.osd_heartbeat_grace,
+                              cfg.mon_osd_beacon_grace) / 2, took
+            # the line reaches the log with the mon's next tick
+            deadline = time.monotonic() + 5.0
+            while time.monotonic() < deadline:
+                lines = [str(e) for e in cluster.mon.cluster_log]
+                if any("osd.5" in ln for ln in lines[8:]):
+                    break
+                await asyncio.sleep(0.05)
+            assert any("osd.5 failed (" in ln and "reporters) -> marked "
+                       "down" in ln for ln in lines), lines[-5:]
+            assert not any("beacon grace expired" in ln for ln in lines)
+            assert _mon_perf(cluster, "mon_osd_marked_down") == 1
+            assert counters().get("osd_hb_failure_reports", 0) \
+                - before.get("osd_hb_failure_reports", 0) >= 2
+            # not marked out: its PGs stay degraded, nothing remaps
+            assert cluster.mon.osdmap.osd_weight[5] > 0
+        finally:
+            await cluster.stop()
+
+    bounded(scenario(), 90)
+
+
+def test_a_hung_peer_is_reported_after_the_grace_with_its_evidence():
+    """A peer that keeps its socket and answers nothing (no refusal to
+    go by) is reported once a ping has waited out the grace in force —
+    set short by THIS test, as a test that needs sub-second detection
+    does — by two reporters, and each report leaves a flight-recorder
+    event that explains itself."""
+    async def scenario():
+        cfg = _fast_config()
+        cfg.osd_heartbeat_interval = 0.1
+        cfg.osd_heartbeat_grace = 0.6
+        cfg.blackbox_enabled = 1
+        cluster = await start_cluster(4, config=cfg)
+        try:
+            before = counters()
+            # first a peer that is slow, not dead: osd.1 answers the first
+            # ping of each connection 0.4 s late, past half the grace in
+            # force and inside it: late replies, and no report
+            slow, seen = cluster.osds[1], set()
+            sound = slow.ms_dispatch
+
+            async def slow_once(conn, msg):
+                if isinstance(msg, M.MPing) and not msg.reply \
+                        and id(conn) not in seen:
+                    seen.add(id(conn))
+                    # the delay IS the stimulus
+                    # graftlint: ignore[fixed-sleep-in-tests]
+                    await asyncio.sleep(0.4)
+                return await sound(conn, msg)
+
+            slow.ms_dispatch = slow_once
+            deadline = time.monotonic() + 10.0
+            while time.monotonic() < deadline:
+                grew = counters()
+                if grew.get("osd_hb_late_replies", 0) \
+                        > before.get("osd_hb_late_replies", 0):
+                    break
+                await asyncio.sleep(0.05)
+            assert grew.get("osd_hb_late_replies", 0) \
+                > before.get("osd_hb_late_replies", 0)
+            assert grew.get("osd_hb_failure_reports", 0) \
+                == before.get("osd_hb_failure_reports", 0)
+            # then one that keeps its sockets and handles nothing more
+            hung = cluster.osds[3]
+            hung._stopped = True
+            await cluster.wait_down(3, timeout=20.0)
+            assert counters()["osd_hb_failure_reports"] \
+                - before.get("osd_hb_failure_reports", 0) >= 2
+            events = [e for o in (0, 1, 2)
+                      for e in cluster.osds[o].flight.events()
+                      if e[2] == "failure_report"]
+            assert len(events) >= 2
+            for _seq, _ts, _kind, data in events:
+                assert data["peer"] == 3 and data["why"] == "grace"
+                assert data["age"] > data["grace"] == 0.6
+                assert "loop_lag_window_max" in data
+        finally:
+            await cluster.stop()
+
+    bounded(scenario(), 90)
+
+
+def test_a_withdrawn_report_does_not_pair_up_with_a_later_one():
+    """One reporter is never enough among three or more OSDs, and a
+    report its reporter withdrew (the peer answered again) does not wait
+    at the mon for a second stray one."""
+    async def scenario():
+        cluster = await start_cluster(4)
+        try:
+            mon = cluster.mon
+            await mon._handle_failure(M.MOSDFailure(failed_osd=2,
+                                                    reporter=0))
+            assert mon.failure_reports[2] == {0}
+            await mon._handle_failure(M.MOSDFailure(
+                failed_osd=2, reporter=0, alive=True))
+            await mon._handle_failure(M.MOSDFailure(failed_osd=2,
+                                                    reporter=1))
+            # past the mon's failure coalesce window and its tick: a
+            # markdown would have been committed by then
+            # graftlint: ignore[fixed-sleep-in-tests]
+            await asyncio.sleep(0.5)
+            assert mon.osdmap.osd_up[2]
+            assert _mon_perf(cluster, "mon_osd_marked_down") == 0
+        finally:
+            await cluster.stop()
+
+    bounded(scenario(), 60)
+
+
+def test_cluster_stop_is_bounded_with_a_peer_connection_wedged():
+    """An OSD that has stopped reading one client connection, with
+    megabytes queued towards it: a graceful close would wait for that
+    buffer to flush, forever (``wait_closed`` in ``messenger.shutdown``:
+    three chip runs ended so).  ``Cluster.stop`` returns all the same."""
+    async def scenario():
+        cluster = await start_cluster(3)
+        client = await cluster.client()
+        pool = await client.pool_create("p", "replicated", pg_num=4,
+                                        size=3)
+        await client.ioctx(pool).write_full("o", b"x" * 4096)
+        wedged = 0
+        for osd in cluster.osds.values():
+            for conn in osd.messenger._accepted:
+                if conn.peer is not None and conn.peer.type == "client" \
+                        and not conn.closed:
+                    conn.reader._transport.pause_reading()
+                    wedged += 1
+        assert wedged
+        for conn in client.objecter.messenger._out.values():
+            if conn.peer_addr in {tuple(o.messenger.my_addr)
+                                  for o in cluster.osds.values()}:
+                conn.writer.write(b"\0" * (32 << 20))
+        t0 = time.monotonic()
+        await cluster.stop()
+        return time.monotonic() - t0
+
+    took = bounded(scenario(), 60)
+    assert took < 20.0, took
+
+
+# ------------------------------------- the objecter: no resend on a timer
+
+def test_a_slow_op_is_not_sent_again_while_its_target_stands():
+    """An op whose reply takes longer than ``osd_client_op_timeout + 2``
+    is waited for, not sent again: the target is up in the client's map
+    and the connection that carried the op is alive.  One send, one
+    execution, one acknowledgement."""
+    async def scenario():
+        cfg = _fast_config()
+        cfg.osd_client_op_timeout = 0.2     # a look every 2.2 s
+        cluster = await start_cluster(3, config=cfg)
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create("p", "replicated", pg_num=4,
+                                            size=3)
+            io = client.ioctx(pool)
+            await io.write_full("warm", b"w")
+            sends = []
+            sound_send = client.objecter._send_op
+
+            async def counted(msg, addr):
+                sends.append(msg.oid)
+                return await sound_send(msg, addr)
+
+            client.objecter._send_op = counted
+            served = []
+            for osd in cluster.osds.values():
+                sound = osd._handle_client_op
+
+                async def slow(conn, msg, sound=sound):
+                    served.append(msg.oid)
+                    # two looks of the client (2.2 s each) pass: the
+                    # duration IS the stimulus
+                    # graftlint: ignore[fixed-sleep-in-tests]
+                    await asyncio.sleep(5.0)
+                    return await sound(conn, msg)
+
+                osd._handle_client_op = slow
+            await io.write_full("slow", b"s" * 100)
+            assert sends.count("slow") == 1
+            assert served.count("slow") == 1
+            assert await io.read("slow") == b"s" * 100
+        finally:
+            await cluster.stop()
+
+    bounded(scenario(), 90)
+
+
+# ----------------------------------------------------- the metric readers
+
+HB_READERS = {"hb_rtt_ms.write": 2.5, "hb_late_share.write": 0.5}
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_the_hb_metric_files_read_the_hand_worked_values(cell_name):
+    """4000 replies whose round trips sum to 10 s, 20 of them late:
+    2.5 ms and 0.5 %, through the accepted ``counter_ratio`` reader; a
+    program without the counters (the parent commit) reads nothing and
+    nothing raises."""
+    from benchmark.harness import layers
+    from benchmark.harness.loader import load_cell
+
+    cell = load_cell(cell_name)
+    growth = {"osd_hb_replies": 4000, "osd_hb_rtt_ns": 10_000_000_000,
+              "osd_hb_late_replies": 20}
+    for readings, want in (
+            (layers.Readings(config=cell.config, device_kind="TPU v5 lite",
+                             attribution={}, counters=growth,
+                             slice_counters={}, trace=None), HB_READERS),
+            (layers.Readings(config=cell.config, device_kind="TPU v5 lite",
+                             attribution={}, counters={},
+                             slice_counters={}, trace=None),
+             dict.fromkeys(HB_READERS))):
+        for name, value in want.items():
+            got = layers.read_metric(name, cell.per_layer[name], readings)
+            assert got == (pytest.approx(value) if value is not None
+                           else None), name
+
+
+def test_every_cell_of_the_benchmark_loads_through_the_loader():
+    """ROADMAP C10: every cell's files exist and agree with their
+    entries (the loader raises otherwise), every name in a ``workloads``
+    list is a cell, every cell reports ``setup_s``, another end-to-end
+    metric and a per-layer metric, and each per-layer metric moves an
+    end-to-end metric its cells report."""
+    from benchmark.harness.loader import load_cell
+
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        spec = json.load(f)
+    cells = [w["name"] for w in spec["workloads"]]
+    assert tuple(cells) == CELLS
+    configs = {c["name"]: c for c in spec["configs"]}
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    for metric in spec["end_to_end"] + spec["per_layer"]:
+        for name in metric.get("workloads", ()):
+            assert name in cells, (metric["name"], name)
+    for w in spec["workloads"]:
+        cell = load_cell(w["name"])
+        assert cell.config_name in configs
+        assert os.path.join("benchmark", "configs",
+                            cell.config_name + ".json") \
+            == configs[cell.config_name]["file"]
+        assert set(configs[cell.config_name]["reduced"]) \
+            == set(cell.config["reduced"])
+        assert cell.config["osds"] >= cell.config["k"] + cell.config["m"]
+        assert "setup_s" in cell.end_to_end and len(cell.end_to_end) >= 2
+        assert cell.per_layer
+        for metric in spec["per_layer"]:
+            if w["name"] in metric.get("workloads", cells):
+                assert metric["name"] in cell.per_layer
+                assert metric["moves"] in cell.end_to_end
+                assert metric["moves"] in e2e
+    # the new deployment: the accepted traffic file on the new config,
+    # one chip, and the two heartbeat metrics in all three cells
+    new = load_cell("k4m2_write_4m_t16")
+    assert (new.config_name, new.traffic_name, new.chips) \
+        == ("rados_k4m2_8osd", "write_4m_t16", 1)
+    old = load_cell("k2m1_write_4m_t16")
+    assert set(new.per_layer) == set(old.per_layer)
+    assert new.traffic == old.traffic
+    for metric in spec["per_layer"][-2:]:
+        assert metric["name"] in HB_READERS
+        assert metric["workloads"] == list(CELLS)
+        assert (metric["layer"], metric["moves"], metric["better"],
+                metric["source"]) == ("wire", "write_p95_ms", "lower",
+                                      "program_counter")
