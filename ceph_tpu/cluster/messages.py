@@ -5,8 +5,18 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from ceph_tpu.cluster.messenger import Addr, Message
+from ceph_tpu.cluster.messenger import Addr, Message, oob, reduce_with
 from ceph_tpu.osdmap.osdmap import PGid
+
+
+class _DataOutOfBand:
+    """A message whose ``data`` is object data: the field is offered to
+    the frame as a buffer beside the pickle (``messenger.oob``), and the
+    receiver gets it as a read-only ``memoryview`` of the frame when it
+    went that way.  Small ``data`` pickles in band as ever."""
+
+    def __reduce_ex__(self, protocol):
+        return reduce_with(self, data=oob(self.data, protocol))
 
 
 # -- mon <-> daemons --------------------------------------------------------
@@ -179,9 +189,15 @@ class MMonPaxos(Message):
 # -- client <-> osd ---------------------------------------------------------
 
 
+# op verbs whose kwargs carry object data under "data"
+_DATA_OPS = frozenset({"write_full", "write", "append"})
+
+
 @dataclass
 class MOSDOp(Message):
-    """Client op (reference MOSDOp): ops are (opname, kwargs) pairs."""
+    """Client op (reference MOSDOp): ops are (opname, kwargs) pairs.
+    The ``data`` of a write verb is offered to the frame out of band
+    (``messenger.oob``)."""
 
     reqid: Tuple[str, int] = ("", 0)
     pgid: Optional[PGid] = None
@@ -197,9 +213,18 @@ class MOSDOp(Message):
     # and sub-ops inherit it so replicas shed dead work too
     deadline: Optional[float] = None
 
+    def __reduce_ex__(self, protocol):
+        ops = [(verb, {**kw, "data": oob(kw["data"], protocol)})
+               if verb in _DATA_OPS and "data" in kw else (verb, kw)
+               for verb, kw in self.ops]
+        return reduce_with(self, ops=ops)
+
 
 @dataclass
 class MOSDOpReply(Message):
+    """``data`` is one op's output or, for a vector of ops, the list of
+    theirs: read bytes are offered to the frame out of band."""
+
     reqid: Tuple[str, int] = ("", 0)
     result: int = 0
     data: Any = None
@@ -208,6 +233,23 @@ class MOSDOpReply(Message):
     # ambiguous (a cls lock EBUSY is an op RESULT to surface, not a
     # congestion signal to retry)
     throttled: bool = False
+
+    def __reduce_ex__(self, protocol):
+        data = self.data
+        if type(data) is list:
+            data = [oob(out, protocol) for out in data]
+        return reduce_with(self, data=oob(data, protocol))
+
+    def own_data(self) -> None:
+        """The receiver's door: callers of the client API get ``bytes``,
+        so a ``data`` that arrived as a view of the frame is copied out
+        here, once, and the frame is let go."""
+        data = self.data
+        if type(data) is memoryview:
+            self.data = bytes(data)
+        elif type(data) is list:
+            self.data = [bytes(out) if type(out) is memoryview else out
+                         for out in data]
 
 
 @dataclass
@@ -303,7 +345,7 @@ class MOSDRepOpReply(Message):
 
 
 @dataclass
-class MOSDECSubOpWrite(Message):
+class MOSDECSubOpWrite(_DataOutOfBand, Message):
     """Shard write (reference MOSDECSubOpWrite, ECBackend.cc:921).
 
     chunk_off/shard_size carry the RMW sub-range: data lands at chunk_off
@@ -375,7 +417,7 @@ class MOSDECSubOpRead(Message):
 
 
 @dataclass
-class MOSDECSubOpReadReply(Message):
+class MOSDECSubOpReadReply(_DataOutOfBand, Message):
     reqid: Tuple[str, int] = ("", 0)
     result: int = 0
     shard: int = -1
@@ -388,7 +430,7 @@ class MOSDECSubOpReadReply(Message):
 
 
 @dataclass
-class MOSDPGPush(Message):
+class MOSDPGPush(_DataOutOfBand, Message):
     """Recovery push (reference push/pull recovery, ReplicatedBackend).
     op="push" writes the object; op="delete" removes it (a logged delete
     replayed onto a stale member)."""

@@ -5,7 +5,8 @@ Messenger.h, Dispatcher.h; AsyncMessenger event loops): entity-named
 endpoints, per-peer Connections with ordered delivery and reconnect,
 dispatchers receiving typed messages.  Transport is asyncio TCP on
 loopback (the reference's tier-3 standalone tests run the same way:
-N daemons x 1 host over real sockets).  Frames are length-prefixed and
+N daemons x 1 host over real sockets; every frame crosses a socket,
+also between daemons of one process).  Frames are length-prefixed and
 typed: ordinary messages are pickles — an internal trust boundary, like
 the reference's cephx-signed native encoding is within a cluster —
 while the cephx handshake frames use FIXED struct encodings so that no
@@ -14,10 +15,34 @@ frames on a connection without a session key are rejected outright).
 
 Integrity (reference cephx message signing, src/auth/cephx/): when the
 messenger holds a cluster secret, every frame carries a truncated
-HMAC-SHA256 over the payload; receivers verify before unpickling and
+HMAC-SHA256 over the payload (the pickle AND every out-of-band
+buffer); receivers verify before unpickling and
 reset the connection on mismatch, so a byte-flipped or forged frame can
 never reach a dispatcher.  auth "none" (no secret) stays the default,
 like the reference's auth_supported=none dev mode.
+
+Copies (PR 29).  A frame is ``<u32 len><type><body>``.  Sending: the
+message is pickled with protocol 5; a field that carries object data
+(``messages.py`` says which, through ``oob``) and is read-only and at
+least ``_OOB_MIN`` long stays OUT of the pickle, and header, pickle,
+those buffers and the signature go to the transport as separate
+buffers (``writelines``: one ``sendmsg``).  A payload byte is not
+copied in user space on the sending side; the replay buffer keeps
+references.  Receiving: ``_FrameStream`` has the transport
+``recv_into`` one ``bytearray`` per large frame, and verification,
+unpickling and the message's out-of-band fields are all views of that
+buffer.  The ONE user-space copy a payload byte takes per hop is its
+consumer's, at its own door: ``Transaction.write`` / ``write_planar``
+(``store.py``: the store owns what it keeps), ``MOSDOpReply.own_data``
+(the client API returns ``bytes``), the encode tick's fill of its host
+batch.  Exceptions, all bounded: a frame that fits the
+``_RECV_SCRATCH`` buffer (256 KiB) is read into it and cut out of it
+(one more copy; for these a read less is worth more than a copy
+less), as is the head of a large frame that came in the same read as
+its length prefix (at most ``_RECV_PEEK`` = 4 KiB within a run of
+large frames); a buffer under ``_OOB_MIN``, or one
+that could change under the replay buffer (``bytearray``, a writable
+array), is copied into the pickle and out of it as before.
 
 Reliability (reference AsyncConnection reconnect/replay semantics):
 outgoing traffic runs over per-peer SESSIONS with monotonically
@@ -32,6 +57,8 @@ osd-osd policy replaying out_q after a session reset.
 from __future__ import annotations
 
 import asyncio
+import collections
+import copyreg
 import itertools
 import pickle
 import struct
@@ -45,27 +72,58 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ceph_tpu.cluster.optracker import mark_current
 from ceph_tpu.utils.lockdep import DepLock
+from ceph_tpu.utils.perf import KERNELS
 
 Addr = Tuple[str, int]
 
 _SID = itertools.count(1)
 
-# stream buffer limit: asyncio's 64 KiB default pauses/resumes the
-# transport several times inside EVERY 1 MiB data frame (flow-control
-# churn per sub-write); sized to hold a whole large frame.  Socket
-# buffers get the same treatment so a burst of shard sub-writes drains
-# in few syscalls (TCP_NODELAY is asyncio's default already).
+# how many bytes of complete, not yet consumed frames a connection holds
+# before it stops reading its socket (the read loop resumes it as it
+# takes frames): room for a few large frames, so a burst of shard
+# sub-writes is received while the one before is in dispatch.  Socket
+# buffers are sized alike so the burst drains in few syscalls
+# (TCP_NODELAY is asyncio's default already).
 _STREAM_LIMIT = 4 << 20
 _SOCK_BUF = 2 << 20
+# the buffer a connection reads into while it is between large frames.
+# A read takes whatever the socket holds, up to the room that is left:
+# length prefixes and every frame that fits (acks, pings, replies, the
+# 64 KiB cell's ops, sub-writes and client batches) are cut out of it,
+# as many as the read brought, and a frame that fits but has not
+# arrived whole waits in it for the next read.  For these a turn of the
+# loaded loop costs more than a copy: a first version that gave every
+# split frame a buffer of its own took a read more per frame and read
+# 3 ms more `wire_ms` and -2.6% on the 64 KiB cell (PR 29).  Only a
+# frame that CANNOT fit gets a buffer of its own (_FrameStream), whose
+# body takes several reads anyway.  Right after such a frame only
+# _RECV_PEEK bytes are offered: such frames come in runs (a data lane's
+# sub-writes one way, its acks the other), and what is read beside the
+# next one's length prefix is moved once more
+_RECV_SCRATCH = 256 << 10
+_RECV_PEEK = 4 << 10
+# a length prefix over this is a corrupt or hostile one, not a frame: a
+# frame's buffer is allocated when its prefix is read, before any of it
+# has arrived (the largest frames are client batches of 16 x 4 MiB)
+_MAX_FRAME = 1 << 30
+# a buffer at least this long leaves the pickle and rides the frame out
+# of band (oob, _encode).  The crossing point on a scratch micro-run
+# (CPU dev host; one sub-write per frame, send + receive + the store's
+# copy): out of band costs ~2 us more per frame in header, PickleBuffer
+# and view slices, which the two saved copies give back between 48 and
+# 64 KiB (+0.2 us at 48 KiB, -0.8 us at 64 KiB, -5 us at 128 KiB, -360 us
+# at 1 MiB).  The 64 KiB cell's client ops sit on it, its 32 KiB
+# sub-writes stay in band.
+_OOB_MIN = 64 << 10
 # how long a closing endpoint waits for its transport to flush before it
 # aborts it (Connection.close, Messenger.shutdown)
 _CLOSE_WAIT_S = 1.0
 
 
-def _tune_socket(writer) -> None:
+def _tune_socket(stream: "_FrameStream") -> None:
     import socket as _socket
 
-    sock = writer.get_extra_info("socket")
+    sock = stream.transport.get_extra_info("socket")
     if sock is None:
         return
     try:
@@ -73,6 +131,191 @@ def _tune_socket(writer) -> None:
         sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_RCVBUF, _SOCK_BUF)
     except OSError:  # pragma: no cover - exotic transports
         pass
+
+
+class _FrameStream(asyncio.BufferedProtocol):
+    """One TCP connection, both halves, as the messenger uses it:
+    ``read_frame`` / ``write`` + ``drain`` / ``close`` + ``wait_closed``.
+
+    Receive: the transport ``recv_into``s the buffer ``get_buffer``
+    hands it.  A frame too large for the scratch buffer gets ONE
+    ``bytearray(n)`` of its own and the socket is read straight into
+    it until it is full: no growing buffer, no slice copy, no memmove.
+    Between such frames reads go to the fixed scratch buffer, out of
+    which the length prefixes and the frames that fit are cut (one copy
+    each, and a partial one is moved to the front first when frames
+    before it were taken; of a large frame, the head that came in the
+    same read as its length prefix is moved so: at most ``_RECV_PEEK``
+    bytes when the frame before it was large too).
+    Complete frames queue for ``read_frame``; past ``_STREAM_LIMIT``
+    queued bytes the socket is not read until the read loop has taken
+    some, so a reader that waits (for a ``Throttle``, in dispatch)
+    stops the drain and TCP pushes back on the peer.
+
+    Send: ``write`` hands a frame's parts to the transport as they are
+    (``writelines``: one ``sendmsg``, no joined copy); ``drain`` waits
+    while the transport is over its high-water mark."""
+
+    def __init__(self, on_connect=None):
+        self._loop = asyncio.get_running_loop()
+        self._on_connect = on_connect
+        self.transport: Optional[asyncio.Transport] = None
+        self._scratch = bytearray(_RECV_SCRATCH)
+        self._have = 0                       # unparsed bytes in scratch
+        self._peek = False                   # the last frame was large
+        self._frame: Optional[bytearray] = None   # the frame being filled
+        self._pos = 0
+        self._frames: collections.deque = collections.deque()
+        self._queued = 0
+        self._reader: Optional[asyncio.Future] = None
+        self._error: Optional[Exception] = None
+        self._write_paused = False
+        self._drainers: collections.deque = collections.deque()
+        # done once connection_lost ran
+        self._closed = self._loop.create_future()
+
+    # -- transport callbacks ------------------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if self._on_connect is not None:
+            self._accept_task = self._loop.create_task(
+                self._on_connect(self))
+
+    def get_buffer(self, sizehint: int):
+        if self._frame is not None:
+            return memoryview(self._frame)[self._pos:]
+        if self._peek and self._have < 4:
+            return memoryview(self._scratch)[
+                self._have:self._have + _RECV_PEEK]
+        return memoryview(self._scratch)[self._have:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if self._error is not None:
+            return      # a broken stream frames nothing more
+        if self._frame is not None:
+            self._pos += nbytes
+            if self._pos == len(self._frame):
+                frame, self._frame = self._frame, None
+                self._peek = True
+                self._deliver(frame)
+            return
+        have = self._have + nbytes
+        scratch = memoryview(self._scratch)
+        off = 0
+        while have - off >= 4:
+            (n,) = struct.unpack_from("<I", scratch, off)
+            if not 1 <= n <= _MAX_FRAME:
+                self._fail(ConnectionError(f"bad frame length {n}"))
+                return
+            end = off + 4 + n
+            if end <= have:
+                self._peek = False
+                self._deliver(bytes(scratch[off + 4:end]))
+                off = end
+                continue
+            if 4 + n > len(scratch):
+                # it cannot fit: the rest goes straight into its own
+                # buffer
+                got = have - off - 4
+                self._frame = bytearray(n)
+                self._frame[:got] = scratch[off + 4:have]
+                self._pos = got
+                off = have
+            break
+        if off:
+            # what is left (a split prefix, a frame that fits and is not
+            # whole yet) goes to the front: room for the rest of it
+            scratch[:have - off] = scratch[off:have]
+        self._have = have - off
+
+    def _deliver(self, frame) -> None:
+        self._frames.append(frame)
+        self._queued += len(frame)
+        if self._queued >= _STREAM_LIMIT:
+            self.transport.pause_reading()      # no-op while paused
+        self._wake_reader()
+
+    def _wake_reader(self) -> None:
+        if self._reader is not None and not self._reader.done():
+            self._reader.set_result(None)
+
+    def _fail(self, exc: Exception) -> None:
+        if self._error is None:
+            self._error = exc
+        self._wake_reader()
+
+    def eof_received(self):
+        self._fail(ConnectionResetError("connection closed by peer"))
+        return False    # the transport closes itself
+
+    def connection_lost(self, exc) -> None:
+        self._closed.set_result(None)
+        self._fail(exc if isinstance(exc, ConnectionError)
+                   else ConnectionResetError("connection lost"))
+        self._wake_drainers()
+
+    def pause_writing(self) -> None:
+        self._write_paused = True
+
+    def resume_writing(self) -> None:
+        self._write_paused = False
+        self._wake_drainers()
+
+    def _wake_drainers(self) -> None:
+        for waiter in self._drainers:
+            if not waiter.done():
+                waiter.set_result(None)
+
+    # -- what the messenger calls -------------------------------------------
+
+    async def read_frame(self):
+        """The next complete frame (type byte first, length prefix
+        off): a ``bytearray`` of its own, or ``bytes`` cut out of the
+        scratch buffer.  Frames already received are handed out before
+        the connection's end is raised."""
+        while not self._frames:
+            if self._error is not None:
+                raise self._error
+            self._reader = self._loop.create_future()
+            try:
+                await self._reader
+            finally:
+                self._reader = None
+        frame = self._frames.popleft()
+        self._queued -= len(frame)
+        if self._queued < _STREAM_LIMIT:
+            self.transport.resume_reading()     # no-op unless paused
+        return frame
+
+    def write(self, parts: list) -> None:
+        # a closing transport takes nothing more (as StreamWriter.write
+        # drops it); drain() is where the sender learns of it
+        if not self.transport.is_closing():
+            self.transport.writelines(parts)
+
+    async def drain(self) -> None:
+        if self.transport.is_closing():
+            # let connection_lost run, so that a write into a transport
+            # that is going away surfaces here and not a frame later
+            await asyncio.sleep(0)
+        while self._write_paused and not self._closed.done():
+            waiter = self._loop.create_future()
+            self._drainers.append(waiter)
+            try:
+                # flow control, not an RPC: the transport's
+                # resume_writing or connection_lost ends it
+                await waiter  # graftlint: ignore[rpc-timeout]
+            finally:
+                self._drainers.remove(waiter)
+        if self._closed.done():
+            raise ConnectionResetError("connection lost")
+
+    def close(self) -> None:
+        self.transport.close()
+
+    async def wait_closed(self) -> None:
+        await asyncio.shield(self._closed)
 
 
 @dataclass(frozen=True)
@@ -148,7 +391,10 @@ class _Session:
     def __init__(self):
         self.conn: Optional["Connection"] = None
         self.seq = 0
-        self.unacked: "OrderedDict[int, bytes]" = OrderedDict()
+        # seq -> the frame as _encode made it (pickle, out-of-band
+        # buffers): unsigned, so a replay signs with the new
+        # connection's key
+        self.unacked: "OrderedDict[int, _Frame]" = OrderedDict()
         self.overflowed = False
         # set by a chaos frame drop: NO later frame may go out until the
         # tail is replayed — the peer's acks are CUMULATIVE (ack of N
@@ -161,7 +407,7 @@ class _Session:
         # the bare attr `lock`
         self.order_lock = DepLock("messenger.session")
 
-    def buffer(self, seq: int, frame: bytes) -> None:
+    def buffer(self, seq: int, frame: "_Frame") -> None:
         self.unacked[seq] = frame
         while len(self.unacked) > self.MAX_UNACKED:
             # cannot trim silently and still promise at-least-once: mark
@@ -178,12 +424,11 @@ class _Session:
 
 
 class Connection:
-    def __init__(self, messenger: "Messenger", reader, writer,
+    def __init__(self, messenger: "Messenger", stream: _FrameStream,
                  peer: Optional[EntityName] = None,
                  peer_addr: Optional[Addr] = None):
         self.messenger = messenger
-        self.reader = reader
-        self.writer = writer
+        self.stream = stream
         self.peer = peer
         self.peer_addr = peer_addr
         self._send_lock = DepLock("messenger.conn_send")
@@ -214,24 +459,12 @@ class Connection:
             hs = _encode_hs(msg)
             if hs is not None:
                 # handshake: fixed struct, pre-session, unsigned
-                bufs = [struct.pack("<I", len(hs)), hs]
+                parts = [struct.pack("<I", len(hs)), hs]
             else:
-                payload = pickle.dumps(msg)
-                secret = self._sign_key()
-                sig = _sign(secret, payload) if secret is not None \
-                    else b""
-                # zero-copy framing: header/payload/signature go to the
-                # transport as separate buffers — a 1 MiB payload is
-                # never re-materialized into a fresh frame bytes
-                bufs = [struct.pack("<IB",
-                                    1 + len(payload) + len(sig),
-                                    _FT_MSG), payload]
-                if sig:
-                    bufs.append(sig)
+                parts = _frame_parts(self._sign_key(), _encode(msg))
             try:
-                for b in bufs:
-                    self.writer.write(b)
-                await self.writer.drain()
+                self.stream.write(parts)
+                await self.stream.drain()
             except (ConnectionError, RuntimeError):
                 self.closed = True
                 raise
@@ -246,11 +479,11 @@ class Connection:
         endpoint still owed were void anyway."""
         self.closed = True
         try:
-            self.writer.close()
-            await asyncio.wait_for(self.writer.wait_closed(),
+            self.stream.close()
+            await asyncio.wait_for(self.stream.wait_closed(),
                                    _CLOSE_WAIT_S)
         except asyncio.TimeoutError:
-            self.writer.transport.abort()
+            self.stream.transport.abort()
         except (ConnectionError, OSError, RuntimeError):
             pass  # best-effort close of an already-dying transport
 
@@ -321,12 +554,115 @@ SIG_LEN = 16
 # pickled Message (signed when a key is bound); types 1-3 are the cephx
 # handshake in FIXED struct encodings, so no unauthenticated byte ever
 # reaches the pickle deserializer (the r4 advisor's high finding: the
-# old handshake pickled first and authenticated after).
-_FT_MSG, _FT_AUTH, _FT_AUTH_REQ, _FT_AUTH_REPLY = 0, 1, 2, 3
+# old handshake pickled first and authenticated after).  Type 4 is a
+# Message whose large buffers ride out of band of its pickle:
+#   <u16 nbufs><u32 pickle_len><u32 buf_len>*nbufs <pickle> <buf>* [sig]
+# signed over everything from the type byte to the last buffer, so the
+# type, the lengths and every payload byte are under the signature.
+_FT_MSG, _FT_AUTH, _FT_AUTH_REQ, _FT_AUTH_REPLY, _FT_MSG_OOB = 0, 1, 2, 3, 4
+
+# a message as it is framed: (pickle, the buffers it refers to in order).
+# No buffers: an in-band frame (type 0), the pickle and nothing else.
+_Frame = Tuple[bytes, tuple]
 
 
-def _sign(secret: bytes, payload: bytes) -> bytes:
-    return _hmac.new(secret, payload, hashlib.sha256).digest()[:SIG_LEN]
+def _sign(secret: bytes, *parts) -> bytes:
+    mac = _hmac.new(secret, digestmod=hashlib.sha256)
+    for part in parts:
+        mac.update(part)
+    return mac.digest()[:SIG_LEN]
+
+
+def oob(data, protocol: int):
+    """What a message's ``__reduce_ex__`` puts in place of a field that
+    carries object data: a ``PickleBuffer`` over it when it is worth
+    offering to the frame (pickle protocol 5: ``bytes`` of at least
+    ``_OOB_MIN``, or a ``memoryview``, which is how such a field arrives
+    and which no protocol pickles as it is), else the field itself.
+    Whether the buffer then leaves the pickle is ``_encode``'s call; a
+    pickler without a buffer callback writes it in band as ``bytes``."""
+    kind = type(data)
+    if kind is memoryview:
+        return pickle.PickleBuffer(data) if protocol >= 5 else bytes(data)
+    if kind is bytes and protocol >= 5 and len(data) >= _OOB_MIN:
+        return pickle.PickleBuffer(data)
+    return data
+
+
+def reduce_with(msg: "Message", **fields):
+    """``object.__reduce_ex__``'s result for a message (new object, then
+    its ``__dict__``) with ``fields`` in place of the attributes of the
+    same names: the message itself is left as it is."""
+    return copyreg.__newobj__, (type(msg),), {**msg.__dict__, **fields}
+
+
+def _encode(msg: "Message") -> _Frame:
+    """Pickle ``msg``.  A buffer that is offered (``oob``, or numpy's own
+    protocol-5 reduce) leaves the pickle only if it is read-only and at
+    least ``_OOB_MIN`` long: the frame stays in the replay buffer and
+    may go out again, so it must never carry bytes that can change
+    after the send returned; anything else is copied into the pickle
+    here, as before."""
+    bufs = []
+
+    def in_band(buf: pickle.PickleBuffer) -> bool:
+        raw = buf.raw()
+        if raw.readonly and raw.nbytes >= _OOB_MIN:
+            bufs.append(raw)
+            return False
+        return True
+
+    payload = pickle.dumps(msg, protocol=5, buffer_callback=in_band)
+    out = sum(len(b) for b in bufs)
+    KERNELS.inc("msgr_frame_bytes", len(payload) + out)
+    if out:
+        KERNELS.inc("msgr_oob_bytes", out)
+    return payload, tuple(bufs)
+
+
+def _frame_parts(key: Optional[bytes], frame: _Frame) -> list:
+    """The frame as the list of buffers that goes to the transport:
+    header, pickle, the out-of-band buffers as they are, signature.
+    Nothing is joined, so a payload byte is not copied on its way
+    out."""
+    payload, bufs = frame
+    sig_len = SIG_LEN if key is not None else 0
+    if not bufs:
+        parts = [struct.pack("<IB", 1 + len(payload) + sig_len, _FT_MSG),
+                 payload]
+        if key is not None:
+            parts.append(_sign(key, payload))
+        return parts
+    lens = [len(b) for b in bufs]
+    head = struct.pack(
+        f"<IBHI{len(bufs)}I",
+        7 + 4 * len(bufs) + len(payload) + sum(lens) + sig_len,
+        _FT_MSG_OOB, len(bufs), len(payload), *lens)
+    parts = [head, payload, *bufs]
+    if key is not None:
+        parts.append(_sign(key, memoryview(head)[4:], *parts[1:]))
+    return parts
+
+
+def _decode_oob(body: memoryview) -> "Message":
+    """Unpickle a type-4 frame's body (type byte and signature off,
+    ALREADY verified): the buffers reach the message as read-only views
+    of the frame's own buffer, which they keep alive."""
+    try:
+        nbufs, plen = struct.unpack_from("<HI", body)
+        lens = struct.unpack_from(f"<{nbufs}I", body, 6)
+    except struct.error:
+        raise ConnectionError("malformed out-of-band frame")
+    pos = 6 + 4 * nbufs
+    if pos + plen + sum(lens) != len(body):
+        raise ConnectionError("malformed out-of-band frame")
+    payload = body[pos:pos + plen]
+    pos += plen
+    views = []
+    for n in lens:
+        views.append(body[pos:pos + n])
+        pos += n
+    return pickle.loads(payload, buffers=views)
 
 
 def _encode_hs(msg: Message) -> Optional[bytes]:
@@ -448,14 +784,19 @@ class Messenger:
         self.dispatchers.append(d)
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> Addr:
-        self._server = await asyncio.start_server(
-            self._accept, host, port, limit=_STREAM_LIMIT)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: _FrameStream(on_connect=self._accept), host, port)
         self.my_addr = self._server.sockets[0].getsockname()[:2]
         return self.my_addr
 
-    async def _accept(self, reader, writer) -> None:
-        _tune_socket(writer)
-        conn = Connection(self, reader, writer)
+    async def _open(self, addr: Addr) -> _FrameStream:
+        _, stream = await asyncio.get_running_loop().create_connection(
+            _FrameStream, addr[0], addr[1])
+        return stream
+
+    async def _accept(self, stream: _FrameStream) -> None:
+        _tune_socket(stream)
+        conn = Connection(self, stream)
         if self._closing:
             # a peer raced our shutdown: refuse, or the read loop would
             # keep Server.wait_closed() (which since py3.12 awaits every
@@ -472,16 +813,16 @@ class Messenger:
     async def _read_loop(self, conn: Connection) -> None:
         try:
             while True:
-                hdr = await conn.reader.readexactly(4)
-                (n,) = struct.unpack("<I", hdr)
-                if n < 1:
-                    raise ConnectionError("empty frame")
-                frame = await conn.reader.readexactly(n)
-                # memoryview slicing: verification, signature strip, and
-                # unpickle all run on views of the one received buffer —
-                # no per-frame payload re-materialization (round 11)
-                ftype, payload = frame[0], memoryview(frame)[1:]
-                if ftype != _FT_MSG:
+                frame = await conn.stream.read_frame()
+                n = len(frame)
+                # verification, signature strip and unpickle all run on
+                # views of the buffer the socket was read into; an
+                # out-of-band field of the message is one more such
+                # view, so a payload byte has not been copied in user
+                # space when the message reaches its dispatcher
+                view = memoryview(frame)
+                ftype, payload = frame[0], view[1:]
+                if ftype not in (_FT_MSG, _FT_MSG_OOB):
                     # handshake frames: fixed struct decode, no pickle
                     # (tiny; decoded from a plain bytes copy)
                     msg = _decode_hs(ftype, bytes(payload))
@@ -499,13 +840,18 @@ class Messenger:
                     is not None else self.secret
                 if verify_key is not None:
                     # verify BEFORE unpickling: unauthenticated bytes
-                    # must never reach the deserializer
+                    # must never reach the deserializer.  An out-of-band
+                    # frame is signed from its type byte on (pickle,
+                    # lengths and every buffer), an in-band one over
+                    # its pickle: a flipped type byte fails both
+                    signed = view[:-SIG_LEN] if ftype == _FT_MSG_OOB \
+                        else payload[:-SIG_LEN]
                     if len(payload) < SIG_LEN or not _hmac.compare_digest(
-                            _sign(verify_key, payload[:-SIG_LEN]),
-                            payload[-SIG_LEN:]):
+                            _sign(verify_key, signed), view[-SIG_LEN:]):
                         raise ConnectionError("bad message signature")
                     payload = payload[:-SIG_LEN]
-                msg = pickle.loads(payload)
+                msg = _decode_oob(payload) if ftype == _FT_MSG_OOB \
+                    else pickle.loads(payload)
                 if conn.peer is None:
                     conn.peer = msg.src
                 if msg.trace is not None:
@@ -529,8 +875,10 @@ class Messenger:
                 pol = self.policy_for(conn)
                 thr = pol.throttle if pol is not None else None
                 if thr is not None:
-                    # byte-budget backpressure: waiting here stops this
-                    # socket's drain, pushing TCP backpressure to the peer
+                    # byte-budget backpressure: while this waits no
+                    # frame is taken from the stream, which stops reading
+                    # its socket once _STREAM_LIMIT bytes of frames
+                    # queue: TCP backpressure reaches the peer
                     if await thr.acquire(n) and msg.trace is not None:
                         # the wait was real: stamp it so attribution
                         # books the delta as throttle_wait, not wire
@@ -552,8 +900,7 @@ class Messenger:
                     if thr is not None and \
                             not getattr(msg, "_throttle_held", False):
                         await thr.release(n)
-        except (asyncio.IncompleteReadError, ConnectionError,
-                asyncio.CancelledError):
+        except (ConnectionError, asyncio.CancelledError):
             # actually CLOSE the socket (not just flag it): a signature
             # mismatch must tear the TCP stream down so the peer's session
             # sees the failure and reconnect+replay engages, instead of
@@ -615,9 +962,8 @@ class Messenger:
         proof = _hmac.new(self.auth.entity_secret,
                           b"authreq:" + self.auth.entity.encode() + nonce,
                           hashlib.sha256).digest()[:SIG_LEN]
-        reader, writer = await asyncio.open_connection(
-            mon_addr[0], mon_addr[1], limit=_STREAM_LIMIT)
-        conn = Connection(self, reader, writer, peer_addr=tuple(mon_addr))
+        conn = Connection(self, await self._open(mon_addr),
+                          peer_addr=tuple(mon_addr))
         fut = asyncio.get_event_loop().create_future()
         self._auth_waiters[id(conn)] = fut
         task = asyncio.get_event_loop().create_task(self._read_loop(conn))
@@ -648,10 +994,9 @@ class Messenger:
         conn = lane.get(tuple(addr))
         if conn is not None and not conn.closed:
             return conn
-        reader, writer = await asyncio.open_connection(
-            addr[0], addr[1], limit=_STREAM_LIMIT)
-        _tune_socket(writer)
-        conn = Connection(self, reader, writer, peer_addr=tuple(addr))
+        stream = await self._open(addr)
+        _tune_socket(stream)
+        conn = Connection(self, stream, peer_addr=tuple(addr))
         if self.auth is not None:
             # authorizer-first (reference connection handshake): present
             # the ticket before any session traffic; the session key
@@ -704,13 +1049,16 @@ class Messenger:
                 # so the buffered replay frame carries the same partial
                 # tick — the item loss is real, not racing replay
                 self.chaos.mutate_batch(msg)
-            payload = pickle.dumps(msg)
-            # buffer the UNSIGNED payload and sign at write time with the
+            frame = _encode(msg)
+            # buffer the UNSIGNED frame and sign at write time with the
             # connection's key: a cephx ticket renewal mints a new session
             # key for NEW connections, while frames replayed over a fresh
             # connection must carry the fresh key's signature (signing at
-            # buffer time would wedge the replay after every renewal)
-            sess.buffer(sess.seq, payload)
+            # buffer time would wedge the replay after every renewal).
+            # What is buffered are the pickle and REFERENCES to the
+            # out-of-band buffers, which are read-only (_encode): a
+            # replay sends the bytes the first send did
+            sess.buffer(sess.seq, frame)
             fate = None
             if self.chaos is not None:
                 fate = self.chaos.on_frame(addr)
@@ -743,7 +1091,7 @@ class Messenger:
                     self._track(
                         asyncio.get_event_loop().create_task(
                             self._late_send(sess, addr, sess.seq,
-                                            payload, fate.reorder)))
+                                            frame, fate.reorder)))
                     return
             try:
                 if sess.needs_replay:
@@ -753,12 +1101,12 @@ class Messenger:
                     await self._reconnect_replay(sess, addr)
                     return
                 conn = await self.connect(addr)
-                bufs = self._frame_bufs(conn, payload)
-                self._write_frame(conn, bufs)
+                parts = _frame_parts(conn._sign_key(), frame)
+                conn.stream.write(parts)
                 if fate is not None and fate.dup:
-                    self._write_frame(conn, bufs)  # duplicate delivery:
+                    conn.stream.write(parts)  # duplicate delivery:
                     # handlers are idempotent by contract — prove it
-                await conn.writer.drain()
+                await conn.stream.drain()
                 # flush boundary on the CURRENT op's timeline (sub-op
                 # fan-out runs under the op context; no-op otherwise)
                 mark_current("msgr:flushed")
@@ -789,7 +1137,7 @@ class Messenger:
             pass
 
     async def _late_send(self, sess: _Session, addr: Addr, seq: int,
-                         payload: bytes, delay: float) -> None:
+                         frame: _Frame, delay: float) -> None:
         """Chaos reorder: this frame goes out AFTER traffic that was
         sent later (ordered-delivery violation, deliberately).  A
         failure here is a DROP, and by then the cumulative ack of later
@@ -800,14 +1148,14 @@ class Messenger:
         await asyncio.sleep(delay)
         try:
             conn = await self.connect(addr)
-            self._write_frame(conn, self._frame_bufs(conn, payload))
-            await conn.writer.drain()
+            conn.stream.write(_frame_parts(conn._sign_key(), frame))
+            await conn.stream.drain()
         except (ConnectionError, OSError, RuntimeError):
             if self._closing:
                 return
             async with sess.order_lock:
                 if seq not in sess.unacked:
-                    sess.unacked[seq] = payload
+                    sess.unacked[seq] = frame
                     for s in sorted(sess.unacked):
                         sess.unacked.move_to_end(s)
                 sess.needs_replay = True
@@ -819,25 +1167,6 @@ class Messenger:
         from ceph_tpu.utils.tasks import track_task
 
         return track_task(self._tasks, task)
-
-    def _frame_bufs(self, conn: Connection, payload: bytes) -> list:
-        """Frame as a buffer list (header, payload, signature), written
-        sequentially: large payloads pass straight to the transport
-        instead of being copied into a fresh frame bytes per hop (the
-        round-11 zero-copy framing; replay buffers still hold only the
-        single pickled payload)."""
-        key = conn._sign_key()
-        sig = _sign(key, payload) if key is not None else b""
-        bufs = [struct.pack("<IB", 1 + len(payload) + len(sig),
-                            _FT_MSG), payload]
-        if sig:
-            bufs.append(sig)
-        return bufs
-
-    @staticmethod
-    def _write_frame(conn: Connection, bufs: list) -> None:
-        for b in bufs:
-            conn.writer.write(b)
 
     async def _reconnect_replay(self, sess: _Session, addr: Addr,
                                 retries: int = 3) -> None:
@@ -877,10 +1206,10 @@ class Messenger:
                 await old.close()
             try:
                 conn = await self.connect(addr)
-                for payload in sess.unacked.values():
-                    self._write_frame(conn, self._frame_bufs(conn,
-                                                             payload))
-                await conn.writer.drain()
+                for frame in sess.unacked.values():
+                    conn.stream.write(
+                        _frame_parts(conn._sign_key(), frame))
+                await conn.stream.drain()
                 sess.needs_replay = False
                 return
             except (ConnectionError, OSError, RuntimeError) as e:

@@ -247,11 +247,13 @@ class Objecter(Dispatcher):
             self._batch_reply_frames += 1
             self._batch_reply_items += len(msg.items)
             for item in msg.items:
+                item.own_data()
                 fut = self._inflight.pop(tuple(item.reqid), None)
                 if fut and not fut.done():
                     fut.set_result(item)
             return True
         if isinstance(msg, M.MOSDOpReply):
+            msg.own_data()
             fut = self._inflight.pop(tuple(msg.reqid), None)
             if fut and not fut.done():
                 fut.set_result(msg)

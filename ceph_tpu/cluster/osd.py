@@ -620,6 +620,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         if isinstance(msg, M.MOSDOpReply):
             # reply to one of OUR internal client ops (copy-from /
             # tier traffic): resolve the waiter
+            msg.own_data()
             fut = self._internal_inflight.pop(tuple(msg.reqid), None)
             if fut is not None and not fut.done():
                 fut.set_result(msg)

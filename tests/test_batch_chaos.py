@@ -82,6 +82,79 @@ def test_injector_none_with_only_batch_rates_off():
     assert inj is not None and inj.batch_item_drop == 0.3
 
 
+@pytest.mark.parametrize("fault", ["dup", "drop", "reorder"])
+def test_wire_fault_on_out_of_band_batch_frames(fault):
+    """Every frame of a run of 2 MiB sub-write batches meets the fault
+    (rate 1.0) on its way out.  The frame's parts list — pickle plus
+    references to the out-of-band buffers — is what ``dup`` writes
+    twice, what ``drop`` leaves in the replay buffer for the
+    retransmission, and what ``reorder`` hands to the late send: every
+    batch arrives at least once with the bytes it was sent with."""
+    import os
+
+    from ceph_tpu.cluster.messenger import (
+        Dispatcher, EntityName, Messenger)
+    from ceph_tpu.utils import Config
+    from ceph_tpu.utils.perf import KERNELS
+
+    async def scenario():
+        got = []
+
+        class Sink(Dispatcher):
+            async def ms_dispatch(self, conn, msg):
+                if isinstance(msg, M.MOSDECSubOpWriteBatch):
+                    got.append((msg.epoch, type(msg.items[0].data),
+                                bytes(msg.items[0].data)))
+                    return True
+                return False
+
+        rx = Messenger(EntityName("osd", 1))
+        rx.add_dispatcher(Sink())
+        addr = await rx.bind()
+        cfg = Config(chaos_seed=29, **{f"chaos_net_{fault}": 1.0})
+        tx = Messenger(EntityName("osd", 2), config=cfg)
+        assert tx.chaos is not None
+        counter = {"dup": "net_dups", "drop": "net_drops",
+                   "reorder": "net_reorders"}[fault]
+        before = _counters().get(counter, 0)
+        oob_before = KERNELS.get("msgr_oob_bytes")
+        datas = [os.urandom(2 << 20) for _ in range(5)]
+        try:
+            for i, data in enumerate(datas):
+                await tx.send_message(M.MOSDECSubOpWriteBatch(
+                    items=[M.MOSDECSubOpWrite(reqid=("c", i), shard=1,
+                                              data=data)],
+                    epoch=i), addr)
+            loop = asyncio.get_event_loop()
+            deadline = loop.time() + 20.0
+            want = 2 * len(datas) if fault == "dup" else len(datas)
+            while (len(got) < want or
+                   {e for e, _k, _d in got} != set(range(len(datas)))) \
+                    and loop.time() < deadline:
+                await asyncio.sleep(0.02)
+            assert {e for e, _k, _d in got} == set(range(len(datas)))
+            assert all(kind is memoryview and blob == datas[e]
+                       for e, kind, blob in got)
+            assert _counters()[counter] - before >= len(datas)
+            # framed once each: a duplicate, a replay or a late send
+            # writes the parts again, it does not encode again
+            assert KERNELS.get("msgr_oob_bytes") - oob_before == \
+                sum(len(d) for d in datas)
+            if fault == "dup":
+                assert len(got) >= 2 * len(datas)
+            if fault != "reorder":
+                firsts = []
+                for e, _k, _d in got:
+                    if e not in firsts:
+                        firsts.append(e)
+                assert firsts == sorted(firsts)
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
 # ------------------------------- sub-write batcher per-item semantics
 
 

@@ -400,8 +400,15 @@ def test_map_distribution_is_incremental():
             # full maps; the pool-create broadcasts must all be incremental
             assert perf.get("mon_inc_maps_sent", 0) >= 8, perf
             assert perf.get("mon_full_maps_sent", 0) <= 6, perf
-            # clients converge on the same epoch as the mon
-            await client.objecter._refresh_map()
+            # clients converge on the same epoch as the mon.  Converge-
+            # poll: an OSD's own request (up_thru after peering the new
+            # pools) can move the mon's epoch 25-75 ms after the last
+            # pool_create returned, between a refresh and the comparison
+            for _ in range(50):
+                await client.objecter._refresh_map()
+                if client.objecter.osdmap.epoch == cluster.mon.osdmap.epoch:
+                    break
+                await asyncio.sleep(0.05)
             assert client.objecter.osdmap.epoch == cluster.mon.osdmap.epoch
         finally:
             await cluster.stop()

@@ -6,10 +6,13 @@ toward idempotent handlers.
 """
 
 import asyncio
+import os
 
 from tests._flaky import contention_retry
 import pytest
 
+from ceph_tpu.cluster import messages as M
+from ceph_tpu.cluster import messenger as msgr
 from ceph_tpu.cluster.messenger import (
     Connection,
     Dispatcher,
@@ -17,6 +20,7 @@ from ceph_tpu.cluster.messenger import (
     Message,
     Messenger,
 )
+from ceph_tpu.utils.perf import KERNELS
 from dataclasses import dataclass, field
 from typing import List
 
@@ -27,23 +31,62 @@ class Num(Message):
 
 
 class Collector(Dispatcher):
+    """Numbers as they arrive; of a shard sub-write its shard number
+    (and in ``blobs`` what it carried); ``resets`` counts the
+    connections the messenger tore down."""
+
     def __init__(self):
         self.got: List[int] = []
+        self.blobs: List[tuple] = []
+        self.msgs: List[Message] = []
+        self.resets = 0
 
     async def ms_dispatch(self, conn: Connection, msg) -> bool:
         if isinstance(msg, Num):
             self.got.append(msg.n)
             return True
+        if isinstance(msg, M.MOSDECSubOpWrite):
+            self.got.append(msg.shard)
+            self.blobs.append((msg.shard, bytes(msg.data)))
+            self.msgs.append(msg)
+            return True
+        if isinstance(msg, (M.MOSDECSubOpWriteBatch, M.MOSDOp)):
+            self.msgs.append(msg)
+            return True
         return False
+
+    async def ms_handle_reset(self, conn: Connection) -> None:
+        self.resets += 1
+
+
+async def _until(cond, timeout: float = 10.0) -> bool:
+    """Converge-poll: ``cond()`` within ``timeout`` seconds."""
+    deadline = asyncio.get_event_loop().time() + timeout
+    while not cond() and asyncio.get_event_loop().time() < deadline:
+        await asyncio.sleep(0.02)
+    return cond()
+
+
+def _oob_bytes() -> int:
+    return KERNELS.get("msgr_oob_bytes")
 
 
 def run(coro):
     return asyncio.run(coro)
 
 
-def test_reconnect_replays_unacked_in_order():
+def _payload(i: int, size: int) -> bytes:
+    return bytes([i % 251]) * size
+
+
+@pytest.mark.parametrize("size", [0, 200_000],
+                         ids=["in_band", "out_of_band"])
+def test_reconnect_replays_unacked_in_order(size):
     """Kill the TCP connection mid-stream: every message still arrives,
-    in order (duplicates allowed — at-least-once), nothing lost."""
+    in order (duplicates allowed — at-least-once), nothing lost.  With
+    ``size`` the frames are sub-writes whose data rides out of band: the
+    replay buffer holds the pickle and references to the buffers, and
+    what is replayed are the bytes the first send carried."""
     async def scenario():
         rx = Messenger(EntityName("osd", 1))
         coll = Collector()
@@ -57,8 +100,10 @@ def test_reconnect_replays_unacked_in_order():
                     # hard-drop the transport under the sender's feet
                     conn = tx._out.get(tuple(addr))
                     if conn:
-                        conn.writer.close()
-                await tx.send_message(Num(n=i), addr)
+                        conn.stream.close()
+                await tx.send_message(
+                    M.MOSDECSubOpWrite(shard=i, data=_payload(i, size))
+                    if size else Num(n=i), addr)
             # converge-poll: reconnect + replay land asynchronously
             deadline = asyncio.get_event_loop().time() + 10.0
             while set(coll.got) < set(range(total)) and \
@@ -73,6 +118,11 @@ def test_reconnect_replays_unacked_in_order():
                 if not dedup or n > dedup[-1]:
                     dedup.append(n)
             assert dedup == list(range(total))
+            if size:
+                assert all(blob == _payload(i, size)
+                           for i, blob in coll.blobs), \
+                    [i for i, blob in coll.blobs
+                     if blob != _payload(i, size)]
         finally:
             await tx.shutdown()
             await rx.shutdown()
@@ -160,7 +210,7 @@ def test_ec_write_survives_connection_drops():
                     # sever every osd-to-osd connection in the cluster
                     for osd in cluster.osds.values():
                         for conn in list(osd.messenger._out.values()):
-                            conn.writer.close()
+                            conn.stream.close()
                 await io.write_full(oid, payloads[oid], timeout=60)
             for oid, data in payloads.items():
                 assert await io.read(oid, timeout=60) == data, oid
@@ -335,3 +385,417 @@ def test_byte_throttle_backpressure():
             await server.shutdown()
 
     asyncio.run(scenario())
+
+
+# ------------------------------------- frames without copies (PR 29)
+
+OOB = msgr._OOB_MIN
+
+
+async def _pair(**kw):
+    """A receiving and a sending messenger, the receiver bound."""
+    rx = Messenger(EntityName("osd", 1), **kw.get("rx", {}))
+    coll = Collector()
+    rx.add_dispatcher(coll)
+    addr = await rx.bind()
+    tx = Messenger(EntityName("osd", 2), **kw.get("tx", {}))
+    return rx, tx, coll, addr
+
+
+@pytest.mark.parametrize("size", [0, 1, OOB - 1, OOB, 4 << 20])
+def test_frame_round_trip_by_size(size):
+    """Bytes equal at every size; the data leaves the pickle only at or
+    over the threshold, and then arrives as a read-only view of the
+    buffer the frame was received into: structurally uncopied."""
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        try:
+            data = os.urandom(size)
+            before = _oob_bytes()
+            framed = KERNELS.get("msgr_frame_bytes")
+            await tx.send_message(M.MOSDECSubOpWrite(shard=3, data=data),
+                                  addr)
+            assert await _until(lambda: coll.msgs)
+            got = coll.msgs[0].data
+            assert got == data and len(got) == size
+            assert KERNELS.get("msgr_frame_bytes") - framed >= size
+            if size < OOB:
+                assert _oob_bytes() == before
+                assert type(got) is bytes
+                return
+            assert _oob_bytes() - before == size
+            assert type(got) is memoryview and got.readonly
+            # the view's owner is the frame: the pickle and the data in
+            # one buffer, longer than the data alone
+            assert len(got.obj) > size
+            if size > msgr._RECV_SCRATCH:
+                # a frame of its own buffer, filled by recv_into
+                assert type(got.obj) is bytearray
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def _shape_batch_many():
+    datas = [os.urandom(100_000 + i) for i in range(3)]
+    msg = M.MOSDECSubOpWriteBatch(items=[
+        M.MOSDECSubOpWrite(shard=i, data=d) for i, d in enumerate(datas)])
+    return msg, lambda m: [it.data for it in m.items], datas, \
+        [memoryview] * 3
+
+
+def _shape_batch_mixed():
+    datas = [os.urandom(100_000), b"tiny", os.urandom(70_000),
+             bytearray(os.urandom(100_000))]
+    msg = M.MOSDECSubOpWriteBatch(items=[
+        M.MOSDECSubOpWrite(shard=i, data=d) for i, d in enumerate(datas)])
+    # a bytearray can change after the send returned: it is copied into
+    # the pickle, whatever its length, and arrives as what it was
+    return msg, lambda m: [it.data for it in m.items], datas, \
+        [memoryview, bytes, memoryview, bytearray]
+
+
+def _shape_mosdop():
+    datas = [os.urandom(1 << 20), os.urandom(100_000)]
+    msg = M.MOSDOp(oid="o", ops=[
+        ("write_full", {"data": datas[0]}),
+        ("setxattr", {"name": "k", "value": datas[1]})])
+    return msg, lambda m: [m.ops[0][1]["data"], m.ops[1][1]["value"]], \
+        datas, [memoryview, bytes]
+
+
+@pytest.mark.parametrize("shape", [_shape_batch_many, _shape_batch_mixed,
+                                   _shape_mosdop],
+                         ids=["batch_many", "batch_mixed", "mosdop"])
+def test_frame_round_trip_by_shape(shape):
+    """Several out-of-band buffers in one frame, a mix with in-band
+    items, and an MOSDOp whose data sits in ``ops[i][1]["data"]``."""
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        try:
+            msg, fields, datas, kinds = shape()
+            before = _oob_bytes()
+            await tx.send_message(msg, addr)
+            assert await _until(lambda: coll.msgs)
+            got = fields(coll.msgs[0])
+            assert [type(g) for g in got] == kinds
+            assert all(g == d for g, d in zip(got, datas))
+            views = [g for g in got if type(g) is memoryview]
+            assert _oob_bytes() - before == sum(len(v) for v in views)
+            # one frame, one buffer: every view has the same owner
+            assert len({id(v.obj) for v in views}) == 1
+            # the sender's message is as it was
+            assert all(type(f) is type(d) for f, d in
+                       zip(fields(msg), datas))
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def _signed():
+    return {"rx": {"secret": b"k"}, "tx": {"secret": b"k"}}
+
+
+def _cephx():
+    from ceph_tpu.cluster.auth import CephxContext
+
+    master = b"m" * 32
+    return {"rx": {"auth": CephxContext("osd.1", master=master)},
+            "tx": {"auth": CephxContext("osd.2", master=master)}}
+
+
+@pytest.mark.parametrize("mode", [_signed, _cephx],
+                         ids=["signed", "cephx"])
+def test_flipped_out_of_band_byte_is_refused(mode):
+    """The signature covers every out-of-band buffer: one flipped bit
+    in a buffer is refused before dispatch and resets the connection,
+    as a flipped bit in a pickle is; the session then reconnects."""
+    async def scenario():
+        rx, tx, coll, addr = await _pair(**mode())
+        try:
+            await tx.send_message(
+                M.MOSDECSubOpWrite(shard=1, data=_payload(1, 200_000)),
+                addr)
+            assert await _until(lambda: coll.got == [1])
+            conn = await tx.connect(addr)
+            bad = M.MOSDECSubOpWrite(shard=666, data=_payload(2, 200_000))
+            bad.src = tx.name
+            parts = msgr._frame_parts(conn._sign_key(),
+                                      msgr._encode(bad))
+            assert parts[0][4] == msgr._FT_MSG_OOB and len(parts) == 4
+            flipped = bytearray(parts[2])
+            flipped[100_000] ^= 1
+            parts[2] = bytes(flipped)
+            conn.stream.write(parts)
+            assert await _until(lambda: coll.resets >= 1)
+            assert 666 not in coll.got
+            # the torn-down connection is replaced and traffic goes on
+            await tx.send_message(
+                M.MOSDECSubOpWrite(shard=3, data=_payload(3, 200_000)),
+                addr)
+            assert await _until(lambda: 3 in coll.got)
+            assert 666 not in coll.got
+            assert coll.blobs[-1] == (3, _payload(3, 200_000))
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+UNPICKLED: List[int] = []
+
+
+def _touch():
+    UNPICKLED.append(1)
+
+
+class _Boom:
+    def __reduce__(self):
+        return _touch, ()
+
+
+def test_out_of_band_frame_on_unauthenticated_cephx_connection():
+    """cephx: a connection that has not presented an authorizer may
+    send handshake frames only; an out-of-band data frame is refused
+    BEFORE any deserialization and the connection is closed."""
+    import pickle
+
+    async def scenario():
+        rx, tx, coll, addr = await _pair(**_cephx())
+        try:
+            frame = (pickle.dumps(_Boom(), protocol=5),
+                     (memoryview(b"x" * OOB),))
+            reader, writer = await asyncio.open_connection(*addr)
+            writer.writelines(msgr._frame_parts(None, frame))
+            await writer.drain()
+            # the server closes on us: EOF, not a reply
+            assert await asyncio.wait_for(reader.read(), 10.0) == b""
+            writer.close()
+            assert UNPICKLED == [] and coll.msgs == []
+            assert await _until(lambda: coll.resets >= 1)
+            # the control: deserialized, that pickle does leave its mark
+            pickle.loads(frame[0])
+            assert UNPICKLED == [1]
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def test_throttle_backpressure_stops_the_socket_drain():
+    """One connection, more bytes than the receiver's queue and both
+    socket buffers hold, a dispatcher that does not return: the stream
+    stops reading its socket, its queue stays bounded, and the SENDER
+    stalls in drain — TCP backpressure reached the peer.  When the
+    dispatcher lets go, everything arrives in order."""
+    from ceph_tpu.cluster.messenger import Policy, Throttle
+
+    async def scenario():
+        gate = asyncio.Event()
+        seen = []
+
+        class Slow(Dispatcher):
+            async def ms_dispatch(self, conn, msg):
+                if isinstance(msg, M.MOSDECSubOpWrite):
+                    seen.append(msg.shard)
+                    await gate.wait()
+                    return True
+                return False
+
+        rx = Messenger(EntityName("osd", 0))
+        rx.add_dispatcher(Slow())
+        rx.set_policy("client", Policy(throttle=Throttle(600_000)))
+        addr = await rx.bind()
+        tx = Messenger(EntityName("client", 1))
+        total, size = 64, 512 << 10      # 32 MiB
+        sent = []
+
+        async def send_all():
+            for i in range(total):
+                await tx.send_message(
+                    M.MOSDECSubOpWrite(shard=i, data=_payload(i, size)),
+                    addr)
+                sent.append(i)
+
+        task = asyncio.get_event_loop().create_task(send_all())
+        try:
+            assert await _until(lambda: seen == [0])
+            assert await _until(
+                lambda: rx._accepted and
+                not rx._accepted[0].stream.transport.is_reading())
+            # negative-condition window: nothing more may be admitted,
+            # read or sent while the budget is out
+            await asyncio.sleep(0.3)  # graftlint: ignore[fixed-sleep-in-tests]
+            stream = rx._accepted[0].stream
+            assert seen == [0]
+            assert not stream.transport.is_reading()
+            assert stream._queued < msgr._STREAM_LIMIT + size + 4096
+            assert not task.done() and len(sent) < total
+            gate.set()
+            await asyncio.wait_for(task, 30.0)
+            assert await _until(lambda: len(seen) == total, 30.0)
+            assert seen == list(range(total))
+        finally:
+            gate.set()
+            task.cancel()
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("verb", ["write", "write_planar"])
+def test_transaction_copies_what_it_is_given(verb):
+    """The store never aliases a frame: ``Transaction.write`` and
+    ``write_planar`` keep ``bytes`` of their own, so a view of a receive
+    buffer is let go and a later change of that buffer is not seen."""
+    from ceph_tpu.cluster.store import Transaction
+
+    frame = bytearray(os.urandom(4096))
+    view = memoryview(frame)[1024:3072].toreadonly()
+    want = bytes(view)
+    txn = Transaction()
+    if verb == "write":
+        txn.write("c", "o", 0, view)
+    else:
+        txn.write_planar("c", "o", 0, view, 256)
+    kept = txn.ops[0][4]
+    assert type(kept) is bytes and kept == want
+    frame[:] = bytes(4096)
+    assert kept == want
+
+
+def test_stored_object_does_not_alias_the_frame():
+    """End to end: a ``write_full`` whose data crossed the wire out of
+    band (client op and shard sub-writes alike) is stored as bytes the
+    store owns, on every shard holder, and reads back."""
+    async def scenario():
+        from ceph_tpu.cluster.vstart import _fast_config, start_cluster
+
+        cluster = await start_cluster(3, config=_fast_config())
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create(
+                "ec", "erasure", pg_num=4,
+                ec_profile={"plugin": "jerasure", "k": "2", "m": "1",
+                            "technique": "reed_sol_van"})
+            io = client.ioctx(pool)
+            data = os.urandom(1 << 20)
+            before = _oob_bytes()
+            await io.write_full("big", data)
+            # the client op (1 MiB) and two sub-writes (512 KiB each)
+            assert _oob_bytes() - before >= 2 << 20
+            held = 0
+            for osd in cluster.osds.values():
+                for objs in osd.store._colls.values():
+                    obj = objs.get("big")
+                    if obj is not None:
+                        held += 1
+                        assert type(obj.data) is bytearray
+                        assert len(obj.data) == 512 << 10
+            assert held == 3
+            got = await io.read("big")
+            assert type(got) is bytes and got == data
+        finally:
+            await cluster.stop()
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("cell_name", [
+    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16"])
+def test_wire_oob_share_reads_the_hand_worked_value(cell_name):
+    """8 GB framed of which 6 GB out of band: 75 %, through the accepted
+    ``counter_ratio`` reader; a program without the counters (the parent
+    commit) reads nothing and nothing raises."""
+    from benchmark.harness import layers
+    from benchmark.harness.loader import load_cell
+
+    cell = load_cell(cell_name)
+    name = "wire_oob_share.write"
+    for growth, want in (
+            ({"msgr_frame_bytes": 8_000_000_000,
+              "msgr_oob_bytes": 6_000_000_000}, pytest.approx(75.0)),
+            ({"msgr_frame_bytes": 1_000_000}, 0.0),
+            ({}, None)):
+        readings = layers.Readings(
+            config=cell.config, device_kind="TPU v5 lite", attribution={},
+            counters=growth, slice_counters={}, trace=None)
+        assert layers.read_metric(name, cell.per_layer[name],
+                                  readings) == want
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_frame_stream_reassembles_any_chunking(seed):
+    """The receiver alone, fed as a transport feeds it (``get_buffer``,
+    then ``buffer_updated`` with what arrived): frames of every kind —
+    tiny, split length prefixes, just fitting the scratch buffer, just
+    not fitting, large — in reads of random sizes come out whole, equal
+    and in order."""
+    import random
+    import struct
+
+    class Transport:
+        def pause_reading(self):
+            pass
+
+        def resume_reading(self):
+            pass
+
+    async def scenario():
+        rng = random.Random(seed)
+        scratch = msgr._RECV_SCRATCH
+        sizes = [1, 100, 70_000, 3, scratch - 4, 5, scratch - 3,
+                 300_000, 2, 2 << 20, 64, 64, scratch - 4, 40_000]
+        rng.shuffle(sizes)
+        frames = [rng.randbytes(n) for n in sizes]
+        wire = b"".join(struct.pack("<I", len(f)) + f for f in frames)
+        stream = msgr._FrameStream()
+        stream.transport = Transport()
+        pos = 0
+        while pos < len(wire):
+            buf = stream.get_buffer(-1)
+            assert len(buf) > 0
+            take = min(len(buf), len(wire) - pos,
+                       rng.choice([1, 3, 7, 1000, 70_000, 1 << 20]))
+            buf[:take] = wire[pos:pos + take]
+            stream.buffer_updated(take)
+            pos += take
+        got = [await stream.read_frame() for _ in frames]
+        assert [bytes(g) for g in got] == frames
+        # only what cannot fit the scratch buffer got one of its own
+        assert [type(g) is bytearray for g in got] == \
+            [4 + len(f) > scratch for f in frames]
+        assert stream._queued == 0 and not stream._frames
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("prefix", [0, msgr._MAX_FRAME + 1, 0xFFFFFFFF])
+def test_bad_length_prefix_closes_the_connection(prefix):
+    """An empty frame, or a length no frame has: nothing is allocated
+    for it, nothing behind it is framed, the connection is reset."""
+    import struct
+
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        try:
+            reader, writer = await asyncio.open_connection(*addr)
+            good = msgr._frame_parts(None, msgr._encode(Num(n=7)))
+            writer.write(struct.pack("<I", prefix) + b"".join(good))
+            await writer.drain()
+            assert await asyncio.wait_for(reader.read(), 10.0) == b""
+            writer.close()
+            assert await _until(lambda: coll.resets >= 1)
+            assert coll.got == []
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())

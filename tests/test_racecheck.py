@@ -23,6 +23,7 @@ from ceph_tpu.analysis.racecheck import (NULL_RACE, RaceTracker, _NullRace,
                                          race_run)
 from ceph_tpu.utils.lockdep import DepLock
 from ceph_tpu.utils.schedfuzz import SchedFuzzLoop, run_fuzzed
+from tests._flaky import contention_retry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -580,7 +581,12 @@ def test_planar_rewind_restores_attrs_and_version():
 # ------------------------------------------------------- the race smokes
 
 
+# the scenario's verdict waits a bounded time for recovery to converge:
+# under the suite's six workers that budget was missed once in seven
+# whole runs (PR 29: "obj1 unreadable", PG_RECOVERING at the verdict),
+# never in 55 runs of the test alone or ten at a time
 @pytest.mark.chaos
+@contention_retry()
 def test_race_smoke_batch_seeds():
     """Tier-1 dynamic gate: shrunk batch-smoke under the perturbed loop
     with the tracker armed, three seeds.  Seed 2 is the one that
