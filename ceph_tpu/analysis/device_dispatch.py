@@ -19,9 +19,8 @@ the coalescer module itself):
   (``self._compute(stripemod.encode_stripes, ...)`` — the dominant
   idiom: the executor hop does not change who pays the dispatch).
 
-Accepted remnants (the legacy ``osd_batch_tick_ops=0`` bisection path,
-the not-yet-coalesced read/recovery decodes) live in the suppression
-baseline, where removing one is a visible diff.
+An accepted remnant would live in the suppression baseline, where
+removing it is a visible diff; today there is none.
 """
 
 from __future__ import annotations

@@ -133,11 +133,11 @@ def repo_root() -> str:
 
 
 def default_paths(root: Optional[str] = None) -> List[str]:
-    """The whole-repo file set: the package, scripts, bench + entry, and
-    the test suite (minus the deliberately-bad lint corpus)."""
+    """The whole-repo file set: the package, scripts, the graft entry
+    and the test suite (minus the deliberately-bad lint corpus)."""
     root = root or repo_root()
     roots = [os.path.join(root, d) for d in ("ceph_tpu", "scripts", "tests")]
-    singles = [os.path.join(root, f) for f in ("bench.py", "__graft_entry__.py")]
+    singles = [os.path.join(root, "__graft_entry__.py")]
     out = []
     for r in roots:
         for dirpath, dirnames, filenames in os.walk(r):

@@ -2,11 +2,10 @@
 
 One shared ``PerfCounters`` registry, like the device-kernel ``KERNELS``
 registry in utils/perf.py: every injector increments it, every daemon's
-admin socket serves it via ``chaos report``, and bench.py checks it so a
-benchmark run that ate injected faults can never masquerade as a clean
-number.  ``chaos_total() == 0`` is the machine-checkable form of the
-no-op contract: with all injectors disabled, nothing in the hot path
-ever reaches an increment.
+admin socket serves it via ``chaos report``, so a run that ate injected
+faults can never masquerade as a clean number.  ``chaos_total() == 0``
+is the machine-checkable form of the no-op contract: with all injectors
+disabled, nothing in the hot path ever reaches an increment.
 """
 
 from __future__ import annotations

@@ -250,23 +250,20 @@ class ECBackendMixin:
         return await self._ec_commit_finish(st, token)
 
     async def _ec_write(self, pool: PGPool, st: PGState, oid: str,
-                        data: bytes, offset: Optional[int],
-                        snapc=None) -> int:
-        """Serial (full-PG-lock) EC write incl. the RMW sequence — the
-        ``osd_pipeline_writes=0`` fallback and the path for compound
-        read-modify callers that hold st.lock across multiple ops
-        (copy_from, rollback, EC truncate's read-then-rewrite).
-        Callers hold the PG-wide st.lock across the whole op, so
-        overlapping RMWs can never interleave.  The hot path uses
-        ``_ec_write_pipelined`` instead, which narrows the locks to the
-        ordered commit section."""
+                        data: bytes, snapc=None) -> int:
+        """Serial (full-PG-lock) EC full-object write: the path for
+        compound callers that hold st.lock across multiple ops
+        (copy_from, rollback, through ``_op_write_full``), so nothing
+        can interleave.  The hot path uses ``_ec_write_pipelined``
+        instead, which narrows the locks to the ordered commit
+        section."""
         codec = self._codec(pool)
         sinfo = self._sinfo(pool, codec)
         if not self._ec_acting_writeable(pool, codec, st):
             return -11
         shards, crcs, new_size, chunk_off, layout = \
             await self._ec_prepare_write(
-                pool, st, oid, data, offset, codec, sinfo)
+                pool, st, oid, data, None, codec, sinfo)
         try:
             token = await self._ec_commit_start(
                 pool, st, oid, new_size, shards, crcs, snapc, codec,
@@ -281,9 +278,8 @@ class ECBackendMixin:
         """The pure-compute half of an EC write: RMW read-merge (when
         offset is given) + coalesced encode.  Returns ``(shards, crcs,
         new_size, chunk_off, layout)``.  Shared verbatim by the serial
-        and pipelined paths so the two stay bit-identical by
-        construction (the tier-1 exactness gate compares their stored
-        bytes).  In planar mode the RMW read-half books the sanctioned
+        (compound) and pipelined paths so the two stay bit-identical by
+        construction.  In planar mode the RMW read-half books the sanctioned
         egress (inside the read coalescer) and the re-encode books the
         sanctioned ingest — the merge itself is logical bytes, which
         is the CLIENT's layout, not a shard layout conversion."""
@@ -424,32 +420,23 @@ class ECBackendMixin:
                     if subctx is not None:
                         sub.trace = dict(subctx)
                     subs.append((osd, sub))
-                if self.config.osd_batch_tick_ops > 0:
-                    # batched fan-out (round 11): same-tick sub-writes
-                    # for one peer share a frame; a failed send still
-                    # surfaces per sub-write, so the every-shard-durable
-                    # rule holds
-                    results = await asyncio.gather(
-                        *(self._sub_batcher.send(o, s) for o, s in subs),
-                        return_exceptions=True)
-                    for res in results:
-                        if isinstance(res, asyncio.CancelledError):
-                            # daemon stop / chaos crash mid-fan-out:
-                            # propagate — counting cancellation as a
-                            # peer send failure would swallow the
-                            # teardown (the swallowed-async-error bug
-                            # class graftlint now polices)
-                            raise res
-                        if isinstance(res, BaseException):
-                            send_failures += 1
-                            self._waiter_dec(reqid)
-                else:
-                    for osd, sub in subs:
-                        try:
-                            await self._send_osd(osd, sub)
-                        except (ConnectionError, OSError, RuntimeError):
-                            send_failures += 1
-                            self._waiter_dec(reqid)
+                # batched fan-out: same-tick sub-writes for one peer
+                # share a frame; a failed send still surfaces per
+                # sub-write, so the every-shard-durable rule holds
+                results = await asyncio.gather(
+                    *(self._sub_batcher.send(o, s) for o, s in subs),
+                    return_exceptions=True)
+                for res in results:
+                    if isinstance(res, asyncio.CancelledError):
+                        # daemon stop / chaos crash mid-fan-out:
+                        # propagate — counting cancellation as a peer
+                        # send failure would swallow the teardown (the
+                        # swallowed-async-error bug class graftlint
+                        # polices)
+                        raise res
+                    if isinstance(res, BaseException):
+                        send_failures += 1
+                        self._waiter_dec(reqid)
                 mark_current("ec_sub_write_sent")
         except BaseException:
             # frontier hygiene: a registered-but-unresolved entry would
@@ -518,15 +505,14 @@ class ECBackendMixin:
         """Encode one op's stripe range -> (shards, crcs-or-None,
         layout).
 
-        With ``osd_batch_tick_ops`` > 0 the encode rides the per-tick
-        coalescer (cluster/batcher.py): every same-profile write in the
-        tick shares ONE planar conversion + fused dispatch + crc32c
-        batch, and the op's timeline gets the round-11 attribution
-        stages — ``batch_wait`` (parked awaiting its tick) and
-        ``batch_encode`` (its amortized share of the coalesced
-        dispatch).  At 0 this is exactly the round-10 per-op dispatch.
+        The encode rides the per-tick coalescer (cluster/batcher.py):
+        every same-profile write in the tick shares ONE planar
+        conversion + fused dispatch + crc32c batch, and the op's
+        timeline gets the attribution stages ``batch_wait`` (parked
+        awaiting its tick) and ``batch_encode`` (its amortized share of
+        the coalesced dispatch).
 
-        Round 19 (planar at rest): when the gate is on, the tick runs
+        Planar at rest: when ``_planar_mode`` holds, the tick runs
         ``encode_planes_multi`` and the returned shards are (n, 8,
         cols) AT-REST plane matrices with plane-major crcs —
         layout == "planar8" tells the commit path to land and ship
@@ -535,33 +521,23 @@ class ECBackendMixin:
 
         planar = self._planar_mode(codec, sinfo)
         layout = planar_store.LAYOUT_PLANAR if planar else None
-        if self.config.osd_batch_tick_ops > 0:
-            mark_current("batch_parked")
-            shards, crcs, (t0, t1, batch_n) = \
-                await self._ec_batcher.encode(codec, sinfo, data,
-                                              want_crc, planar=planar)
-            op = CURRENT_OP.get()
-            if op is not None:
-                # amortized attribution: this op's share of the tick's
-                # encode wall; the rest of the window books as parked
-                # time (both stamps stay monotone: t1 - share >= t0)
-                share = (t1 - t0) / max(batch_n, 1)
-                op.mark_at("batch_tick", t1 - share)
-                op.mark_at("batch_encoded", t1)
-            if planar:
-                # the tick's client-bytes -> planes hop was this op's
-                # one sanctioned ingest conversion — stamp it so
-                # `bench.py --attribute` books it as planar_convert
-                mark_current("planar_ingest")
-            return shards, crcs, layout
-        mark_current("ec_encode")
-        # round 16: even the per-op anchor dispatches through the
-        # sanctioned coalescer module (batcher.encode_once) — zero
-        # device entry points on cluster/ op paths outside that seam
-        shards = await self._ec_batcher.encode_once(codec, sinfo, data,
-                                                    planar=planar)
-        mark_current("planar_ingest" if planar else "ec_encoded")
-        return shards, None, layout
+        mark_current("batch_parked")
+        shards, crcs, (t0, t1, batch_n) = \
+            await self._ec_batcher.encode(codec, sinfo, data,
+                                          want_crc, planar=planar)
+        op = CURRENT_OP.get()
+        if op is not None:
+            # amortized attribution: this op's share of the tick's
+            # encode wall; the rest of the window books as parked
+            # time (both stamps stay monotone: t1 - share >= t0)
+            share = (t1 - t0) / max(batch_n, 1)
+            op.mark_at("batch_tick", t1 - share)
+            op.mark_at("batch_encoded", t1)
+        if planar:
+            # the tick's client-bytes -> planes hop was this op's one
+            # sanctioned ingest conversion: the planar_convert stage
+            mark_current("planar_ingest")
+        return shards, crcs, layout
 
     def _apply_shard(self, pgid: PGid, oid: str, shard: int, data: bytes,
                      chunk_off: int, shard_size: int, hinfo: Dict,
@@ -1293,8 +1269,8 @@ class ECBackendMixin:
             codec, sinfo, avail, logical_len, planar=planar)
         if planar:
             # the assemble's planes -> logical-bytes hop was this op's
-            # one sanctioned egress conversion — stamp it so
-            # `bench.py --attribute` books it as planar_convert
+            # one sanctioned egress conversion: the planar_convert
+            # stage
             mark_current("planar_egress")
         return out
 

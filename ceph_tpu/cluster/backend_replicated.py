@@ -42,25 +42,15 @@ class ReplicatedBackendMixin:
                 .truncate(_coll(st.pgid), oid, size)
                 .set_version(_coll(st.pgid), oid, version[1]))
 
-    # replicated write: local txn + MOSDRepOp fan-out (ReplicatedBackend)
     async def _op_write_full(self, pool: PGPool, st: PGState, oid: str,
                              data: bytes, snapc=None) -> int:
+        """Serial full-object write for compound callers that hold
+        st.lock across several ops (copy_from, rollback).  Replicated:
+        local txn + MOSDRepOp fan-out (ReplicatedBackend)."""
         if pool.is_erasure():
-            return await self._ec_write(pool, st, oid, data, offset=None,
-                                        snapc=snapc)
+            return await self._ec_write(pool, st, oid, data, snapc=snapc)
         version = self._next_version(st)
         txn = self._txn_write_full(st, oid, data, snapc, version)
-        return await self._replicate_txn(st, txn, "modify", oid, version)
-
-    async def _op_write(self, pool: PGPool, st: PGState, oid: str,
-                        offset: int, data: bytes, snapc=None) -> int:
-        """Partial write at (offset, len) — the RMW path for EC pools
-        (reference ECBackend::start_rmw, ECBackend.cc:1785)."""
-        if pool.is_erasure():
-            return await self._ec_write(pool, st, oid, data, offset=offset,
-                                        snapc=snapc)
-        version = self._next_version(st)
-        txn = self._txn_write(st, oid, offset, data, snapc, version)
         return await self._replicate_txn(st, txn, "modify", oid, version)
 
     def _head_size(self, pool: PGPool, st: PGState, oid: str,
@@ -75,27 +65,6 @@ class ReplicatedBackendMixin:
             return missing if self.store.stat(coll, oid) is None else 0
         s = self.store.stat(coll, oid)
         return missing if s is None else s
-
-    async def _op_truncate(self, pool: PGPool, st: PGState, oid: str,
-                           size: int, snapc=None) -> int:
-        """CEPH_OSD_OP_TRUNCATE.  Replicated: a store truncate in the
-        replicated txn.  EC: re-encode the surviving prefix (the
-        reference routes EC truncates through the RMW machinery too)."""
-        if pool.is_erasure():
-            cur = self._head_size(pool, st, oid)
-            if size == cur:
-                return 0
-            if size < cur:
-                head = await self._op_read(pool, st, oid, 0, size)
-                head = head.ljust(size, b"\0")
-            else:
-                head = (await self._op_read(pool, st, oid, 0, cur)
-                        ).ljust(size, b"\0")
-            return await self._ec_write(pool, st, oid, head, offset=None,
-                                        snapc=snapc)
-        version = self._next_version(st)
-        txn = self._txn_truncate(st, oid, size, snapc, version)
-        return await self._replicate_txn(st, txn, "modify", oid, version)
 
     async def _op_delete_pipelined(self, pool: PGPool, st: PGState,
                                    oid: str, snapc=None) -> int:
@@ -160,10 +129,10 @@ class ReplicatedBackendMixin:
         """Apply locally + fan out with the log entry; commit when all
         acting replicas ack (reference PrimaryLogPG::issue_repop,
         PrimaryLogPG.cc:9173).  Serial shape — the caller holds st.lock
-        across the whole call (compound/meta/trim mutations and the
-        ``osd_pipeline_writes=0`` fallback).  The hot data path uses
-        the start/finish split so the ack wait runs with the PG lock
-        released (round 12: one durability story with pipelined EC)."""
+        across the whole call (compound/meta/trim mutations).  The hot
+        data path uses the start/finish split so the ack wait runs with
+        the PG lock released (one durability story with pipelined
+        EC)."""
         token = await self._replicate_txn_start(st, txn, op, oid, version)
         return await self._replicate_txn_finish(st, token)
 

@@ -14,8 +14,8 @@ under load the encode-in-flight window is exactly what accumulates the
 next tick's batch.  That also gives the double-buffering the design
 calls for: while tick T encodes in the executor, tick T-1's ops are
 already fanning out sub-writes and tick T+1 is accumulating.
-`osd_batch_tick_ops` bounds a tick's batch; `osd_batch_tick_window`
-optionally stretches accumulation after an idle-start request.
+`osd_batch_tick_ops` bounds a tick's batch (at least 1: a cap of one
+is the per-op reference the bit-exactness tests compare against).
 
 This module is the ONE sanctioned device-dispatch seam for per-op EC
 encodes under cluster/ — the `per-op-device-dispatch` graftlint rule
@@ -62,7 +62,7 @@ class SubWriteBatcher:
     frame (one pickle, one session frame, one transport ack, one
     batched reply) instead of one frame per op.  Same self-clocking
     shape as EncodeBatcher: a lone sub-write sends immediately as a
-    plain MOSDECSubOpWrite — the wire format of the unbatched path."""
+    plain MOSDECSubOpWrite."""
 
     def __init__(self, osd):
         self._osd = osd
@@ -94,7 +94,7 @@ class SubWriteBatcher:
                 pending = self._pending.get(target)
                 if not pending:
                     break
-                cap = max(1, osd.config.osd_batch_tick_ops)
+                cap = osd.config.osd_batch_tick_ops
                 batch = pending[:cap]
                 self._pending[target] = pending[cap:]
                 try:
@@ -138,10 +138,9 @@ class OpBatcher:
     transport ack) instead of one MOSDOp frame per op — the per-op
     frame churn PR 6's attribution measured dominating the t16 wall.
     Same self-clocking group-commit shape: a lone op sends immediately
-    as a plain MOSDOp (the wire format of the unbatched path, so the
-    ``objecter_batch_tick_ops=0`` anchor and a 1-op tick are
-    bit-identical on the wire), and the send-in-flight window is
-    exactly what accumulates the next tick's batch.
+    as a plain MOSDOp (so ``objecter_batch_tick_ops=1`` puts every op
+    in a frame of its own), and the send-in-flight window is exactly
+    what accumulates the next tick's batch.
 
     Per-op semantics survive batching end to end: each item keeps its
     own reqid/future in ``objecter._inflight`` (a shed item un-acks
@@ -182,18 +181,12 @@ class OpBatcher:
                 if not pending:
                     break
                 t0 = _time.time()
-                window = obj.config.objecter_batch_tick_window
-                if window and len(pending) == 1:
-                    # optional accumulation stretch after an idle start
-                    await asyncio.sleep(window)
-                    pending = self._pending.get(addr) or []
-                cap = max(1, obj.config.objecter_batch_tick_ops)
+                cap = obj.config.objecter_batch_tick_ops
                 batch = pending[:cap]
                 self._pending[addr] = pending[cap:]
                 try:
                     if len(batch) == 1:
-                        # lone op: the plain legacy frame, byte-exact
-                        # with the objecter_batch_tick_ops=0 anchor
+                        # lone op: the plain MOSDOp frame
                         await obj.messenger.send_message(batch[0][0],
                                                          addr)
                     else:
@@ -246,10 +239,10 @@ class ClientReplyBatcher:
     """Round 18: the OSD's reply-edge coalescer — terminal MOSDOpReply
     frames destined for one client connection park here and ship as ONE
     MOSDOpReplyBatch per reply tick.  Same self-clocking shape: a lone
-    reply sends immediately as a plain MOSDOpReply (the legacy wire
-    format), so replies are never delayed waiting for tick-mates — the
-    zero-acked-past-deadline gate depends on that.  Shed ops never
-    enter (no reply exists), so absence-means-unacked holds per item."""
+    reply sends immediately as a plain MOSDOpReply, so replies are
+    never delayed waiting for tick-mates — the zero-acked-past-deadline
+    gate depends on that.  Shed ops never enter (no reply exists), so
+    absence-means-unacked holds per item."""
 
     def __init__(self, osd):
         self._osd = osd
@@ -276,7 +269,7 @@ class ClientReplyBatcher:
                 pending = self._pending.get(key)
                 if not pending:
                     break
-                cap = max(1, osd.config.objecter_batch_tick_ops)
+                cap = osd.config.objecter_batch_tick_ops
                 batch = pending[:cap]
                 self._pending[key] = pending[cap:]
                 conn = batch[0][0]
@@ -504,7 +497,7 @@ class ReadBatcher:
                 pending = self._pending.get(key)
                 if not pending:
                     break
-                cap = max(1, osd.config.osd_batch_tick_ops)
+                cap = osd.config.osd_batch_tick_ops
                 batch = pending[:cap]
                 self._pending[key] = pending[cap:]
                 t0 = osd.clock.monotonic()
@@ -588,27 +581,6 @@ class EncodeBatcher:
         # add a spurious failure mode under first-call XLA compiles
         return await fut  # graftlint: ignore[rpc-timeout]
 
-    async def encode_once(self, codec, sinfo, data,
-                          planar: bool = False):
-        """The ``osd_batch_tick_ops=0`` legacy per-op encode — the
-        round-10 bisection anchor — hosted INSIDE the sanctioned
-        dispatch seam: exactly the per-op ``encode_stripes`` executor
-        hop, no coalescing, no batch crc (replicas re-checksum, the
-        round-10 contract).  Living here rather than in backend_ec
-        keeps the ``per-op-device-dispatch`` rule honest: every device
-        dispatch of the cluster data plane, legacy branch included,
-        routes through this module.  ``planar``: the per-op variant of
-        the planar tick — a 1-request ``encode_planes_multi``."""
-        from ceph_tpu.ec import stripe as stripemod
-
-        if planar:
-            [(planes, _crcs)] = await self._osd._compute(
-                stripemod.encode_planes_multi, codec, sinfo, [data],
-                [False])
-            return planes
-        return await self._osd._compute(
-            stripemod.encode_stripes, codec, sinfo, data)
-
     async def _drain(self, key, codec, sinfo) -> None:
         """Tick loop for one codec profile; exits when idle (the next
         request re-arms it).  The empty-check/exit runs with no await in
@@ -627,12 +599,7 @@ class EncodeBatcher:
                 pending = self._pending.get(key)
                 if not pending:
                     break
-                window = osd.config.osd_batch_tick_window
-                if window and len(pending) == 1:
-                    # optional accumulation stretch after an idle start
-                    await asyncio.sleep(window)
-                    pending = self._pending.get(key) or []
-                cap = max(1, osd.config.osd_batch_tick_ops)
+                cap = osd.config.osd_batch_tick_ops
                 batch = pending[:cap]
                 self._pending[key] = pending[cap:]
                 # crash seam: the tick's batch is composed but the

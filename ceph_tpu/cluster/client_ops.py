@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import asyncio
 import time
-from collections import deque
 from typing import List, Set
 
 from ceph_tpu.cluster import messages as M
@@ -25,8 +24,7 @@ class _BatchConn:
     ClientReplyBatcher into MOSDOpReplyBatch ticks; every other send
     (watch/notify pushes, map frames) forwards to the raw connection
     untouched.  Only batch-arrived ops get batched replies — a plain
-    MOSDOp frame keeps its plain reply, which is what keeps
-    objecter_batch_tick_ops=0 a bit-exact legacy anchor."""
+    MOSDOp frame keeps its plain reply."""
 
     def __init__(self, osd, raw):
         self._osd = osd
@@ -221,13 +219,9 @@ class ClientOpsMixin:
 
     def _qos_evict_source(self):
         """The queue QoS-enforced shedding evicts from under admission
-        pressure: the legacy global mclock queue, or the sharded queues
-        (each shard owns a DmClockQueue).  None without mclock."""
-        if self._opq is not None:
-            return self._opq
-        if self._shardedq is not None and self._shardedq.use_mclock:
-            return self._shardedq
-        return None
+        pressure: the sharded queues (each shard owns a DmClockQueue).
+        None without mclock."""
+        return self._shardedq if self._shardedq.use_mclock else None
 
     def _shed_if_expired(self, msg: M.MOSDOp) -> bool:
         """Dead-work shedding at dequeue: an op past its client-stamped
@@ -280,62 +274,24 @@ class ClientOpsMixin:
         # explicit pushback — the end of unbounded queueing
         if not await self._admit_or_pushback(conn, msg, m):
             return
-        if self._shardedq is not None:
-            # sharded dispatch (round 11): the shard owns queueing,
-            # shedding, and the dispatch tick; PG-affine hashing keeps
-            # per-object ordering inside one shard
-            qos_client = None
-            default = None
-            if self._shardedq.use_mclock:
-                qos_client = self._qos_entity(msg.reqid[0])
-                default = self._qos_default_for(qos_client)
-            self._shardedq.enqueue(conn, msg, qos_client, default)
-            return
-        if self._opq is not None:
+        # the shard owns queueing, shedding and the dispatch tick, off
+        # the messenger read loop; PG-affine hashing keeps per-object
+        # ordering inside one shard
+        qos_client = None
+        default = None
+        if self._shardedq.use_mclock:
             qos_client = self._qos_entity(msg.reqid[0])
             default = self._qos_default_for(qos_client)
-            self._opq.ensure_client(qos_client, default)
-            # queue ONLY (conn, msg, stamp): map/pool/PG/primary state is
-            # re-resolved at dequeue time, and ops that outlived the
-            # client's attempt window are dropped (the client has already
-            # resent; executing the stale copy would double-apply)
-            self._opq.enqueue(qos_client,
-                              (conn, msg, time.monotonic()))
-            self.perf.inc("osd_ops_queued_mclock")
-            self._queued_depth += 1
-            self.perf.set("osd_dispatch_queue_depth", self._queued_depth)
-            self._opq_event.set()
-            return
-        # detach execution from the messenger read loop (the reference
-        # never executes ops on the msgr thread — ShardedOpWQ): a
-        # mutation that waits on sub-op acks would otherwise block THIS
-        # connection's dispatch, and when the op's client is another OSD
-        # (tier agent internal_op) the sub-op ack can ride the very
-        # connection the inline dispatch is blocking — a head-of-line
-        # deadlock that only the op timeout unwinds (surfaced by
-        # graft-chaos work: _reply_osd routes sub-op acks over the
-        # lossless session, i.e. the peer's outgoing client connection).
-        # Detached but NOT unordered: ops from one client connection to
-        # one PG execute in arrival order (a pipelined A-then-B must
-        # apply as A then B), so each (conn, pg) gets a FIFO drained by
-        # its own task; different PGs still run in parallel.
-        key = (id(conn), msg.pgid)
-        q = self._ordered_q.get(key)
-        if q is None:
-            q = self._ordered_q[key] = deque()
-        q.append((conn, msg))
-        self._queued_depth += 1
-        self.perf.set("osd_dispatch_queue_depth", self._queued_depth)
-        if key not in self._ordered_active:
-            self._spawn_drainer(key, q)
+        self._shardedq.enqueue(conn, msg, qos_client, default)
 
     def _batch_conn(self, conn):
         """The STABLE reply-routing wrapper for one client connection:
-        ordered-FIFO and dup-cache keys use (id(conn), pgid), so every
-        batch item from one connection must see the SAME wrapper object
-        across frames (a fresh wrapper per frame would fork per-PG
-        ordering).  Keyed by id() with an identity re-check, so a
-        recycled id after a reconnect can never serve a stale wrap."""
+        the shards' per-group FIFOs key on (id(conn), pgid, oid), so
+        every batch item from one connection must see the SAME wrapper
+        object across frames (a fresh wrapper per frame would fork
+        per-object ordering).  Keyed by id() with an identity re-check,
+        so a recycled id after a reconnect can never serve a stale
+        wrap."""
         key = id(conn)
         wrapped = self._batch_conns.get(key)
         if wrapped is None or wrapped._raw is not conn:
@@ -387,92 +343,6 @@ class ClientOpsMixin:
                 except (ConnectionError, OSError, RuntimeError):
                     pass
 
-    def _spawn_drainer(self, key, q) -> None:
-        """Mark the FIFO active and start its drain task, tracked in
-        _opq_running so stop() can cancel it.  The loop profiler (when
-        on) wraps it: spawn count + create->first-run queued delay +
-        wall time land in the osd_loop_task_* counters."""
-        self._ordered_active.add(key)
-        t = asyncio.get_event_loop().create_task(
-            self.loopmon.wrap(self._drain_ordered(key, q)))
-        self._opq_running.add(t)
-        t.add_done_callback(self._opq_running.discard)
-
-    async def _drain_ordered(self, key, q) -> None:
-        """Serve one (connection, PG) FIFO to empty, in order.  The
-        empty-check/cleanup below runs with no await in between, so an
-        enqueue can never race the drainer's exit (single event loop)."""
-        try:
-            while q:
-                conn, msg = q.popleft()
-                self._queued_depth = max(0, self._queued_depth - 1)
-                self.perf.set("osd_dispatch_queue_depth",
-                              self._queued_depth)
-                await self._serve_admitted(conn, msg)
-        finally:
-            self._ordered_active.discard(key)
-            if q and not self._stopped:
-                # the drainer died mid-queue (cancellation): respawn so
-                # the queued ops are not stranded
-                self._spawn_drainer(key, q)
-            elif self._ordered_q.get(key) is q:
-                del self._ordered_q[key]
-
-    async def _opq_drain(self) -> None:
-        """Serve the dmClock queue (the ShardedOpWQ dequeue loop): QoS
-        decides WHEN an op starts; execution runs as its own task so one
-        slow write never head-of-line blocks other clients/PGs."""
-        while not self._stopped:
-            item = self._opq.dequeue()
-            if item is None:
-                # dead-work purge BEFORE pacing: an op already past its
-                # deadline must not wait for its L-tag — shed it now so
-                # its admission budget frees for live work (skewable
-                # clock, like every shed decision on this daemon)
-                now = self.clock.time()
-                expired = self._opq.purge(
-                    lambda it: getattr(it[1], "deadline", None)
-                    is not None and now > it[1].deadline
-                    and not self._is_control_op(it[1]))
-                for e_conn, e_msg, _stamp in expired:
-                    self._queued_depth = max(0, self._queued_depth - 1)
-                    self.perf.set("osd_dispatch_queue_depth",
-                                  self._queued_depth)
-                    self._shed_if_expired(e_msg)
-                    await self._admit_release(e_msg)
-                wait = self._opq.next_eligible_in()
-                if wait is not None:
-                    # throttled: sleep until the earliest L-tag matures
-                    await asyncio.sleep(min(max(wait, 0.002), 0.25))
-                else:
-                    self._opq_event.clear()
-                    try:
-                        await asyncio.wait_for(self._opq_event.wait(), 5.0)
-                    except asyncio.TimeoutError:
-                        pass
-                continue
-            conn, msg, stamp = item
-            self._queued_depth = max(0, self._queued_depth - 1)
-            self.perf.set("osd_dispatch_queue_depth", self._queued_depth)
-            # dmclock conformance ride the perf/Prometheus path: which
-            # share of dequeues was reservation-driven vs spare capacity
-            self.perf.set("osd_qos_served_reservation",
-                          self._opq.stats["served_reservation"])
-            self.perf.set("osd_qos_served_spare",
-                          self._opq.stats["served_spare"])
-            self.perf.set("osd_qos_evicted",
-                          self._opq.stats["evicted"])
-            if time.monotonic() - stamp > self.config.osd_client_op_timeout:
-                # the client abandoned this attempt and resent: executing
-                # the stale copy would double-apply the op
-                self.perf.inc("osd_ops_dropped_stale")
-                await self._admit_release(msg)
-                continue
-            t = asyncio.get_event_loop().create_task(
-                self.loopmon.wrap(self._serve_admitted(conn, msg)))
-            self._opq_running.add(t)
-            t.add_done_callback(self._opq_running.discard)
-
     async def _serve_admitted(self, conn, msg) -> None:
         """Serve one admitted op, returning its admission budget (and
         the messenger byte-throttle claim) however it exits — incl. the
@@ -517,9 +387,7 @@ class ClientOpsMixin:
 
         spec = QoSSpec(reservation=reservation, weight=weight,
                        limit=limit)
-        if self._opq is not None:
-            self._opq.set_client(client, spec)
-        if self._shardedq is not None and self._shardedq.use_mclock:
+        if self._shardedq.use_mclock:
             self._shardedq.set_client(client, spec)
 
     # ops whose effects are not idempotent under at-least-once delivery;
@@ -550,14 +418,14 @@ class ClientOpsMixin:
 
     def _compound_write_guard(self, pool, st: PGState, oid: str):
         """Object-lock guard for compound EC mutations that commit
-        UNDER st.lock (copy_from, rollback): with pipelined writes on,
-        an in-flight RMW reads-merges under only the object lock — a
-        compound data commit slipping inside that window would be
-        overwritten by the RMW's merged full stripe (lost update).
-        Acquired BEFORE st.lock (the pg.objlock -> pg.lock order).
-        Replicated pools / pipeline-off need no guard (their commits
-        and RMW reads share st.lock already)."""
-        if pool.is_erasure() and self.config.osd_pipeline_writes > 0:
+        UNDER st.lock (copy_from, rollback): an in-flight pipelined RMW
+        reads-merges under only the object lock — a compound data
+        commit slipping inside that window would be overwritten by the
+        RMW's merged full stripe (lost update).  Acquired BEFORE
+        st.lock (the pg.objlock -> pg.lock order).  Replicated pools
+        need no guard (their commits and RMW reads share st.lock
+        already)."""
+        if pool.is_erasure():
             return self._obj_write_lock(st, oid)
         import contextlib
 
@@ -831,62 +699,40 @@ class ClientOpsMixin:
     async def _do_one_op(self, conn, msg, m, pool, st, opname, args):
         """One op of the vector -> (result, out_data).
 
-        Round 12: the hot mutation verbs (write_full, write, zero,
-        append, truncate, delete, create) commit through ONE pipelined
-        frontier path for both pool kinds — prepare under the object
-        write lock (EC read-merge-encode) or the PG lock (replicated
-        txn build), ordered commit section under the PG lock, ack wait
-        with everything released.  ``osd_pipeline_writes=0`` restores
-        the round-10 full-PG-lock serial commits as the bit-exactness
-        anchor.  Compound read-modify verbs (copy_from, rollback, exec,
-        xattr/omap) keep the serial shape — they still register with
-        the same commit frontier via _replicate_txn."""
-        pipe = self.config.osd_pipeline_writes > 0
+        The hot mutation verbs (write_full, write, zero, append,
+        truncate, delete, create) commit through ONE pipelined frontier
+        path for both pool kinds — prepare under the object write lock
+        (EC read-merge-encode) or the PG lock (replicated txn build),
+        ordered commit section under the PG lock, ack wait with
+        everything released.  Compound read-modify verbs (copy_from,
+        rollback, exec, xattr/omap) keep the serial shape — they still
+        register with the same commit frontier via _replicate_txn."""
         if opname == "write_full":
             if pool.is_erasure():
-                if pipe:
-                    # encode outside the PG lock, ordered commit under
-                    # it, ack wait after release — the PG admits the
-                    # next write while this one's shards commit
-                    r = await self._ec_write_pipelined(
-                        pool, st, msg.oid, args["data"], None,
-                        snapc=msg.snapc)
-                else:
-                    async with st.lock:
-                        r = await self._ec_write(
-                            pool, st, msg.oid, args["data"], None,
-                            snapc=msg.snapc)
+                # encode outside the PG lock, ordered commit under it,
+                # ack wait after release — the PG admits the next write
+                # while this one's shards commit
+                r = await self._ec_write_pipelined(
+                    pool, st, msg.oid, args["data"], None,
+                    snapc=msg.snapc)
                 return r, None
-            if pipe:
-                r = await self._rep_mutate_pipelined(
-                    st, msg.oid,
-                    lambda version: self._txn_write_full(
-                        st, msg.oid, args["data"], msg.snapc, version))
-                return r, None
-            async with st.lock:
-                r = await self._op_write_full(
-                    pool, st, msg.oid, args["data"], snapc=msg.snapc)
+            r = await self._rep_mutate_pipelined(
+                st, msg.oid,
+                lambda version: self._txn_write_full(
+                    st, msg.oid, args["data"], msg.snapc, version))
             return r, None
         if opname in ("write", "zero"):
             data = args["data"] if opname == "write" \
                 else b"\0" * args["length"]
             offset = args["offset"]
-            if pipe:
-                if pool.is_erasure():
-                    r = await self._ec_write_pipelined(
-                        pool, st, msg.oid, data, offset,
-                        snapc=msg.snapc)
-                else:
-                    r = await self._rep_mutate_pipelined(
-                        st, msg.oid,
-                        lambda version: self._txn_write(
-                            st, msg.oid, offset, data, msg.snapc,
-                            version))
-                return r, None
-            async with st.lock:
-                r = await self._op_write(pool, st, msg.oid,
-                                         offset, data,
-                                         snapc=msg.snapc)
+            if pool.is_erasure():
+                r = await self._ec_write_pipelined(
+                    pool, st, msg.oid, data, offset, snapc=msg.snapc)
+            else:
+                r = await self._rep_mutate_pipelined(
+                    st, msg.oid,
+                    lambda version: self._txn_write(
+                        st, msg.oid, offset, data, msg.snapc, version))
             return r, None
         if opname == "read":
             try:
@@ -898,20 +744,16 @@ class ClientOpsMixin:
             except FileNotFoundError:
                 return -2, None
         if opname == "delete":
-            if pipe:
-                r = await self._op_delete_pipelined(pool, st, msg.oid,
-                                                    snapc=msg.snapc)
-                return r, None
-            async with st.lock:
-                r = await self._op_delete(pool, st, msg.oid,
-                                          snapc=msg.snapc)
+            r = await self._op_delete_pipelined(pool, st, msg.oid,
+                                                snapc=msg.snapc)
             return r, None
         if opname == "append":
             # CEPH_OSD_OP_APPEND: a write at the CURRENT size — atomic
-            # under the object write lock (pipelined; concurrent
-            # appends serialize per object, do_osd_ops:4917 case) or
-            # the PG lock (serial fallback)
-            if pipe and pool.is_erasure():
+            # under the object write lock (EC; concurrent appends
+            # serialize per object, do_osd_ops:4917 case) or the PG
+            # lock (replicated: the size is read inside the commit
+            # section)
+            if pool.is_erasure():
                 async with self._obj_write_lock(st, msg.oid):
                     size = self._head_size(pool, st, msg.oid)
                     token = await self._ec_start_objlocked(
@@ -919,48 +761,31 @@ class ClientOpsMixin:
                         msg.snapc)
                 r = await self._ec_commit_finish(st, token)
                 return r, size
-            if pipe:
-                sizebox = []
+            sizebox = []
 
-                def _build(version):
-                    sizebox.append(
-                        self._head_size(pool, st, msg.oid))
-                    return self._txn_write(st, msg.oid, sizebox[0],
-                                           args["data"], msg.snapc,
-                                           version)
+            def _build(version):
+                sizebox.append(self._head_size(pool, st, msg.oid))
+                return self._txn_write(st, msg.oid, sizebox[0],
+                                       args["data"], msg.snapc, version)
 
-                r = await self._rep_mutate_pipelined(st, msg.oid,
-                                                     _build)
-                return r, sizebox[0] if sizebox else 0
-            async with st.lock:
-                size = self._head_size(pool, st, msg.oid)
-                r = await self._op_write(pool, st, msg.oid,
-                                         size, args["data"],
-                                         snapc=msg.snapc)
-            return r, size
+            r = await self._rep_mutate_pipelined(st, msg.oid, _build)
+            return r, sizebox[0] if sizebox else 0
         if opname == "truncate":
-            if pipe and pool.is_erasure():
+            if pool.is_erasure():
                 r = await self._ec_truncate_pipelined(
                     pool, st, msg.oid, args["size"], snapc=msg.snapc)
                 return r, None
-            if pipe:
-                r = await self._rep_mutate_pipelined(
-                    st, msg.oid,
-                    lambda version: self._txn_truncate(
-                        st, msg.oid, args["size"], msg.snapc,
-                        version))
-                return r, None
-            async with st.lock:
-                r = await self._op_truncate(pool, st, msg.oid,
-                                            args["size"],
-                                            snapc=msg.snapc)
+            r = await self._rep_mutate_pipelined(
+                st, msg.oid,
+                lambda version: self._txn_truncate(
+                    st, msg.oid, args["size"], msg.snapc, version))
             return r, None
         if opname == "create":
             # exclusive create (CEPH_OSD_OP_CREATE + EXCL flag): the
-            # exists-check must be atomic with the commit start, so the
-            # pipelined shape holds the object lock (EC) / PG lock
-            # (replicated) across both
-            if pipe and pool.is_erasure():
+            # exists-check must be atomic with the commit start, so it
+            # holds the object lock (EC) / PG lock (replicated) across
+            # both
+            if pool.is_erasure():
                 async with self._obj_write_lock(st, msg.oid):
                     if self._head_size(pool, st, msg.oid,
                                        missing=None) is not None:
@@ -969,24 +794,16 @@ class ClientOpsMixin:
                         pool, st, msg.oid, b"", None, msg.snapc)
                 r = await self._ec_commit_finish(st, token)
                 return r, None
-            if pipe:
-                async with st.lock:
-                    if self._head_size(pool, st, msg.oid,
-                                       missing=None) is not None:
-                        return -17, None  # EEXIST
-                    version = self._next_version(st)
-                    txn = self._txn_write_full(st, msg.oid, b"",
-                                               msg.snapc, version)
-                    token = await self._replicate_txn_start(
-                        st, txn, "modify", msg.oid, version)
-                r = await self._replicate_txn_finish(st, token)
-                return r, None
             async with st.lock:
-                if self._head_size(pool, st, msg.oid, missing=None) \
-                        is not None:
+                if self._head_size(pool, st, msg.oid,
+                                   missing=None) is not None:
                     return -17, None  # EEXIST
-                r = await self._op_write_full(
-                    pool, st, msg.oid, b"", snapc=msg.snapc)
+                version = self._next_version(st)
+                txn = self._txn_write_full(st, msg.oid, b"",
+                                           msg.snapc, version)
+                token = await self._replicate_txn_start(
+                    st, txn, "modify", msg.oid, version)
+            r = await self._replicate_txn_finish(st, token)
             return r, None
         if opname == "cmpxattr":
             # CEPH_OSD_OP_CMPXATTR (eq): gate for compound client

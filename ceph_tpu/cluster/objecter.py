@@ -84,8 +84,9 @@ class Objecter(Dispatcher):
         self._primary_cache: Tuple[Optional[int], Dict] = (None, {})
         # reply-leg tail timelines (round 11): the OSD's terminal reply
         # carries a trace whose hop stamps + our completion stamp cover
-        # the previously-untraced reply flight + client wakeup; bench
-        # --attribute merges these so wall_coverage holds on short ops
+        # the previously-untraced reply flight + client wakeup; an
+        # attribution run merges these so wall_coverage holds on short
+        # ops
         from collections import deque as _deque
 
         self._op_tails: "_deque" = _deque(maxlen=4096)
@@ -101,10 +102,8 @@ class Objecter(Dispatcher):
 
         self.flight = FlightRecorder.from_config(
             f"client.{self.display_name}", self.config)
-        # client-edge op coalescer (round 18): the objecter twin of the
-        # OSD's SubWriteBatcher.  Built unconditionally — the gate is
-        # consulted PER SEND (objecter_batch_tick_ops, injectargs-able),
-        # so 0 keeps the legacy one-frame-per-op anchor byte-for-byte.
+        # client-edge op coalescer: the objecter twin of the OSD's
+        # SubWriteBatcher; every op frame leaves through it
         from ceph_tpu.cluster.batcher import OpBatcher
 
         self._tasks: Set[asyncio.Task] = set()
@@ -315,8 +314,8 @@ class Objecter(Dispatcher):
         self._op_tails.append(evs)
 
     def drain_op_tails(self):
-        """Return and clear the recorded reply tails (bench --attribute
-        drains once after warm-up, once after the timing window)."""
+        """Return and clear the recorded reply tails (an attribution
+        run drains once after warm-up, once after the timing window)."""
         out = [list(e) for e in self._op_tails]
         self._op_tails.clear()
         return out
@@ -425,14 +424,10 @@ class Objecter(Dispatcher):
         self._cwnd_event.set()
 
     async def _send_op(self, msg: M.MOSDOp, addr: Tuple) -> None:
-        """Route one op frame out: through the per-(session, OSD) tick
-        coalescer when client batching is on, else the legacy per-op
-        frame.  Gated per SEND so objecter_batch_tick_ops=0 is a live
-        anchor (injectargs mid-run flips the path for the next op)."""
-        if self.config.objecter_batch_tick_ops > 0:
-            await self._op_batcher.send(addr, msg)
-        else:
-            await self.messenger.send_message(msg, addr)
+        """Route one op frame out through the per-(session, OSD) tick
+        coalescer; the cap is read per tick (objecter_batch_tick_ops,
+        injectargs-able) and a 1-op tick ships the plain MOSDOp."""
+        await self._op_batcher.send(addr, msg)
 
     async def _await_reply(self, fut, pgid, primary: int, addr: Tuple,
                            deadline: float):

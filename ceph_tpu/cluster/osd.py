@@ -211,40 +211,28 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         # peer is judged by pings it was sent, never by our own silence
         self._hb_unanswered: Dict[int, float] = {}
         self._reported: Set[int] = set()
-        # dmClock op scheduling (reference mClockClientQueue plugged into
-        # ShardedOpWQ): enabled by osd_op_queue=mclock; ops enqueue per
-        # client and a drain task serves them by reservation/weight/limit
-        self._opq = None
-        self._opq_event = asyncio.Event()
+        # tasks serving client ops (the shards' group drainers and mclock
+        # dequeues) and recovery rounds, so stop() can cancel them
         self._opq_running: Set[asyncio.Task] = set()
-        # default (non-mclock) dispatch: per-(connection, PG) FIFO
-        # queues drained off the messenger read loop — the reference
-        # orders a client session's ops per PG (ShardedOpWQ pg queues)
-        self._ordered_q: Dict[Tuple[int, PGid], object] = {}
-        self._ordered_active: Set[Tuple[int, PGid]] = set()
+        # dmClock op scheduling (reference mClockClientQueue plugged into
+        # each ShardedOpWQ shard): enabled by osd_op_queue=mclock; every
+        # shard owns its own DmClockQueue and serves it by
+        # reservation/weight/limit
         self._opq_default = None
         if self.config.osd_op_queue == "mclock":
-            from ceph_tpu.cluster.dmclock import DmClockQueue, QoSSpec
+            from ceph_tpu.cluster.dmclock import QoSSpec
 
             self._opq_default = QoSSpec(
                 reservation=self.config.osd_mclock_default_reservation,
                 weight=self.config.osd_mclock_default_weight,
                 limit=self.config.osd_mclock_default_limit)
-            if self.config.osd_op_shards == 0:
-                # legacy global queue; with shards on, each shard owns
-                # its own DmClockQueue (mClockClientQueue-per-shard)
-                self._opq = DmClockQueue()
-        # sharded dispatch (round 11, ShardedOpWQ analog): PG-affine
-        # shards with tick-bounded drain; 0 = the legacy path above
-        self._shardedq = None
-        if self.config.osd_op_shards > 0:
-            from ceph_tpu.cluster.sharded_wq import ShardedOpWQ
+        # sharded dispatch (ShardedOpWQ analog): PG-affine shards with
+        # tick-bounded drain
+        from ceph_tpu.cluster.sharded_wq import ShardedOpWQ
 
-            self._shardedq = ShardedOpWQ(self,
-                                         self.config.osd_op_shards)
+        self._shardedq = ShardedOpWQ(self, self.config.osd_op_shards)
         # per-tick stripe-batch coalescer + per-peer sub-write frame
-        # batcher (cluster/batcher.py): EC writes ride both when
-        # osd_batch_tick_ops > 0
+        # batcher (cluster/batcher.py): EC writes ride both
         from ceph_tpu.cluster.batcher import (ClientReplyBatcher,
                                               EncodeBatcher,
                                               ReadBatcher,
@@ -257,8 +245,8 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         self._read_batcher = ReadBatcher(self)
         # client-edge reply coalescer (round 18): acks for ops that
         # arrived inside an MOSDOpBatch leave as MOSDOpReplyBatch ticks;
-        # per-conn wrapper identity must be STABLE — the ordered-FIFO
-        # keys are (id(conn), pgid) — so batch conns are cached here
+        # per-conn wrapper identity must be STABLE — the shards' group
+        # FIFOs key on id(conn) — so batch conns are cached here
         self._reply_batcher = ClientReplyBatcher(self)
         self._batch_conns: Dict[int, object] = {}
         # (pgid, oid) pairs with an in-flight async read-repair, so a
@@ -345,10 +333,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         self._track(loop.create_task(self._heartbeat_loop()))
         self._track(loop.create_task(self._scrub_loop()))
         self._track(loop.create_task(self._tier_agent_loop()))
-        if self._opq is not None:
-            self._track(loop.create_task(self._opq_drain()))
-        if self._shardedq is not None:
-            self._shardedq.start()
+        self._shardedq.start()
         if self.loopmon.enabled:
             self._track(loop.create_task(self.loopmon.sample()))
         if self._peering_pending:
@@ -429,14 +414,12 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
 
     @property
     def _mclock_dispatch(self) -> bool:
-        """Is client-op dispatch QoS-queued (global legacy queue or
-        per-shard mclock)?  Governs the internal-op loopback choice:
-        under FIFO-ordered dispatch a self-targeted nested op must run
-        direct (same-(conn,PG) group serialization would deadlock);
-        under mclock each dequeue is a free task, so self-messaging is
-        safe and required."""
-        return self._opq is not None or (
-            self._shardedq is not None and self._shardedq.use_mclock)
+        """Is client-op dispatch QoS-queued (per-shard mclock)?  Governs
+        the internal-op loopback choice: under FIFO-ordered dispatch a
+        self-targeted nested op must run direct (same-(conn, PG, object)
+        group serialization would deadlock); under mclock each dequeue
+        is a free task, so self-messaging is safe and required."""
+        return self._shardedq.use_mclock
 
     @property
     def mon_addr(self) -> Addr:
@@ -1068,9 +1051,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
                       "completed graft-trace spans (args: trace_id | n)")
 
         def _dmclock(cmd):
-            if self._opq is not None:
-                return {"enabled": True, **self._opq.dump()}
-            if self._shardedq is not None and self._shardedq.use_mclock:
+            if self._shardedq.use_mclock:
                 return {"enabled": True, **self._shardedq.dump()}
             return {"enabled": False}
 

@@ -4,23 +4,28 @@ Structural mirror of the reference's ShardedOpWQ (src/osd/OSD.cc: ops
 land in one of N shards by PG hash; each shard's own lock + queue serve
 dequeues).  A PG always maps to one shard, so per-PG ordering survives
 sharding by construction; within a shard, ops dequeue on a bounded
-DISPATCH TICK and execute concurrently (per-(connection, PG) arrival
-order preserved through per-group FIFOs — exactly the legacy
-guarantee), which is what lines concurrent EC writes up at the encode
-coalescer (cluster/batcher.py): tick alignment turns N per-op device
-dispatches into one.
+DISPATCH TICK and execute concurrently (per-(connection, PG, object)
+arrival order preserved through per-group FIFOs), which is what lines
+concurrent EC writes up at the encode coalescer (cluster/batcher.py):
+tick alignment turns N per-op device dispatches into one.
 
-The round-10 scheduling machinery moves INSIDE the shard: with
+Execution is detached from the messenger read loop (the reference never
+executes ops on the msgr thread): a mutation that waits on sub-op acks
+would otherwise block ITS connection's dispatch, and when the op's
+client is another OSD (tier agent internal_op) the sub-op ack can ride
+the very connection an inline dispatch would be blocking — a
+head-of-line deadlock that only the op timeout unwinds.
+
+The scheduling machinery lives INSIDE the shard: with
 osd_op_queue=mclock every shard owns its own DmClockQueue (the
 reference plugs mClockClientQueue into each ShardedOpWQ shard the same
 way), and deadline purging, stale-attempt drops, and QoS-enforced
-eviction run per shard.  FIFO mode keeps per-(conn, PG) group FIFOs;
-mclock mode spawns a task per dequeued op (QoS decides order, the
-legacy global-mclock semantics).
+eviction run per shard.  FIFO mode keeps per-(conn, PG, object) group
+FIFOs; mclock mode spawns a task per dequeued op (QoS decides order).
 
-``osd_op_shards=0`` (the config default) bypasses this module entirely
-— the round-10 per-(conn, PG) FIFO / global-mclock path is preserved
-verbatim as the bisection anchor.
+This is the OSD's only client-op dispatch path (``osd_op_shards`` >= 1;
+one shard with ``osd_batch_tick_ops=1`` is the per-op reference the
+bit-exactness tests compare against).
 """
 
 from __future__ import annotations
@@ -55,7 +60,7 @@ class ShardedOpWQ:
         self.use_mclock = osd.config.osd_op_queue == "mclock"
         self.shards = [
             _Shard(i, DmClockQueue() if self.use_mclock else None)
-            for i in range(max(1, nshards))]
+            for i in range(nshards)]
 
     def start(self) -> None:
         for sh in self.shards:
@@ -175,7 +180,7 @@ class ShardedOpWQ:
                 await self._idle(sh)
                 continue
             tick = [item]
-            cap = max(1, osd.config.osd_batch_tick_ops or 64)
+            cap = osd.config.osd_batch_tick_ops
             while len(tick) < cap:
                 nxt = self._pop(sh)
                 if nxt is None:
@@ -187,9 +192,9 @@ class ShardedOpWQ:
                     msg.trace.setdefault("events", []).append(
                         (f"shard:{sh.idx}:tick", tick_wall))
                 if sh.opq is not None:
-                    # legacy-mclock semantics per op: stale-attempt
-                    # drop, conformance gauges, a free-running task
-                    # (QoS already decided the order)
+                    # mclock semantics per op: stale-attempt drop,
+                    # conformance gauges, a free-running task (QoS
+                    # already decided the order)
                     self._dec_depth()
                     if time.monotonic() - stamp > \
                             osd.config.osd_client_op_timeout:

@@ -465,18 +465,25 @@ class Cluster:
 
 
 def _fast_config() -> Config:
-    """The product configuration of a vstart cluster: what the benchmark,
-    ``chip_smoke.py`` and the tests serve from (the vstart analog of
-    ceph.conf overrides).  Recovery, op and tick timings are fast; the
-    failure timings are a deployment's, in upstream's proportions at a
-    tenth of its scale, because every daemon of the cluster shares one
-    event loop with the data frames: a grace must outlast the worst lag
-    a loaded loop shows, two OSDs must agree before a third is marked
-    down, and a down OSD is not marked out (and its PGs remapped) inside
-    anybody's measured window.  A daemon that is really dead is found at
-    once all the same: its peers' pings are refused (osd.py,
-    ``_heartbeat_loop``).  A test that needs a grace to expire within a
-    second, or an OSD marked out, sets those values itself."""
+    """``Config()`` plus a vstart cluster's TIMINGS, and nothing else:
+    what the benchmark, ``chip_smoke.py`` and the tests serve from (the
+    vstart analog of ceph.conf overrides).  The data plane is plain
+    ``Config()``'s.  The keys set here: ``osd_heartbeat_interval``,
+    ``osd_heartbeat_grace``, ``mon_tick_interval``,
+    ``mon_osd_down_out_interval``, ``mon_osd_min_down_reporters``,
+    ``mon_osd_beacon_grace``, ``osd_recovery_delay_start``,
+    ``osd_client_op_timeout``, ``rados_osd_op_timeout``.
+
+    Recovery, op and tick timings are fast; the failure timings are a
+    deployment's, in upstream's proportions at a tenth of its scale,
+    because every daemon of the cluster shares one event loop with the
+    data frames: a grace must outlast the worst lag a loaded loop shows,
+    two OSDs must agree before a third is marked down, and a down OSD is
+    not marked out (and its PGs remapped) inside anybody's measured
+    window.  A daemon that is really dead is found at once all the same:
+    its peers' pings are refused (osd.py, ``_heartbeat_loop``).  A test
+    that needs a grace to expire within a second, or an OSD marked out,
+    sets those values itself."""
     return Config(
         osd_heartbeat_interval=0.5,     # upstream 6 s
         osd_heartbeat_grace=10.0,       # upstream 20 s
@@ -489,21 +496,6 @@ def _fast_config() -> Config:
         # XLA first-compiles of codec shapes can take tens of seconds on a
         # loaded CPU; client retries must outlast them
         rados_osd_op_timeout=90.0,
-        # batched data plane (round 11): vstart clusters run the sharded
-        # dispatch + per-tick stripe-batch coalescing path — the plain
-        # Config() zero-defaults remain the per-op bisection anchor
-        osd_op_shards=2,
-        osd_batch_tick_ops=16,
-        # client-edge batching (round 18): the objecter coalesces a
-        # tick's ops per (session, OSD) into MOSDOpBatch frames with
-        # batched replies; objecter_batch_tick_ops=0 stays the per-op
-        # frame anchor for bit-exactness and same-host A/B
-        objecter_batch_tick_ops=16,
-        # planar at rest (round 19): vstart clusters store EC shards as
-        # packed bit-planes end-to-end; osd_ec_planar_at_rest=0 (the
-        # plain Config() default) stays the byte-at-rest bit-exactness
-        # anchor for bisection and same-session A/B
-        osd_ec_planar_at_rest=1,
     )
 
 

@@ -15,31 +15,3 @@ from ceph_tpu.crush.types import (  # noqa: F401
     CRUSH_ITEM_UNDEF,
 )
 from ceph_tpu.crush.scalar import ScalarMapper  # noqa: F401
-
-
-def bench_map(n_osds: int = 10_000, n_pgs: int = 1_000_000, iters: int = 3):
-    """Whole-map placement throughput (mappings/s) for bench.py."""
-    import time
-
-    import jax
-    import numpy as np
-
-    from ceph_tpu.crush.mapper import TensorMapper
-    from ceph_tpu.crush.types import build_three_level
-
-    # 10k OSDs as deployed: root -> 40 racks -> 16 hosts -> 16 osds
-    n_racks = max(1, n_osds // 256)
-    cmap, rule = build_three_level(
-        n_racks=n_racks, hosts_per_rack=16, osds_per_host=16, numrep=3
-    )
-    mapper = TensorMapper(cmap)
-    xs = np.arange(n_pgs, dtype=np.uint32)
-    weights = np.full(cmap.max_devices, 0x10000, dtype=np.uint32)
-    out = mapper.do_rule_batch(rule, xs, result_max=3, weights=weights)
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = mapper.do_rule_batch(rule, xs, result_max=3, weights=weights)
-        jax.block_until_ready(out)
-    dt = (time.perf_counter() - t0) / iters
-    return n_pgs / dt

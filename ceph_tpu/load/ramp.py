@@ -9,7 +9,7 @@ sweep stops at the first failing step (or when the scale list runs
 out).
 
 The result is a ``LOAD_r*.json`` artifact beside the BENCH records,
-carrying the SAME trust-model stamps bench.py enforces: mode
+carrying the trust-model stamps of those records: mode
 ``cluster_vstart``, a NULL ``vs_baseline`` (load artifacts are never a
 baseline ratio), and ``session_only: true`` — the dev host is
 load-sensitive (BENCH_NOTES round 12), so absolute knee numbers only

@@ -9,8 +9,8 @@ dispatch (each iteration feeding a cheap xor of its output back into
 the next so nothing can be hoisted), completion forced by a one-element
 host readback — the dispatch/readback floor cancels exactly.
 
-``device_loop_slope`` is that harness; bench.py and ad-hoc profiling
-both call it, and a ``tag`` records the honest per-step seconds into
+``device_loop_slope`` is that harness for ad-hoc profiling, and a
+``tag`` records the honest per-step seconds into
 the process-wide KERNELS registry (``t_<tag>`` time counters) so
 ``perf dump`` carries real device timings next to the invocation/byte
 counters.

@@ -14,7 +14,7 @@ This is the instrument ROADMAP items 1-2 are blocked on: the
 module answers "where does each millisecond actually go" per stage —
 dispatch-queue wait, PG-lock wait, device encode, store commit,
 sub-write fan-out — aggregated across completed ops
-(``dump_op_attribution`` admin command, ``bench.py --attribute``).
+(``dump_op_attribution`` admin command, ``scripts/trace.py attribute``).
 """
 
 from __future__ import annotations
@@ -46,8 +46,8 @@ EVENT_STAGE = {
     "dup_refused_from_log": "dup_cache",
     # overload-regime stages (round 10): client congestion-window wait,
     # dead-work shed at dequeue, straggler hedge on degraded EC reads —
-    # so wall_coverage holds with backpressure enabled (bench.py
-    # --attribute books throttle waits instead of losing them to "wire")
+    # so wall_coverage holds with backpressure enabled (throttle waits
+    # are booked instead of being lost to "wire")
     "objecter:throttle_wait": "throttle_wait",
     "shed_expired": "shed",
     "ec_hedge_sent": "hedge",
@@ -74,16 +74,15 @@ EVENT_STAGE = {
     # per-(session, OSD) tick coalescer books queued-for-tick time
     # (client_batch_wait) plus its AMORTIZED share of the tick's frame
     # build/send (client_batch_send) — the client twin of
-    # batch_wait/batch_encode, so wall_coverage holds with
-    # objecter_batch_tick_ops > 0
+    # batch_wait/batch_encode, so wall_coverage holds
     "objecter:batch_tick": "client_batch_wait",
     "objecter:batch_sent": "client_batch_send",
     # planar at rest (round 19): the two SANCTIONED layout hops — the
     # coalesced encode's client-bytes -> planes ingest and the read
-    # assemble's planes -> client-bytes egress — book as planar_convert
-    # so `bench.py --attribute` shows exactly what the at-rest format
-    # costs (steady-state shard traffic between them is conversion-free
-    # by contract; the pinned counter proves it)
+    # assemble's planes -> client-bytes egress — book as planar_convert:
+    # exactly what the at-rest format costs (steady-state shard traffic
+    # between them is conversion-free by contract; the pinned counter
+    # proves it)
     "planar_ingest": "planar_convert",
     "planar_egress": "planar_convert",
 }
@@ -170,8 +169,8 @@ def aggregate(event_lists: Sequence[Sequence[Tuple[float, str]]],
 
     ``measured_wall_s``: the externally measured mean per-op wall time
     (client-observed latency); when given, ``wall_coverage`` reports
-    what fraction of it the traced timeline accounts for — the
-    bench acceptance metric (>= 0.9 on the cluster_io write bench)."""
+    what fraction of it the traced timeline accounts for (the
+    acceptance floor of an attribution run is 0.9)."""
     sums: "OrderedDict[str, float]" = OrderedDict()
     total = 0.0
     n = 0
@@ -223,7 +222,7 @@ async def flush_op_history(cluster, size: int) -> None:
     ``size`` (injectargs 0 -> size through the admin socket).  The
     shared warm-up flush for attribution runs: XLA-compile ops from
     cache warming must never be attributed into a timing window
-    (bench.py --attribute, scripts/trace.py attribute)."""
+    (the benchmark's traced run, scripts/trace.py attribute)."""
     for oid in cluster.osds:
         for n in (0, size):
             await cluster.daemon_command(

@@ -170,8 +170,8 @@ def _breach_ops(bundle: Dict) -> List[Dict]:
 def breach_report(bundle: Dict) -> Dict:
     """Per-stage attribution + top suspects over the breach set.
 
-    Reuses ``trace/attribution.py`` exactly as ``bench.py --attribute``
-    does: each op's event timeline is sliced into stage deltas;
+    Reuses ``trace/attribution.py`` exactly as ``scripts/trace.py
+    attribute`` does: each op's event timeline is sliced into stage deltas;
     ``measured_wall_s`` is the breach set's mean client-visible
     duration, so ``wall_coverage`` reports the fraction of the late
     ops' wall the timelines explain (acceptance: >= 0.9)."""

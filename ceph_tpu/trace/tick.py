@@ -33,8 +33,8 @@ host tracer (on the chip the two agreed to within 3 ms inside a session:
 PERF.md).  NOT ``osd.clock`` (chaos-skewed) and not ``perf_counter``.
 
 Always on.  Nothing here syncs with the device: a phase times what the
-thread does today.  Outside a tick (``encode_once``, tests and tools
-calling ``encode_planes_multi`` directly) :func:`phase` returns the
+thread does today.  Outside a tick (tests and tools calling
+``encode_planes_multi`` directly) :func:`phase` returns the
 shared :data:`NULL_PHASE` and :func:`device_calls` / :func:`annotate`
 do nothing.  A dumped tick is a list of dicts with ``Span.dump()``'s
 fields, so ``assemble_tree`` and ``perfetto.chrome_trace_from_spans``

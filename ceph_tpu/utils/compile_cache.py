@@ -2,7 +2,7 @@
 
 A chip call starts with no compiled code, and the served path compiles a
 program per shape bucket inside the first ops that meet it.  Every entry
-point (`chip_smoke.py`, `bench.py`, `scripts/load.py|chaos.py|trace.py`,
+point (`chip_smoke.py`, `scripts/load.py|chaos.py|trace.py`,
 ``python -m ceph_tpu.cluster.vstart``) calls :func:`enable` before its
 first JAX computation so those compiles are kept.
 

@@ -24,15 +24,10 @@ class Option:
 
 
 OPTIONS: List[Option] = [
-    # messenger
-    Option("ms_type", str, "async", "messenger transport"),
-    Option("ms_bind_host", str, "127.0.0.1"),
-    Option("ms_connect_timeout", float, 5.0),
     # osd
     Option("osd_heartbeat_interval", float, 0.5, "peer ping period (s)"),
     Option("osd_heartbeat_grace", float, 2.0, "grace before failure report"),
     Option("osd_pool_default_size", int, 3, min=1, max=16),
-    Option("osd_pool_default_min_size", int, 2, min=1),
     Option("osd_pool_default_pg_num", int, 32, min=1),
     Option("osd_recovery_delay_start", float, 0.0),
     Option("osd_client_op_timeout", float, 10.0),
@@ -123,52 +118,24 @@ OPTIONS: List[Option] = [
            "path.  0 = detect only", min=0, max=1),
     Option("osd_op_queue", str, "fifo",
            "client op scheduling: fifo | mclock (dmClock QoS)"),
-    # sharded dispatch + per-tick stripe-batch coalescing (round 11):
-    # the ShardedOpWQ analog.  Zero defaults preserve the round-10
-    # per-op dispatch/encode path exactly — the bisection anchor; vstart
-    # _fast_config (tests + bench) turns both on.
-    Option("osd_op_shards", int, 0,
+    # the served data plane: PG-affine dispatch shards, the per-tick
+    # stripe-batch encode coalescer and the client-edge frame coalescer
+    # (cluster/sharded_wq.py, cluster/batcher.py).  Each cap is at
+    # least 1; a cap of one is the per-op reference the bit-exactness
+    # tests compare the coalesced path against.
+    Option("osd_op_shards", int, 2,
            "client-op dispatch shards (PG-affine hashing; each shard "
            "drains on a bounded dispatch tick and owns its own "
-           "mclock/FIFO queue + shedding).  0 = the per-(conn,PG) "
-           "FIFO / global-mclock legacy path", min=0),
-    Option("osd_batch_tick_ops", int, 0,
+           "mclock/FIFO queue + shedding)", min=1),
+    Option("osd_batch_tick_ops", int, 16,
            "max EC stripe-batch encodes coalesced into ONE device "
            "dispatch per tick (one to_planar, one fused encode, one "
-           "crc32c batch).  0 = per-op encode (legacy)", min=0),
-    Option("osd_batch_tick_window", float, 0.0,
-           "extra accumulation window (s) after a tick's first encode "
-           "request; 0 = pure group-commit self-clocking (a lone op "
-           "never waits)", min=0),
-    # client-edge op coalescing (round 18): the objecter twin of the
-    # OSD tick batchers.  Ops targeting the same OSD park in a
-    # per-(session, OSD) coalescer and ship as ONE MOSDOpBatch frame
-    # per tick; replies coalesce back as ONE MOSDOpReplyBatch per reply
-    # tick.  Per-item semantics are preserved end to end: a THROTTLED
-    # or shed item un-acks only itself and AIMD pushback/ack accounting
-    # stays per item.  0 = one MOSDOp frame + one reply per op — the
-    # legacy bit-exactness / same-host A/B anchor; vstart _fast_config
-    # turns it on.
-    Option("objecter_batch_tick_ops", int, 0,
+           "crc32c batch); also bounds a dispatch tick, a read tick and "
+           "a peer's sub-write frame", min=1),
+    Option("objecter_batch_tick_ops", int, 16,
            "max client ops coalesced into ONE MOSDOpBatch frame per "
-           "(session, OSD) tick; a 1-op tick ships the plain legacy "
-           "MOSDOp frame.  0 = per-op frames (the anchor)", min=0),
-    Option("objecter_batch_tick_window", float, 0.0,
-           "extra accumulation window (s) after a client tick's first "
-           "parked op; 0 = pure group-commit self-clocking (a lone op "
-           "never waits)", min=0),
-    # unified pipelined commit frontier (round 12): EC RMW and
-    # replicated-pool mutations commit through the same split
-    # commit-start (under the PG lock) / ack-wait (lock released)
-    # path as round-11 pipelined EC full writes, all registered with
-    # the PG's commit frontier.  0 = the round-10 full-PG-lock commit
-    # for EVERY mutation — the serial bit-exactness anchor.
-    Option("osd_pipeline_writes", int, 1,
-           "pipeline mutation commits: hold the PG lock only for the "
-           "ordered commit section, await fan-out acks with it "
-           "released (EC full/RMW + replicated unified).  0 = legacy "
-           "full-lock serial commits (bisection anchor)",
-           min=0, max=1),
+           "(session, OSD) tick, and replies into ONE MOSDOpReplyBatch; "
+           "a 1-op tick ships the plain MOSDOp frame", min=1),
     Option("osd_op_complaint_time", float, 30.0,
            "ops blocked this long raise 'slow ops' warnings "
            "(reference osd_op_complaint_time; 0 disables)", min=0),
@@ -328,16 +295,16 @@ OPTIONS: List[Option] = [
     Option("mds_lease_ttl", float, 2.0),
     Option("mds_beacon_interval", float, 1.0),
     # ec
-    Option("osd_ec_batch_size", int, 64, "stripes per device dispatch"),
     Option("osd_ec_stripe_unit", int, 4096),
-    # bit-planar AT-REST shards (round 19): EC shard objects are stored,
-    # shipped (sub-writes/sub-reads/recovery push), and verified as
-    # packed bit-plane matrices — zero layout conversions on the
-    # steady-state write/read/RMW/recovery/scrub paths (pinned by the
-    # ec_planar_unseamed counter).  0 = byte-at-rest, the
-    # bisection/bit-exactness anchor; requires w=8 matrix codecs and
-    # stripe_unit % 8 == 0 (else the OSD quietly stays on bytes).
-    Option("osd_ec_planar_at_rest", int, 0, min=0, max=1),
+    # bit-planar AT-REST shards: EC shard objects are stored, shipped
+    # (sub-writes/sub-reads/recovery push) and verified as packed
+    # bit-plane matrices — zero layout conversions on the steady-state
+    # write/read/RMW/recovery/scrub paths (pinned by the
+    # ec_planar_unseamed counter).  Needs a w=8 matrix codec and
+    # stripe_unit % 8 == 0; a pool without them (LRC, SHEC) stays on
+    # byte-at-rest whatever this says (ec.stripe.planar_at_rest_ok).
+    # 0 = byte-at-rest for every pool.
+    Option("osd_ec_planar_at_rest", int, 1, min=0, max=1),
     # route EC pool batch encode/decode through the sharded mesh engine
     # (parallel/engine.py): "on" = use a device mesh, "off" = the
     # single-device codec engines.  ("on" needs >1 jax device; the mesh
@@ -346,11 +313,6 @@ OPTIONS: List[Option] = [
     Option("osd_ec_mesh_devices", int, 0),  # 0 = all visible devices
     # store
     Option("memstore_device_bytes", int, 1 << 30),
-    Option("bluestore_csum_type", str, "crc32c"),
-    # debug
-    Option("debug_ms", int, 0, min=0, max=20),
-    Option("debug_osd", int, 0, min=0, max=20),
-    Option("debug_mon", int, 0, min=0, max=20),
     # chaos (deterministic fault injection, ceph_tpu/chaos/): the
     # injectargs-able analog of the reference's ms_inject_socket_failures
     # / filestore_debug_inject_read_err debug seams.  All-zero defaults

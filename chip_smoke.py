@@ -216,7 +216,7 @@ async def serve_ec_objects(seed: int, n_objects: int = 64,
             return out
 
         # first compiles happen inside served ops: meet the tick's shape
-        # buckets before the checked window (as bench_cluster_io does)
+        # buckets before the checked window (as the benchmark's warm-up does)
         warm = rng.integers(0, 256, object_size, dtype=np.uint8).tobytes()
         for width in sorted({1, min(4, in_flight), in_flight}):
             await timed(f"warm_write_x{width}", _in_flight(

@@ -10,8 +10,7 @@ dict, or ``{daemon: payload}``) into Chrome-trace/Perfetto JSON with no
 cluster and no jax in sight.  ``demo`` boots a 3-OSD vstart cluster
 with tracing enabled, drives one EC write + read, and prints the op's
 cross-daemon span tree and stage attribution.  ``attribute`` runs a
-short EC write burst and prints the aggregated per-stage breakdown —
-the instrument behind ``bench.py --attribute``.
+short EC write burst and prints the aggregated per-stage breakdown.
 
 Exit codes (tested like scripts/chaos.py): 0 success, 1 bad/missing
 input or an incomplete trace, 2 usage error (argparse).

@@ -1,8 +1,8 @@
 """Test configuration: force a virtual 8-device CPU mesh.
 
 Must run before jax is imported anywhere.  Multi-chip sharding tests use this
-virtual mesh; real-TPU benchmarking goes through bench.py, which does not
-import this file.
+virtual mesh; on the chip the program runs through ``benchmark/run.py`` and
+``chip_smoke.py``, which do not import this file.
 """
 
 import os

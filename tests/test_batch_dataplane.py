@@ -3,7 +3,7 @@
 The coalesced tick must be invisible in the bytes: N concurrent writes
 through sharded dispatch + per-tick stripe-batch coalescing produce
 byte-identical shards (and stored CRCs) to the same writes issued
-serially through the round-10 per-op path — including mixed-profile
+one at a time through one shard and 1-op ticks — including mixed-profile
 ticks and the 1-op-tick degenerate case.  Unit level, the multi-op
 encode and the batched row CRC must match their per-op/host
 equivalents exactly.
@@ -214,19 +214,56 @@ def test_frontier_rebuild_and_learn():
     assert st2.frontier_recovering == {(1, 3)}
 
 
-def test_fast_config_enables_batched_data_plane():
-    """The vstart config (tests, bench, chaos scenarios incl. the
-    tier-1 overload-smoke run) exercises sharded dispatch + coalescing;
-    plain Config() keeps the zero-default per-op path for bisection."""
+def test_plain_config_is_the_product_data_plane():
+    """Plain ``Config()`` selects the served data plane (sharded
+    dispatch, both coalescers at a cap above one, planar at rest), so a
+    tool or daemon built without a config runs what the cells measure
+    (``_fast_config()`` only adds timings: tests/test_client_batch.py)."""
     from ceph_tpu.utils import Config
 
-    cfg = _fast_config()
-    assert cfg.osd_op_shards > 0 and cfg.osd_batch_tick_ops > 0
-    # round 18: the client edge coalesces too — same anchor rule
-    assert cfg.objecter_batch_tick_ops > 0
     plain = Config()
-    assert plain.osd_op_shards == 0 and plain.osd_batch_tick_ops == 0
-    assert plain.objecter_batch_tick_ops == 0
+    assert plain.osd_op_shards == 2
+    assert plain.osd_batch_tick_ops == 16
+    assert plain.objecter_batch_tick_ops == 16
+    assert plain.osd_ec_planar_at_rest == 1
+
+
+@pytest.mark.parametrize("cap", ["osd_op_shards", "osd_batch_tick_ops",
+                                 "objecter_batch_tick_ops"])
+def test_zero_is_refused_for_every_cap(cap):
+    """The "0" of each cap selected a superseded data plane; that plane
+    is gone, so zero is refused at construction and at injectargs (a
+    cap of one is the per-op reference)."""
+    from ceph_tpu.utils import Config
+
+    with pytest.raises(ValueError, match=cap):
+        Config(**{cap: 0})
+    cfg = Config(**{cap: 1})
+    with pytest.raises(ValueError, match=cap):
+        cfg.injectargs({cap: 0})
+    assert cfg.get(cap) == 1
+
+
+def test_every_option_is_read_by_some_module():
+    """An option nothing reads is a setting that does nothing: every
+    ``Option`` of utils/config.py is named somewhere in ``ceph_tpu/``
+    outside the table that declares it."""
+    import pathlib
+    import re
+
+    import ceph_tpu
+    from ceph_tpu.utils import config as configmod
+
+    root = pathlib.Path(ceph_tpu.__file__).parent
+    here = pathlib.Path(configmod.__file__)
+    source = "\n".join(
+        p.read_text() for p in sorted(root.rglob("*.py")) if p != here)
+    # the schema's own file counts only below the OPTIONS table (the
+    # Config methods read the auth keys)
+    source += here.read_text().split("\nclass Config:", 1)[1]
+    words = set(re.findall(r"\w+", source))
+    unread = [o.name for o in configmod.OPTIONS if o.name not in words]
+    assert not unread, unread
 
 
 # ---------------------------------------------------------- cluster level
@@ -323,30 +360,34 @@ def _shard_snapshot(cluster, client, pools):
 def test_coalesced_writes_bit_exact_vs_per_op_path():
     """THE round-11 acceptance invariant: concurrent writes through
     sharded dispatch + coalescing leave every OSD's stored shards and
-    CRCs byte-identical to the same writes issued serially through the
-    legacy per-op path (mixed-profile ticks + RMW + 1-op tick
-    included)."""
+    CRCs byte-identical to the same writes issued one at a time through
+    one shard and 1-op ticks — every op its own dispatch, its own
+    encode and its own sub-write frames (mixed-profile ticks + RMW +
+    1-op tick included)."""
     async def run_path(coalesced: bool):
         cfg = _fast_config()
         if not coalesced:
-            # the full round-10 serial anchor: per-op dispatch/encode
-            # AND full-PG-lock commits (no pipelined frontier)
-            cfg.osd_op_shards = 0
-            cfg.osd_batch_tick_ops = 0
-            cfg.osd_pipeline_writes = 0
+            # the per-op reference on the one path: a cap of one
+            # everywhere on the OSD, ops issued serially
+            cfg.osd_op_shards = 1
+            cfg.osd_batch_tick_ops = 1
         cluster = await start_cluster(5, config=cfg)
         try:
             client, pools = await _write_workload(
                 cluster, concurrent=coalesced)
             snap = _shard_snapshot(cluster, client, pools)
+            ticks = sum(o.perf.get("osd_batch_ticks")
+                        for o in cluster.osds.values())
+            coalesced_ops = sum(o.perf.get("osd_batch_coalesced_ops")
+                                for o in cluster.osds.values())
             if coalesced:
                 # every full write really rode the coalescer
-                ticks = sum(o.perf.get("osd_batch_ticks")
-                            for o in cluster.osds.values())
-                coalesced_ops = sum(
-                    o.perf.get("osd_batch_coalesced_ops")
-                    for o in cluster.osds.values())
                 assert ticks > 0 and coalesced_ops >= 12
+            else:
+                # the reference coalesced nothing: every tick held one op
+                assert coalesced_ops == ticks
+                assert not any(o.perf.get("osd_subwrite_batches")
+                               for o in cluster.osds.values())
             return snap
         finally:
             await cluster.stop()
@@ -361,7 +402,7 @@ def test_coalesced_writes_bit_exact_vs_per_op_path():
 @contention_retry()
 def test_client_batched_frames_bit_exact_vs_per_op_frames():
     """THE round-18 acceptance invariant: the SAME concurrent workload
-    through MOSDOpBatch client frames vs legacy per-op MOSDOp frames
+    through MOSDOpBatch client frames vs one plain MOSDOp frame per op
     (OSD-interior coalescing identical on both sides) leaves every
     OSD's stored shards and CRCs byte-identical — mixed verbs
     (write/RMW/append/truncate/delete), replicated + EC pools, and the
@@ -369,8 +410,8 @@ def test_client_batched_frames_bit_exact_vs_per_op_frames():
     async def run_path(client_batched: bool):
         cfg = _fast_config()
         if not client_batched:
-            # the anchor: per-op client frames, everything else equal
-            cfg.objecter_batch_tick_ops = 0
+            # the reference: a frame per op, everything else equal
+            cfg.objecter_batch_tick_ops = 1
         cluster = await start_cluster(5, config=cfg)
         try:
             client, pools = await _write_workload(

@@ -2,7 +2,7 @@
 coalescing with batched replies.
 
 Unit level: the objecter's OpBatcher coalesces a tick's ops to one OSD
-into ONE MOSDOpBatch frame (a lone op ships the plain legacy MOSDOp),
+into ONE MOSDOpBatch frame (a lone op ships the plain MOSDOp),
 and the reply-batch scatter resolves each item's future individually —
 per-item ``throttled`` flags preserved, a reqid ABSENT from the reply
 tick left pending (the SubWriteBatcher un-ack rule at the client edge).
@@ -70,7 +70,7 @@ def test_reply_batch_scatters_per_item_preserving_throttled_and_absence():
 def test_op_batcher_coalesces_per_osd_and_lone_op_ships_plain_frame():
     """Concurrent sends to one OSD pack into MOSDOpBatch frames (with
     the amortized client_batch_wait/send trace stamps); a lone op to
-    another OSD ships the plain legacy MOSDOp, unstamped."""
+    another OSD ships the plain MOSDOp, unstamped."""
 
     async def scenario():
         obj = _mk_objecter(objecter_batch_tick_ops=8)
@@ -93,7 +93,7 @@ def test_op_batcher_coalesces_per_osd_and_lone_op_ships_plain_frame():
                              obj._send_op(op(99), addr_b))
         a_frames = [m for a, m in sent if a == addr_a]
         b_frames = [m for a, m in sent if a == addr_b]
-        # OSD b saw a lone op: the plain legacy frame, no batch stamps
+        # OSD b saw a lone op: the plain frame, no batch stamps
         assert len(b_frames) == 1 and isinstance(b_frames[0], M.MOSDOp)
         assert all(name not in ("objecter:batch_tick",
                                 "objecter:batch_sent")
@@ -120,12 +120,12 @@ def test_op_batcher_coalesces_per_osd_and_lone_op_ships_plain_frame():
     run(scenario())
 
 
-def test_op_batcher_zero_gate_keeps_legacy_per_op_frames():
-    """objecter_batch_tick_ops=0 (the anchor): every op ships its own
-    MOSDOp frame and the batcher is never armed."""
+def test_op_batcher_cap_of_one_ships_every_op_in_its_own_plain_frame():
+    """objecter_batch_tick_ops=1 (the per-op reference): every op ships
+    its own plain MOSDOp frame, unstamped, and no tick is counted."""
 
     async def scenario():
-        obj = _mk_objecter()  # zero-default gate
+        obj = _mk_objecter(objecter_batch_tick_ops=1)
         sent = []
 
         async def fake_send(msg, addr):
@@ -139,8 +139,8 @@ def test_op_batcher_zero_gate_keeps_legacy_per_op_frames():
             for i in range(4)])
         assert len(sent) == 4
         assert all(isinstance(m, M.MOSDOp) for m in sent)
-        assert not obj._op_batcher._workers
         assert obj.flow_counters()["client_batch_ticks"] == 0
+        await obj.stop()
 
     run(scenario())
 
@@ -196,13 +196,21 @@ def test_client_batch_attribution_stage_math():
     assert stages["wire"] > 0
 
 
-def test_fast_config_enables_client_batching_and_plain_config_does_not():
-    """vstart clusters run the client-edge coalescer; plain Config()
-    keeps the per-op frame anchor (the bisection rule every batching
-    layer follows)."""
-    cfg = _fast_config()
-    assert cfg.objecter_batch_tick_ops > 0
-    assert Config().objecter_batch_tick_ops == 0
+def test_fast_config_differs_from_config_only_in_its_named_timings():
+    """``_fast_config()`` is ``Config()`` plus the timing keys its
+    docstring names, and nothing else: no data-plane switch hides in
+    it."""
+    import re
+
+    fast, base = _fast_config().show(), Config().show()
+    differs = {k for k in base if fast[k] != base[k]}
+    named = set(re.findall(r"``(\w+)``", _fast_config.__doc__)) & set(base)
+    assert differs <= named, differs - named
+    # the one named key whose value is also the default
+    assert named - differs == {"osd_heartbeat_interval"}
+    assert all(k.endswith(("_interval", "_grace", "_timeout",
+                           "_delay_start", "_reporters"))
+               for k in named), named
 
 
 # ---------------------------------------------------------- cluster level

@@ -298,14 +298,17 @@ def test_unified_telemetry_end_to_end():
             # byte-path ec_matmul counters remain for non-planar routes)
             dk = perf["device_kernels"]
             # round 11: CPU backends run the coalesced write path on the
-            # vectorized host GF engine (ec_host_matmul_*); device
-            # backends keep the planar/byte matmul counters
+            # vectorized host GF engine (ec_host_planar_matmul_* for a
+            # planar-at-rest pool, ec_host_matmul_* for a byte one);
+            # device backends keep the planar/byte matmul counters
             assert dk.get("planar_matmul_calls", 0) >= 1 \
                 or dk.get("ec_matmul_calls", 0) >= 1 \
-                or dk.get("ec_host_matmul_calls", 0) >= 1
+                or dk.get("ec_host_matmul_calls", 0) >= 1 \
+                or dk.get("ec_host_planar_matmul_calls", 0) >= 1
             assert dk.get("planar_convert_to_planar_bytes", 0) >= 1 \
                 or dk.get("ec_matmul_bytes", 0) >= 1 \
-                or dk.get("ec_host_matmul_bytes", 0) >= 1
+                or dk.get("ec_host_matmul_bytes", 0) >= 1 \
+                or dk.get("ec_host_planar_matmul_bytes", 0) >= 1
             schema = await cluster.daemon_command(
                 f"osd.{primary}", "perf schema")
             assert schema[f"osd.{primary}"]["osd_op_lat_hist"]["type"] \
@@ -327,13 +330,10 @@ def test_unified_telemetry_end_to_end():
             assert "objecter:submit" in ev
             assert any(e.startswith("msgr:") for e in ev)
             assert "dispatched" in ev
-            # coalesced tick marks (default config) or the per-op pair
-            assert "batch_encoded" in ev or "ec_encode" in ev
+            assert "batch_encoded" in ev    # the coalesced tick mark
             assert "store:journal_queued" in ev
             assert "commit" in ev
-            enc = "batch_encoded" if "batch_encoded" in ev \
-                else "ec_encode"
-            assert ev.index("dispatched") < ev.index(enc) < \
+            assert ev.index("dispatched") < ev.index("batch_encoded") < \
                 ev.index("commit")
             assert traced[0].get("trace_id")
 

@@ -236,11 +236,11 @@ def test_byte_throttle_release_after_dispatch_and_attribution():
 
         config = _fast_config()
         config.osd_client_message_size_cap = 150_000
-        # per-op frames: the byte-budget release under test is a
-        # per-MESSAGE property.  The round-18 client coalescer would
+        # per-op frames (a cap of one): the byte-budget release under
+        # test is a per-MESSAGE property.  The client coalescer would
         # pack all three writes into ONE MOSDOpBatch frame, which the
         # cap admits as a single oversize message and never blocks.
-        config.objecter_batch_tick_ops = 0
+        config.objecter_batch_tick_ops = 1
         cluster = await start_cluster(3, config=config)
         try:
             client = await cluster.client()

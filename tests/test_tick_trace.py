@@ -252,7 +252,7 @@ def test_outside_a_tick_the_phase_is_one_shared_noop():
         assert p is ticktrace.NULL_PHASE
     ticktrace.device_calls(3)
     ticktrace.annotate(1, 1, 1)
-    # a direct call (tests, tools, encode_once) records no tick and
+    # a direct call (tests, tools) records no tick and
     # feeds none of its counters
     codec = factory({"plugin": "jerasure", "technique": "reed_sol_van",
                      "k": "2", "m": "1"})

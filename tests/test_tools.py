@@ -172,3 +172,31 @@ def test_rados_bench_modes_on_ec_pool(capsys):
     finally:
         loop.run_until_complete(cluster.stop())
         loop.close()
+
+
+def test_rados_cli_on_plain_config_sends_through_the_client_coalescer(
+        capsys):
+    """The CLI builds its client on plain ``Config()``, which is the
+    product: concurrent bench writes leave as MOSDOpBatch frames through
+    the objecter's OpBatcher, the client edge the cells measure."""
+    from ceph_tpu.cluster.vstart import start_cluster
+
+    loop = asyncio.new_event_loop()
+    cluster = loop.run_until_complete(start_cluster(3))
+    try:
+        client = loop.run_until_complete(cluster.client())
+        loop.run_until_complete(
+            client.pool_create("benchrep", "replicated", pg_num=4, size=2))
+        mon = f"{cluster.mon_addrs[0][0]}:{cluster.mon_addrs[0][1]}"
+        assert loop.run_until_complete(rados._run(rados.parse_args(
+            ["--mon", mon, "-p", "benchrep", "bench", "0.5", "write",
+             "-t", "8", "--block-size", "4096"]))) == 0
+        assert "bandwidth" in capsys.readouterr().out
+        frames = sum(o.perf.get("osd_client_batch_frames")
+                     for o in cluster.osds.values())
+        items = sum(o.perf.get("osd_client_batch_items")
+                    for o in cluster.osds.values())
+        assert frames > 0 and items > frames
+    finally:
+        loop.run_until_complete(cluster.stop())
+        loop.close()

@@ -436,11 +436,9 @@ def test_traced_op_cross_daemon_smoke():
             assert "objecter:submit" in names       # client-side stamps
             assert any(n.startswith("msgr:") and n.endswith(":recv")
                        for n in names)              # wire arrival
-            # device-encode evidence: the coalesced tick marks (default
-            # vstart config) or the per-op pair (osd_batch_tick_ops=0)
-            assert (("batch_parked" in names and "batch_tick" in names
-                     and "batch_encoded" in names)
-                    or ("ec_encode" in names and "ec_encoded" in names))
+            # device-encode evidence: the coalesced tick marks
+            assert ("batch_parked" in names and "batch_tick" in names
+                    and "batch_encoded" in names)
             assert "store:commit" in names
             assert "ec_sub_write_sent" in names
             assert "sub_write_acked" in names
@@ -455,8 +453,7 @@ def test_traced_op_cross_daemon_smoke():
             assert abs(sum(stages.values()) - total) < 1e-9
             assert total >= 0.85 * wall, (total, wall, stages)
             # device work books as the amortized coalesced-tick stage
-            # (default config) or the legacy per-op device_encode
-            assert "batch_encode" in stages or "device_encode" in stages
+            assert "batch_encode" in stages
             # the admin aggregation agrees
             primary = client.objecter._target_osd(
                 client.objecter.object_pgid(pool, "traced"))

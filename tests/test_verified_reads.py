@@ -3,7 +3,7 @@
 The read-side twin of tests/test_batch_dataplane.py's write gate: the
 coalesced decode must be invisible in the bytes — N concurrent reads
 through the read coalescer return byte-identical data to the same reads
-issued serially through the per-op anchor path (mixed-profile ticks,
+issued one at a time through 1-op ticks (mixed-profile ticks,
 the 1-op tick, degraded fast-k reads, and the recovery reencode
 included).  Unit level, the multi decode/reencode must match their
 per-op equivalents exactly, and the corruption matrix proves every
@@ -214,7 +214,7 @@ def test_read_batcher_verify_and_fault_isolation():
 async def _read_workload(cluster, concurrent: bool):
     """Write a fixed workload (two EC profiles + RMW + a solo object),
     then read every object — concurrently (coalesced ticks) or serially
-    (the per-op anchor).  Returns {(pool_name, oid): bytes} plus the
+    (the per-op reference).  Returns {(pool_name, oid): bytes} plus the
     expected payloads."""
     client = await cluster.client()
     pool_a = await client.pool_create(
@@ -269,15 +269,15 @@ async def _read_workload(cluster, concurrent: bool):
 def test_batched_reads_bit_exact_vs_per_op_path():
     """THE round-16 read gate: concurrent reads through the read
     coalescer (verify-on-read enabled) return byte-identical data to
-    the same reads issued serially through the per-op anchor — full
-    and sub-range reads, mixed profiles, plus a degraded fast-k read
-    with a shard holder stopped."""
+    the same reads issued one at a time through one shard and 1-op
+    ticks — full and sub-range reads, mixed profiles, plus a degraded
+    fast-k read with a shard holder stopped."""
     async def run_path(coalesced: bool):
         cfg = _fast_config()
         if not coalesced:
-            cfg.osd_op_shards = 0
-            cfg.osd_batch_tick_ops = 0
-            cfg.osd_pipeline_writes = 0
+            # the per-op reference on the one path: a cap of one
+            cfg.osd_op_shards = 1
+            cfg.osd_batch_tick_ops = 1
         cluster = await start_cluster(5, config=cfg)
         try:
             client, expect, got, got_parts, (pool_a, io_a) = \
@@ -298,13 +298,19 @@ def test_batched_reads_bit_exact_vs_per_op_path():
             await cluster.kill_osd(victim)
             degraded = await io_a.read("ra1", timeout=120)
             assert degraded == expect[("a", "ra1")]
+            ticks = sum(o.perf.get("osd_read_batch_ticks")
+                        for o in cluster.osds.values())
             if coalesced:
                 # healthy reads short-circuit (pure host interleave +
                 # inline hw crc); the DEGRADED decode above is what
                 # must ride a coalesced tick
-                ticks = sum(o.perf.get("osd_read_batch_ticks")
-                            for o in cluster.osds.values())
                 assert ticks > 0
+            else:
+                # the reference coalesced nothing: every tick held one
+                # decode
+                assert ticks == sum(
+                    o.perf.get("osd_read_batch_coalesced")
+                    for o in cluster.osds.values())
             return {k: (got[k], got_parts[k]) for k in expect}, degraded
         finally:
             await cluster.stop()
