@@ -44,6 +44,21 @@ class ECSizeMismatch(Exception):
         self.size = size
 
 
+def _shard_bytes(shard) -> memoryview:
+    """One shard of a tick's results — an (8, cols) plane block or a
+    byte row, C-contiguous — as the flat bytes that land in the store
+    and ride the wire: a read-only VIEW, not ``tobytes()``.  Nothing
+    writes to a tick's results once the tick has returned, and the
+    view says so: ``messenger._encode`` hands a read-only buffer of at
+    least ``_OOB_MIN`` to the transport as it is (the replay buffer's
+    reference pins the op's planes until the ack, as it pinned the
+    ``bytes``) and copies a writable one into the pickle.  Flat,
+    because ``len()`` of an (8, cols) view is 8."""
+    flat = shard.reshape(-1)
+    flat.setflags(write=False)
+    return flat.data
+
+
 def choose_decode_group(got: Dict[int, Tuple[bytes, int, int]],
                         need_k: int, committed,
                         committed_before=None) -> Tuple[
@@ -342,9 +357,12 @@ class ECBackendMixin:
         ``_ec_commit_finish`` resolves outside the lock.
 
         ``layout`` == "planar8" means ``shards[i]`` is an (8, cols)
-        AT-REST plane matrix: tobytes() serializes it row-major — the
-        same bytes that land in the store and ride the wire, so the
-        commit path is conversion-free end to end (round 19)."""
+        AT-REST plane matrix, C-contiguous in the tick's per-op block:
+        its own memory, row-major, IS what lands in the store and rides
+        the wire, so the commit path is conversion-free end to end
+        (round 19) and copy-free up to each holder's store, which takes
+        the one copy it keeps (``_shard_bytes``; byte-at-rest layouts
+        hand out their byte rows the same way)."""
         from ceph_tpu.cluster.optracker import mark_current
 
         # re-checked UNDER the lock: the acting set can shrink during
@@ -386,7 +404,7 @@ class ECBackendMixin:
                     peers.append((osd, shard))
             if my_shard is not None:
                 self._apply_shard(st.pgid, oid, my_shard,
-                                  shards[my_shard].tobytes(), chunk_off,
+                                  _shard_bytes(shards[my_shard]), chunk_off,
                                   shard_size, hinfo_for(my_shard),
                                   pre_ops=pre_ops, layout=layout)
                 mark_current("store:journal_queued")
@@ -409,7 +427,7 @@ class ECBackendMixin:
                 for osd, shard in peers:
                     sub = M.MOSDECSubOpWrite(
                         reqid=reqid, pgid=st.pgid, oid=oid, shard=shard,
-                        data=shards[shard].tobytes(),
+                        data=_shard_bytes(shards[shard]),
                         chunk_off=chunk_off,
                         shard_size=shard_size, hinfo=hinfo_for(shard),
                         entry=entry,
@@ -632,6 +650,9 @@ class ECBackendMixin:
         old_layout = self.store.object_layout(coll, oid)
         cols = shard_size // Q
         col_off = chunk_off // Q
+        # a view of what came (bytes, a frame's memoryview, the tick's
+        # planes): needed only to clip and, with no crc shipped, to
+        # checksum; ``data`` itself goes to the store, which copies it
         window = planar_store.blob_to_planes(data)
         if col_off + window.shape[1] > cols:
             # window overshoots the final shard (byte path: write then
@@ -1376,7 +1397,7 @@ class ECBackendMixin:
                 continue
             if targets is not None and osd not in targets:
                 continue
-            blob = chunks[shard].tobytes()
+            blob = _shard_bytes(chunks[shard])
             if osd == self.osd_id:
                 self._apply_shard(st.pgid, oid, shard, blob, 0,
                                   shard_len, hinfo, layout=out_layout)

@@ -32,11 +32,21 @@ references.  Receiving: ``_FrameStream`` has the transport
 ``recv_into`` one ``bytearray`` per large frame, and verification,
 unpickling and the message's out-of-band fields are all views of that
 buffer.  The ONE user-space copy a payload byte takes per hop is its
-consumer's, at its own door: ``Transaction.write`` / ``write_planar``
-(``store.py``: the store owns what it keeps), ``MOSDOpReply.own_data``
+consumer's, at its own door: the store's (``store.py``: it owns what
+it keeps, a ``bytearray`` of its own; ``Transaction.write`` takes its
+``bytes`` at once, ``write_planar`` keeps the view it is given and
+``MemStore`` copies it when the op is applied), ``MOSDOpReply.own_data``
 (the client API returns ``bytes``), the encode tick's fill of its host
-batch.  Exceptions, all bounded: a frame that fits the
-``_RECV_SCRATCH`` buffer (256 KiB) is read into it and cut out of it
+batch.  For ``write_planar`` that holds since PR 32, and of a write
+that replaces the whole shard (``plane_off`` 0 and a window of
+``total_cols`` columns: every ``write_full``); a partial window (an
+append, an RMW, one clipped to the shard) is spliced into the old plane
+matrix as before: a zero fill of the shard and two more copies of it.
+The EC fan-out's sub-writes set out the same way: their ``data`` is a
+flat read-only view of the encode tick's planes
+(``backend_ec._shard_bytes``), not ``tobytes()``, and so is what the
+primary's own store is given.  Exceptions, all bounded: a frame that
+fits the ``_RECV_SCRATCH`` buffer (256 KiB) is read into it and cut out of it
 (one more copy; for these a read less is worth more than a copy
 less), as is the head of a large frame that came in the same read as
 its length prefix (at most ``_RECV_PEEK`` = 4 KiB within a run of

@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.cluster.optracker import mark_current
 from ceph_tpu.ec import planar_store
+from ceph_tpu.utils.perf import KERNELS
 
 
 @dataclass
@@ -56,9 +57,14 @@ class Transaction:
         column ``plane_off`` (= byte offset / 8) and size the object to
         exactly ``total_cols`` columns (= shard bytes / 8).  One op
         covers the byte path's write+truncate pair, and the object's
-        layout becomes planar."""
+        layout becomes planar.
+
+        ``data`` (``bytes``, a ``memoryview``, a contiguous array) is
+        kept as it is given and must not change before the transaction
+        is queued: the store takes its one copy of it when the op is
+        applied, and a journal its ``bytes`` in ``encode``."""
         self.ops.append(("write_planar", coll, oid, plane_off,
-                         bytes(data), total_cols))
+                         data, total_cols))
         return self
 
     def truncate(self, coll: str, oid: str, size: int):
@@ -110,7 +116,12 @@ class Transaction:
         return self
 
     def encode(self) -> bytes:
-        return pickle.dumps(self.ops)
+        # a planar window may still be a view of a frame or of a tick's
+        # planes (write_planar), which no pickle takes
+        return pickle.dumps([
+            (*op[:4], bytes(op[4]), op[5])
+            if op[0] == "write_planar" and type(op[4]) is not bytes
+            else op for op in self.ops])
 
     @classmethod
     def decode(cls, blob: bytes) -> "Transaction":
@@ -294,23 +305,36 @@ class MemStore(ObjectStore):
             _, coll, oid, plane_off, data, total_cols = op
             o = self._coll(coll).setdefault(oid, Obj())
             old = len(o.data)
-            window = planar_store.blob_to_planes(data)
-            if o.data and o.layout == planar_store.LAYOUT_PLANAR:
-                cur = planar_store.blob_to_planes(bytes(o.data))
-            elif o.data:
-                # a planar write landing on a byte-at-rest object: the
-                # config gate flipped mid-life — convert once, counted
-                # (zero-pad to the 8-byte packing quantum; EC shards are
-                # stripe-unit aligned so this is a non-EC-object guard)
-                raw = bytes(o.data)
-                if len(raw) % 8:
-                    raw += b"\0" * (8 - len(raw) % 8)
-                cur = planar_store.shard_to_planes(raw, seam="relayout")
+            blob = memoryview(data)
+            KERNELS.inc("store_planar_write_bytes", blob.nbytes)
+            if plane_off == 0 and blob.nbytes == 8 * total_cols:
+                # the window IS the new shard (every full-shard write):
+                # nothing of the old object survives it, so it is not
+                # read, and the store's own copy is the only one made
+                o.data[:] = blob
+                KERNELS.inc("store_planar_direct_bytes", blob.nbytes)
             else:
-                cur = None
-            merged = planar_store.splice_columns(
-                cur, plane_off, window, total_cols)
-            o.data[:] = planar_store.planes_to_blob(merged)
+                # a partial (or overshooting) window lands in the old
+                # plane matrix, zero-extended or cut to total_cols
+                window = planar_store.blob_to_planes(blob)
+                if o.data and o.layout == planar_store.LAYOUT_PLANAR:
+                    cur = planar_store.blob_to_planes(bytes(o.data))
+                elif o.data:
+                    # a planar write landing on a byte-at-rest object:
+                    # the config gate flipped mid-life — convert once,
+                    # counted (zero-pad to the 8-byte packing quantum;
+                    # EC shards are stripe-unit aligned so this is a
+                    # non-EC-object guard)
+                    raw = bytes(o.data)
+                    if len(raw) % 8:
+                        raw += b"\0" * (8 - len(raw) % 8)
+                    cur = planar_store.shard_to_planes(raw,
+                                                       seam="relayout")
+                else:
+                    cur = None
+                o.data[:] = planar_store.planes_to_blob(
+                    planar_store.splice_columns(
+                        cur, plane_off, window, total_cols))
             o.layout = planar_store.LAYOUT_PLANAR
             o.version += 1
             self._used += len(o.data) - old

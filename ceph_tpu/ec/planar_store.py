@@ -105,8 +105,10 @@ def planes_to_shard(planes: np.ndarray, *, seam: Optional[str] = None) -> bytes:
 def blob_to_planes(blob) -> np.ndarray:
     """At-rest plane BLOB (row-major serialization) -> (8, L/8) view.
 
-    NOT a layout conversion — the blob already is the plane matrix."""
-    arr = np.frombuffer(bytes(blob), dtype=np.uint8)
+    NOT a layout conversion — the blob already is the plane matrix, and
+    the result is a view of it (read-only where the blob is): ``bytes``,
+    a ``memoryview`` of a frame, a contiguous array."""
+    arr = np.frombuffer(blob, dtype=np.uint8)
     if arr.size % 8:
         raise ValueError(f"planar blob size {arr.size} not 8-row")
     return arr.reshape(8, arr.size // 8)

@@ -651,24 +651,30 @@ def test_throttle_backpressure_stops_the_socket_drain():
 
 
 @pytest.mark.parametrize("verb", ["write", "write_planar"])
-def test_transaction_copies_what_it_is_given(verb):
-    """The store never aliases a frame: ``Transaction.write`` and
-    ``write_planar`` keep ``bytes`` of their own, so a view of a receive
-    buffer is let go and a later change of that buffer is not seen."""
-    from ceph_tpu.cluster.store import Transaction
+def test_store_copies_what_it_is_given(verb):
+    """The store never aliases a frame: what it keeps of a view of a
+    receive buffer is a copy of its own (``Transaction.write`` takes it
+    at once, ``write_planar`` leaves it to the store's apply, PR 32),
+    so a later change of that buffer is not seen and the frame is let
+    go."""
+    from ceph_tpu.cluster.store import MemStore, Transaction
 
     frame = bytearray(os.urandom(4096))
     view = memoryview(frame)[1024:3072].toreadonly()
     want = bytes(view)
-    txn = Transaction()
+    txn = Transaction().create_collection("c")
     if verb == "write":
         txn.write("c", "o", 0, view)
+        assert type(txn.ops[1][4]) is bytes
     else:
         txn.write_planar("c", "o", 0, view, 256)
-    kept = txn.ops[0][4]
-    assert type(kept) is bytes and kept == want
+    store = MemStore()
+    store.queue_transaction(txn)
+    del txn, view
     frame[:] = bytes(4096)
-    assert kept == want
+    frame.extend(b"\0")     # BufferError while anything views the frame
+    obj = store._colls["c"]["o"]
+    assert type(obj.data) is bytearray and obj.data == want
 
 
 def test_stored_object_does_not_alias_the_frame():
