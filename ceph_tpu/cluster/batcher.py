@@ -607,10 +607,13 @@ class EncodeBatcher:
                 osd._chaos_point("tick_mid_encode")
                 t0 = osd.clock.monotonic()
                 try:
+                    # a planar tick is told the cap: its first tick of
+                    # a size compiles the buckets a full tick would meet
                     results = await _compute_tick(
                         osd, ticktrace.ENCODE_TICK, batch, encode_fn,
                         codec, sinfo, [r.data for r in batch],
-                        [r.want_crc for r in batch])
+                        [r.want_crc for r in batch],
+                        *((cap,) if key[3] else ()))
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:

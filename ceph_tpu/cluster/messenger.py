@@ -624,6 +624,7 @@ def _encode(msg: "Message") -> _Frame:
 
     payload = pickle.dumps(msg, protocol=5, buffer_callback=in_band)
     out = sum(len(b) for b in bufs)
+    KERNELS.inc("msgr_frames")
     KERNELS.inc("msgr_frame_bytes", len(payload) + out)
     if out:
         KERNELS.inc("msgr_oob_bytes", out)

@@ -29,6 +29,8 @@ from ceph_tpu.crush.types import (
     RULE_CHOOSELEAF_FIRSTN,
     RULE_CHOOSELEAF_INDEP,
     RULE_EMIT,
+    RULE_SET_CHOOSELEAF_TRIES,
+    RULE_SET_CHOOSE_TRIES,
     RULE_TAKE,
     Rule,
 )
@@ -690,6 +692,11 @@ class Monitor(Dispatcher):
         new.apply_incremental(copy.deepcopy(inc))
         if placement:
             for pid, pool in new.pools.items():
+                if pid not in old.pools:
+                    continue   # nothing was placed before: no donor
+                # one walk a pool and a side, not one a PG
+                new_rows = new.pool_raw_up(pid)
+                old_rows = old.pool_raw_up(pid)
                 for seed in range(pool.pg_num):
                     pgid = PGid(pid, seed)
                     if pgid in inc.new_pg_temp:
@@ -710,11 +717,11 @@ class Monitor(Dispatcher):
                     # were the observed failure mode — an out committed
                     # mid-blip saw empty donors (no mint, data stranded)
                     # or degraded newcomers (a crippled entry).
-                    new_raw = new.pg_raw_up(pgid)
+                    new_raw = new_rows[seed]
                     new_set = {o for o in new_raw if o >= 0}
                     if not new_set:
                         continue
-                    old_raw = old.pg_raw_up(pgid)
+                    old_raw = old_rows[seed] if seed < len(old_rows) else []
                     donors = [o for o in old_raw
                               if o >= 0 and o < new.max_osd
                               and new.osd_exists[o]]
@@ -1578,8 +1585,13 @@ class Monitor(Dispatcher):
             ec_profile["stripe_unit"] = str(codec.stripe_unit(
                 int(ec_profile.get("stripe_unit",
                                    self.config.osd_ec_stripe_unit))))
-            # ErasureCode::create_rule analog: indep chooseleaf rule
+            # ErasureCode::create_rule analog: indep chooseleaf rule,
+            # with the tries upstream's add_simple_rule gives every
+            # indep rule: at the tunables' 50 a pool that takes all of
+            # its hosts (k+m of k+m) leaves one slot in ~60 PGs unfilled
             rule = Rule(steps=[
+                (RULE_SET_CHOOSELEAF_TRIES, 5, 0),
+                (RULE_SET_CHOOSE_TRIES, 100, 0),
                 (RULE_TAKE, root, 0),
                 (RULE_CHOOSELEAF_INDEP, size, 1),
                 (RULE_EMIT, 0, 0)], type=POOL_TYPE_ERASURE)

@@ -23,7 +23,12 @@ from ceph_tpu.cluster.messenger import (
     Messenger,
 )
 from ceph_tpu.ops.jenkins import str_hash_rjenkins
-from ceph_tpu.osdmap.osdmap import OSDMap, PGid, ceph_stable_mod
+from ceph_tpu.osdmap.osdmap import (
+    OSDMap,
+    PGid,
+    ceph_stable_mod,
+    placement_snapshot,
+)
 from ceph_tpu.utils import Config
 from ceph_tpu.utils.backoff import AIMDWindow, ExpBackoff
 from ceph_tpu.utils.tasks import track_task
@@ -81,7 +86,8 @@ class Objecter(Dispatcher):
         # THROTTLED (-EBUSY) pushback — the primary flow-control signal,
         # replacing blind wait_for timeouts.  Wide open until the first
         # pushback, so with throttles off (default) it never constrains.
-        self._primary_cache: Tuple[Optional[int], Dict] = (None, {})
+        self._primary_cache: Tuple[Optional[int], Dict, Dict] = \
+            (None, {}, {})
         # reply-leg tail timelines (round 11): the OSD's terminal reply
         # carries a trace whose hop stamps + our completion stamp cover
         # the previously-untraced reply flight + client wakeup; an
@@ -287,16 +293,24 @@ class Objecter(Dispatcher):
         # per-epoch primary cache: the scalar CRUSH walk per op was a
         # measurable slice of the t16 hot path; any map change bumps the
         # epoch and drops the whole cache (pg_temp/primary_temp ride
-        # epochs too, so staleness is impossible by construction)
+        # epochs too, so staleness is impossible by construction).  A
+        # miss resolves from the pool's one walk, kept for the epoch: a
+        # wide EC pool's scalar walk is ~18 ms a PG, and the first op to
+        # each PG of every epoch paid it on the loop the ops share
         m = self.osdmap
-        epoch, cache = self._primary_cache
+        epoch, primaries, pools = self._primary_cache
         if epoch != m.epoch:
-            cache = {}
-            self._primary_cache = (m.epoch, cache)
-        primary = cache.get(pgid)
+            primaries, pools = {}, {}
+            self._primary_cache = (m.epoch, primaries, pools)
+        primary = primaries.get(pgid)
         if primary is None:
-            _, _, _, primary = m.pg_to_up_acting_osds(pgid)
-            cache[pgid] = primary
+            pool = m.pools.get(pgid.pool)
+            if pool is None or pgid.seed >= pool.pg_num:
+                return -1
+            snap = pools.get(pgid.pool)
+            if snap is None:
+                snap = pools[pgid.pool] = placement_snapshot(m, pgid.pool)
+            primary = primaries[pgid] = snap.resolve(pgid.seed)[3]
         return primary
 
     def _record_reply_tail(self, reply) -> None:

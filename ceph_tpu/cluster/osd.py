@@ -1254,6 +1254,10 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         if old is not None and newmap.epoch < old.epoch:
             return  # stale full map
         self.osdmap = newmap
+        # a full map's crush_gen counts another object's mutations: no
+        # kept key may vouch for it (the snapshots still feed the diff)
+        for snap in self._placement_cache.values():
+            snap.key = None
         self.perf.inc("osd_map_epochs_applied",
                       max(1, newmap.epoch - old.epoch) if old is not None
                       else 1)
@@ -1329,17 +1333,41 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
         """Recompute PG membership and queue peering for the PGs an
         epoch actually moved; returns True when peering has work.
 
-        Round 14: with osd_map_vectorized_delta (default) each pool's
-        resolved placement is snapshotted after every advance and
-        DIFFED against the previous one (osdmap.placement_delta) — one
-        batched dispatch plus whole-pool array compares per epoch, zero
-        per-PG Python for unaffected PGs, and only primaries whose
-        up/acting moved re-peer.  With it off, every PG rescans and any
-        change re-peers every primary PG — the per-PG-scan bit-exactness
-        anchor (the pre-round-14 behavior).  PG log/last_update are
+        Every pool keeps the snapshot of its last walk (its resolved
+        placement as arrays, and the ``placement_key`` it was walked
+        at).  An epoch walks only the pools whose key moved: a new
+        pool, an address, a flag or another pool's pg_temp leaves the
+        rest alone.  A pool that is walked is walked once, whole, by
+        the engine ``OSDMap.placement_engine`` picks from the work the
+        walk is (the scalar chain for a few dozen draws, the numpy
+        host walk up to tens of thousands, the device mapper beyond:
+        osdmap.py says where they cross and why), and DIFFED against
+        its last snapshot (``placement_delta``): zero per-PG Python
+        for unaffected PGs, and only primaries whose up/acting moved
+        re-peer.  With osd_map_vectorized_delta off nothing is kept,
+        every PG rescans and any change re-peers every primary PG —
+        the per-PG-scan bisection anchor.  PG log/last_update are
         preserved across map changes (and reloaded from the pgmeta
         object when the collection already exists on store — the
         load_pgs resume path, reference OSD.cc:2572)."""
+        m = self.osdmap
+        t0, walks0 = time.perf_counter_ns(), m.scalar_walks
+        work, walked, resolved = self._advance_pools()
+        took = time.perf_counter_ns() - t0
+        KERNELS.inc("osd_map_advances")
+        KERNELS.inc("osd_map_advance_ns", took)
+        KERNELS.inc("osd_map_pgs_resolved", resolved)
+        KERNELS.inc("osd_map_scalar_walks", m.scalar_walks - walks0)
+        if self.flight and took > 100e6:
+            # the loop every daemon shares stood still this long
+            self.flight.record("map_advance", epoch=m.epoch,
+                               pools_walked=walked, pgs_resolved=resolved,
+                               ms=round(took / 1e6, 1))
+        return work
+
+    def _advance_pools(self) -> Tuple[bool, int, int]:
+        """``_advance_pgs``'s work: (peering has work, pools walked,
+        PGs resolved)."""
         from ceph_tpu.osdmap.osdmap import placement_delta, \
             placement_snapshot
 
@@ -1351,7 +1379,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
             self._placement_cache.clear()
         changed = False
         to_peer: Set[PGid] = set()
-        batch_min = self.config.osd_map_batch_min_pgs
+        walked = resolved = 0
         # pg_num growth: split local PGs whose persisted split watermark
         # trails the pool's pg_num, BEFORE recomputing membership, so
         # child PGStates load the split-out meta/objects (reference
@@ -1370,7 +1398,12 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
                     changed = True
         for pool_id, pool in m.pools.items():
             old_snap = self._placement_cache.get(pool_id)
-            snap = placement_snapshot(m, pool_id, batch_min)
+            if old_snap is not None and \
+                    old_snap.key == m.placement_key(pool_id):
+                continue    # nothing this pool is placed by has moved
+            snap = placement_snapshot(m, pool_id)
+            walked += 1
+            resolved += pool.pg_num
             if use_vec:
                 self._placement_cache[pool_id] = snap
             seeds = None
@@ -1473,7 +1506,7 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
             self.perf.inc("osd_pgs_repeered", len(to_peer))
             self._peering_pending.update(to_peer)
             self._unclean_pgs.update(to_peer)
-        return bool(to_peer)
+        return bool(to_peer), walked, resolved
 
     # ------------------------------------------------------------ heartbeat
 

@@ -29,6 +29,8 @@ RULE_SET_CHOOSE_LOCAL_TRIES = 10
 RULE_SET_CHOOSE_LOCAL_FALLBACK_TRIES = 11
 RULE_SET_CHOOSELEAF_VARY_R = 12
 RULE_SET_CHOOSELEAF_STABLE = 13
+RULE_CHOOSE_OPS = (RULE_CHOOSE_FIRSTN, RULE_CHOOSELEAF_FIRSTN,
+                   RULE_CHOOSE_INDEP, RULE_CHOOSELEAF_INDEP)
 
 BUCKET_UNIFORM = 1
 BUCKET_LIST = 2

@@ -17,6 +17,8 @@ compiled executables instead of triggering per-size recompiles.
 
 from __future__ import annotations
 
+import logging
+import threading
 from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
@@ -721,7 +723,80 @@ def _select_shard_planes(full_planes: np.ndarray,
     return full_planes[idx]
 
 
-def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
+# -- a tick's buckets, compiled before a tick needs them --------------------
+#
+# A tick's first meeting with a bucket compiles three programs inside a
+# served op: seconds on the chip, and the ops of every caller queue behind
+# it.  Whoever warms a pool (a deployment's first writes, the benchmark's
+# bursts) meets the buckets its bursts happen to coalesce into; a burst of
+# 16 spread over a dozen OSDs never puts 5 objects into one tick, and a
+# window later does (PR 33: 1 run in 15, a 3 s hole).  So the first tick
+# of ops of a size runs, on a thread of its own and on zeros, the buckets
+# that a tick of as many such ops as the caller coalesces would meet, up
+# to the largest batch a tick has been seen to run on the chip.
+
+_WARM_BUCKETS: set = set()      # (shape, bucket): met by a tick or warmed
+_WARM_SIZES: set = set()        # (shape, an op's bucket): chain started
+_WARM_MAX_BYTES = 32 << 20      # 8 x 4 MiB objects
+
+
+def _warm_tick_buckets(codec, sinfo: StripeInfo, total: int, bb: int,
+                       ops: int, max_ops: int, crcs: bool) -> None:
+    """Called by a device tick of ``ops`` ops, ``total`` stripes, that
+    ran at bucket ``bb``, from a caller that coalesces ``max_ops``."""
+    shape = (type(codec), sinfo.k, codec.get_chunk_count(),
+             sinfo.chunk_size, crcs)
+    _WARM_BUCKETS.add((shape, bb))
+    one = _bucket(-(-total // ops))
+    if (shape, one) in _WARM_SIZES:
+        return
+    _WARM_SIZES.add((shape, one))
+    top = min(_bucket(one * max_ops), _WARM_MAX_BYTES // sinfo.stripe_width)
+    chain = []
+    while one <= top:
+        chain.append(one)
+        one *= 2
+    threading.Thread(target=_warm_buckets, name="ec-warm-buckets",
+                     args=(codec, sinfo, shape, chain, crcs),
+                     daemon=True).start()
+
+
+def _warm_buckets(codec, sinfo: StripeInfo, shape, chain, crcs: bool) -> None:
+    """Run the tick's device programs once at each bucket of ``chain``
+    that no tick has met meanwhile, on zeros and off the counters: they
+    say what was served."""
+    from ceph_tpu.ops import crc32c as crcmod
+    from ceph_tpu.utils.perf import KERNELS
+
+    try:
+        for bb in chain:
+            if (shape, bb) in _WARM_BUCKETS:
+                continue
+            _WARM_BUCKETS.add((shape, bb))
+            with KERNELS.muted():
+                pb = codec.to_planar(np.zeros(
+                    (bb, sinfo.k, sinfo.chunk_size), dtype=np.uint8))
+                parity_pb = codec.encode_planar(pb)
+                if crcs and _device_crcs_ok(pb):
+                    np.asarray(crcmod.planar_chunk_crcs(
+                        (pb.planes, parity_pb.planes), sinfo.chunk_size))
+                np.asarray(parity_pb.planes)
+    except Exception:   # a warm that fails costs a later tick its compile
+        logging.getLogger("ceph_tpu.ec").exception(
+            "warming buckets %s failed", chain)
+
+
+def _device_crcs_ok(pb) -> bool:
+    """Can the chunk-crc program take this batch's planes?  Chosen by
+    what the batch says of itself; any other layout crcs on the host."""
+    from ceph_tpu.ops import crc32c as crcmod
+
+    return pb.layout == "bitpack" and pb.w == 8 \
+        and pb.chunk_size <= crcmod._PLANAR_DEV_MAX
+
+
+def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
+                        max_ops: int = 0):
     """Coalesced encode emitting AT-REST PLANES: the planar-at-rest twin
     of ``encode_stripes_multi``.
 
@@ -733,7 +808,10 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
     row view, bit-identical to the byte anchor.  Client bytes pack into
     planes exactly ONCE (the sanctioned ingest conversion, booked on
     the ``ec_planar_ingest`` counters); parity is derived in the plane
-    domain and shard bytes are never materialized.
+    domain and shard bytes are never materialized.  ``max_ops``: how
+    many ops the caller coalesces into a tick at most (the batcher's
+    cap); given, the first device tick of ops of a size compiles the
+    buckets such ticks can meet ahead of them (``_warm_tick_buckets``).
     """
     from ceph_tpu.ec import planar_store as pstore
     from ceph_tpu.ops import crc32c as crcmod
@@ -798,8 +876,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
         # encode -> crc back to back.  Chosen by what the batch says of
         # itself; any other layout keeps the host crc below.
         chunk_crcs = None
-        if any(want_crcs) and pb.layout == "bitpack" and pb.w == 8 \
-                and pb.chunk_size <= crcmod._PLANAR_DEV_MAX:
+        if any(want_crcs) and _device_crcs_ok(pb):
             with ticktrace.phase("crc"):
                 chunk_crcs = crcmod.planar_chunk_crcs(
                     (pb.planes, parity_pb.planes), unit)
@@ -819,6 +896,9 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None):
                                         want_crcs, unit)
         with ticktrace.phase("slice"):
             all_planes = np.vstack([data_planes, parity_planes])
+        if max_ops:
+            _warm_tick_buckets(codec, sinfo, total, bb, len(datas), max_ops,
+                               any(want_crcs))
     # per-op at-rest planes slice straight out of the coalesced plane
     # matrix: op columns are contiguous (unit % 8 == 0), shard s is
     # plane rows s*8..s*8+8 — no conversion, no transpose of payload

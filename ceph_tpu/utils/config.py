@@ -66,8 +66,6 @@ OPTIONS: List[Option] = [
            "ops/s cap for the background class (0 = unlimited, like "
            "every dmclock limit)"),
     Option("osd_map_cache_size", int, 50),
-    Option("osd_map_batch_min_pgs", int, 256,
-           "pools with at least this many PGs use batched placement"),
     # control plane at scale (round 14): vectorized epoch deltas,
     # bounded delta chains, and peering storm control.  The vectorized
     # path defaults ON; 0 restores the per-PG rescan + full re-peer —

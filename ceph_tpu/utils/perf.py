@@ -27,6 +27,7 @@ every daemon folds it into its own ``perf dump``.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, List, Optional
@@ -95,6 +96,7 @@ class PerfCounters:
     def __init__(self, name: str):
         self.name = name
         self._lock = threading.Lock()
+        self._muted: set = set()     # idents of the threads inside muted()
         self._counters: Dict[str, int] = {}
         # name -> [count, sum, last, min, max]
         self._avgs: Dict[str, list] = {}
@@ -135,7 +137,22 @@ class PerfCounters:
 
     # -- updates -------------------------------------------------------------
 
+    @contextlib.contextmanager
+    def muted(self):
+        """``inc``s of the calling thread are dropped inside: for work
+        that runs the counted paths and serves nothing (the EC bucket
+        warm), so that the counters keep saying what was served."""
+        me = threading.get_ident()
+        self._muted.add(me)
+        try:
+            yield
+        finally:
+            self._muted.discard(me)
+
     def inc(self, name: str, amount: int = 1) -> None:
+        # an empty set costs the hot path a truth test, nothing more
+        if self._muted and threading.get_ident() in self._muted:
+            return
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + amount
 

@@ -272,9 +272,7 @@ async def serve_ec_objects(seed: int, n_objects: int = 64,
             raise AssertionError(f"recovery rebuilt nothing on osd.{victim}")
         await read_all("read_after_recovery")
 
-        placement_engine = placement_snapshot(
-            cluster.mon.osdmap, pool,
-            cluster.config.osd_map_batch_min_pgs).mode
+        placement_engine = placement_snapshot(cluster.mon.osdmap, pool).mode
     except BaseException as exc:
         # leave evidence on stdout before the traceback: which daemons
         # the mon holds up, and what each OSD counted (flaps, timeouts)
@@ -334,9 +332,9 @@ def phase_cluster(seed: int, **size) -> None:
         "on the host, on the host: "
         + ("google_crc32c (hardware instruction)"
            if crc32c._gcrc is not None else "numpy table loop"),
-        placement_note="the pool has fewer than osd_map_batch_min_pgs PGs, "
-        "so placement ran on the scalar CRUSH chain; the CRUSH kernel ran "
-        "in phase 2 only")
+        placement_note="the pool's walk is under osdmap.DEVICE_WALK_MIN_DRAWS "
+        "expected draws, so placement ran on the host (placement_engine "
+        "says which walk); the CRUSH kernel ran in phase 2 only")
     check_device_did_the_work(report)
 
 

@@ -83,14 +83,14 @@ def test_affected_pgs_bit_identical_to_scalar_scan(ptype):
                             pool_type=ptype, size=3)
     for name, m2 in _mutations(m):
         want = affected_pgs_scalar(m, m2, 1)
-        got = affected_pgs(m, m2, 1, batch_min=1000)  # scalar snapshots
+        got = affected_pgs(m, m2, 1, engine="scalar")
         assert got == want, (name, sorted(got - want), sorted(want - got))
         # a mutation must actually affect something (or the case is
         # vacuous) — except mark_in back to the current weight
         if name not in ("mark_in",):
             assert want, name
         # identity diff: no epoch, no affected PGs
-        assert affected_pgs(m, m, 1, batch_min=1000) == set()
+        assert affected_pgs(m, m, 1, engine="scalar") == set()
 
 
 def test_affected_pgs_batched_mode_matches_scalar_scan():
@@ -102,7 +102,7 @@ def test_affected_pgs_batched_mode_matches_scalar_scan():
                             pool_type=POOL_TYPE_REPLICATED, size=3)
     for name, m2 in _mutations(m):
         want = affected_pgs_scalar(m, m2, 1)
-        got = affected_pgs(m, m2, 1, batch_min=1)     # batched arrays
+        got = affected_pgs(m, m2, 1, engine="device")
         assert got == want, (name, sorted(got - want), sorted(want - got))
 
 

@@ -1,8 +1,11 @@
-"""The jerasure k=4 m=2 pool on 8 OSDs (``benchmark/configs/
-rados_k4m2_8osd.json``) against a plain reference, at small size on the
-CPU with the device branches forced, and what lets it serve without a
-stall: failure detection that survives a stalled loop, a stop that is
-bounded, the heartbeat counters and the two metric files that read them.
+"""The benchmark's wider deployments — the jerasure k=4 m=2 pool on 8
+OSDs (``benchmark/configs/rados_k4m2_8osd.json``) and the ISA k=8 m=4
+pool on a host's dozen (``rados_isa_k8m4_12osd.json``), one case each of
+every served-against-reference test — against a plain reference, at
+small size on the CPU with the device branches forced, and what lets
+them serve without a stall: failure detection that survives a stalled
+loop, a stop that is bounded, placement at a map change that does not
+hold the loop (PR 33), the counters and the metric files that read them.
 
 The reference: for what is served, name -> payload; for what is stored,
 a scalar GF(2^8) Vandermonde encode written here from the field's
@@ -28,12 +31,21 @@ from ceph_tpu.ops import crc32c as crcmod
 from ceph_tpu.utils import KERNELS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "rados_k4m2_8osd.json"), encoding="utf-8") as _f:
-    DEPLOYMENT = json.load(_f)
-K, MM, UNIT = DEPLOYMENT["k"], DEPLOYMENT["m"], DEPLOYMENT["stripe_unit"]
+
+
+def _deployment(name):
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json"),
+              encoding="utf-8") as f:
+        return json.load(f)
+
+
+DEPLOYMENTS = {name: _deployment(name)
+               for name in ("rados_k4m2_8osd", "rados_isa_k8m4_12osd")}
+DEPLOYMENT = DEPLOYMENTS["rados_k4m2_8osd"]     # the failure-detection cases
+WIDE = DEPLOYMENTS["rados_isa_k8m4_12osd"]      # the placement cases
 SIZES = {"4k": 4096, "64k+1": 65537, "1m": 1 << 20}
-CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16")
+CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
+         "k8m4_write_4m_t16")
 
 
 def bounded(coro, seconds):
@@ -66,50 +78,57 @@ def _gf_mul_tables():
     return table
 
 
-def _golden_case():
+def _golden_case(profile, k, m):
     with open(os.path.join(ROOT, "tests", "golden", "ec_golden.jsonl"),
               encoding="utf-8") as f:
         for line in f:
             case = json.loads(line)
             if (case["plugin"], case["technique"], case["k"], case["m"],
                     case.get("w", 8), case["packetsize"]) == \
-                    ("jerasure", "reed_sol_van", K, MM, 8, 0):
+                    (profile["plugin"], profile["technique"], k, m, 8, 0):
                 return case
-    raise AssertionError("no golden vector for reed_sol_van k=4 m=2")
+    raise AssertionError(f"no golden vector for {profile}")
 
 
 class Reference:
-    def __init__(self):
-        self.case = _golden_case()
+    def __init__(self, deployment):
+        self.k, self.m = deployment["k"], deployment["m"]
+        self.unit = deployment["stripe_unit"]
+        self.case = _golden_case(deployment["ec_profile"], self.k, self.m)
         self.matrix = np.array(self.case["matrix"],
-                               dtype=np.uint8).reshape(MM, K)
+                               dtype=np.uint8).reshape(self.m, self.k)
         self.mul = _gf_mul_tables()
 
     def parity(self, chunks):
         """chunks: (k, n) bytes -> (m, n) parity bytes, scalar products
         looked up per byte and XORed."""
-        out = np.zeros((MM, chunks.shape[1]), dtype=np.uint8)
-        for j in range(MM):
-            for c in range(K):
+        out = np.zeros((self.m, chunks.shape[1]), dtype=np.uint8)
+        for j in range(self.m):
+            for c in range(self.k):
                 out[j] ^= self.mul[self.matrix[j, c]][chunks[c]]
         return out
 
     def shards(self, payload: bytes):
         """The k+m shards as stored: the object zero-padded to whole
-        stripes of k x UNIT, shard i = chunk i of every stripe in turn."""
-        width = K * UNIT
-        stripes = -(-len(payload) // width)
-        padded = np.zeros(stripes * width, dtype=np.uint8)
+        stripes of k x unit, shard i = chunk i of every stripe in turn."""
+        k, unit = self.k, self.unit
+        stripes = -(-len(payload) // (k * unit))
+        padded = np.zeros(stripes * k * unit, dtype=np.uint8)
         padded[:len(payload)] = np.frombuffer(payload, dtype=np.uint8)
-        data = padded.reshape(stripes, K, UNIT).transpose(1, 0, 2) \
-            .reshape(K, stripes * UNIT)
+        data = padded.reshape(stripes, k, unit).transpose(1, 0, 2) \
+            .reshape(k, stripes * unit)
         return [bytes(r) for r in data] + \
             [bytes(r) for r in self.parity(data)]
 
 
+@pytest.fixture(scope="module", params=list(DEPLOYMENTS))
+def deployment(request):
+    return DEPLOYMENTS[request.param]
+
+
 @pytest.fixture(scope="module")
-def reference():
-    return Reference()
+def reference(deployment):
+    return Reference(deployment)
 
 
 def test_reference_agrees_with_the_independent_oracle(reference):
@@ -120,7 +139,7 @@ def test_reference_agrees_with_the_independent_oracle(reference):
     case = reference.case
     payload = _lcg_bytes(case["seed"], case["object_size"])
     n = case["chunk_size"]
-    data = np.frombuffer(payload, dtype=np.uint8).reshape(K, n)
+    data = np.frombuffer(payload, dtype=np.uint8).reshape(reference.k, n)
     chunks = [bytes(r) for r in data] + \
         [bytes(r) for r in reference.parity(data)]
     for got, want in zip(chunks, case["chunks"]):
@@ -130,15 +149,15 @@ def test_reference_agrees_with_the_independent_oracle(reference):
 
 # ---------------------------------------------- served against the reference
 
-async def _serve():
+async def _serve(deployment):
     """One cluster, everything the cases below look at."""
-    cluster = await start_cluster(DEPLOYMENT["osds"])
+    cluster = await start_cluster(deployment["osds"])
     out = {"stored": {}, "reads": {}, "holders": {}}
     try:
         client = await cluster.client()
         pool = await client.pool_create(
-            "k4m2", DEPLOYMENT["pool_type"], pg_num=DEPLOYMENT["pg_num"],
-            ec_profile=dict(DEPLOYMENT["ec_profile"]))
+            "pool", deployment["pool_type"], pg_num=deployment["pg_num"],
+            ec_profile=dict(deployment["ec_profile"]))
         io = client.ioctx(pool)
         rng = np.random.default_rng(28)
         payloads = {
@@ -179,7 +198,7 @@ async def _serve():
         await read_all("healthy")
         # two holders of DATA shards of one 1 MiB object: with the first
         # down its reads decode from one parity row, with both down from
-        # the two (m = 2: what k2m1 cannot show)
+        # the two (m >= 2: what k2m1 cannot show)
         _, holders = acting("1m_0")
         victims = [holders[1], holders[2]]
         before = counters()
@@ -201,14 +220,14 @@ async def _serve():
 
 
 @pytest.fixture(scope="module")
-def served():
+def served(deployment):
     from ceph_tpu.ec import stripe
 
     sound = stripe._host_engine_ok
     stripe._host_engine_ok = lambda codec: False    # the device branches
     before = counters()
     try:
-        out = bounded(_serve(), 420)
+        out = bounded(_serve(deployment), 420)
     finally:
         stripe._host_engine_ok = sound
     out["host_engine_calls"] = sum(
@@ -226,7 +245,7 @@ def test_stored_shards_equal_the_scalar_reference(served, reference, label):
             continue
         want = reference.shards(payload)
         rows = served["stored"][name]
-        assert len(rows) == K + MM
+        assert len(rows) == reference.k + reference.m
         for shard_attr, shard, stored, _crc in rows:
             assert shard_attr == shard
             assert stored == want[shard], (name, shard)
@@ -532,6 +551,281 @@ def test_a_slow_op_is_not_sent_again_while_its_target_stands():
     bounded(scenario(), 90)
 
 
+# ------------------------------------- placement at a map change (PR 33)
+#
+# The wide pool takes 12 of 12 hosts ``indep``: ~46 bucket draws a PG on
+# the scalar chain, 18 ms a PG, and before PR 33 every OSD walked every
+# PG of every pool at every epoch on the one loop: a pool create of 32
+# PGs took 9 s and a killed OSD was marked down after 5.
+
+def _wide_map(pg_num):
+    """The map a 12-OSD vstart cluster holds after the wide pool's
+    create, built the way ``start_cluster`` and the mon build it."""
+    from ceph_tpu.crush.types import (
+        RULE_CHOOSELEAF_INDEP, RULE_EMIT, RULE_SET_CHOOSELEAF_TRIES,
+        RULE_SET_CHOOSE_TRIES, RULE_TAKE, Rule, build_hierarchy)
+    from ceph_tpu.osdmap.osdmap import OSDMap, PGPool, POOL_TYPE_ERASURE
+
+    n = WIDE["osds"]
+    cmap, _ = build_hierarchy(n, 1, numrep=3)
+    root = max(cmap.buckets.values(), key=lambda b: b.type).id
+    rule = cmap.add_rule(Rule(steps=[
+        (RULE_SET_CHOOSELEAF_TRIES, 5, 0), (RULE_SET_CHOOSE_TRIES, 100, 0),
+        (RULE_TAKE, root, 0), (RULE_CHOOSELEAF_INDEP, n, 1),
+        (RULE_EMIT, 0, 0)], type=POOL_TYPE_ERASURE))
+    m = OSDMap(cmap, max_osd=n)
+    m.add_pool(PGPool(pool_id=1, type=POOL_TYPE_ERASURE, size=n,
+                      min_size=WIDE["k"], pg_num=pg_num, pgp_num=pg_num,
+                      crush_rule=rule))
+    return m
+
+
+def _one_down(m):
+    m.mark_down(5)
+
+
+def _one_out(m):
+    m.mark_out(7)
+
+
+def _pg_temp(m):
+    from ceph_tpu.osdmap.osdmap import PGid
+
+    m.mark_out(3)
+    m.pg_temp[PGid(1, 4)] = [11, 10, 9, 8, 7, 6, 5, 4, 3, 2, 1, 0]
+    m.primary_temp[PGid(1, 9)] = 6
+
+
+@pytest.mark.parametrize("pg_num", [32, 128])
+@pytest.mark.parametrize("change", [None, _one_down, _one_out, _pg_temp],
+                         ids=["healthy", "one_down", "one_out", "pg_temp"])
+def test_the_engine_an_advance_picks_equals_the_scalar_chain(change,
+                                                             pg_num):
+    """``placement_snapshot`` as ``_advance_pgs`` calls it — the host
+    walk for this pool — against ``pg_to_up_acting_osds``, the scalar
+    reference, seed for seed; and with upstream's tries every slot of
+    every PG is filled while all twelve are in."""
+    from ceph_tpu.crush.types import CRUSH_ITEM_NONE
+    from ceph_tpu.osdmap.osdmap import (PGid, _norm_placement,
+                                        placement_snapshot)
+
+    m = _wide_map(pg_num)
+    if change:
+        change(m)
+    assert m.placement_engine(1) == "host"
+    before = m.scalar_walks
+    snap = placement_snapshot(m, 1)
+    assert snap.mode == "host"
+    # the walk itself asked the scalar chain only for the temp seeds
+    assert m.scalar_walks - before == len(snap.resolved) <= 2
+    for seed in range(pg_num):
+        want = _norm_placement(12, False,
+                               *m.pg_to_up_acting_osds(PGid(1, seed)))
+        assert snap.resolve(seed) == want, seed
+    if change is None:
+        assert not (snap.up == CRUSH_ITEM_NONE).any()
+        assert sorted(set(snap.up.reshape(-1).tolist())) == list(range(12))
+
+
+def test_a_walk_is_kept_until_what_crush_reads_changes():
+    """Marking an OSD down, a pg_temp or another pool's arrival ask
+    CRUSH nothing new: the pool's raw walk is reused (and an unrelated
+    change leaves even its ``placement_key`` alone); a weight, a bucket
+    or the pool's own pg_num walk again."""
+    import copy
+
+    from ceph_tpu.osdmap.osdmap import PGid, PGPool
+
+    m = _wide_map(32)
+    key = m.placement_key(1)
+    m.pool_mapping(1)
+    walk = m._walks[1]
+    m.add_pool(PGPool(pool_id=2, size=3, pg_num=8, pgp_num=8,
+                      crush_rule=0))
+    m.pg_temp[PGid(2, 1)] = [1, 2, 3]
+    m.flags.add("nearfull")
+    m.osd_addrs[3] = ("127.0.0.1", 1)
+    assert m.placement_key(1) == key
+    m.mark_down(5)
+    m.pg_temp[PGid(1, 4)] = list(range(12))
+    assert m.placement_key(1) != key
+    m.pool_mapping(1)
+    assert m._walks[1] is walk
+    for change in (lambda x: x.mark_out(7),
+                   lambda x: x.invalidate_mappers(),
+                   lambda x: setattr(x.pools[1], "pg_num", 64)):
+        m2 = copy.deepcopy(m)
+        m2._walks = dict(m._walks)
+        change(m2)
+        assert m2.placement_key(1) != m.placement_key(1)
+        m2.pool_mapping(1)
+        assert m2._walks[1] is not walk
+
+
+def _map_counters():
+    return {k: v for k, v in counters().items()
+            if k.startswith("osd_map_")}
+
+
+def test_an_epoch_walks_only_the_pools_it_can_have_moved():
+    """The test that COUNTS.  On the wide deployment: the pool's create
+    resolves each PG once an OSD, by the host walk, with no scalar
+    ``do_rule``; an epoch that adds an unrelated pool resolves 0 PGs of
+    the wide pool; an epoch that marks an OSD down costs each survivor
+    at most one resolution a PG, and again no scalar walk of the wide
+    pool; the kill is seen within a second or two, not five."""
+    n, pg_num = WIDE["osds"], WIDE["pg_num"]
+
+    async def scenario():
+        cluster = await start_cluster(n)
+        try:
+            client = await cluster.client()
+            c0 = _map_counters()
+            t0 = time.monotonic()
+            wide = await client.pool_create(
+                "wide", "erasure", pg_num=pg_num,
+                ec_profile=dict(WIDE["ec_profile"]))
+            create_s = time.monotonic() - t0
+            io = client.ioctx(wide)
+            await io.write_full("a", b"x" * 65536)
+            c1 = _map_counters()
+            snaps = {o: osd._placement_cache[wide]
+                     for o, osd in cluster.osds.items()}
+            assert {s.mode for s in snaps.values()} == {"host"}
+            assert c1["osd_map_pgs_resolved"] \
+                - c0.get("osd_map_pgs_resolved", 0) == n * pg_num
+            assert c1.get("osd_map_scalar_walks", 0) \
+                == c0.get("osd_map_scalar_walks", 0)
+            assert c1["osd_map_advances"] > c0.get("osd_map_advances", 0)
+            assert c1["osd_map_advance_ns"] > c0.get("osd_map_advance_ns",
+                                                     0)
+
+            other = await client.pool_create("other", "replicated",
+                                             pg_num=8, size=3)
+            await client.ioctx(other).write_full("b", b"y" * 4096)
+            c2 = _map_counters()
+            # the wide pool's snapshot is the very object it was
+            for o, osd in cluster.osds.items():
+                assert osd._placement_cache[wide] is snaps[o]
+            assert c2["osd_map_pgs_resolved"] \
+                - c1["osd_map_pgs_resolved"] == n * 8
+
+            t0 = time.monotonic()
+            await cluster.kill_osd(1)
+            await cluster.wait_down(1)
+            down_s = time.monotonic() - t0
+            deadline = time.monotonic() + 10
+            while any(osd.osdmap.epoch < cluster.mon.osdmap.epoch
+                      for o, osd in cluster.osds.items() if o != 1):
+                assert time.monotonic() < deadline
+                await asyncio.sleep(0.05)   # the epoch reaches every OSD
+            c3 = _map_counters()
+            walked = c3["osd_map_pgs_resolved"] - c2["osd_map_pgs_resolved"]
+            assert 0 < walked <= (n - 1) * (pg_num + 8)
+            # the 8-PG pool is the scalar chain's; the wide one asked
+            # CRUSH nothing (an OSD going down moves no weight)
+            assert c3.get("osd_map_scalar_walks", 0) \
+                - c2.get("osd_map_scalar_walks", 0) <= (n - 1) * 8
+            assert await io.read("a") == b"x" * 65536
+            return create_s, down_s
+        finally:
+            await cluster.stop()
+
+    create_s, down_s = bounded(scenario(), 120)
+    # 9.1 s and 4.9 s before PR 33 on the CPU dev host (0.5 and 0.6
+    # after); generous, so that only a return of the walk fails
+    assert create_s < 4.0, create_s
+    assert down_s < 3.0, down_s
+
+
+def test_a_dozen_osds_with_128_pgs_create_and_serve_under_a_wall_bound():
+    """Upstream's ~100 PGs an OSD for this pool: 39 s of placement
+    before PR 33 (past the heartbeat grace), 0.6 s after on the CPU dev
+    host.  The bound is generous: a return of the per-PG walk fails
+    loudly, not slowly."""
+    async def scenario():
+        cluster = await start_cluster(WIDE["osds"])
+        try:
+            client = await cluster.client()
+            t0 = time.monotonic()
+            pool = await client.pool_create(
+                "wide", "erasure", pg_num=128,
+                ec_profile=dict(WIDE["ec_profile"]))
+            create_s = time.monotonic() - t0
+            io = client.ioctx(pool)
+            await io.write_full("a", b"z" * 65536, timeout=60)
+            assert await io.read("a") == b"z" * 65536
+            return create_s
+        finally:
+            await cluster.stop()
+
+    assert bounded(scenario(), 150) < 15.0
+
+
+def test_the_first_tick_of_a_size_compiles_a_full_ticks_buckets(monkeypatch):
+    """A burst of 16 over a dozen OSDs warms the buckets of 1-4 objects;
+    a window later coalesces 5 (PR 33, on the chip: 1 run in 15 compiled
+    for 3 s inside a served op).  Told how many ops its caller
+    coalesces, the first tick of ops of a size runs the buckets a full
+    tick of them would meet, on zeros, on a thread of its own, off the
+    counters, once, and never past the largest batch a tick has run."""
+    import threading
+
+    from ceph_tpu.ec import factory, stripe
+
+    monkeypatch.setattr(stripe, "_host_engine_ok", lambda codec: False)
+    monkeypatch.setattr(stripe, "_WARM_BUCKETS", set())
+    monkeypatch.setattr(stripe, "_WARM_SIZES", set())
+    chains, done, run = [], threading.Event(), stripe._warm_buckets
+
+    def spy(codec, sinfo, shape, chain, crcs):
+        met = {b for s, b in stripe._WARM_BUCKETS if s == shape}
+        run(codec, sinfo, shape, chain, crcs)
+        chains.append((chain, sorted(met)))
+        done.set()
+
+    monkeypatch.setattr(stripe, "_warm_buckets", spy)
+    codec = factory(dict(WIDE["ec_profile"]))
+    sinfo = stripe.StripeInfo(WIDE["k"], WIDE["stripe_unit"])
+
+    def tick(stripes, ops=1, max_ops=4):
+        done.clear()
+        before = counters().get("planar_matmul_calls", 0)
+        out = stripe.encode_planes_multi(
+            codec, sinfo, [bytes(stripes * sinfo.stripe_width)] * ops,
+            [True] * ops, max_ops)
+        assert len(out) == ops and out[0][0].shape \
+            == (12, 8, stripes * sinfo.chunk_size // 8)
+        return before
+
+    before = tick(2)            # ops of 2 stripes, up to 4 a tick: 2, 4, 8
+    assert done.wait(120)
+    assert chains == [([2, 4, 8], [2])]     # 2 was the tick's own
+    assert {b for _s, b in stripe._WARM_BUCKETS} == {2, 4, 8}
+    # the tick's matmul; the warm's are off the counters
+    assert counters()["planar_matmul_calls"] - before == 1
+    tick(2)                     # the size is known: nothing new,
+    tick(2, ops=3)              # whatever the tick's own bucket
+    assert not done.wait(0.3) and len(chains) == 1
+    tick(16)                    # another size: its own chain, once
+    assert done.wait(120) and chains[1][0] == [16, 32, 64]
+    monkeypatch.setattr(stripe, "_WARM_MAX_BYTES", 256 * sinfo.stripe_width)
+    tick(128)                   # 512 would pass the largest batch
+    assert done.wait(120) and chains[2][0] == [128, 256]
+    done.clear()                # a caller that names no cap warms nothing
+    stripe.encode_planes_multi(codec, sinfo, [bytes(1024 * 32768)], [True])
+    assert not done.wait(0.3) and len(chains) == 3
+
+
+def test_msgr_frames_counts_one_a_message():
+    from ceph_tpu.cluster import messenger
+
+    before = counters().get("msgr_frames", 0)
+    messenger._encode(M.MPing(stamp=1.0))
+    messenger._encode(M.MPing(stamp=2.0, reply=True))
+    assert counters()["msgr_frames"] - before == 2
+
+
 # ----------------------------------------------------- the metric readers
 
 HB_READERS = {"hb_rtt_ms.write": 2.5, "hb_late_share.write": 0.5}
@@ -557,6 +851,39 @@ def test_the_hb_metric_files_read_the_hand_worked_values(cell_name):
                              attribution={}, counters={},
                              slice_counters={}, trace=None),
              dict.fromkeys(HB_READERS))):
+        for name, value in want.items():
+            got = layers.read_metric(name, cell.per_layer[name], readings)
+            assert got == (pytest.approx(value) if value is not None
+                           else None), name
+
+
+SHAPE_READERS = {"frames_per_op.write": 46.0, "stack_groups.write": 2.0}
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_the_shape_metric_files_read_the_hand_worked_values(cell_name):
+    """1400 ops coalesced while the messengers framed 64400 messages: 46
+    frames an op; 1200 planar matmul calls of 2 K-stacking groups each:
+    2.0 — through the accepted ``counter_ratio`` reader.  A program
+    without ``msgr_frames`` (the parent commit) raises nothing: that
+    reader leaves a metric out only when its DENOMINATOR did not grow,
+    so it reads 0.0 there (PERF.md section 3 says so); with no op
+    coalesced it reads nothing."""
+    from benchmark.harness import layers
+    from benchmark.harness.loader import load_cell
+
+    cell = load_cell(cell_name)
+    growth = {"msgr_frames": 64400, "ec_coalesced_ops": 1400,
+              "planar_stack_groups": 2400, "planar_matmul_calls": 1200}
+    parent = {k: v for k, v in growth.items() if k != "msgr_frames"}
+    for counters_, want in (
+            (growth, SHAPE_READERS),
+            (parent, {"frames_per_op.write": 0.0,
+                      "stack_groups.write": 2.0}),
+            ({}, dict.fromkeys(SHAPE_READERS))):
+        readings = layers.Readings(
+            config=cell.config, device_kind="TPU v5 lite", attribution={},
+            counters=counters_, slice_counters={}, trace=None)
         for name, value in want.items():
             got = layers.read_metric(name, cell.per_layer[name], readings)
             assert got == (pytest.approx(value) if value is not None
@@ -596,18 +923,31 @@ def test_every_cell_of_the_benchmark_loads_through_the_loader():
                 assert metric["name"] in cell.per_layer
                 assert metric["moves"] in cell.end_to_end
                 assert metric["moves"] in e2e
-    # the new deployment: the accepted traffic file on the new config,
-    # one chip, and the two heartbeat metrics in all three cells
-    new = load_cell("k4m2_write_4m_t16")
-    assert (new.config_name, new.traffic_name, new.chips) \
-        == ("rados_k4m2_8osd", "write_4m_t16", 1)
+    # the wider deployments: the accepted traffic file on their own
+    # config, one chip, every per-layer metric of the first cell, and
+    # the counter metrics PR 28 and PR 33 brought in all four cells
     old = load_cell("k2m1_write_4m_t16")
-    assert set(new.per_layer) == set(old.per_layer)
-    assert new.traffic == old.traffic
-    hb = [m for m in spec["per_layer"] if m["name"] in HB_READERS]
-    assert len(hb) == len(HB_READERS)
-    for metric in hb:
-        assert metric["workloads"] == list(CELLS)
-        assert (metric["layer"], metric["moves"], metric["better"],
-                metric["source"]) == ("wire", "write_p95_ms", "lower",
-                                      "program_counter")
+    for name, config in (("k4m2_write_4m_t16", "rados_k4m2_8osd"),
+                         ("k8m4_write_4m_t16", "rados_isa_k8m4_12osd")):
+        new = load_cell(name)
+        assert (new.config_name, new.traffic_name, new.chips) \
+            == (config, "write_4m_t16", 1)
+        assert set(new.per_layer) == set(old.per_layer)
+        assert new.traffic == old.traffic
+    for readers, layer_moves in (
+            (HB_READERS, {"hb_rtt_ms.write": ("wire", "write_p95_ms",
+                                              "lower"),
+                          "hb_late_share.write": ("wire", "write_p95_ms",
+                                                  "lower")}),
+            (SHAPE_READERS, {"frames_per_op.write": ("wire", "write_MBps",
+                                                     "lower"),
+                             "stack_groups.write": ("kernels",
+                                                    "write_MBps",
+                                                    "higher")})):
+        found = [m for m in spec["per_layer"] if m["name"] in readers]
+        assert len(found) == len(readers)
+        for metric in found:
+            assert metric["workloads"] == list(CELLS)
+            assert (metric["layer"], metric["moves"], metric["better"]) \
+                == layer_moves[metric["name"]]
+            assert metric["source"] == "program_counter"

@@ -715,7 +715,8 @@ def test_stored_object_does_not_alias_the_frame():
 
 
 @pytest.mark.parametrize("cell_name", [
-    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16"])
+    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
+    "k8m4_write_4m_t16"])
 def test_wire_oob_share_reads_the_hand_worked_value(cell_name):
     """8 GB framed of which 6 GB out of band: 75 %, through the accepted
     ``counter_ratio`` reader; a program without the counters (the parent

@@ -24,9 +24,10 @@ def test_stable_mod():
         assert 0 <= v < 12
 
 
+@pytest.mark.parametrize("engine", ["host", "device"])
 @pytest.mark.parametrize("ptype", [POOL_TYPE_REPLICATED, POOL_TYPE_ERASURE],
                          ids=["replicated", "erasure"])
-def test_batched_matches_scalar(ptype):
+def test_batched_matches_scalar(ptype, engine):
     m = build_simple_osdmap(n_osds=24, osds_per_host=4, pg_num=64,
                             pool_type=ptype, size=3)
     m.mark_down(5)
@@ -34,7 +35,13 @@ def test_batched_matches_scalar(ptype):
     m.set_primary_affinity(2, 0x8000)
     pg = PGid(1, 3)
     m.pg_upmap_items[pg] = [(m.pg_to_up_acting_osds(pg)[0][0], 11)]
-    up, upp = m.pool_mapping(1)
+    assert m._pool_mapping(1, engine)[2] == engine
+    up, upp = m.pool_mapping(1, engine)
+    # the mon's down-blind rows: the scalar chain short of the up filter
+    raw_up = m.pool_raw_up(1)
+    for s in range(64):
+        raw, _pps = m._pg_to_raw_osds(m.pools[1], PGid(1, s))
+        assert raw_up[s] == m._apply_upmap(m.pools[1], PGid(1, s), raw), s
     for s in range(64):
         want_up, want_p, _, _ = m.pg_to_up_acting_osds(PGid(1, s))
         got = [int(v) for v in up[s] if v != CRUSH_ITEM_NONE] \
@@ -206,12 +213,17 @@ def test_pool_mapping_scalar_fallback_uniform_bucket():
                       crush_rule=ruleno, name="u"))
     with pytest.raises(NotImplementedError):
         _ = m.tensor_mapper
-    up, upp = m.pool_mapping(1)  # must not raise: scalar fallback
-    for seed in range(32):
-        su, supp, _, _ = m.pg_to_up_acting_osds(PGid(1, seed))
-        row = [int(o) for o in up[seed] if o != CRUSH_ITEM_NONE]
-        assert row == su, seed
-        assert int(upp[seed]) == supp
+    with pytest.raises(NotImplementedError):
+        _ = m.host_mapper
+    # neither vector engine takes the map, asked for or picked: both
+    # must not raise, and fall to the scalar walk
+    for engine in ("device", None):
+        up, upp = m.pool_mapping(1, engine=engine)
+        for seed in range(32):
+            su, supp, _, _ = m.pg_to_up_acting_osds(PGid(1, seed))
+            row = [int(o) for o in up[seed] if o != CRUSH_ITEM_NONE]
+            assert row == su, seed
+            assert int(upp[seed]) == supp
     # the fallback must be SURFACED, not silent (r3 verdict weakness #5):
     # counted on the map and reported by the mon 'status' command
     assert getattr(m, "scalar_fallbacks", 0) >= 1

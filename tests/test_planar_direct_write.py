@@ -353,7 +353,8 @@ def test_served_writes_take_the_one_copy_path(k, m, osds):
 
 
 @pytest.mark.parametrize("cell_name", [
-    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16"])
+    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
+    "k8m4_write_4m_t16"])
 def test_store_direct_share_reads_the_hand_worked_value(cell_name):
     """6 GB of planar shard bytes landed of which 4.5 GB direct: 75 %,
     through the accepted ``counter_ratio`` reader; a program without

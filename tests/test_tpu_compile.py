@@ -86,11 +86,14 @@ def test_planar_matmul_xla_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_batch_to_planes_compiles_for_v5e(one_chip):
+# one k8m4 object, and the largest bucket warmed ahead (ec/stripe.py:
+# 8 x 4 MiB objects, _WARM_MAX_BYTES)
+@pytest.mark.parametrize("bb", [128, 1024])
+def test_batch_to_planes_compiles_for_v5e(one_chip, bb):
     from ceph_tpu.ec.planar import _batch_to_planes_bitpack
 
     _batch_to_planes_bitpack.lower(
-        _shape((128, 8, 4096), jnp.uint8, one_chip), 8).compile()
+        _shape((bb, 8, 4096), jnp.uint8, one_chip), 8).compile()
 
 
 def test_crc32c_batch_compiles_for_v5e(one_chip):
@@ -105,7 +108,8 @@ def test_crc32c_batch_compiles_for_v5e(one_chip):
 
 # the encode tick's largest buckets: 8 x 4 MiB objects (48 MiB of planes)
 @pytest.mark.parametrize("k,m,bb", [pytest.param(2, 1, 4096, id="k2m1"),
-                                    pytest.param(4, 2, 2048, id="k4m2")])
+                                    pytest.param(4, 2, 2048, id="k4m2"),
+                                    pytest.param(8, 4, 1024, id="k8m4")])
 def test_chunk_crcs_program_compiles_for_v5e(one_chip, k, m, bb):
     from ceph_tpu.ops.crc32c import _chunk_crcs_jit
 
