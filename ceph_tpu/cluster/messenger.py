@@ -62,6 +62,42 @@ the unacked tail replayed IN ORDER.  Delivery is therefore ordered
 at-least-once — handlers are idempotent by design (absolute-offset
 writes, versioned log appends), exactly like the reference's lossless
 osd-osd policy replaying out_q after a session reset.
+
+The ack (PR 35) is state, not a frame; a message costs ONE frame.  A
+session frame names its session: ``sid`` (its messenger's) and
+``src_addr`` (where that messenger listens, ``my_addr``: the address
+the maps publish and peers dial; carried by every session frame rather
+than learnt per connection, so a replayed frame opens a connection like
+any other).  The receiver notes the newest ``seq`` it has taken of that
+session (``_owe``: an ``_Owed`` on the connection the frames come in
+on, found again under the listening address) and dispatches at once.
+Whatever frame it next writes to that peer takes the ack along in its
+header field ``ack`` = ``(sid, seq)``: a session frame of its own to
+that listening address (``send_message``: an OSD's commit reply carries
+the sub-write's ack, the next sub-write or reply carries the reply's)
+or a raw send on that connection (``Connection.send``: the op's reply
+carries the client op's).  The ack counts as gone only when its frame
+was written; one in a frame that did not set out stays owed.  Whoever
+receives a frame with an ``ack`` that names its own ``sid`` trims its
+session to the frame's sender from the front, everything up to ``seq``:
+acks are cumulative, which is sound because a session's frames arrive
+in order (a gated session sends nothing past a dropped frame); one that
+names another ``sid`` was owed to a messenger that had the address
+before and trims nothing.  An ack goes ALONE, as a ``_MsgAck``
+(``_ack_alone``, the only place one is made), in four cases: nothing
+carried it for ``_ACK_DELAY_S`` (the messenger's one timer,
+``_flush_acks``; no timer per connection); ``_ACK_BYTES`` are owed to
+one session (large frames pin their payloads in the sender's replay
+buffer); ``_Owed.MAX_FRAMES`` frames are (a quarter of what that buffer
+holds before it overflows); or a frame arrived AGAIN (``seq`` not above
+what was taken: a replay, a duplicate — the sender is recovering and is
+told at once, once per replayed tail; the frame is dispatched all the
+same, at-least-once).  Acks owed on a connection that dies are void:
+the sender replays more than it had to, never less.  The constants are
+derived where they are defined, beside ``_OOB_MIN``; none is an option.
+``KERNELS`` ``msgr_acks_owed`` counts the session frames received,
+``msgr_acks_carried`` those whose ack left inside another frame.  The
+heartbeat lane has no session and no acks.
 """
 
 from __future__ import annotations
@@ -108,7 +144,7 @@ _SOCK_BUF = 2 << 20
 # frame that CANNOT fit gets a buffer of its own (_FrameStream), whose
 # body takes several reads anyway.  Right after such a frame only
 # _RECV_PEEK bytes are offered: such frames come in runs (a data lane's
-# sub-writes one way, its acks the other), and what is read beside the
+# sub-writes one way, their replies the other), and what is read beside the
 # next one's length prefix is moved once more
 _RECV_SCRATCH = 256 << 10
 _RECV_PEEK = 4 << 10
@@ -128,6 +164,24 @@ _OOB_MIN = 64 << 10
 # how long a closing endpoint waits for its transport to flush before it
 # aborts it (Connection.close, Messenger.shutdown)
 _CLOSE_WAIT_S = 1.0
+# when an owed ack goes alone (module docstring, "The ack").  The delay:
+# above the time a frame that goes back anyway takes to set out (a
+# sub-write's commit reply 70-150 ms, a client op's reply the op's own
+# 0.5-0.7 s at the widest pool's p95: ledger, PR 33), far under anything
+# that waits on a peer (heartbeat grace 10 s, op timeouts 5 s and up);
+# what it costs is a second of one-way traffic held in the sender's
+# replay buffer, and replayed once more if the connection dies.  The
+# bytes: big frames pin their payloads in that buffer (a sub-write's
+# shard is a view of the whole encode tick's planes), so a stream that
+# nothing answers is acknowledged every 16 MiB: eight of the largest
+# shards (2 MiB at k=2), four client ops of 4 MiB, twice what a
+# connection holds in flight anyway (two socket buffers of _SOCK_BUF
+# and _STREAM_LIMIT of received frames = 8 MiB), so the ack that a
+# reply carries is not beaten to it by a closed loop's burst.  The
+# frames: a quarter of what the sender's buffer holds before it
+# overflows, so three quarters are left for what is in flight.
+_ACK_DELAY_S = 1.0
+_ACK_BYTES = 16 << 20
 
 
 def _tune_socket(stream: "_FrameStream") -> None:
@@ -339,7 +393,12 @@ class EntityName:
 
 @dataclass
 class Message:
-    """Base message; src/seq/sid are stamped by the sending messenger.
+    """Base message; src/seq/sid/src_addr/ack are stamped by the sending
+    messenger.  A session frame (``sid`` set) says in ``src_addr`` where
+    its messenger listens, which is how the peer's own frames to that
+    address find the ack they can carry; ``ack`` is such a carried ack:
+    ``(sid, seq)``, "the messenger ``sid``'s session to me: everything
+    up to ``seq`` has arrived" (module docstring, "The ack").
 
     ``trace`` is the op-lifecycle trace header (round 6 telemetry): a
     {"id", "events": [(name, wall_ts), ...]} dict minted by the objecter
@@ -351,14 +410,15 @@ class Message:
     src: Optional[EntityName] = field(default=None, init=False)
     seq: int = field(default=0, init=False)
     sid: int = field(default=0, init=False)
+    src_addr: Optional[Addr] = field(default=None, init=False)
+    ack: Optional[Tuple[int, int]] = field(default=None, init=False)
     trace: Optional[dict] = field(default=None, init=False)
 
 
 @dataclass
 class _MsgAck(Message):
-    """Transport-level ack: trims the sender's replay buffer."""
-
-    acked: int = 0
+    """An owed ack that nothing carried: a frame that is its header's
+    ``ack`` and nothing else (``Messenger._ack_alone`` makes it)."""
 
 
 @dataclass
@@ -427,10 +487,42 @@ class _Session:
             self.unacked.popitem(last=False)
 
     def ack(self, seq: int) -> None:
-        for s in [s for s in self.unacked if s <= seq]:
-            del self.unacked[s]
-        if not self.unacked:
+        # the buffer is in seq order (buffer appends, _late_send sorts
+        # what it puts back), so what an ack frees is its front
+        unacked = self.unacked
+        while unacked and next(iter(unacked)) <= seq:
+            unacked.popitem(last=False)
+        if not unacked:
             self.overflowed = False  # fully acked: contract restored
+
+
+class _Owed:
+    """What this messenger owes ONE peer session, as received on one
+    connection: the newest sequence number taken, the newest whose ack
+    has left (in a frame or alone), and of what lies between them the
+    frames, the bytes and when it goes alone; and the ``taken`` at which
+    a frame that arrived again was last answered."""
+
+    __slots__ = ("conn", "sid", "taken", "acked", "count", "nbytes", "due",
+                 "told")
+
+    # owed frames at which the ack goes alone: the sender's buffer
+    # overflows at MAX_UNACKED, in flight included
+    MAX_FRAMES = _Session.MAX_UNACKED // 4
+
+    def __init__(self, conn: "Connection", sid: int, taken: int):
+        self.conn: Optional["Connection"] = conn
+        self.sid = sid
+        self.taken = self.acked = taken
+        self.count = 0
+        self.nbytes = 0
+        self.due = 0.0
+        self.told = -1
+
+    def ack(self) -> Optional[Tuple[int, int]]:
+        """What a frame to the peer says of it: nothing if nothing is
+        owed."""
+        return (self.sid, self.taken) if self.taken > self.acked else None
 
 
 class Connection:
@@ -444,6 +536,9 @@ class Connection:
         self._send_lock = DepLock("messenger.conn_send")
         self._seq = 0
         self.closed = False
+        # the acks owed to the session whose frames this connection
+        # brings in (Messenger._owe); a raw send on it carries them
+        self.owed: Optional[_Owed] = None
         # cephx session state (set by the authorizer handshake):
         # subsequent frames both ways sign with the session key, and
         # dispatchers consult peer_caps for authorization
@@ -460,6 +555,9 @@ class Connection:
         async with self._send_lock:
             self._seq += 1
             msg.seq = self._seq
+            # not a session frame, whatever a hop before this one made of
+            # the object: its seq numbers this connection, not a session
+            msg.sid, msg.src_addr = 0, None
             if msg.trace is not None:
                 # hop stamp for replies riding raw connections (the
                 # reply-leg half of op attribution; send_message stamps
@@ -467,13 +565,18 @@ class Connection:
                 msg.trace.setdefault("events", []).append(
                     (f"msgr:{self.messenger.name}:send", _time.time()))
             hs = _encode_hs(msg)
+            ack = None
             if hs is not None:
                 # handshake: fixed struct, pre-session, unsigned
                 parts = [struct.pack("<I", len(hs)), hs]
             else:
+                # the reply on the connection the request came in on
+                # takes the request's ack with it
+                msg.ack = ack = self.owed and self.owed.ack()
                 parts = _frame_parts(self._sign_key(), _encode(msg))
             try:
                 self.stream.write(parts)
+                self.messenger._ack_left(self.owed, ack)
                 await self.stream.drain()
             except (ConnectionError, RuntimeError):
                 self.closed = True
@@ -759,6 +862,13 @@ class Messenger:
         # pings and their replies and nothing else (send_heartbeat)
         self._hb_out: Dict[Addr, Connection] = {}
         self._sessions: Dict[Addr, _Session] = {}
+        # acks owed (module docstring, "The ack"): by the address the
+        # owed session's messenger listens on, which is where
+        # send_message finds what its frame can carry; and those that
+        # owe anything now, which is what the one flush timer walks
+        self._owed_to: Dict[Addr, _Owed] = {}
+        self._owing: Set[_Owed] = set()
+        self._ack_timer: Optional[asyncio.TimerHandle] = None
         self._accepted: List[Connection] = []
         # live-task registry: completed tasks self-discard, or a chaos
         # run would grow one dead Task per dropped/reordered frame for
@@ -872,17 +982,23 @@ class Messenger:
                     # "wire" stage boundary in op attribution
                     msg.trace.setdefault("events", []).append(
                         (f"msgr:{self.name}:recv", _time.time()))
-                if isinstance(msg, _MsgAck):
-                    sess = self._sessions.get(conn.peer_addr)
+                ack = msg.ack
+                if ack is not None and ack[0] == self.sid:
+                    # whatever the frame is, it says how far our session
+                    # to its sender has arrived: on a connection we
+                    # opened that is the session to its address, on an
+                    # accepted one the sender's frame says where it
+                    # listens.  An ack that names another sid was owed
+                    # to a messenger that had this address before us
+                    sess = self._sessions.get(conn.peer_addr or msg.src_addr)
                     if sess is not None:
-                        sess.ack(msg.acked)
+                        sess.ack(ack[1])
+                if isinstance(msg, _MsgAck):
                     continue
                 if msg.sid:
-                    # session traffic: ack so the sender can trim replay
-                    try:
-                        await conn.send(_MsgAck(acked=msg.seq))
-                    except (ConnectionError, OSError, RuntimeError):
-                        pass
+                    # session traffic: the sender trims its replay
+                    # buffer by the ack it is now owed
+                    self._owe(conn, msg, n)
                 pol = self.policy_for(conn)
                 thr = pol.throttle if pol is not None else None
                 if thr is not None:
@@ -917,6 +1033,14 @@ class Messenger:
             # sees the failure and reconnect+replay engages, instead of
             # writing into a blackholed socket until overflow
             await conn.close()
+            owed = conn.owed
+            if owed is not None:
+                # what was owed on it is void: the session's next send
+                # finds the connection dead and replays its tail.  What
+                # it has taken stays known under the session's address,
+                # without the connection and its buffers
+                self._ack_left(owed, (owed.sid, owed.taken), carried=False)
+                owed.conn = conn.owed = None
             for d in self.dispatchers:
                 try:
                     await d.ms_handle_reset(conn)
@@ -927,6 +1051,84 @@ class Messenger:
 
                     logging.getLogger("ceph_tpu.msgr").exception(
                         "%s: ms_handle_reset hook failed", self.name)
+
+    def _owe(self, conn: Connection, msg: Message, nbytes: int) -> None:
+        """A session frame arrived: its ack is owed, not sent."""
+        KERNELS.inc("msgr_acks_owed")
+        owed = conn.owed
+        if owed is None:
+            # a connection brings in one session's frames: its opener's
+            prev = self._owed_to.get(msg.src_addr)
+            # the same session on a new connection: what it replays of
+            # what the old one brought is known
+            owed = conn.owed = _Owed(
+                conn, msg.sid, prev.taken if prev is not None
+                and prev.sid == msg.sid else msg.seq - 1)
+            if msg.src_addr is not None:
+                self._owed_to[msg.src_addr] = owed
+        if msg.seq <= owed.taken:
+            # a frame again (a replay, a duplicate, one that was
+            # overtaken): the sender is recovering, tell it at once how
+            # far it got — once, not for every frame of a replayed tail
+            if owed.told != owed.taken:
+                owed.told = owed.taken
+                self._ack_alone(owed)
+            return
+        if owed.taken == owed.acked:
+            owed.due = _time.monotonic() + _ACK_DELAY_S
+            self._owing.add(owed)
+        owed.taken = msg.seq
+        owed.count += 1
+        owed.nbytes += nbytes
+        if owed.nbytes >= _ACK_BYTES or owed.count >= owed.MAX_FRAMES:
+            self._ack_alone(owed)
+        elif self._ack_timer is None and not self._closing:
+            self._ack_timer = asyncio.get_running_loop().call_later(
+                _ACK_DELAY_S, self._flush_acks)
+
+    def _ack_left(self, owed: Optional[_Owed],
+                  ack: Optional[Tuple[int, int]],
+                  carried: bool = True) -> None:
+        """``ack``, if the frame had one, has been written to ``owed``'s
+        peer: inside a frame (``carried``) or alone.  What arrived since
+        it was made is still owed."""
+        if ack is None or ack[1] <= owed.acked:
+            return      # nothing, or another frame took it meanwhile
+        seq = ack[1]
+        done = seq == owed.taken
+        n = owed.count if done else min(owed.count, seq - owed.acked)
+        if carried:
+            KERNELS.inc("msgr_acks_carried", n)
+        owed.acked = seq
+        owed.count -= n
+        if done:
+            owed.nbytes = 0
+            self._owing.remove(owed)
+
+    def _ack_alone(self, owed: _Owed) -> None:
+        """The one place a ``_MsgAck`` is made: on the connection the
+        frames came in on, written and not drained (it is a hundred
+        bytes, and a peer that does not read must not hold up the
+        others' acks)."""
+        conn = owed.conn
+        ack = _MsgAck()
+        ack.src = self.name
+        ack.ack = owed.sid, owed.taken
+        if not conn.closed:
+            conn.stream.write(_frame_parts(conn._sign_key(), _encode(ack)))
+        self._ack_left(owed, ack.ack, carried=False)
+
+    def _flush_acks(self) -> None:
+        """The messenger's one timer: every owed ack that nothing carried
+        within ``_ACK_DELAY_S`` goes alone, and the timer is set for the
+        next one due."""
+        self._ack_timer = None
+        now = _time.monotonic()
+        for owed in [o for o in self._owing if o.due <= now]:
+            self._ack_alone(owed)
+        if self._owing and not self._closing:
+            self._ack_timer = asyncio.get_running_loop().call_later(
+                min(o.due for o in self._owing) - now, self._flush_acks)
 
     async def _handle_auth_frame(self, conn: Connection, msg) -> bool:
         """cephx transport frames (already struct-decoded — the pickle
@@ -1050,6 +1252,11 @@ class Messenger:
             msg.src = self.name
             msg.seq = sess.seq
             msg.sid = self.sid
+            msg.src_addr = self.my_addr
+            # a frame to the address a session reaches us from takes
+            # that session's ack with it
+            owed = self._owed_to.get(addr)
+            msg.ack = ack = owed and owed.ack()
             if msg.trace is not None:
                 # messenger hop stamp: the trace header records when this
                 # endpoint put the message on the wire
@@ -1110,10 +1317,15 @@ class Messenger:
                     # unacked tail (this frame is buffered, so it rides
                     # the replay) before anything newer goes out
                     await self._reconnect_replay(sess, addr)
+                    self._ack_left(owed, ack)
                     return
                 conn = await self.connect(addr)
                 parts = _frame_parts(conn._sign_key(), frame)
                 conn.stream.write(parts)
+                # only now: an ack in a frame that did not set out (a
+                # chaos fate above, a peer that cannot be reached) stays
+                # owed to the next frame
+                self._ack_left(owed, ack)
                 if fate is not None and fate.dup:
                     conn.stream.write(parts)  # duplicate delivery:
                     # handlers are idempotent by contract — prove it
@@ -1131,6 +1343,7 @@ class Messenger:
                 if self._closing:
                     raise
                 await self._reconnect_replay(sess, addr)
+                self._ack_left(owed, ack)
 
     async def _replay_later(self, sess: _Session, addr: Addr,
                             delay: float) -> None:
@@ -1248,6 +1461,9 @@ class Messenger:
             # the config outlives this messenger (daemon bounces reuse
             # it): leave no observer behind to pin dead incarnations
             self.config.remove_observer(self._chaos_observer)
+        if self._ack_timer is not None:
+            self._ack_timer.cancel()
+            self._ack_timer = None
         if self._server:
             self._server.close()
         # together, not in turn: each close is bounded (_CLOSE_WAIT_S),

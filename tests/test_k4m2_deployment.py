@@ -890,6 +890,52 @@ def test_the_shape_metric_files_read_the_hand_worked_values(cell_name):
                            else None), name
 
 
+def test_a_write_burst_carries_its_acks():
+    """The counter twin of ``ack_carried_share.write``: a burst of k=4
+    m=2 writes on the deployment's 8 OSDs ends with at least four fifths
+    of the session frames' acks carried by the frames that went back
+    anyway (the sub-write's commit reply, the op's reply, the next
+    sub-write), and every write reads back.  A change that brings the
+    ack frames back fails here, not only in a metric."""
+    async def scenario():
+        cluster = await start_cluster(DEPLOYMENT["osds"],
+                                      config=_fast_config())
+        try:
+            client = await cluster.client()
+            pool = await client.pool_create(
+                "pool", DEPLOYMENT["pool_type"], pg_num=32,
+                ec_profile=dict(DEPLOYMENT["ec_profile"]))
+            io = client.ioctx(pool)
+            rng = np.random.default_rng(35)
+            payloads = {f"burst_{i}": rng.integers(
+                0, 256, 65537, dtype=np.uint8).tobytes()
+                for i in range(96)}
+            before = counters()
+            names = iter(payloads)
+
+            async def caller():
+                for name in names:
+                    await io.write_full(name, payloads[name], timeout=120)
+
+            await asyncio.gather(*(caller() for _ in range(16)))
+            grew = {k: counters().get(k, 0) - before.get(k, 0)
+                    for k in ("msgr_acks_owed", "msgr_acks_carried",
+                              "msgr_frames", "ec_coalesced_ops")}
+            got = await asyncio.gather(*(io.read(n, timeout=120)
+                                         for n in payloads))
+            assert dict(zip(payloads, got)) == payloads
+            return grew
+        finally:
+            await cluster.stop()
+
+    grew = bounded(scenario(), 240)
+    assert grew["ec_coalesced_ops"] == 96
+    # an op is its own frame, its reply, five sub-writes and their
+    # commits (fewer where the coalescers batched them)
+    assert grew["msgr_acks_owed"] >= 96 * 4
+    assert grew["msgr_acks_carried"] >= 0.8 * grew["msgr_acks_owed"], grew
+
+
 def test_every_cell_of_the_benchmark_loads_through_the_loader():
     """ROADMAP C10: every cell's files exist and agree with their
     entries (the loader raises otherwise), every name in a ``workloads``

@@ -79,20 +79,35 @@ def _payload(i: int, size: int) -> bytes:
     return bytes([i % 251]) * size
 
 
+# the ack delay of the two cases of a reset: no ack has left when the
+# connection dies (every frame since the start is replayed), or every
+# ack left at the next turn of the loop (about what the parent did)
+ACKS = {"acks_owed": 30.0, "acks_sent": 0.0}
+
+
+@pytest.fixture(params=list(ACKS))
+def acks(request, monkeypatch):
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", ACKS[request.param])
+    return request.param
+
+
 @pytest.mark.parametrize("size", [0, 200_000],
                          ids=["in_band", "out_of_band"])
-def test_reconnect_replays_unacked_in_order(size):
+def test_reconnect_replays_unacked_in_order(size, acks):
     """Kill the TCP connection mid-stream: every message still arrives,
     in order (duplicates allowed — at-least-once), nothing lost.  With
     ``size`` the frames are sub-writes whose data rides out of band: the
     replay buffer holds the pickle and references to the buffers, and
-    what is replayed are the bytes the first send carried."""
+    what is replayed are the bytes the first send carried.  With acks
+    owed at the reset the replay is the whole stream so far."""
     async def scenario():
         rx = Messenger(EntityName("osd", 1))
         coll = Collector()
         rx.add_dispatcher(coll)
         addr = await rx.bind()
         tx = Messenger(EntityName("osd", 2))
+        # a daemon listens: its session is known again on a new connection
+        await tx.bind()
         try:
             total = 60
             for i in range(total):
@@ -118,6 +133,9 @@ def test_reconnect_replays_unacked_in_order(size):
                 if not dedup or n > dedup[-1]:
                     dedup.append(n)
             assert dedup == list(range(total))
+            if acks == "acks_owed":
+                # each reset replayed more than the frame that met it
+                assert len(coll.got) >= total + 20
             if size:
                 assert all(blob == _payload(i, size)
                            for i, blob in coll.blobs), \
@@ -130,9 +148,11 @@ def test_reconnect_replays_unacked_in_order(size):
     run(scenario())
 
 
-def test_reconnect_survives_receiver_restart():
+def test_reconnect_survives_receiver_restart(acks):
     """The receiving endpoint dies completely and comes back on the same
-    port: the unacked tail replays to the new incarnation."""
+    port: the unacked tail replays to the new incarnation.  An old one
+    that died owing its acks has freed nothing of the sender's buffer,
+    and nothing the new one says frees a frame it has not taken."""
     async def scenario():
         rx = Messenger(EntityName("osd", 1))
         coll = Collector()
@@ -147,6 +167,9 @@ def test_reconnect_survives_receiver_restart():
             while set(coll.got) < set(range(10)) and \
                     asyncio.get_event_loop().time() < deadline:
                 await asyncio.sleep(0.02)
+            sess = tx._sessions[tuple(addr)]
+            if acks == "acks_owed":
+                assert list(sess.unacked) == list(range(1, 11))
             await rx.shutdown()
 
             rx2 = Messenger(EntityName("osd", 1))
@@ -165,6 +188,11 @@ def test_reconnect_survives_receiver_restart():
                 # the new incarnation received at least the new tail; any
                 # unacked old frames replayed too (at-least-once)
                 assert set(range(10, 20)) <= got, sorted(got)
+                if acks == "acks_owed":
+                    assert list(sess.unacked) == list(range(1, 21))
+                    dedup = sorted(set(coll2.got))
+                    assert [n for i, n in enumerate(coll2.got)
+                            if n not in coll2.got[:i]] == dedup
             finally:
                 await rx2.shutdown()
         finally:
@@ -385,6 +413,346 @@ def test_byte_throttle_backpressure():
             await server.shutdown()
 
     asyncio.run(scenario())
+
+
+# --------------------------------- the ack is state, not a frame (PR 35)
+
+@dataclass
+class Rep(Message):
+    n: int = 0
+
+
+class Replier(Collector):
+    """Answers every ``Num`` with a ``Rep``: over its own session to the
+    sender's listening address, as ``osd.py::_reply_osd`` does, or raw
+    on the connection the ``Num`` came in on, as an op's reply goes."""
+
+    def __init__(self, messenger, lane):
+        super().__init__()
+        self.messenger, self.lane = messenger, lane
+        self.reps: List[int] = []
+
+    async def ms_dispatch(self, conn, msg) -> bool:
+        if isinstance(msg, Rep):
+            self.reps.append(msg.n)
+            return True
+        if isinstance(msg, Num) and self.lane == "session":
+            await self.messenger.send_message(Rep(n=msg.n), msg.src_addr)
+        elif isinstance(msg, Num) and self.lane == "raw":
+            await conn.send(Rep(n=msg.n))
+        return await super().ms_dispatch(conn, msg)
+
+
+def _ack_counters(since=(0, 0, 0)):
+    """Frames made, session frames received, those whose ack was
+    carried: as they stand, or their growth ``since``."""
+    return [KERNELS.get(c) - was for c, was in zip(
+        ("msgr_frames", "msgr_acks_owed", "msgr_acks_carried"), since)]
+
+
+async def _bound_pair(lane):
+    """Two messengers that both listen, each answering the other."""
+    a, b = Messenger(EntityName("osd", 1)), Messenger(EntityName("osd", 2))
+    da, db = Replier(a, lane), Replier(b, lane)
+    a.add_dispatcher(da)
+    b.add_dispatcher(db)
+    return a, b, da, db, await a.bind(), await b.bind()
+
+
+@pytest.mark.parametrize("lane", ["session", "raw"])
+def test_request_and_reply_frame_no_ack(lane, monkeypatch):
+    """Twenty requests, each answered: forty frames and not one
+    ``_MsgAck``; every ack but the last reply's left inside the frame
+    that went back anyway, and both replay buffers are trimmed by them.
+    The last one goes alone once nothing has carried it for the ack
+    delay: one frame more."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 0.3)
+
+    async def scenario():
+        a, b, da, db, addr_a, addr_b = await _bound_pair(lane)
+        try:
+            total = 20
+            before = _ack_counters()
+            for i in range(total):
+                await a.send_message(Num(n=i), addr_b)
+                assert await _until(lambda: len(da.reps) == i + 1)
+                # the reply brought the request's ack
+                assert not a._sessions[addr_b].unacked
+            grew = _ack_counters(before)
+            if lane == "session":
+                # the replies are session frames too: each request
+                # carries the ack of the reply before it
+                assert grew == [2 * total, 2 * total, 2 * total - 1]
+                assert list(b._sessions[addr_a].unacked) == [total]
+                assert await _until(
+                    lambda: not b._sessions[addr_a].unacked, 5.0)
+                assert _ack_counters(before)[0] == 2 * total + 1
+            else:
+                assert grew == [2 * total, total, total]
+                assert addr_a not in b._sessions
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    run(scenario())
+
+
+def test_replayed_tail_is_answered_with_one_ack(monkeypatch):
+    """Ten frames taken and their acks owed when the connection dies:
+    the next send replays all ten before its own, the receiver knows
+    the session again by its sid and listening address, and the first
+    replayed frame is answered at once with ONE ``_MsgAck`` that frees
+    the ten; all are dispatched again (at-least-once)."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 60.0)
+
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        await tx.bind()
+        try:
+            for i in range(10):
+                await tx.send_message(Num(n=i), addr)
+            assert await _until(lambda: len(coll.got) == 10)
+            sess = tx._sessions[tuple(addr)]
+            assert len(sess.unacked) == 10
+            before = _ack_counters()
+            tx._out[tuple(addr)].stream.close()
+            await tx.send_message(Num(n=10), addr)
+            assert await _until(lambda: list(sess.unacked) == [11])
+            assert coll.got == list(range(10)) + list(range(11))
+            assert _ack_counters(before) == [2, 11, 0]
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def test_one_way_stream_is_acked_within_the_delay(monkeypatch):
+    """Nothing goes back: the stream's acks are owed until the delay has
+    passed, then ONE ``_MsgAck`` frees all of it."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 0.4)
+
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        try:
+            before = _ack_counters()
+            for i in range(10):
+                await tx.send_message(Num(n=i), addr)
+            assert await _until(lambda: len(coll.got) == 10)
+            sess = tx._sessions[tuple(addr)]
+            assert len(sess.unacked) == 10
+            assert await _until(lambda: not sess.unacked, 5.0)
+            assert _ack_counters(before) == [11, 10, 0]
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("replies", ["none", "session"])
+def test_replay_buffer_is_bounded_under_shard_sized_frames(replies,
+                                                           monkeypatch):
+    """Sub-writes of 1 MiB, eight in flight, with an ack delay nothing
+    reaches.  Unanswered, the bytes threshold frees them: an ack alone
+    every ``_ACK_BYTES``, so the sender never holds more than that plus
+    what a connection has in flight (its two socket buffers and the
+    receiver's queue) and one frame.  Answered as an OSD answers, the
+    replies carry the acks and the buffer never passes the window."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 60.0)
+    size, total, window = 1 << 20, 64, 8
+
+    class Committer(Collector):
+        def __init__(self, messenger):
+            super().__init__()
+            self.messenger = messenger
+
+        async def ms_dispatch(self, conn, msg) -> bool:
+            if isinstance(msg, M.MOSDECSubOpWrite):
+                self.got.append(msg.shard)
+                if replies == "session":
+                    await self.messenger.send_message(
+                        Rep(n=msg.shard), msg.src_addr)
+                return True
+            return False
+
+    async def scenario():
+        a, b, da, _, addr_a, addr_b = await _bound_pair("session")
+        commits = Committer(b)
+        b.dispatchers.insert(0, commits)
+        try:
+            before = _ack_counters()
+            held = []
+            for i in range(total):
+                await a.send_message(
+                    M.MOSDECSubOpWrite(shard=i, data=_payload(i, size)),
+                    addr_b)
+                if replies == "session":
+                    assert await _until(
+                        lambda: len(da.reps) > i - window)
+                unacked = a._sessions[addr_b].unacked
+                held.append((len(unacked), sum(
+                    len(p) + sum(len(x) for x in bufs)
+                    for p, bufs in unacked.values())))
+            assert await _until(lambda: len(commits.got) == total, 30.0)
+            grew = _ack_counters(before)
+            frame = size + 1024
+            if replies == "none":
+                in_flight = 2 * msgr._SOCK_BUF + msgr._STREAM_LIMIT
+                bound = msgr._ACK_BYTES + in_flight + frame
+                # an ack alone for every threshold's worth, no more
+                assert grew == [total + total * size // msgr._ACK_BYTES,
+                                total, 0]
+                assert len(a._sessions[addr_b].unacked) \
+                    < msgr._ACK_BYTES // size
+            else:
+                bound = window * frame
+                assert await _until(lambda: len(da.reps) == total)
+                assert _ack_counters(before)[0] == 2 * total
+            assert max(n for n, _ in held) <= bound // size, held
+            assert max(nbytes for _, nbytes in held) <= bound, held
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    run(scenario())
+
+
+def test_frames_owed_near_the_buffer_bound_are_acked_at_once(monkeypatch):
+    """A one-way stream of small frames, faster than any delay: the ack
+    goes alone every ``_Owed.MAX_FRAMES``, a quarter of what the
+    sender's buffer holds, so a stream nothing answers never overflows
+    it."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 60.0)
+
+    async def scenario():
+        rx, tx, coll, addr = await _pair()
+        try:
+            every = msgr._Owed.MAX_FRAMES
+            total = 2 * msgr._Session.MAX_UNACKED
+            sess = None
+            for i in range(total):
+                await tx.send_message(Num(n=i), addr)
+                await asyncio.sleep(0)      # a sender that lets others run
+                sess = sess or tx._sessions[tuple(addr)]
+                assert not sess.overflowed
+            assert await _until(lambda: len(coll.got) == total)
+            assert await _until(lambda: len(sess.unacked) < every)
+            assert coll.got == list(range(total))
+        finally:
+            await tx.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def test_ack_naming_a_dead_incarnation_trims_nothing(monkeypatch):
+    """A messenger is replaced by a new one on the same address while
+    its peer still owes the old one's session acks.  The peer's next
+    frame to that address carries them, named by the old ``sid``: the
+    new incarnation's buffer, whose sequence numbers they would cover,
+    keeps every frame; an ack that names ITS session trims."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 60.0)
+
+    async def scenario():
+        rx, old, coll, addr = await _pair()
+        host, port = await old.bind()
+        new = Messenger(EntityName("osd", 2))
+        got = Collector()
+        new.add_dispatcher(got)
+        try:
+            for i in range(5):
+                await old.send_message(Num(n=i), addr)
+            assert await _until(lambda: len(coll.got) == 5)
+            owed = rx._owed_to[(host, port)]
+            assert owed.ack() == (old.sid, 5)
+            await old.shutdown()
+            await new.bind(host, port)
+            # the new incarnation's own session to rx, three frames that
+            # rx has not answered: hold them back from rx's notice of the
+            # new sid by writing them into the buffer only
+            sess = new._sessions[tuple(addr)] = msgr._Session()
+            for seq in (1, 2, 3):
+                sess.seq = seq
+                sess.buffer(seq, msgr._encode(Num(n=100 + seq)))
+            # what rx sends next to that address carries the old ack
+            await rx.send_message(Num(n=7), (host, port))
+            assert await _until(lambda: got.got == [7])
+            assert list(sess.unacked) == [1, 2, 3]
+            assert owed.ack() is None       # it left, and was not taken
+            # the control: the same frame naming the session that is there
+            conn = await rx.connect((host, port))
+            named = Num(n=8)
+            named.src, named.src_addr = rx.name, rx.my_addr
+            named.ack = (new.sid, 2)
+            conn.stream.write(msgr._frame_parts(None, msgr._encode(named)))
+            assert await _until(lambda: got.got == [7, 8])
+            assert list(sess.unacked) == [3]
+        finally:
+            await new.shutdown()
+            await old.shutdown()
+            await rx.shutdown()
+
+    run(scenario())
+
+
+def test_ack_of_a_frame_that_did_not_set_out_stays_owed(monkeypatch):
+    """The peer cannot be reached at its listening address (a one-way
+    partition): the session frame that would have carried the ack fails,
+    and the ack is not lost with it — the raw reply on the connection
+    the request came in on takes it (``_reply_osd``'s fallback)."""
+    monkeypatch.setattr(msgr, "_ACK_DELAY_S", 60.0)
+
+    async def scenario():
+        a, b, da, db, addr_a, addr_b = await _bound_pair("none")
+        try:
+            await a.send_message(Num(n=1), addr_b)
+            assert await _until(lambda: db.got == [1])
+            owed = b._owed_to[addr_a]
+            assert owed.ack() == (a.sid, 1)
+
+            async def refuse(addr):
+                raise ConnectionRefusedError("partitioned")
+
+            monkeypatch.setattr(b, "_open", refuse)
+            with pytest.raises(ConnectionError):
+                await b.send_message(Rep(n=1), addr_a)
+            assert owed.ack() == (a.sid, 1)
+            assert list(a._sessions[addr_b].unacked) == [1]
+            await b._accepted[0].send(Rep(n=1))
+            assert await _until(lambda: da.reps == [1])
+            assert owed.ack() is None
+            assert not a._sessions[addr_b].unacked
+        finally:
+            await a.shutdown()
+            await b.shutdown()
+
+    run(scenario())
+
+
+@pytest.mark.parametrize("cell_name", [
+    "k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
+    "k8m4_write_4m_t16"])
+def test_ack_carried_share_reads_the_hand_worked_value(cell_name):
+    """48000 session frames received of which 45600 had their ack
+    carried: 95 %, through the accepted ``counter_ratio`` reader; a
+    program without the counters (the parent commit) reads nothing and
+    nothing raises."""
+    from benchmark.harness import layers
+    from benchmark.harness.loader import load_cell
+
+    cell = load_cell(cell_name)
+    name = "ack_carried_share.write"
+    for growth, want in (
+            ({"msgr_acks_owed": 48000, "msgr_acks_carried": 45600},
+             pytest.approx(95.0)),
+            ({"msgr_acks_owed": 100}, 0.0),
+            ({"msgr_frames": 64400}, None)):
+        readings = layers.Readings(
+            config=cell.config, device_kind="TPU v5 lite", attribution={},
+            counters=growth, slice_counters={}, trace=None)
+        assert layers.read_metric(name, cell.per_layer[name],
+                                  readings) == want
 
 
 # ------------------------------------- frames without copies (PR 29)
