@@ -1588,7 +1588,12 @@ class Monitor(Dispatcher):
             # ErasureCode::create_rule analog: indep chooseleaf rule,
             # with the tries upstream's add_simple_rule gives every
             # indep rule: at the tunables' 50 a pool that takes all of
-            # its hosts (k+m of k+m) leaves one slot in ~60 PGs unfilled
+            # its hosts (k+m of k+m) leaves one slot in ~60 PGs unfilled.
+            # An LRC profile without crush-locality gets this rule from
+            # upstream's create_rule too (one chooseleaf indep step over
+            # the failure domain); ErasureCodeLrc.create_rule's
+            # multi-step rule is for a map that has the locality buckets
+            # (racks) and is not wired in here.
             rule = Rule(steps=[
                 (RULE_SET_CHOOSELEAF_TRIES, 5, 0),
                 (RULE_SET_CHOOSE_TRIES, 100, 0),

@@ -10,7 +10,7 @@ minimum_to_decode (:91-108), profile coercion helpers (:280-328), and the
 from __future__ import annotations
 
 import errno
-from typing import Dict, Iterable, List, Mapping, Set
+from typing import Dict, Iterable, List, Mapping, Optional, Set
 
 import numpy as np
 
@@ -113,6 +113,16 @@ class ErasureCode(ErasureCodeInterface):
         if len(available_chunks) < k:
             raise ECError(errno.EIO, "not enough chunks to decode")
         return set(sorted(available_chunks)[:k])
+
+    def decode_sources(self, want, available) -> Optional[List[int]]:
+        """Which of the chunks ``available`` (those that came, in the
+        caller's order) a decode of the chunks ``want`` should multiply.
+        None says the code has no opinion: any k of them will do, which
+        is an MDS code's answer, and the caller takes the first k.  A
+        code for which not every k will do answers with the chunks, and
+        raises ECError(EIO) where ``available`` cannot produce ``want``
+        (``ErasureCodeLrc``)."""
+        return None
 
     # -- encode / decode ----------------------------------------------------
 

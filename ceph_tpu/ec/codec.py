@@ -252,6 +252,33 @@ class _DeviceMatrixEngine:
             bitmat, chunks, tuple(src_rows), self.word_bytes)
 
 
+def matrix_engine(codec):
+    """The codec's engine where it is a bytewise GF(2^8) matrix code the
+    host GF engine, the plane entry points of ``ec/stripe.py`` and the
+    mesh engine can drive: ``coding`` ((m, k) bytes), ``_enc_bitmat``,
+    ``decode_matrix(src, want)`` (chunk[want] = R @ chunk[src]; raises
+    where ``src`` cannot produce ``want``) and ``decode_bitmat``.  None
+    for every other code: a wider field, a packet-interleaved chunk
+    layout (the bytes differ from the bytewise product of the same
+    matrix), no matrix at all.  The ONE place that asks; whoever needs
+    to know calls this.  The answer is kept on the codec: a pool's
+    codec is asked at every op."""
+    try:
+        return codec._matrix_engine
+    except AttributeError:
+        pass
+    eng = getattr(codec, "engine", None)
+    if eng is None:
+        return None         # none (yet): nothing to remember
+    if getattr(eng, "w", 0) != 8 or \
+            getattr(eng, "coding", None) is None or \
+            not hasattr(eng, "decode_matrix") or \
+            getattr(codec, "packetsize", None) is not None:
+        eng = None
+    codec._matrix_engine = eng
+    return eng
+
+
 class _DeviceBitEngine:
     """Engine for NATIVE GF(2) bit-matrix codes (liberation family): the
     code is defined directly by an (m*w, k*w) 0/1 matrix with no byte

@@ -17,14 +17,16 @@ subsets, exactly like the reference routes bufferlists between plugins.
 from __future__ import annotations
 
 import errno
+import itertools
 import json
 from dataclasses import dataclass, field
-from typing import Dict, List, Mapping, Set
+from typing import Dict, List, Mapping, Optional, Set, Tuple
 
 import numpy as np
 
-from ceph_tpu.ec.base import ErasureCode
+from ceph_tpu.ec.codec import MatrixCodec, _DeviceMatrixEngine, matrix_engine
 from ceph_tpu.ec.interface import ECError, ErasureCodeInterface, ErasureCodeProfile
+from ceph_tpu.ec.table_cache import DecodeTableCache
 
 DEFAULT_KML = "-1"
 
@@ -51,19 +53,39 @@ class Step:
     n: int
 
 
-class ErasureCodeLrc(ErasureCode):
+class _FlatLayersEngine(_DeviceMatrixEngine):
+    """The layer walk as ONE matrix engine: the seam the plane entry
+    points of ``ec/stripe.py`` drive (``codec.matrix_engine``).  The
+    generator is the composed one (``_flat_coding_matrix``: every parity,
+    global or local, over the data chunks), so encode is the base
+    class's one matmul.  Decode is not a survivor-submatrix inversion:
+    the four chunks of a local group have rank 3, so not every k rows
+    invert, and fewer than k decode a chunk of their own group.
+    ``decode_matrix`` composes the bottom-up layer walk over exactly the
+    chunks it is given and RAISES where no layer can make progress."""
+
+    def __init__(self, codec: "ErasureCodeLrc"):
+        super().__init__(codec.data_chunk_count,
+                         codec.chunk_count - codec.data_chunk_count,
+                         codec._flat_coding_matrix())
+        self._walk = codec._flat_decode_matrix
+
+    def decode_matrix(self, src_rows: Tuple[int, ...],
+                      out_rows: Tuple[int, ...]) -> np.ndarray:
+        return self._walk(tuple(src_rows), tuple(out_rows))
+
+
+class ErasureCodeLrc(MatrixCodec):
     def __init__(self):
         super().__init__()
         self.layers: List[Layer] = []
         self.chunk_count = 0
         self.data_chunk_count = 0
         self.rule_steps: List[Step] = [Step("chooseleaf", "host", 0)]
-        # jitted batch entry points: the layer routing must be ONE device
-        # dispatch — eager per-layer gathers/scatters cost a runtime round
-        # trip each, which dominates end-to-end throughput
-        self._enc_jit = None
-        self._enc_planar_bitmat = None
+        # per erasure pattern: the composed pruned recovery, and per
+        # (want, available): the sources chosen for it
         self._dec_jit: Dict = {}
+        self._sources = DecodeTableCache()
 
     # -- profile parsing ----------------------------------------------------
 
@@ -245,6 +267,14 @@ class ErasureCodeLrc(ErasureCode):
         self.chunk_count = len(mapping)
         self._layers_sanity_checks()
         self.to_mapping(profile)
+        self.k = self.data_chunk_count
+        self.m = self.chunk_count - self.data_chunk_count
+        # the batch and plane paths multiply by the flattened layers,
+        # which are matrices only where every layer is a bytewise
+        # GF(2^8) matrix code; any other stack keeps the scalar walk
+        if all(matrix_engine(layer.erasure_code) is not None
+               for layer in self.layers):
+            self.engine = _FlatLayersEngine(self)
         # kml-generated parameters are internal; do not expose them
         # (reference :545-550)
         if profile.get("l") and profile["l"] != DEFAULT_KML:
@@ -329,6 +359,44 @@ class ErasureCodeLrc(ErasureCode):
                       f"not enough chunks in {sorted(available_chunks)} "
                       f"to read {sorted(want_to_read)}")
 
+    def decode_sources(self, want, available) -> Optional[List[int]]:
+        """Which of the logical chunks ``available`` (those that came) a
+        decode of the logical chunks ``want`` should multiply;
+        ECError(EIO) where the layers cannot produce ``want`` from
+        them.  Not every k chunks of this code will do (a local group
+        of four has rank 3), so this code always has an answer
+        (``ErasureCode.decode_sources``): the first k of ``available``,
+        in its order, that the layer walk decodes ``want`` from — an
+        MDS code's rule wherever that set decodes — and all of
+        ``available`` where no k of them do and the walk over all still
+        gets there."""
+        available = list(available)
+        key = (frozenset(want), tuple(available))
+        chosen = self._sources.get(key)
+        if chosen is None:
+            chosen = next(
+                (list(c) for c in itertools.combinations(available, self.k)
+                 if self._decodable(c, want)), None)
+            if chosen is None:
+                if not self._decodable(available, want):
+                    raise ECError(
+                        errno.EIO, f"{sorted(available)} do not decode "
+                        f"{sorted(want)}")
+                chosen = available
+            self._sources.put(key, chosen)
+        return list(chosen)
+
+    def _decodable(self, src, want) -> bool:
+        """Can the layers produce ``want`` from exactly ``src``?"""
+        src = set(src)
+        try:
+            self._decode_plan(
+                tuple(c for c in range(self.chunk_count) if c not in src),
+                tuple(c for c in want if c not in src))
+        except ECError:
+            return False
+        return True
+
     # -- encode / decode ----------------------------------------------------
 
     def encode_chunks(self, chunks: Dict[int, np.ndarray]) -> None:
@@ -402,13 +470,9 @@ class ErasureCodeLrc(ErasureCode):
         Every LRC parity — global or local — is a linear function of the
         data (local layers that read global parities compose through
         them), so the whole layered encode collapses to a single MXU
-        matmul.  The honest benchmark showed the per-layer walk paying
-        tiny-K matmuls plus scatter materializations for 8.9 GB/s; the
-        flattened matrix runs at the plain-RS rate.  encode_chunks keeps
-        the literal layer walk (it IS the reference semantics the goldens
-        pin); this matrix is algebraically identical by construction."""
-        import numpy as np
-
+        matmul.  encode_chunks keeps the literal layer walk (it IS the
+        reference semantics the goldens pin); this matrix is
+        algebraically identical by construction."""
         from ceph_tpu.ops import gf8
 
         k = self.data_chunk_count
@@ -428,145 +492,125 @@ class ErasureCodeLrc(ErasureCode):
                 expr[cout] = acc
         return np.stack([expr[c] for c in coding_pos])
 
+    def planar_supported(self, chunk_size: int) -> bool:
+        return self.engine is not None and \
+            super().planar_supported(chunk_size)
+
+    def stripe_unit(self, default: int) -> int:
+        """The smallest unit >= ``default`` that every layer's chunk
+        layout accepts (a packet-interleaved layer needs multiples of
+        its w * packetsize)."""
+        unit = super().stripe_unit(default)
+        while True:
+            nxt = max(layer.erasure_code.stripe_unit(unit)
+                      for layer in self.layers)
+            if nxt == unit:
+                return unit
+            unit = nxt
+
     def encode_batch(self, data):
         """(B, k, S) logical data -> (B, m, S) coding chunks,
-        device-resident, as ONE flattened-generator MXU matmul (see
-        _flat_coding_matrix).
-
-        The encode bit-matrix stays HOST numpy and is passed as a jit
-        ARGUMENT: a jit must not close over a device array.
-        """
-        import jax
-
-        from ceph_tpu.ops import gf8
-
-        if self._enc_jit is None:
-            flat_bitmat = gf8.expand_bitmatrix(self._flat_coding_matrix())
-
-            def impl(data, bitmat):
-                import jax.numpy as jnp
-
-                data = jnp.asarray(data, dtype=jnp.uint8)
-                b, k, s = data.shape
-                cols = data.transpose(1, 0, 2).reshape(k, b * s)
-                out = gf8.bitmatrix_matmul(bitmat, cols)
-                return out.reshape(out.shape[0], b, s).transpose(1, 0, 2)
-
-            self._enc_jit = (jax.jit(impl), flat_bitmat)
-        fn, bitmat = self._enc_jit
-        return fn(data, bitmat)
+        device-resident, as ONE flattened-generator MXU matmul.  A stack
+        with a layer that is no bytewise GF(2^8) matrix code has no such
+        generator: its batch is the literal layer walk over the shard
+        rows (every layout a layer has is local to a chunk, so the walk
+        over the stripes' chunks concatenated is the walk stripe by
+        stripe), byte-at-rest."""
+        if self.engine is not None:
+            return self.engine.encode_parity_batch(data)
+        data = np.asarray(data, dtype=np.uint8)
+        b, k, s = data.shape
+        data_pos, coding_pos = self._positions()
+        rows = data.transpose(1, 0, 2).reshape(k, b * s)
+        chunks = {p: np.zeros(b * s, dtype=np.uint8) for p in coding_pos}
+        chunks.update((p, rows[j].copy()) for j, p in enumerate(data_pos))
+        self.encode_chunks(chunks)
+        return np.stack([chunks[p] for p in coding_pos]) \
+            .reshape(len(coding_pos), b, s).transpose(1, 0, 2)
 
     def decode_batch(self, erasures, chunks, want=None):
-        """Batched single-pattern reconstruction, walking layers bottom-up
-        exactly like decode_chunks.  ``chunks``: (B, n, S) in logical order
-        with zeros at erased ids; ``erasures`` = every unavailable logical
-        id; ``want`` = subset to return (default all).  Returns
-        (B, len(want), S).  Jitted per erasure pattern: the whole walk is
-        one device dispatch, recovery plans cached like the reference's
-        decode-table caches."""
+        """Batched single-pattern reconstruction.  ``chunks``: (B, n, S)
+        in logical order with zeros at erased ids; ``erasures`` = every
+        unavailable logical id; ``want`` = subset to return (default
+        all).  Returns (B, len(want), S): the composed layer walk as one
+        gather + matmul, plans cached like the reference's decode
+        tables; the literal walk for a stack that has no flat engine."""
         import jax
 
-        if want is None:
-            want = tuple(erasures)
-        key = (tuple(erasures), tuple(want))
-        cached = self._dec_jit.get(key)
-        if cached is None:
-            cached = self._dec_jit[key] = self._build_flat_decode(key)
-        fn, bitmat, src_ids = cached
-        return fn(bitmat, jax.numpy.asarray(chunks), src_ids)
-
-    def _build_flat_decode(self, key):
-        """Compose the bottom-up layer walk for one erasure pattern into
-        ONE recovery matrix over the AVAILABLE logical chunks (round 5;
-        same flattening as encode — the walk is linear, so the per-step
-        tiny-K matmuls + scatters collapse to a single gather+matmul).
-        Host-side per pattern, cached like the reference decode tables."""
-        import numpy as np
-
         from ceph_tpu.ec.codec import _gather_encode_batch_jit
+
+        want = tuple(erasures if want is None else want)
+        if self.engine is not None:
+            bitmat, src_ids = self._planar_decode_plan(tuple(erasures), want)
+            return _gather_encode_batch_jit(
+                bitmat, jax.numpy.asarray(chunks), src_ids)
+        chunks = np.asarray(chunks, dtype=np.uint8)
+        b, n, s = chunks.shape
+        pos = self.chunk_mapping
+        rows = chunks.transpose(1, 0, 2).reshape(n, b * s)
+        decoded = {pos[e]: rows[e].copy() for e in range(n)}
+        have = {pos[e]: decoded[pos[e]] for e in range(n)
+                if e not in erasures}
+        self.decode_chunks({pos[e] for e in want}, have, decoded)
+        return np.stack([decoded[pos[e]] for e in want]) \
+            .reshape(len(want), b, s).transpose(1, 0, 2)
+
+    def _planar_decode_plan(self, erasures, want):
+        """(recovery bit-matrix, source chunk ids) for one erasure
+        pattern: the walk composed over every available chunk, then
+        pruned to the chunks it uses (round 6, locality: a single local
+        erasure pulls its group of l, not all n-1 survivors — the
+        reference's minimum_to_decode read set, ErasureCodeLrc.cc:572,
+        applied to the batched matmul; coefficients are untouched, only
+        the source set shrinks)."""
         from ceph_tpu.ops import gf8
 
-        erasures, want = key
-        steps, out_pos = self._decode_plan(erasures, want)
+        key = (erasures, want)
+        cached = self._dec_jit.get(key)
+        if cached is None:
+            avail = tuple(e for e in range(self.chunk_count)
+                          if e not in erasures)
+            flat = self._flat_decode_matrix(avail, want)
+            used = np.flatnonzero(flat.any(axis=0))
+            if used.size == 0:
+                used = np.arange(min(1, len(avail)))
+            cached = self._dec_jit[key] = (
+                gf8.expand_bitmatrix(np.ascontiguousarray(flat[:, used])),
+                tuple(avail[int(i)] for i in used))
+        return cached
+
+    def _flat_decode_matrix(self, src: Tuple[int, ...],
+                            want: Tuple[int, ...]) -> np.ndarray:
+        """Compose the bottom-up layer walk that ``decode_chunks`` makes
+        with exactly the logical chunks ``src`` available into ONE
+        (len(want), len(src)) GF(2^8) recovery matrix (the walk is
+        linear, so its per-step tiny-K matmuls collapse).  Raises
+        ECError(EIO) where the walk cannot reach ``want``: a source set
+        of too low a rank is refused here, never decoded."""
+        from ceph_tpu.ops import gf8
+
+        steps, out_pos = self._decode_plan(
+            tuple(e for e in range(self.chunk_count) if e not in src),
+            tuple(want))
         logical_to_pos = list(self.chunk_mapping)
-        avail_logical = tuple(e for e in range(self.chunk_count)
-                              if e not in erasures)
-        basis = {logical_to_pos[e]: i
-                 for i, e in enumerate(avail_logical)}
         expr: dict = {}
-        for p, i in basis.items():
-            row = np.zeros(len(avail_logical), dtype=np.uint8)
+        for i, e in enumerate(src):
+            row = np.zeros(len(src), dtype=np.uint8)
             row[i] = 1
-            expr[p] = row
-        for layer, local_erasures, layer_erased in steps:
-            src = self._layer_src(layer, local_erasures)
+            expr[logical_to_pos[e]] = row
+        for layer, local_erasures, _layer_erased in steps:
+            lsrc = self._layer_src(layer, local_erasures)
             rmat = layer.erasure_code.engine.decode_matrix(
-                src, local_erasures)              # (out, src) bytes
+                lsrc, local_erasures)              # (out, src) bytes
             for r, out_local in enumerate(local_erasures):
-                acc = np.zeros(len(avail_logical), dtype=np.uint8)
-                for j, s_local in enumerate(src):
+                acc = np.zeros(len(src), dtype=np.uint8)
+                for j, s_local in enumerate(lsrc):
                     coef = int(rmat[r, j])
                     if coef:
                         acc ^= gf8.gf_mul(coef,
                                           expr[layer.chunks[s_local]])
                 expr[layer.chunks[out_local]] = acc
-        flat = np.stack([expr[p] for p in out_pos])
-        # round 6 (locality): drop all-zero columns so the device gather
-        # reads ONLY the chunks the composed recovery actually uses — a
-        # single local erasure pulls its l+1-group, not all n-1 survivors
-        # (the reference's minimum_to_decode read set, ErasureCodeLrc.cc:572,
-        # applied to the batched matmul).  Coefficients are untouched, so
-        # the result stays bit-identical; only the source set shrinks.
-        used = np.flatnonzero(flat.any(axis=0))
-        if used.size == 0:
-            used = np.arange(min(1, len(avail_logical)))
-        flat = np.ascontiguousarray(flat[:, used])
-        src_ids = tuple(avail_logical[int(i)] for i in used)
-        bitmat = gf8.expand_bitmatrix(flat)
-        return _gather_encode_batch_jit, bitmat, src_ids
-
-    # -- bit-planar device layout (round 6) ---------------------------------
-    #
-    # LRC's layer walk is flattened to single matrices (encode: the
-    # composed generator; decode: the composed pruned recovery), so the
-    # planar path is the same one-matmul story as the plain matrix codes:
-    # packed planes in, packed planes out, conversion only at the host
-    # boundary.  LRC layers are w=8 matrix codes, so w is always 8 here.
-
-    def planar_supported(self, chunk_size: int) -> bool:
-        from ceph_tpu.ec.planar import PlanarBatch
-
-        return PlanarBatch.supported(chunk_size, 8)
-
-    def to_planar(self, batch):
-        from ceph_tpu.ec.planar import PlanarBatch
-
-        return PlanarBatch.from_batch(batch, w=8)
-
-    def encode_planar(self, pb):
-        from ceph_tpu.ops import gf8
-
-        if self._enc_planar_bitmat is None:
-            self._enc_planar_bitmat = gf8.expand_bitmatrix(
-                self._flat_coding_matrix())
-        planes = gf8.planar_matmul(self._enc_planar_bitmat, pb.planes)
-        return pb.with_planes(planes, self.chunk_count -
-                              self.data_chunk_count)
-
-    def decode_planar(self, erasures, pb, want=None):
-        from ceph_tpu.ec.planar import _select_chunk_rows
-        from ceph_tpu.ops import gf8
-
-        if want is None:
-            want = tuple(erasures)
-        key = (tuple(erasures), tuple(want))
-        cached = self._dec_jit.get(key)
-        if cached is None:
-            cached = self._dec_jit[key] = self._build_flat_decode(key)
-        _, bitmat, src_ids = cached
-        src_planes = _select_chunk_rows(pb.planes, 8, src_ids)
-        return pb.with_planes(gf8.planar_matmul(bitmat, src_planes),
-                              len(want))
+        return np.stack([expr[p] for p in out_pos])
 
     @staticmethod
     def _layer_src(layer, local_erasures):

@@ -23,6 +23,8 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from ceph_tpu.ec.interface import ECError
+
 
 class StripeInfo:
     """stripe_info_t analog: all offset arithmetic for a (k, stripe_unit)
@@ -140,9 +142,9 @@ def _host_engine_ok(codec) -> bool:
 
     if jax.default_backend() != "cpu":
         return False
-    eng = getattr(codec, "engine", None)
-    return eng is not None and getattr(eng, "w", 0) == 8 and \
-        getattr(eng, "coding", None) is not None
+    from ceph_tpu.ec.codec import matrix_engine
+
+    return matrix_engine(codec) is not None
 
 
 def _gf_apply_host(mat: np.ndarray, batch: np.ndarray) -> np.ndarray:
@@ -419,14 +421,37 @@ def assemble_data_stripes(sinfo: StripeInfo, shards: Mapping[int, object],
     return _assemble_logical(rows, k, nstripes, unit, logical_size)
 
 
+def _decode_src(codec, want, erasures) -> Tuple[int, ...]:
+    """The chunks a decode of ``want`` multiplies, of the chunks that
+    came (all but ``erasures``): the codec's choice
+    (``decode_sources``), and for a code with no opinion, for which any
+    k will do, the first k.  ECError where the codec says those that
+    came cannot produce ``want``: refused here, before any decode, and
+    counted."""
+    avail = [s for s in range(codec.get_chunk_count())
+             if s not in erasures]
+    try:
+        chosen = codec.decode_sources(set(want), avail)
+    except ECError:
+        from ceph_tpu.utils.perf import KERNELS
+
+        KERNELS.inc("ec_decode_sources_refused")
+        raise
+    if chosen is None:
+        return tuple(avail[:codec.get_data_chunk_count()])
+    return tuple(sorted(chosen))
+
+
 def _host_decode_matrix(codec, src: Tuple[int, ...],
                         want: Tuple[int, ...]) -> Optional[np.ndarray]:
     """GF(2^8) recovery matrix for the host engine (chunk[want] =
-    R @ chunk[src]), or None when this codec/pattern cannot be solved
-    by plain survivor-submatrix inversion (non-MDS plans like SHEC fall
-    back to the codec's own decode machinery)."""
-    eng = getattr(codec, "engine", None)
-    if eng is None or not hasattr(eng, "decode_matrix"):
+    R @ chunk[src]), or None when this codec is no bytewise matrix code
+    or its engine cannot solve the pattern from ``src`` (non-MDS plans
+    like SHEC fall back to the codec's own decode machinery)."""
+    from ceph_tpu.ec.codec import matrix_engine
+
+    eng = matrix_engine(codec)
+    if eng is None:
         return None
     try:
         return np.asarray(eng.decode_matrix(tuple(src), tuple(want)),
@@ -503,7 +528,7 @@ def decode_stripes_multi(codec, sinfo: StripeInfo, reqs):
             ofs += ns
         recovered = None
         if host:
-            src = tuple(s for s in range(n) if s not in erasures)[:k]
+            src = _decode_src(codec, want, erasures)
             rmat = _host_decode_matrix(codec, src, want)
             if rmat is not None:
                 recovered = _gf_apply_host(rmat, full[:, list(src), :])
@@ -592,8 +617,7 @@ def reencode_stripes_multi(codec, sinfo: StripeInfo, reqs):
         if host:
             rmat = None
             if want:
-                src = tuple(s for s in range(n)
-                            if s not in erasures)[:k]
+                src = _decode_src(codec, want, erasures)
                 rmat = _host_decode_matrix(codec, src, want)
             if not want or rmat is not None:
                 if want:
@@ -655,27 +679,21 @@ def planar_at_rest_ok(codec, unit: int) -> bool:
     """Can this (codec, stripe_unit) pool store EC shards as packed
     bit-planes at rest?
 
-    Requires the bitpack layout contract: a MatrixCodec-family engine
-    (w == 8, byte coding matrix, survivor-submatrix decode) and a
-    stripe unit that is a multiple of the 8-byte packing quantum.
-    Packet-interleaved codecs (the BitmatrixCodec family — their planar
-    form is the packet-row matrix, a different serialization) and
-    exotic plans (LRC/SHEC locality groups, mesh adapters) keep
-    byte-at-rest; the config gate falls back per pool, not per cluster.
+    Requires the bitpack layout contract: a bytewise GF(2^8) matrix
+    engine (``codec.matrix_engine``: the Reed-Solomon families, SHEC,
+    and LRC, whose layers flatten to one generator and whose decode
+    composes the layer walk) and a stripe unit that is a multiple of
+    the 8-byte packing quantum.  Packet-interleaved codecs (the
+    BitmatrixCodec family — their planar form is the packet-row matrix,
+    a different serialization), wider fields, an LRC stack with such a
+    layer, and mesh adapters keep byte-at-rest; the gate falls back per
+    pool, not per cluster.
     """
-    eng = getattr(codec, "engine", None)
-    if eng is None or getattr(eng, "w", 0) != 8:
+    from ceph_tpu.ec.codec import matrix_engine
+
+    if matrix_engine(codec) is None or unit <= 0 or unit % 8:
         return False
-    if getattr(eng, "coding", None) is None:
-        return False
-    if not hasattr(eng, "decode_matrix"):
-        return False
-    if getattr(codec, "packetsize", None) is not None:
-        return False
-    if unit <= 0 or unit % 8:
-        return False
-    sup = getattr(codec, "planar_supported", None)
-    return bool(sup and sup(unit))
+    return _planar_ok(codec, unit)
 
 
 def _planes_rows_for(codec, src: Tuple[int, ...],
@@ -1025,9 +1043,9 @@ def decode_planes_multi(codec, sinfo: StripeInfo, reqs):
     KERNELS.inc("ec_coalesced_read_ticks")
     KERNELS.inc("ec_coalesced_reads", sum(len(g) for g in groups.values()))
     for (erasures, want), items in groups.items():
-        src = tuple(s for s in range(n) if s not in erasures)[:k]
+        src = _decode_src(codec, want, erasures)
         total_cols = sum(ns for _i, _a, ns, _ls in items) * unit // 8
-        src_planes = np.zeros((k * 8, total_cols), dtype=np.uint8)
+        src_planes = np.zeros((len(src) * 8, total_cols), dtype=np.uint8)
         c0 = 0
         for _i, arrs, ns, _ls in items:
             cw = ns * unit // 8
@@ -1097,7 +1115,6 @@ def reencode_planes_multi(codec, sinfo: StripeInfo, reqs):
     KERNELS.inc("ec_coalesced_reencodes",
                 sum(len(g) for g in groups.values()))
     for (erasures, want), items in groups.items():
-        src = tuple(s for s in range(n) if s not in erasures)[:k]
         total_cols = sum(ns for _i, _a, ns, _ls in items) * unit // 8
         full = np.zeros((n * 8, total_cols), dtype=np.uint8)
         c0 = 0
@@ -1106,8 +1123,8 @@ def reencode_planes_multi(codec, sinfo: StripeInfo, reqs):
             for s, a in arrs.items():
                 full[s * 8:s * 8 + 8, c0:c0 + cw] = a
             c0 += cw
-        rec = None
         if want:
+            src = _decode_src(codec, want, erasures)
             rec = _planes_rows_for(codec, src,
                                    want, _select_shard_planes(full, src))
             if rec is None:

@@ -35,7 +35,12 @@ class MeshECEngine:
     EC pools default to."""
 
     def __init__(self, mesh: Mesh, k: int, m: int,
-                 coding: np.ndarray):
+                 coding: np.ndarray, decode_rows=None):
+        """``decode_rows(src, want)``: the codec engine's own recovery
+        matrix, for a code whose decode is not the inversion of any k
+        survivor rows (the adapter passes it, and the sources)."""
+        if decode_rows is not None:
+            self._decode_rows = decode_rows
         self.mesh = mesh
         self.k, self.m = k, m
         self.n = k + m
@@ -117,14 +122,14 @@ class MeshECEngine:
         return np.stack(rows)
 
     def _build_decode(self, src: Tuple[int, ...], want: Tuple[int, ...]):
-        k = self.k
         bitmat = gf8.expand_bitmatrix(self._decode_rows(src, want))
         src_arr = np.asarray(src)
 
         def step(chunks):
             b, _, chunk = chunks.shape
             survivors = chunks[:, src_arr, :]
-            cols = survivors.transpose(1, 0, 2).reshape(k, b * chunk)
+            cols = survivors.transpose(1, 0, 2).reshape(len(src),
+                                                        b * chunk)
             out = gf8.bitmatrix_matmul(bitmat, cols)
             return out.reshape(len(want), b, chunk).transpose(1, 0, 2)
 
@@ -132,16 +137,19 @@ class MeshECEngine:
                        out_shardings=self._data_sh)
 
     def decode_batch(self, erasures: Tuple[int, ...], chunks,
-                     want: Tuple[int, ...] = None):
+                     want: Tuple[int, ...] = None,
+                     src: Tuple[int, ...] = None):
         """codec contract: chunks (B, k+m, S); rebuild ``want`` (default
-        = erasures) from k survivors.  The survivor gather crosses the
-        'shard' mesh axis — the ICI analog of the sub-read fan-out."""
+        = erasures) from the survivors ``src`` (default: the first k).
+        The survivor gather crosses the 'shard' mesh axis — the ICI
+        analog of the sub-read fan-out."""
         erasures = tuple(erasures)
         if want is None:
             want = erasures
         want = tuple(want)
-        avail = tuple(i for i in range(self.n) if i not in erasures)
-        src = avail[: self.k]
+        if src is None:
+            src = tuple(i for i in range(self.n)
+                        if i not in erasures)[: self.k]
         key = (src, want)
         if key not in self._dec_jit:
             self._dec_jit[key] = self._build_decode(src, want)
@@ -209,7 +217,8 @@ class MeshCodecAdapter:
         n = codec.get_chunk_count()
         self._k, self._n = k, n
         self._mesh_engine = MeshECEngine(
-            mesh, k, n - k, np.asarray(codec.engine.coding))
+            mesh, k, n - k, np.asarray(codec.engine.coding),
+            decode_rows=codec.engine.decode_matrix)
         self._data_axis = mesh.shape["data"]
 
     # the bit-planar entry points are single-device (the mesh engine
@@ -238,8 +247,13 @@ class MeshCodecAdapter:
         return self._mesh_engine.encode_batch(data)[:b]
 
     def decode_batch(self, erasures, chunks, want=None):
+        from ceph_tpu.ec.stripe import _decode_src
+
         chunks, b = self._pad(np.asarray(chunks))
-        return self._mesh_engine.decode_batch(erasures, chunks, want)[:b]
+        want = tuple(erasures if want is None else want)
+        src = _decode_src(self._codec, want, erasures)
+        return self._mesh_engine.decode_batch(erasures, chunks, want,
+                                              src=src)[:b]
 
 
 def mesh_for_codec(codec, n_devices: int = 0) -> Mesh:
@@ -261,9 +275,9 @@ def wrap_codec_for_mesh(codec, n_devices: int = 0):
     """Return a mesh-routed adapter for codecs with a GF(2^8) coding
     matrix, or the codec unchanged when it cannot ride the mesh engine
     (wide-w / bitmatrix families keep their single-device path)."""
-    eng = getattr(codec, "engine", None)
-    coding = getattr(eng, "coding", None)
-    if coding is None or getattr(eng, "w", 8) != 8:
+    from ceph_tpu.ec.codec import matrix_engine
+
+    if matrix_engine(codec) is None:
         return codec
     return MeshCodecAdapter(codec, mesh_for_codec(codec, n_devices))
 

@@ -298,9 +298,11 @@ OPTIONS: List[Option] = [
     # (sub-writes/sub-reads/recovery push) and verified as packed
     # bit-plane matrices — zero layout conversions on the steady-state
     # write/read/RMW/recovery/scrub paths (pinned by the
-    # ec_planar_unseamed counter).  Needs a w=8 matrix codec and
-    # stripe_unit % 8 == 0; a pool without them (LRC, SHEC) stays on
-    # byte-at-rest whatever this says (ec.stripe.planar_at_rest_ok).
+    # ec_planar_unseamed counter).  Needs a bytewise GF(2^8) matrix
+    # code (the Reed-Solomon families, SHEC, LRC over such layers) and
+    # stripe_unit % 8 == 0; a pool without them (packet-interleaved
+    # techniques, w=16/32) stays on byte-at-rest whatever this says
+    # (ec.stripe.planar_at_rest_ok).
     # 0 = byte-at-rest for every pool.
     Option("osd_ec_planar_at_rest", int, 1, min=0, max=1),
     # route EC pool batch encode/decode through the sharded mesh engine

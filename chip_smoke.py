@@ -20,7 +20,12 @@ fault (non-zero exit, no result line):
    upstream ``rados bench`` shape); read back healthy, with a shard
    holder down (decode on the device) and after recovery (re-encode on
    the device); then the ``KERNELS`` counters must show that the device
-   engine, and within it the Pallas kernel, did the work.
+   engine, and within it the Pallas kernel, did the work;
+4. the LRC k=4 m=2 l=3 pool (PR 37): a tick's flattened planar encode of
+   seeded 4 MiB objects against the literal layer walk, shard crcs
+   included, then phase 3's drill on 8 OSDs under the same counter
+   rules, with every shard landing as planes and the degraded read and
+   the recovery decoding in the plane domain.
 
 With ``--multichip`` it runs only the four-chip mesh engine against the
 single-device codec.  Wall times printed here are a smoke test's, not
@@ -43,6 +48,7 @@ MIB = 1 << 20
 DEFAULT_EC_PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
                       "k": "2", "m": "1"}
 ISA_K8M4 = {"plugin": "isa", "k": "8", "m": "4"}
+LRC_K4M2L3 = {"plugin": "lrc", "k": "4", "m": "2", "l": "3"}
 
 
 def say(**row) -> None:
@@ -116,6 +122,7 @@ def phase_kernels(seed: int, plane_mib: int = 16, crc_blocks: int = 4096,
     from ceph_tpu.crush.scalar import ScalarMapper
     from ceph_tpu.crush.types import build_three_level
     from ceph_tpu.ec import factory
+    from ceph_tpu.ec.stripe import _decode_src
     from ceph_tpu.ops import gf8_pallas
     from ceph_tpu.ops.crc32c import crc32c, crc32c_batch
 
@@ -131,6 +138,13 @@ def phase_kernels(seed: int, plane_mib: int = 16, crc_blocks: int = 4096,
     lost = (1, 6)
     src = tuple(s for s in range(12) if s not in lost)[:8]
     _planar_case("isa_k8m4_decode_e2", isa, src, lost, nbytes, rng)
+    lrc = factory(dict(LRC_K4M2L3))
+    _planar_case("lrc_k4m2l3_encode", lrc, None, None, nbytes, rng)
+    # the composed recovery over the sources a served decode multiplies
+    # (ec/stripe.py::_decode_src): one data shard lost, then two
+    for lost in ((0,), (0, 2)):
+        _planar_case(f"lrc_k4m2l3_decode_e{len(lost)}", lrc,
+                     _decode_src(lrc, lost, lost), lost, nbytes, rng)
 
     blocks = rng.integers(0, 256, (crc_blocks, 4096), dtype=np.uint8)
     got = np.asarray(crc32c_batch(blocks))
@@ -190,7 +204,8 @@ async def _wait_health_ok(client, deadline_s: float) -> None:
 async def serve_ec_objects(seed: int, n_objects: int = 64,
                            object_size: int = 4 * MIB, in_flight: int = 16,
                            n_osds: int = 3,
-                           recover_deadline_s: float = 300.0) -> dict:
+                           recover_deadline_s: float = 300.0,
+                           ec_profile=None, pg_num: int = 8) -> dict:
     """Phase 3: write, read, degraded read, recover, read — through the
     cluster's normal entry points.  Returns the phase's wall times and the
     growth of the ``KERNELS`` counters over it."""
@@ -204,8 +219,9 @@ async def serve_ec_objects(seed: int, n_objects: int = 64,
     cluster = await start_cluster(n_osds, config=_fast_config())
     try:
         client = await cluster.client()
-        pool = await client.pool_create("smoke_ec", "erasure", pg_num=8,
-                                        ec_profile=dict(DEFAULT_EC_PROFILE))
+        pool = await client.pool_create(
+            "smoke_ec", "erasure", pg_num=pg_num,
+            ec_profile=dict(ec_profile or DEFAULT_EC_PROFILE))
         io = client.ioctx(pool)
 
         async def timed(name, coro):
@@ -338,6 +354,76 @@ def phase_cluster(seed: int, **size) -> None:
     check_device_did_the_work(report)
 
 
+# --------------------------------------------------------------- phase 4
+
+def lrc_walk_shards(codec, sinfo, data: bytes) -> np.ndarray:
+    """(n, shard_len) shard rows of ``data`` by the literal layer walk
+    (``ErasureCodeLrc.encode_chunks``), the reference: the code is
+    bytewise, so the walk over whole shards (every stripe's chunk of a
+    shard, concatenated) is the walk stripe by stripe."""
+    k, n, unit = sinfo.k, codec.get_chunk_count(), sinfo.chunk_size
+    ns = sinfo.object_stripes(len(data))
+    batch = np.frombuffer(data.ljust(ns * sinfo.stripe_width, b"\0"),
+                          dtype=np.uint8).reshape(ns, k, unit)
+    pos = codec.chunk_mapping
+    chunks = {p: np.zeros(ns * unit, dtype=np.uint8) for p in range(n)}
+    for j in range(k):
+        chunks[pos[j]] = np.ascontiguousarray(batch[:, j]).reshape(-1)
+    codec.encode_chunks(chunks)
+    return np.stack([chunks[pos[s]] for s in range(n)])
+
+
+def lrc_tick_against_the_walk(seed: int, n_objects: int = 4,
+                              object_size: int = 4 * MIB) -> None:
+    """One tick of seeded objects through ``encode_planes_multi`` (the
+    flattened generator, the chunk-crc program) against the layer walk,
+    shard crcs included."""
+    from ceph_tpu.ec import factory, planar_store
+    from ceph_tpu.ec.stripe import StripeInfo, encode_planes_multi
+    from ceph_tpu.ops.crc32c import crc32c
+
+    codec = factory(dict(LRC_K4M2L3))
+    sinfo = StripeInfo(4, 4096)
+    rng = np.random.default_rng(seed)
+    datas = [rng.integers(0, 256, object_size, dtype=np.uint8).tobytes()
+             for _ in range(n_objects)]
+    out = encode_planes_multi(codec, sinfo, datas, [True] * n_objects)
+    for i, (data, (planes, crcs)) in enumerate(zip(datas, out)):
+        for s, row in enumerate(lrc_walk_shards(codec, sinfo, data)):
+            walk = row.tobytes()
+            if planar_store.planes_to_shard(planes[s], seam=None) != walk:
+                raise AssertionError(f"lrc object {i} shard {s}: the planar "
+                                     "tick differs from the layer walk")
+            if int(crcs[s]) != crc32c(0xFFFFFFFF, walk):
+                raise AssertionError(f"lrc object {i} shard {s}: device "
+                                     "crc differs from crc32c of the walk")
+    say(check="lrc_tick_vs_layer_walk", objects=n_objects,
+        object_bytes=object_size, shards=len(out[0][1]), ok=True)
+
+
+def phase_lrc(seed: int, **size) -> None:
+    lrc_tick_against_the_walk(seed)
+    size = {"n_objects": 16, "n_osds": 8, "pg_num": 16, **size}
+    report = asyncio.run(serve_ec_objects(
+        seed, ec_profile=LRC_K4M2L3, **size))
+    say(phase="lrc", **report)
+    check_device_did_the_work(report)
+    c = report["counters"]
+    # 8 MiB of planes to rest for every 4 MiB a tick ingested (recovery
+    # pushes come on top); a pool off the product plane ingests nothing
+    ingested = c.get("ec_planar_ingest_bytes", 0)
+    if not ingested or c.get("store_planar_write_bytes", 0) < 2 * ingested:
+        raise AssertionError("an LRC shard went to rest as bytes: the pool "
+                             "is off the product plane")
+    if c.get("ec_coalesced_read_ticks", 0) < 1 or \
+            c.get("ec_coalesced_reencode_ticks", 0) < 1:
+        raise AssertionError("no degraded read or no recovery decoded in "
+                             "the plane domain")
+    if c.get("ec_decode_sources_refused", 0):
+        raise AssertionError("a decode refused the chunks that came with "
+                             "one holder down")
+
+
 # ------------------------------------------------------------- multichip
 
 def _show(name: str, arr) -> None:
@@ -414,7 +500,8 @@ def main(argv=None) -> int:
     device = phase_device(compile_cache.enable())
 
     phases = [("multichip", phase_multichip)] if args.multichip else \
-        [("kernels", phase_kernels), ("cluster", phase_cluster)]
+        [("kernels", phase_kernels), ("cluster", phase_cluster),
+         ("lrc", phase_lrc)]
     for name, phase in phases:
         t0 = time.monotonic()
         phase(args.seed)

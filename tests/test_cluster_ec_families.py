@@ -36,8 +36,15 @@ def test_lrc_pool_end_to_end():
             await io.write_full("obj", payload, timeout=120)
             assert await io.read("obj", timeout=120) == payload
 
-            # kill a shard holder; degraded read must still work
+            # a pool of the product plane since PR 36: the flattened
+            # layers behind the one engine seam, shards planar at rest
             pgid = client.objecter.object_pgid(pool, "obj")
+            layouts = [osd.store.object_layout(_coll(pgid), "obj")
+                       for osd in cluster.osds.values()
+                       if "obj" in osd.store.list_objects(_coll(pgid))]
+            assert layouts == ["planar8"] * 8, layouts
+
+            # kill a shard holder; degraded read must still work
             _, _, acting, primary = \
                 client.objecter.osdmap.pg_to_up_acting_osds(pgid)
             victim = next(o for o in acting if o != primary and o >= 0)

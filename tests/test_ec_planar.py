@@ -291,7 +291,7 @@ def test_lrc_single_erasure_decode_reads_only_local_group():
     zeroed[:, 1] = 0
     got = np.asarray(codec.decode_batch((1,), zeroed))
     assert np.array_equal(got[:, 0], full[:, 1])
-    _, _, src_ids = codec._dec_jit[((1,), (1,))]
+    _, src_ids = codec._dec_jit[((1,), (1,))]
     assert len(src_ids) <= 3, (
         f"single local erasure should read the local group, got {src_ids}")
     # planar route agrees and shares the pruned plan

@@ -266,8 +266,15 @@ def test_outside_a_tick_the_phase_is_one_shared_noop():
     assert len(ticktrace.TICKS.ring) == n0
 
 
+@pytest.mark.parametrize("profile", [
+    pytest.param({"plugin": "jerasure", "technique": "reed_sol_van",
+                  "k": "2", "m": "1"}, id="k2m1"),
+    # a code that is not MDS passes through the tick as every tick does
+    pytest.param({"plugin": "lrc", "k": "4", "m": "2", "l": "3"},
+                 id="lrc_k4m2l3"),
+])
 @pytest.mark.parametrize("branch", ["device", "host"])
-def test_crc_device_ticks_counter_and_window(monkeypatch, branch):
+def test_crc_device_ticks_counter_and_window(monkeypatch, branch, profile):
     """One encode tick run by hand on each branch of
     ``encode_planes_multi``: ``ec_tick_crc_device_ticks`` grows by one
     where the device program made the crcs and by nothing on the host
@@ -278,16 +285,20 @@ def test_crc_device_ticks_counter_and_window(monkeypatch, branch):
 
     if branch == "device":
         monkeypatch.setattr(stripe, "_host_engine_ok", lambda codec: False)
-    codec = factory({"plugin": "jerasure", "technique": "reed_sol_van",
-                     "k": "2", "m": "1"})
+    codec = factory(dict(profile))
+    k, n = codec.get_data_chunk_count(), codec.get_chunk_count()
     counters = PerfCounters("synthetic")
     log = ticktrace.TickLog(keep=4, counters=counters)
     tick = log.open(ticktrace.ENCODE_TICK, "osd.0", (1, 2))
-    datas = [bytes(range(256)) * 64, b"y" * 8192]        # 2 + 1 stripes
-    out = tick.run(encode_planes_multi, codec, StripeInfo(2, 4096), datas,
+    # 2 + 1 stripes: two shard lengths
+    datas = [bytes(range(256)) * 32 * k, b"y" * 4096 * k]
+    out = tick.run(encode_planes_multi, codec, StripeInfo(k, 4096), datas,
                    [True, True])
     tick.close()
-    assert all(len(crcs) == 3 for _planes, crcs in out)
+    assert all(len(crcs) == n for _planes, crcs in out)
+    spans = [s for s in tick.dump() if s["name"] == "crc"]
+    assert [s["meta"]["path"] for s in spans] == \
+        ["device" if branch == "device" else "host"] * 2
     got = counters.dump()["synthetic"]
     crc = [(t0, t1, calls) for name, t0, t1, calls in tick.phases()
            if name == "crc"]
@@ -719,7 +730,8 @@ def test_the_nine_entries_agree_with_their_files():
         assert entry["workloads"] == ["k2m1_write_4m_t16",
                                       "k2m1_write_64k_t16",
                                       "k4m2_write_4m_t16",
-                                      "k8m4_write_4m_t16"]
+                                      "k8m4_write_4m_t16",
+                                      "lrc_k4m2l3_write_4m_t16"]
         path = os.path.join(ROOT, "benchmark", "layer_metrics",
                             name + ".json")
         with open(path, encoding="utf-8") as f:

@@ -61,11 +61,14 @@ def _shape(shape, dtype, sharding):
 
 
 # (rw, kw) of the bit-matrix: k8m4 encode, k2m1 encode, k8 two-erasure
-# decode (2 wanted chunks from 8 survivors)
+# decode (2 wanted chunks from 8 survivors), the flattened LRC k4m2l3
+# generator (4 coding rows) and its one-erasure decode (1 chunk from 4)
 _PALLAS_WIDTHS = [
     pytest.param(32, 64, id="k8m4_encode"),
     pytest.param(8, 16, id="k2m1_encode"),
     pytest.param(16, 64, id="k8_decode_e2"),
+    pytest.param(32, 32, id="lrc_k4m2l3_encode"),
+    pytest.param(8, 32, id="lrc_k4m2l3_decode_e1"),
 ]
 
 
@@ -109,7 +112,9 @@ def test_crc32c_batch_compiles_for_v5e(one_chip):
 # the encode tick's largest buckets: 8 x 4 MiB objects (48 MiB of planes)
 @pytest.mark.parametrize("k,m,bb", [pytest.param(2, 1, 4096, id="k2m1"),
                                     pytest.param(4, 2, 2048, id="k4m2"),
-                                    pytest.param(8, 4, 1024, id="k8m4")])
+                                    pytest.param(8, 4, 1024, id="k8m4"),
+                                    pytest.param(4, 4, 2048,
+                                                 id="lrc_k4m2l3")])
 def test_chunk_crcs_program_compiles_for_v5e(one_chip, k, m, bb):
     from ceph_tpu.ops.crc32c import _chunk_crcs_jit
 
