@@ -117,6 +117,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ceph_tpu.cluster.optracker import mark_current
+from ceph_tpu.trace import loopacct
 from ceph_tpu.utils.lockdep import DepLock
 from ceph_tpu.utils.perf import KERNELS
 
@@ -725,12 +726,17 @@ def _encode(msg: "Message") -> _Frame:
             return False
         return True
 
+    acct = loopacct.ACCOUNT
+    t0 = _time.perf_counter_ns() if acct is not None and acct.timing \
+        else 0
     payload = pickle.dumps(msg, protocol=5, buffer_callback=in_band)
     out = sum(len(b) for b in bufs)
     KERNELS.inc("msgr_frames")
     KERNELS.inc("msgr_frame_bytes", len(payload) + out)
     if out:
         KERNELS.inc("msgr_oob_bytes", out)
+    if t0:
+        acct.codec_done(t0)
     return payload, tuple(bufs)
 
 
@@ -905,14 +911,41 @@ class Messenger:
         self.dispatchers.append(d)
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> Addr:
-        self._server = await asyncio.get_running_loop().create_server(
-            lambda: _FrameStream(on_connect=self._accept), host, port)
+        loop = asyncio.get_running_loop()
+
+        def factory():
+            return _FrameStream(on_connect=self._accept)
+
+        # where the loop keeps an account (trace/loopacct.py) the
+        # listening socket is of the account's kind, and so are those it
+        # accepts: their sends and reads are timed.  Same options, same
+        # transport
+        acct = loopacct.of(loop)
+        if acct is None:
+            self._server = await loop.create_server(factory, host, port)
+        else:
+            sock = acct.listen(host, port)
+            try:
+                self._server = await loop.create_server(factory, sock=sock)
+            except BaseException:
+                sock.close()
+                raise
         self.my_addr = self._server.sockets[0].getsockname()[:2]
         return self.my_addr
 
     async def _open(self, addr: Addr) -> _FrameStream:
-        _, stream = await asyncio.get_running_loop().create_connection(
-            _FrameStream, addr[0], addr[1])
+        loop = asyncio.get_running_loop()
+        acct = loopacct.of(loop)
+        if acct is None:
+            _, stream = await loop.create_connection(
+                _FrameStream, addr[0], addr[1])
+            return stream
+        sock = await acct.connect(addr)
+        try:
+            _, stream = await loop.create_connection(_FrameStream, sock=sock)
+        except BaseException:
+            sock.close()
+            raise
         return stream
 
     async def _accept(self, stream: _FrameStream) -> None:
@@ -971,8 +1004,13 @@ class Messenger:
                             _sign(verify_key, signed), view[-SIG_LEN:]):
                         raise ConnectionError("bad message signature")
                     payload = payload[:-SIG_LEN]
+                acct = loopacct.ACCOUNT
+                t0 = _time.perf_counter_ns() if acct is not None \
+                    and acct.timing else 0
                 msg = _decode_oob(payload) if ftype == _FT_MSG_OOB \
                     else pickle.loads(payload)
+                if t0:
+                    acct.codec_done(t0)
                 if conn.peer is None:
                     conn.peer = msg.src
                 if msg.trace is not None:

@@ -11,11 +11,13 @@ from __future__ import annotations
 
 import pickle
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.cluster.optracker import mark_current
 from ceph_tpu.ec import planar_store
+from ceph_tpu.trace import loopacct
 from ceph_tpu.utils.perf import KERNELS
 
 
@@ -249,6 +251,11 @@ class MemStore(ObjectStore):
                         f"{grow} > {self.device_bytes}")
 
     def queue_transaction(self, txn: Transaction) -> None:
+        # the loop's account (trace/loopacct.py): every commit made on
+        # the loop thread, a replica's as much as the primary's
+        acct = loopacct.ACCOUNT
+        t0 = time.perf_counter_ns() if acct is not None and acct.timing \
+            and acct.thread == threading.get_ident() else 0
         if self.chaos is not None:
             # injected ENOSPC refuses the WHOLE txn before any byte
             # lands (atomicity preserved)
@@ -257,6 +264,8 @@ class MemStore(ObjectStore):
         self._commit(txn)
         if self.chaos is not None:
             self.chaos.maybe_rot(self, txn)
+        if t0:
+            acct.store_done(t0)
         # store-commit boundary on the current op's timeline (no-op
         # outside a tracked dispatch — recovery, replicas, scrub)
         mark_current("store:commit")

@@ -21,6 +21,7 @@ from ceph_tpu.cluster.objecter import RadosClient
 from ceph_tpu.cluster.osd import OSDDaemon
 from ceph_tpu.crush.types import build_hierarchy
 from ceph_tpu.osdmap.osdmap import OSDMap
+from ceph_tpu.trace import loopacct
 from ceph_tpu.utils import Config
 
 
@@ -512,6 +513,9 @@ async def start_cluster(n_osds: int = 3, osds_per_host: int = 1,
     runs a Paxos quorum with leader election."""
     import pickle as _pickle
 
+    # the loop every daemon and client of the cluster runs on gets its
+    # account (trace/loopacct.py) before the first socket is made
+    loopacct.install(asyncio.get_running_loop())
     config = config or _fast_config()
     if getattr(config, "race_check_enabled", 0):
         # arm the process-global write-after-read tracker (graft-race);

@@ -18,6 +18,8 @@ model (BENCH_NOTES) is untouched.
                   phases, on the device trace's clock (always on).
 - ``gapjoin``     device idle gaps cut by host cause: ticks laid over a
                   device trace's events.
+- ``loopacct``    the loop's account: busy and parked, on and off the
+                  CPU, in send, recv, store and pickle (always on).
 """
 
 from ceph_tpu.trace.span import (  # noqa: F401

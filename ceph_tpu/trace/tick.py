@@ -80,6 +80,9 @@ _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 
 _OTHER_COUNTERS = (
     ("ec_tick_wall_ns", "ns", "worker thread start -> return"),
+    ("ec_tick_cpu_ns", "ns", "the worker thread's CPU time "
+     "(time.thread_time_ns) between those two stamps: what is left of "
+     "the wall it waited (for the device, for the GIL)"),
     ("ec_tick_handoff_ns", "ns", "loop -> executor thread start, plus "
      "thread return -> drain loop resumes"),
     ("ec_tick_device_calls", "calls", "jitted program launches + explicit "
@@ -130,8 +133,8 @@ class Tick:
     four numbers per phase: [phase index, start, end, device calls]."""
 
     __slots__ = ("log", "name", "daemon", "seq", "op_ids", "stripes",
-                 "bucket", "payload_bytes", "thread", "calls", "t",
-                 "_phase", "_phase_t0", "_phase_calls")
+                 "bucket", "payload_bytes", "thread", "calls", "cpu_ns",
+                 "t", "_phase", "_phase_t0", "_phase_calls")
 
     def __init__(self, log: "TickLog", name: str, daemon: str, seq: int,
                  op_ids: Sequence):
@@ -145,6 +148,7 @@ class Tick:
         self.payload_bytes = 0
         self.thread = 0
         self.calls = 0
+        self.cpu_ns = 0         # the worker thread's CPU time inside run
         self.t = array("q", (log.clock(), 0, 0, 0))
         self._phase = 0
 
@@ -156,11 +160,13 @@ class Tick:
         encode = self.name == ENCODE_TICK
         self.thread = threading.get_ident()
         self.t[1] = log._thread_edge(+1) if encode else log.clock()
+        cpu0 = time.thread_time_ns()
         _CURRENT.tick = self
         try:
             return fn(*args)
         finally:
             _CURRENT.tick = None
+            self.cpu_ns = time.thread_time_ns() - cpu0
             self.t[2] = log._thread_edge(-1) if encode else log.clock()
 
     def phase(self, name: str) -> "Tick":
@@ -315,6 +321,7 @@ class TickLog:
         opened, start, end, closed = tick.t[:4]
         spent = tick.phase_ns()
         inc("ec_tick_wall_ns", end - start)
+        inc("ec_tick_cpu_ns", tick.cpu_ns)
         inc("ec_tick_handoff_ns", (start - opened) + (closed - end))
         for phase, (counter, _desc) in _PHASE_COUNTERS.items():
             inc(counter, spent.get(phase, 0))

@@ -156,6 +156,17 @@ class PerfCounters:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + amount
 
+    def inc_many(self, amounts: Dict[str, int]) -> None:
+        """``inc`` for several counters under one take of the lock: for a
+        recorder that gathers in its own fields and folds them in now and
+        then (trace/loopacct.py)."""
+        if self._muted and threading.get_ident() in self._muted:
+            return
+        counters = self._counters
+        with self._lock:
+            for name, amount in amounts.items():
+                counters[name] = counters.get(name, 0) + amount
+
     def set(self, name: str, value: int) -> None:
         with self._lock:
             self._counters[name] = value

@@ -1,0 +1,606 @@
+"""The loop's account (trace/loopacct.py): what the one event loop spends
+its time on, as ``KERNELS`` counters, and the nine metric files that read
+them through the benchmark's accepted ``counter_ratio`` reader.
+
+One burst of EC writes on a vstart cluster feeds most cases (with every
+call timed; a second, sampled as the product samples, is held against
+it): k=2 m=1 on
+three OSDs, sixteen 64 KiB objects and four of 8 MiB, so that frames
+larger than any socket buffer cross (the client's op is one 8 MiB frame,
+its sub-writes 4 MiB each) and the transport has to finish them from its
+deferred ``_write_ready``.  The burst times the loop's selector a second
+time, independently (a wrapper the test puts UNDER the account's), and
+counts the store's transactions itself.
+"""
+
+import asyncio
+import json
+import os
+import socket
+import threading
+import time
+import types
+
+import numpy as np
+import pytest
+
+from benchmark.harness import layers
+from benchmark.harness.cell import grew, kernel_counters
+from benchmark.harness.loader import load_cell
+from ceph_tpu.cluster import messages as M
+from ceph_tpu.cluster import messenger
+from ceph_tpu.cluster.store import MemStore, Transaction
+from ceph_tpu.cluster.vstart import _fast_config, start_cluster
+from ceph_tpu.trace import loopacct
+from ceph_tpu.trace import tick as ticktrace
+from ceph_tpu.utils.config import OPTIONS
+from ceph_tpu.utils.perf import PerfCounters
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
+         "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16")
+K, M_ = 2, 1
+PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
+           "k": str(K), "m": str(M_)}
+SMALL, LARGE = (16, 64 << 10), (4, 8 << 20)
+N_OPS = SMALL[0] + LARGE[0]
+
+ACCOUNT_COUNTERS = tuple(name for name, _unit, _desc in loopacct._COUNTERS)
+TICK_COUNTERS = ("ec_tick_cpu_ns",)
+# metric file -> (layer, unit, numerator, denominator, scale)
+METRICS = {
+    "loop_busy_share.write": ("event loop", "%", "loop_busy_ns",
+                              "loop_wall_ns", 100),
+    "loop_ms_per_op.write": ("event loop", "ms", "loop_busy_ns",
+                             "ec_coalesced_ops", 1e-6),
+    "loop_offcpu_share.write": ("event loop", "%", "loop_busy_offcpu_ns",
+                                "loop_busy_ns", 100),
+    "loop_send_ms_per_op.write": ("wire", "ms", "loop_sock_send_ns",
+                                  "ec_coalesced_ops", 1e-6),
+    "loop_recv_ms_per_op.write": ("wire", "ms", "loop_sock_recv_ns",
+                                  "ec_coalesced_ops", 1e-6),
+    "loop_sock_ms_per_mib.write": ("wire", "ms/MiB", "loop_sock_ns",
+                                   "loop_sock_send_bytes", 1.048576),
+    "loop_store_ms_per_op.write": ("fan-out and store", "ms",
+                                   "loop_store_ns", "ec_coalesced_ops", 1e-6),
+    "loop_codec_ms_per_op.write": ("wire", "ms", "loop_codec_ns",
+                                   "ec_coalesced_ops", 1e-6),
+    "tick_cpu_share.write": ("EC data plane", "%", "ec_tick_cpu_ns",
+                             "ec_tick_wall_ns", 100),
+}
+# on a program without the account a ratio over the account's own
+# denominator reads nothing; one over the coalescer's ops or the tick's
+# wall, which grow all the same, reads 0.0 (as frames_per_op.write does)
+READ_NOTHING = ("loop_busy_share.write", "loop_offcpu_share.write",
+                "loop_sock_ms_per_mib.write")
+# what a window of the PARENT's program grows: every counter the accepted
+# metrics read, none of this PR's
+PARENT_GROWTH = {"ec_coalesced_ops": 1400, "ec_coalesced_ticks": 1100,
+                 "ec_tick_wall_ns": 24_000_000_000, "msgr_frames": 8000,
+                 "msgr_frame_bytes": 12_000_000_000,
+                 "store_planar_write_bytes": 9_000_000_000}
+
+
+def bounded(coro, seconds):
+    async def _run():
+        return await asyncio.wait_for(coro, seconds)
+    return asyncio.run(_run())
+
+
+def _readings(counters):
+    cell = load_cell(CELLS[0])
+    return cell, layers.Readings(
+        config=cell.config, device_kind="TPU v5 lite", attribution={},
+        counters=counters, slice_counters={}, trace=None)
+
+
+async def _ec_pool(cluster):
+    client = await cluster.client()
+    pool = await client.pool_create("acct", "erasure", pg_num=8,
+                                    ec_profile=dict(PROFILE))
+    return client.ioctx(pool)
+
+
+def _burst(every, rounds=1, sizes=(SMALL, LARGE)):
+    """Growth of every ``KERNELS`` counter over ``rounds`` bursts of
+    ``sizes`` on one cluster whose account times one turn in ``every``,
+    with the test's own readings of the same things beside it."""
+    parked = [0]
+    txns = {"all": 0, "data": 0}
+    commit = MemStore._commit
+
+    def counting_commit(self, txn):
+        txns["all"] += 1
+        txns["data"] += any(op[0] in ("write", "write_planar")
+                            for op in txn.ops)
+        return commit(self, txn)
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        select = loop._selector.select
+
+        def timed_select(timeout=None):
+            t0 = time.perf_counter_ns()
+            try:
+                return select(timeout)
+            finally:
+                parked[0] += time.perf_counter_ns() - t0
+
+        # an instance attribute UNDER the account's proxy, which keeps
+        # the selector's ``select`` as it finds it at install
+        loop._selector.select = timed_select
+        cluster = await start_cluster(3, config=_fast_config())
+        try:
+            acct = loopacct.of(loop)
+            assert acct is not None and acct is loopacct.ACCOUNT
+            assert loopacct.install(loop) is acct       # once a loop
+            io = await _ec_pool(cluster)
+            rng = np.random.default_rng(40)
+            payloads = {}
+            for label, (count, size) in zip("sl", sizes):
+                for i in range(count):
+                    payloads[f"{label}_{i}"] = rng.integers(
+                        0, 256, size, dtype=np.uint8).tobytes()
+            await io.write_full("warm", payloads["s_0"], timeout=120)
+            # a fold takes what gathered up to the last select: let a
+            # turn pass, fold, and open the window on that edge
+            await asyncio.sleep(0)
+            acct.fold()
+            before, parked0, t0 = kernel_counters(), parked[0], acct._edge
+            MemStore._commit = counting_commit
+            try:
+                for _ in range(rounds):
+                    await asyncio.gather(*(io.write_full(n, p, timeout=120)
+                                           for n, p in payloads.items()))
+                await asyncio.sleep(0)
+                acct.fold()
+                grown = grew(kernel_counters(), before)
+                counted = dict(txns)
+                socks = [conn.stream.transport.get_extra_info("socket")
+                         for daemon in (*cluster.mons, *cluster.osds.values())
+                         for lane in (daemon.messenger._out.values(),
+                                      daemon.messenger._hb_out.values(),
+                                      daemon.messenger._accepted)
+                         for conn in lane if not conn.closed]
+                nodelay = [(sock.proto, sock.getsockopt(
+                    socket.IPPROTO_TCP, socket.TCP_NODELAY)) for sock in socks]
+            finally:
+                MemStore._commit = commit
+            wall_ns, parked_ns = acct._edge - t0, parked[0] - parked0
+            got = await asyncio.gather(*(io.read(n, timeout=120)
+                                         for n in payloads))
+            assert dict(zip(payloads, got)) == payloads
+            return {"grew": grown, "wall_ns": wall_ns,
+                    "parked_ns": parked_ns, "txns": counted,
+                    "nodelay": nodelay}
+        finally:
+            await cluster.stop()
+
+    was, loopacct._EVERY = loopacct._EVERY, every
+    try:
+        return bounded(scenario(), 300)
+    finally:
+        loopacct._EVERY = was
+
+
+ROUNDS = 100
+
+
+@pytest.fixture(scope="module")
+def burst():
+    """Every turn timed, not one in sixteen: the burst is small and the
+    cases below compare sums."""
+    return _burst(1)
+
+
+@pytest.fixture(scope="module")
+def bursts_full_and_sampled():
+    """``ROUNDS`` bursts of the small objects with every turn timed, and
+    the same again timed as the product times them."""
+    return (_burst(1, ROUNDS, (SMALL,)),
+            _burst(loopacct._EVERY, ROUNDS, (SMALL,)))
+
+
+# ------------------------------------------------------------ the counters
+
+@pytest.mark.parametrize("name", ACCOUNT_COUNTERS + TICK_COUNTERS)
+def test_every_counter_grows_over_a_burst_of_ec_writes(burst, name):
+    assert burst["grew"].get(name, 0) > 0, name
+
+
+def test_the_per_op_metrics_divide_by_the_coalescers_ops(burst):
+    assert burst["grew"]["ec_coalesced_ops"] == N_OPS
+
+
+def test_busy_and_the_selectors_time_make_the_wall(burst):
+    """The account's busy time plus the time the test's own wrapper saw
+    inside ``select`` is the account's wall within 1%, and that wall is
+    the wall the test's clock saw."""
+    g = burst["grew"]
+    assert g["loop_wall_ns"] == pytest.approx(burst["wall_ns"], rel=0.01)
+    assert g["loop_busy_ns"] + burst["parked_ns"] == \
+        pytest.approx(g["loop_wall_ns"], rel=0.01)
+    assert 0 < g["loop_busy_ns"] <= g["loop_wall_ns"]
+
+
+def test_cpu_time_stays_inside_busy_time(burst):
+    g = burst["grew"]
+    assert 0 < g["loop_busy_cpu_ns"] <= g["loop_busy_ns"]
+    assert g["loop_busy_offcpu_ns"] == \
+        g["loop_busy_ns"] - g["loop_busy_cpu_ns"]
+
+
+def test_the_four_stamps_stay_inside_busy_time(burst):
+    g = burst["grew"]
+    assert g["loop_sock_ns"] == g["loop_sock_send_ns"] + g["loop_sock_recv_ns"]
+    assert g["loop_sock_ns"] + g["loop_store_ns"] + g["loop_codec_ns"] \
+        <= g["loop_busy_ns"]
+
+
+def test_sent_bytes_are_the_bytes_framed_deferred_writes_included(burst):
+    """Every byte the messengers framed (``msgr_frame_bytes``, pickle and
+    out-of-band buffers) and its header (5 bytes in band, up to 19 out of
+    band) left through a timed send, and arrived through a timed read in
+    the same process: within 2%, though the burst's 8 MiB and 4 MiB
+    frames exceed every socket buffer (``_SOCK_BUF`` 2 MiB, doubled at
+    most), so that most of each went out from the transport's deferred
+    ``_write_ready``, in more sends than there were frames."""
+    g = burst["grew"]
+    assert g["msgr_frame_bytes"] > LARGE[0] * LARGE[1] * (K + M_) // K
+    low = g["msgr_frame_bytes"] + 5 * g["msgr_frames"]
+    assert low <= g["loop_sock_send_bytes"] <= 1.02 * low
+    assert g["loop_sock_recv_bytes"] == g["loop_sock_send_bytes"]
+    assert g["loop_sock_send_calls"] > g["msgr_frames"]
+
+
+def test_a_timed_socket_is_a_tcp_socket_as_asyncios_own_are(burst):
+    """The transport sets TCP_NODELAY only on a socket whose ``proto``
+    says TCP, as ``getaddrinfo`` makes asyncio's own say: a timed socket
+    made with proto 0 ran every connection of the cluster under Nagle's
+    algorithm (PR 40's first chip call: -3.5% at 64 KiB).  Every live
+    connection of every daemon, opened or accepted, both lanes."""
+    assert len(burst["nodelay"]) >= 3 * 2 * 2
+    assert all(proto == socket.IPPROTO_TCP and nodelay
+               for proto, nodelay in burst["nodelay"]), burst["nodelay"]
+
+
+def test_the_store_stamp_covers_every_commit_of_an_op(burst):
+    """``loop_store_calls`` is every transaction the stores committed
+    (the test's own count), of which k+m an op carry its shards: the
+    replicas' commits, which ``store_commit_ms.write`` cannot see, are
+    inside ``loop_store_ns``."""
+    g, txns = burst["grew"], burst["txns"]
+    assert g["loop_store_calls"] == txns["all"]
+    assert txns["data"] == (K + M_) * N_OPS
+    assert g["loop_store_calls"] / g["ec_coalesced_ops"] >= K + M_
+
+
+def test_a_ticks_cpu_time_is_read_between_its_walls_stamps(burst):
+    g = burst["grew"]
+    assert 0 < g["ec_tick_cpu_ns"] <= g["ec_tick_wall_ns"]
+
+
+def test_a_tick_off_the_counters_reads_its_threads_cpu_time():
+    """A tick's CPU time is its worker thread's: a sleep adds wall and
+    none of it, a spin adds both."""
+    log = ticktrace.TickLog(counters=PerfCounters("t"))
+    for work, busy in ((lambda: threading.Event().wait(0.05), False),
+                       (lambda: sum(range(400_000)), True)):
+        tick = log.open(ticktrace.ENCODE_TICK, "osd.0")
+        worker = threading.Thread(target=tick.run, args=(work,))
+        worker.start()
+        worker.join(10)
+        assert not worker.is_alive()
+        tick.close()
+        wall = tick.t[2] - tick.t[1]
+        assert 0 <= tick.cpu_ns <= wall
+        assert (tick.cpu_ns > wall // 2) == busy
+    got = log.counters.dump()["t"]
+    assert 0 < got["ec_tick_cpu_ns"] < got["ec_tick_wall_ns"]
+
+
+# ----------------------------------------------------- the account's parts
+
+def test_inc_many_adds_under_one_lock_and_honours_muted():
+    pc = PerfCounters("t")
+    pc.inc("a", 2)
+    pc.inc_many({"a": 3, "b": 4, "c": -1})
+    with pc.muted():
+        pc.inc_many({"a": 100})
+    assert pc.dump()["t"] == {"a": 5, "b": 4, "c": -1}
+
+
+def _turn(acct):
+    """What ``_TimedSelector.select`` does with the account when a turn
+    begins."""
+    acct.turns += 1
+    acct._turn_left -= 1
+    if not acct._turn_left or acct.timing:
+        acct.turn()
+
+
+def test_one_turn_in_sixteen_is_timed_and_booked_sixteen_times():
+    """In a timed turn every call of every kind is timed and counted;
+    in a bare turn the socket is ``socket.socket`` but in name, no clock
+    is read and nothing is counted; a fold books the timed turns'
+    sixteen times."""
+    reads = []
+    real = loopacct._clock
+
+    def clock():
+        reads.append(None)
+        return real()
+
+    assert loopacct._EVERY == 16
+    acct = loopacct.LoopAccount(None, counters=PerfCounters("t"))
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(loopacct, "ACCOUNT", acct)
+    a, b = socket.socketpair()
+    sock = acct.adopt(a.family, a.type, a.proto, a.detach())
+    store = MemStore()
+    store.queue_transaction(Transaction().create_collection("c"))
+    turns, timed = 1600, 0
+    loopacct._clock = clock
+    try:
+        for i in range(turns):
+            _turn(acct)
+            timed += acct.timing
+            assert type(sock) is (loopacct.TimedSocket if acct.timing
+                                  else loopacct.BareSocket)
+            for name in ("sendmsg", "send", "recv_into"):
+                assert (getattr(type(sock), name)
+                        is getattr(socket.socket, name)) != acct.timing
+            before = len(reads)
+            send = sock.sendmsg if i % 2 else sock.send
+            assert send([b"xy"] if i % 2 else b"xy") == 2
+            assert b.recv(8) == b"xy"
+            b.send(b"z")
+            assert sock.recv_into(bytearray(4)) == 1
+            messenger._encode(M.MPing(stamp=1.0))
+            store.queue_transaction(Transaction().touch("c", f"o{i}"))
+            # twice a send and a read, once a frame and a transaction
+            # (they read ``time``'s own on their way in)
+            assert len(reads) - before == (6 if acct.timing else 0)
+        acct.fold()
+    finally:
+        loopacct._clock = real
+        monkey.undo()
+        sock.close()
+        b.close()
+    assert timed == pytest.approx(turns / 16, rel=0.15)
+    got = acct.counters.dump()["t"]
+    assert got["loop_turns"] == turns
+    assert (got["loop_sock_send_calls"], got["loop_sock_send_bytes"]) == \
+        (16 * timed, 16 * 2 * timed)
+    assert (got["loop_sock_recv_calls"], got["loop_sock_recv_bytes"]) == \
+        (16 * timed, 16 * timed)
+    assert got["loop_store_calls"] == 16 * timed
+    assert got["loop_sock_ns"] == \
+        got["loop_sock_send_ns"] + got["loop_sock_recv_ns"]
+    for name in ("loop_sock_send_ns", "loop_sock_recv_ns", "loop_store_ns",
+                 "loop_codec_ns"):
+        assert got[name] > 0 and got[name] % 16 == 0, name
+
+
+@pytest.mark.parametrize("period", (2, 3, 16, 31, 32, 48))
+def test_a_periodic_load_does_not_catch_the_stride(period):
+    """What is periodic in the traffic is periodic in the loop's turns
+    (a heartbeat round, a closed loop of 16 callers in step): one turn a
+    period costs a thousand times the others.  A fixed stride of 16
+    books it every time or never at periods 16, 32 and 48, and a table
+    of strides has a period of its own (its sum); strides drawn at
+    random read the true sum within 15%."""
+    acct = loopacct.LoopAccount(None, counters=PerfCounters("t"))
+    true = 0
+    for i in range(96_000):
+        _turn(acct)
+        cost = 1_000_000 if i % period == 0 else 1_000
+        true += cost
+        if acct.timing:
+            acct.store_ns += cost
+    acct.fold()
+    got = acct.counters.dump()["t"]["loop_store_ns"]
+    assert got == pytest.approx(true, rel=0.15)
+
+
+@pytest.mark.parametrize("name, factor", (
+    ("loop_sock_send_ns", 4), ("loop_sock_recv_ns", 4),
+    ("loop_store_ns", 2), ("loop_codec_ns", 2),
+    ("loop_store_calls", 2), ("loop_sock_send_bytes", 2)))
+def test_the_sampled_sums_are_the_full_ones(bursts_full_and_sampled, name,
+                                            factor):
+    """The same bursts on two clusters, every turn timed on the first
+    and one in sixteen on the second: the same ops, and each sampled sum
+    the full one within ``factor``: a fault in the booking is a factor
+    of 16.  No tighter, because on the dev host (PR 40, seventeen
+    repeats, ~250 timed turns of ~4000, the two runs' own busy time 0.7
+    to 1.1 of each other) transactions and bytes read 0.87 to 1.54 of
+    the full run's, the store's and the codec's time 0.70 to 1.37, and
+    a socket call's 0.28 to 2.33: its cost is heavy-tailed (a few wait
+    milliseconds for the GIL, most take microseconds).  That a period
+    of the traffic cannot meet the stride is the case above's."""
+    full, sampled = (b["grew"] for b in bursts_full_and_sampled)
+    assert sampled["ec_coalesced_ops"] == full["ec_coalesced_ops"] \
+        == ROUNDS * SMALL[0]
+    assert full[name] / factor <= sampled[name] <= full[name] * factor
+
+
+def test_a_timed_socket_books_its_calls_and_accepts_its_own_kind():
+    """Off any loop: a listening socket of the account's kind accepts a
+    socket on the same account, of the kind of the turn that runs; in a
+    timed turn bytes sent and received are booked, and so is a read that
+    would block (it raises as on any socket)."""
+    acct = loopacct.LoopAccount(None, counters=PerfCounters("t"))
+    server = acct.listen("127.0.0.1", 0)
+    server.listen(2)
+    assert type(server) is loopacct.BareSocket
+    clients = [socket.create_connection(server.getsockname())
+               for _ in range(2)]
+    client = clients[0]
+    try:
+        server.setblocking(True)
+        early, _addr = server.accept()
+        assert type(early) is loopacct.BareSocket and early.acct is acct
+        acct.set_timing(True)
+        assert type(server) is type(early) is loopacct.TimedSocket
+        early.close()
+        conn, _addr = server.accept()
+        try:
+            assert type(conn) is loopacct.TimedSocket and conn.acct is acct
+            client = clients[1]
+            assert conn.sendmsg([b"abc", b"defg"]) == 7
+            assert conn.send(b"hi") == 2
+            assert client.recv(16) == b"abcdefghi"
+            client.sendall(b"12345")
+            buf = bytearray(16)
+            assert conn.recv_into(buf) == 5 and buf[:5] == b"12345"
+            conn.setblocking(False)
+            with pytest.raises(BlockingIOError):
+                conn.recv_into(buf)
+            acct.set_timing(False)
+            assert type(conn) is loopacct.BareSocket
+            assert conn.send(b"bare") == 4          # not the account's
+        finally:
+            conn.close()
+    finally:
+        for c in clients:
+            c.close()
+        server.close()
+    assert (acct.send_bytes, acct.send_calls) == (9, 2)
+    assert (acct.recv_bytes, acct.recv_calls) == (5, 2)
+    assert acct.send_ns > 0 and acct.recv_ns > 0
+    acct.fold()
+    got = acct.counters.dump()["t"]
+    assert got["loop_sock_send_bytes"] == 9 * loopacct._EVERY
+    assert got["loop_sock_ns"] == \
+        got["loop_sock_send_ns"] + got["loop_sock_recv_ns"] > 0
+    assert acct.send_bytes == acct.recv_calls == 0      # folded
+
+
+def test_the_codec_and_store_stamps_book_to_the_process_account(monkeypatch):
+    acct = loopacct.LoopAccount(None, counters=PerfCounters("t"))
+    monkeypatch.setattr(loopacct, "ACCOUNT", acct)
+    messenger._encode(M.MPing(stamp=1.0))
+    MemStore().queue_transaction(Transaction().create_collection("c"))
+    assert acct.codec_ns == acct.store_ns == acct.store_calls == 0  # bare
+    acct.timing = True
+    payload, bufs = messenger._encode(M.MPing(stamp=1.0))
+    assert payload and not bufs
+    encoded = acct.codec_ns
+    assert encoded > 0
+    store = MemStore()
+    store.queue_transaction(Transaction().create_collection("c"))
+    assert acct.store_calls == 1 and acct.store_ns > 0
+    # a commit on another thread is not the loop's
+    other = threading.Thread(target=store.queue_transaction,
+                             args=(Transaction().touch("c", "o"),))
+    other.start()
+    other.join(10)
+    assert not other.is_alive()
+    assert acct.store_calls == 1 and acct.codec_ns == encoded
+
+
+# -------------------------------------------- a loop that cannot be timed
+
+def test_a_loop_without_a_selector_gets_no_account(monkeypatch):
+    monkeypatch.setattr(loopacct, "ACCOUNT", None)
+    for loop in (types.SimpleNamespace(),
+                 types.SimpleNamespace(_selector=object(), _ready=[]),
+                 types.SimpleNamespace(_selector=None, _ready=[])):
+        assert loopacct.install(loop) is None
+        assert loopacct.of(loop) is None
+    assert loopacct.ACCOUNT is None
+
+
+def test_a_cluster_on_an_untimed_loop_serves_and_counts_nothing(monkeypatch):
+    """Where the loop has no selector to time the messengers take
+    asyncio's own sockets, the cluster serves as before, none of the
+    account's counters grows, and the metrics read as they do on the
+    parent's program: nothing where the denominator is the account's,
+    0.0 where it is the coalescer's ops."""
+    monkeypatch.setattr(loopacct, "ACCOUNT", None)
+    monkeypatch.setattr(loopacct, "install", lambda loop: None)
+
+    async def scenario():
+        before = kernel_counters()
+        cluster = await start_cluster(3, config=_fast_config())
+        try:
+            assert type(asyncio.get_running_loop()._selector) \
+                is not loopacct._TimedSelector
+            io = await _ec_pool(cluster)
+            data = bytes(range(256)) * 512
+            await io.write_full("o", data, timeout=120)
+            assert await io.read("o", timeout=120) == data
+        finally:
+            await cluster.stop()
+        return grew(kernel_counters(), before)
+
+    grown = bounded(scenario(), 120)
+    assert grown["ec_coalesced_ops"] == 1 and grown["msgr_frames"] > 0
+    assert not set(grown) & set(ACCOUNT_COUNTERS)
+    cell, readings = _readings(grown)
+    for name in METRICS:
+        if name.startswith("loop_"):
+            assert layers.read_metric(name, cell.per_layer[name], readings) \
+                == (None if name in READ_NOTHING else 0.0), name
+
+
+# --------------------------------------------------------- the metric files
+
+@pytest.mark.parametrize("name", list(METRICS))
+def test_a_metric_file_reads_the_hand_worked_value(name):
+    """Through the loader and the accepted ``counter_ratio`` reader: the
+    file names the counters the table of ISSUE 40 gives it, 3 of the
+    numerator over 4 of the denominator read 0.75 x scale, and a window
+    of the parent's program reads nothing where it has neither counter
+    and 0.0 where it has the denominator (``READ_NOTHING``)."""
+    layer, unit, numerator, denominator, scale = METRICS[name]
+    for cell_name in CELLS:
+        reader = load_cell(cell_name).per_layer[name]
+        assert (reader["kind"], reader["layer"], reader["unit"],
+                reader["numerator"], reader["denominator"],
+                reader["scale"], reader["moves"], reader["source"]) == \
+            ("counter_ratio", layer, unit, numerator, denominator, scale,
+             "write_MBps", "program_counter")
+    cell, readings = _readings({numerator: 3_000_000,
+                                denominator: 4_000_000})
+    assert layers.read_metric(name, cell.per_layer[name], readings) == \
+        pytest.approx(0.75 * scale)
+    _cell, parent = _readings(dict(PARENT_GROWTH))
+    assert layers.read_metric(name, cell.per_layer[name], parent) == \
+        (None if name in READ_NOTHING else 0.0)
+
+
+def test_the_nine_entries_come_last_and_every_cell_reports_them():
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        spec = json.load(f)
+    added = spec["per_layer"][-len(METRICS):]
+    assert [m["name"] for m in added] == list(METRICS)
+    for metric in added:
+        layer, unit = METRICS[metric["name"]][:2]
+        assert metric == {"name": metric["name"], "unit": unit,
+                          "better": "lower", "source": "program_counter",
+                          "layer": layer, "moves": "write_MBps",
+                          "workloads": list(CELLS)}
+    assert len(spec["per_layer"]) == 28 + len(METRICS)
+
+
+def test_the_burst_reads_sane_through_the_metric_files(burst):
+    """The acceptance rule of a traced run, on the CPU burst: all nine
+    read something, the busy share is at most 100, and send + recv +
+    store + codec stay inside ``loop_ms_per_op.write``."""
+    cell, readings = _readings(burst["grew"])
+    got = {name: layers.read_metric(name, cell.per_layer[name], readings)
+           for name in METRICS}
+    assert all(v is not None and v > 0 for v in got.values()), got
+    assert got["loop_busy_share.write"] <= 100
+    assert got["tick_cpu_share.write"] <= 100
+    assert got["loop_offcpu_share.write"] < 100
+    assert got["loop_send_ms_per_op.write"] \
+        + got["loop_recv_ms_per_op.write"] \
+        + got["loop_store_ms_per_op.write"] \
+        + got["loop_codec_ms_per_op.write"] <= got["loop_ms_per_op.write"]
+
+
+def test_the_account_brings_no_option():
+    assert len(OPTIONS) == 100
