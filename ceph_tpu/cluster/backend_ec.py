@@ -17,6 +17,7 @@ from ceph_tpu.cluster.store import Transaction
 from ceph_tpu.ec import planar_store
 from ceph_tpu.ops import crc32c as crcmod
 from ceph_tpu.osdmap.osdmap import PGid, PGPool
+from ceph_tpu.trace import loopacct
 
 
 class ECUndersized(Exception):
@@ -796,6 +797,7 @@ class ECBackendMixin:
         await self._reply_osd(conn, msg, M.MOSDECSubOpWriteBatchReply(
             results=results))
 
+    @loopacct.root("osd_op")
     async def _serve_ec_read(self, conn: Connection,
                              msg: M.MOSDECSubOpRead) -> None:
         """``_handle_ec_read`` as a task of its own (see ``_dispatch``).
@@ -946,6 +948,7 @@ class ECBackendMixin:
         if spare and not fut.done():
             delay = self._hedge_delay()
 
+            @loopacct.root("osd_op")
             async def _hedge():
                 await asyncio.sleep(delay)
                 if fut.done() or self._stopped:
@@ -1203,6 +1206,7 @@ class ECBackendMixin:
                           and st.acting[s] != CRUSH_ITEM_NONE})
         reasons = dict(bad)
 
+        @loopacct.root("osd_op")
         async def _repair() -> None:
             try:
                 # the object write lock excludes concurrent writes to

@@ -28,7 +28,7 @@ import asyncio
 from typing import Dict, List, Tuple
 
 from ceph_tpu.cluster.optracker import CURRENT_OP
-from ceph_tpu.trace import tick as ticktrace
+from ceph_tpu.trace import loopacct, tick as ticktrace
 
 
 class _Req:
@@ -69,6 +69,7 @@ class SubWriteBatcher:
         self._pending: Dict[int, List] = {}      # target osd -> [(sub, fut)]
         self._workers: Dict[int, asyncio.Task] = {}
 
+    @loopacct.root("osd_op")     # a task of the fan-out's gather
     async def send(self, target: int, sub) -> None:
         """Queue one sub-write for ``target``; returns when the frame
         carrying it was handed to the session (raises like _send_osd on
@@ -84,6 +85,7 @@ class SubWriteBatcher:
         # (exception), never a cross-daemon wait
         await fut  # graftlint: ignore[rpc-timeout]
 
+    @loopacct.root("tick")
     async def _drain(self, target: int) -> None:
         from ceph_tpu.cluster import messages as M
 
@@ -168,6 +170,7 @@ class OpBatcher:
         # (exception), never a cross-daemon wait
         await fut  # graftlint: ignore[rpc-timeout]
 
+    @loopacct.root("client")
     async def _drain(self, addr: Tuple) -> None:
         import time as _time
 
@@ -260,6 +263,7 @@ class ClientReplyBatcher:
             self._workers[key] = task
             self._osd._track(task)
 
+    @loopacct.root("tick")
     async def _drain(self, key: int) -> None:
         from ceph_tpu.cluster import messages as M
 
@@ -461,6 +465,7 @@ class ReadBatcher:
                 out[ri][j] = (crc is None) or (int(g) == int(crc))
         return out
 
+    @loopacct.root("tick")
     async def _drain(self, key, codec, sinfo) -> None:
         from ceph_tpu.ec import stripe as stripemod
 
@@ -581,6 +586,7 @@ class EncodeBatcher:
         # add a spurious failure mode under first-call XLA compiles
         return await fut  # graftlint: ignore[rpc-timeout]
 
+    @loopacct.root("tick")
     async def _drain(self, key, codec, sinfo) -> None:
         """Tick loop for one codec profile; exits when idle (the next
         request re-arms it).  The empty-check/exit runs with no await in

@@ -16,6 +16,7 @@ from ceph_tpu.cluster import messages as M
 from ceph_tpu.cluster.messenger import Connection
 from ceph_tpu.cluster.pg import PGMETA, PGState, _coll
 from ceph_tpu.cluster.store import Transaction
+from ceph_tpu.trace import loopacct
 
 
 class _BatchConn:
@@ -343,6 +344,7 @@ class ClientOpsMixin:
                 except (ConnectionError, OSError, RuntimeError):
                     pass
 
+    @loopacct.root("osd_op")
     async def _serve_admitted(self, conn, msg) -> None:
         """Serve one admitted op, returning its admission budget (and
         the messenger byte-throttle claim) however it exits — incl. the

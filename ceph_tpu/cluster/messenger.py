@@ -184,6 +184,11 @@ _CLOSE_WAIT_S = 1.0
 _ACK_DELAY_S = 1.0
 _ACK_BYTES = 16 << 20
 
+# whose a read loop's time is in the loop's account (trace/loopacct.py),
+# by its messenger's entity type: an OSD's dispatch, a client's, or
+# (a mon's, a mgr's, an mds's) "other"
+_READER_BUCKET = {"osd": "msgr", "client": "client"}
+
 
 def _tune_socket(stream: "_FrameStream") -> None:
     import socket as _socket
@@ -962,6 +967,7 @@ class Messenger:
         task = asyncio.current_task()
         if task is not None:
             self._track(task)
+            loopacct.tag(task, _READER_BUCKET.get(self.name.type, "other"))
         await self._read_loop(conn)
 
     async def _read_loop(self, conn: Connection) -> None:
@@ -1011,6 +1017,7 @@ class Messenger:
                     else pickle.loads(payload)
                 if t0:
                     acct.codec_done(t0)
+                    acct.cut(type(msg).__name__)
                 if conn.peer is None:
                     conn.peer = msg.src
                 if msg.trace is not None:
@@ -1218,6 +1225,7 @@ class Messenger:
         fut = asyncio.get_event_loop().create_future()
         self._auth_waiters[id(conn)] = fut
         task = asyncio.get_event_loop().create_task(self._read_loop(conn))
+        loopacct.tag(task, _READER_BUCKET.get(self.name.type, "other"))
         self._track(task)
         try:
             await conn.send(_MsgAuthRequest(entity=self.auth.entity,
@@ -1260,6 +1268,7 @@ class Messenger:
             conn.session_key = self.auth.session_key
         lane[tuple(addr)] = conn
         task = asyncio.get_event_loop().create_task(self._read_loop(conn))
+        loopacct.tag(task, _READER_BUCKET.get(self.name.type, "other"))
         self._track(task)
         return conn
 

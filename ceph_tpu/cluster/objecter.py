@@ -29,11 +29,15 @@ from ceph_tpu.osdmap.osdmap import (
     ceph_stable_mod,
     placement_snapshot,
 )
+from ceph_tpu.trace import loopacct
 from ceph_tpu.utils import Config
 from ceph_tpu.utils.backoff import AIMDWindow, ExpBackoff
 from ceph_tpu.utils.tasks import track_task
 
 
+# the loop's account: a caller may make any coroutine of the client
+# library the root of a task of its own (here, IoCtx, RadosClient)
+@loopacct.root("client")
 class Objecter(Dispatcher):
     def __init__(self, name: str, mon_addr,
                  config: Optional[Config] = None):
@@ -670,6 +674,7 @@ class Objecter(Dispatcher):
         raise TimeoutError(f"mon command never succeeded: {last_err}")
 
 
+@loopacct.root("client")
 class IoCtx:
     """Pool I/O context (librados IoCtx analog).
 
@@ -1005,6 +1010,7 @@ class IoCtx:
         return reply.data
 
 
+@loopacct.root("client")
 class RadosClient:
     """librados rados_t analog: connect, pools, ioctx."""
 

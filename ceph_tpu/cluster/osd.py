@@ -1040,6 +1040,17 @@ class OSDDaemon(PGLogMixin, ClientOpsMixin, ReplicatedBackendMixin,
                       "this daemon's newest coalesced device ticks, each "
                       "a root span tiled by its host phases (args: n)")
 
+        def _dump_loop_account(cmd):
+            from ceph_tpu.trace import loopacct
+
+            acct = loopacct.ACCOUNT
+            return acct.dump() if acct is not None else {}
+
+        asok.register("dump_loop_account", _dump_loop_account,
+                      "who ran on the process's one event loop, in its "
+                      "timed turns: own time by bucket and the table by "
+                      "(bucket, root or callback, message class)")
+
         def _trace_dump(cmd):
             a = {**cmd, **cmd.get("args", {})}
             tid = a.get("trace_id")

@@ -35,6 +35,8 @@ import time
 from collections import deque
 from typing import Deque, Dict, Optional, Set, Tuple
 
+from ceph_tpu.trace import loopacct
+
 
 class _Shard:
     __slots__ = ("idx", "fifo", "opq", "event", "groups", "active")
@@ -169,6 +171,7 @@ class ShardedOpWQ:
         except asyncio.TimeoutError:
             pass
 
+    @loopacct.root("osd_op")
     async def _drain(self, sh: _Shard) -> None:
         """One shard's dispatch loop: each iteration is a TICK — pop up
         to the bounded batch, hand every op to execution, yield.  Ops of
@@ -241,6 +244,7 @@ class ShardedOpWQ:
         self.osd._opq_running.add(t)
         t.add_done_callback(self.osd._opq_running.discard)
 
+    @loopacct.root("osd_op")
     async def _drain_group(self, sh: _Shard, key, q) -> None:
         try:
             while q:

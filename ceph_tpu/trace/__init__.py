@@ -19,7 +19,8 @@ model (BENCH_NOTES) is untouched.
 - ``gapjoin``     device idle gaps cut by host cause: ticks laid over a
                   device trace's events.
 - ``loopacct``    the loop's account: busy and parked, on and off the
-                  CPU, in send, recv, store and pickle (always on).
+                  CPU, in send, recv, store and pickle, and who ran
+                  what no stamp covers (always on).
 """
 
 from ceph_tpu.trace.span import (  # noqa: F401

@@ -18,8 +18,31 @@ says what the loop DOES with its time:
                             op's k+m commits, replicas' too
       codec                 ``messenger._encode`` and the unpickle of
                             ``_read_loop``
-    what no stamp covers (dispatch, PG and log work, asyncio's own turn)
-    is busy minus those four, by subtraction.
+    and what no stamp covers, by WHO RAN: asyncio runs a turn's
+    handles one after another out of ``loop._ready``, so the moments it
+    takes them are a complete, exclusive partition of the turn.  A
+    handle's OWN time is the wall from its ``popleft`` to the next (the
+    last one ends where ``select`` is entered) less what the four
+    stamps booked inside it, and goes to one bucket:
+      transport             not a task's step: asyncio's selector
+                            transport (``_read_ready``, ``_write_ready``)
+                            and the ``_FrameStream`` calls they make
+      msgr                  a step of an OSD messenger's ``_read_loop``:
+                            verify, ``_owe``, throttle and all that
+                            ``ms_dispatch`` runs inline
+      osd_op                a step of a task rooted in ``sharded_wq.py``
+                            or spawned by the OSD for an op
+      tick                  a step of one of ``batcher.py``'s drains
+      client                a step of a task rooted outside the daemons
+                            (the benchmark's callers), in the client's
+                            modules, or of a client messenger's read loop
+      other                 anything else: future plumbing, heartbeats,
+                            mon and mgr tasks, timers
+      turn                  the timed busy wall outside any handle:
+                            asyncio's ``_run_once`` itself and the
+                            account's own work at the turn's edge
+    so that in the timed turns, exactly,
+    sum of the seven + send + recv + store + codec = busy.
 
 How.  ``install(loop)`` (``vstart.start_cluster`` calls it) puts a
 ``_TimedSelector`` in place of the loop's own selector: a proxy whose
@@ -28,29 +51,53 @@ The messenger gives the loop sockets of the account's own kind
 (``LoopAccount.listen`` / ``connect``: a ``socket.socket`` that is a
 ``TimedSocket``, its three data calls timed, in a timed turn and a
 ``BareSocket`` in the others, and whose ``accept`` returns its own
-kind), so nothing of asyncio is touched and both lanes are covered.
+kind), so both lanes are covered.  ``loop._ready`` becomes a ``deque``
+of the account's (``BareReady``: no method of its own, so a bare turn
+calls ``collections.deque.popleft`` itself; ``TimedReady`` in a
+timed turn, whose ``popleft`` closes the running span and opens the
+next).
+Who a task is: ``tag(task, bucket)`` where it is made (the messenger's
+read loops, by their owner's entity type), else what the module that
+owns its root coroutine said of it at import (``@root(bucket)`` on the
+coroutine or on its class: by code object), kept on the task (an
+``asyncio.Task`` takes attributes); a plain callback by its function.
+Beside the buckets the account keeps, for timed turns, the table
+``rows``: (bucket, root or callback, message class) -> handles, wall,
+own; ``cut`` in ``_read_loop`` closes the running span and opens one
+under the frame's message class, so a step that takes three frames
+books three rows (admin command ``dump_loop_account``).
 The other stamps ask ``ACCOUNT``, the account of the process's loop, as
 the tick's phases ask ``tick._CURRENT``, whether this turn is timed.
 
 Which loops.  A vstart cluster on asyncio's selector loop, which is what
 Linux and macOS give ``asyncio.run``: every product run and the whole
-benchmark.  ``install`` leans on three private names, checked on
-CPython 3.12, the installation's:
-``BaseSelectorEventLoop._selector`` and ``_ready``, and
-``socket.socket._accept``.  A loop that lacks the first two (a proactor,
-uvloop), and a messenger bound with no cluster around it (unit tests),
-get no account: the messenger then takes asyncio's own sockets, nothing
-is counted, and every metric whose denominator is the account's reads
-nothing.
+benchmark.  ``install`` leans on private names, checked on CPython
+3.12.12, the installation's: ``BaseSelectorEventLoop._selector``;
+``_ready`` as a ``collections.deque`` that ``_run_once`` looks up anew
+for every ``popleft`` and that may be replaced; ``Handle._callback``,
+whose ``__self__`` is the ``asyncio.Task`` for a task's step and wakeup
+alike; and ``socket.socket._accept``.  A loop that lacks the first two
+(a proactor, uvloop), and a messenger bound with no cluster around it
+(unit tests), get no account: the messenger then takes asyncio's own
+sockets, nothing is counted, and every metric whose denominator is the
+account's reads nothing.  A loop whose ``_ready`` is not a plain
+``deque`` gets the account without handle spans: the seven read 0.0.
 
 What it costs.  One turn of the loop in ``_EVERY`` is timed, at random
 strides, and what it gathers is booked ``_EVERY`` times; a bare turn
 pays two clock reads at the selector and an attribute test a frame and
-a transaction (``_EVERY``: why).  What has gathered goes into ``KERNELS`` under ONE
-take of its lock (``inc_many``) when a ``select`` returns and
-``_FOLD_NS`` have passed since the last time, so a reader of ``KERNELS``
-sees the account as of at most that long ago (and a turn).  The thread's CPU clock is
-a real syscall (6.2 us on the chip host): it is read at a fold, and
+a transaction (``_EVERY``: why); a timed turn pays besides, per handle,
+two Python frames, a clock read, a few attribute lookups and a dozen
+adds (PERF.md §6, PR 41: what that costs on the chip host).  That work
+lies INSIDE the spans it measures: a timed turn is longer than a bare
+one by it, so the seven are biased high, by at most ``floor_ns``
+(``table``) a handle; on the chip host ``loop_timed_busy_ns`` read
+0.97-1.12 of ``loop_busy_ns`` a window, 1.07-1.12 on eleven windows
+of sixteen (PERF.md §5).  What has gathered goes
+into ``KERNELS`` under ONE take of its lock (``inc_many``) when a
+``select`` returns and ``_FOLD_NS`` have passed since the last time, so
+a reader of ``KERNELS`` sees the account as of at most that long ago
+(and a turn).  The thread's CPU clock is a real syscall (6.2 us on the chip host): it is read at a fold, and
 around a ``select`` that may sleep; a saturated loop only polls
 (``select(0)``: all 15576 turns of a 64 KiB window, PR 40's chip call 2)
 and pays one read a fold.  Always on, as the tick record is; no option.
@@ -60,7 +107,11 @@ The counters (``declare_counters``) and who reads them: PERF.md §3.
 
 from __future__ import annotations
 
+import asyncio
+import collections
 import functools
+import inspect
+import os
 import random
 import socket
 import threading
@@ -132,7 +183,135 @@ _COUNTERS = (
     ("loop_turns", "turns", "turns of the loop (select calls)"),
     ("loop_callbacks", "handles", "handles ready when a turn's select was "
      "entered + I/O events it returned (timer handles not counted)"),
+    *((f"loop_own_{bucket}_ns", "ns", f"own time of the handles that "
+       f"{who}: their wall from one popleft of loop._ready to the next, "
+       f"less what the send, recv, store and codec stamps booked inside "
+       f"it (the timed turns' x 16; the account's own work a handle "
+       f"lies inside: reads high by up to the table's floor_ns a handle)")
+      for bucket, who in (
+        ("transport", "are no task's step and run asyncio's selector "
+         "transport or the messenger's _FrameStream"),
+        ("msgr", "step an OSD messenger's read loop (inline dispatch "
+         "included)"),
+        ("osd_op", "step a task the OSD made for an op (the sharded queue's "
+         "drains, the serving of an admitted op, the fan-out's sends)"),
+        ("tick", "step one of batcher.py's drains"),
+        ("client", "step a task rooted outside the daemons, in the "
+         "client's modules or at a client messenger's read loop"),
+        ("other", "are none of those (future plumbing, heartbeats, mon "
+         "and mgr tasks, timers)"))),
+    ("loop_own_turn_ns", "ns", "timed busy wall outside any handle: "
+     "asyncio's _run_once (events, timer heap) and the account's own work "
+     "at the turn's edge (the timed turns' x 16)"),
+    ("loop_timed_busy_ns", "ns", "busy wall of the timed turns of a loop "
+     "whose handles are spanned, x 16: exactly the seven loop_own_*_ns + "
+     "loop_sock_send_ns + loop_sock_recv_ns + loop_store_ns + "
+     "loop_codec_ns; biased high against loop_busy_ns by what the "
+     "account itself runs in a timed turn (0.97-1.12 of it a window on "
+     "the chip host, mostly 1.07-1.12)"),
+    ("loop_handles", "handles", "handles taken off loop._ready in the "
+     "timed turns, timers' included (x 16)"),
 )
+
+# who ran: the six buckets a handle's own time goes to and, last, the
+# turn's, which takes what lies between handles
+BUCKETS = ("transport", "msgr", "osd_op", "tick", "client", "other", "turn")
+
+# a task nobody tagged, by its root coroutine's code object: what the
+# module that owns the coroutine said of it at import (``root``)
+_ROOT_BUCKETS: Dict[object, str] = {}
+_PKG = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) + os.sep
+_ASYNCIO = os.path.dirname(os.path.abspath(asyncio.__file__)) + os.sep
+
+# a callback of these classes is the transport's
+_TRANSPORT = ("_SelectorTransport.", "_SelectorSocketTransport.",
+              "_FrameStream.")
+
+
+def root(bucket: str):
+    """Decorator: a task whose root is this coroutine function is
+    ``bucket``'s; on a class, every coroutine function it defines that
+    has not said otherwise itself.  Said once, at import, by the module
+    that owns the name; nothing runs for it in any turn."""
+    assert bucket in BUCKETS, bucket
+
+    def register(what):
+        if isinstance(what, type):
+            for fn in vars(what).values():
+                if inspect.iscoroutinefunction(fn):
+                    _ROOT_BUCKETS.setdefault(fn.__code__, bucket)
+        else:
+            _ROOT_BUCKETS[what.__code__] = bucket
+        return what
+    return register
+
+
+def tag(task: "asyncio.Task", bucket: str) -> None:
+    """``task``'s handles are ``bucket``'s, whatever its root says (from
+    its next one on, where it has run already): for a task whose root
+    cannot say whose it is (``Messenger._read_loop`` is an OSD's, a
+    client's, a mon's), where it is made."""
+    task.loopacct_bucket = bucket
+    task.loopacct_row = None
+
+
+def bucket_of_root(code) -> str:
+    """The bucket of an untagged task whose root coroutine has ``code``:
+    what its module registered; else "other" for this package's code
+    and asyncio's own, and "client" for a root outside both: a caller
+    of the cluster (benchmark, scripts, tests)."""
+    bucket = _ROOT_BUCKETS.get(code)
+    if bucket is None:
+        path = code.co_filename
+        bucket = "other" if path.startswith((_PKG, _ASYNCIO)) else "client"
+    return bucket
+
+
+class _Row:
+    """One row of the account's table: the handles of one (bucket, root
+    coroutine or callback, message class), in timed turns, unscaled.
+    ``task`` says which of the two ``name`` is."""
+
+    __slots__ = ("acct", "bucket", "name", "msg", "task", "handles",
+                 "wall_ns", "own_ns")
+
+    def __init__(self, acct: "LoopAccount", bucket: str, name: str,
+                 msg: str, task: bool):
+        self.acct = acct
+        self.bucket = bucket
+        self.name = name
+        self.msg = msg
+        self.task = task
+        self.handles = self.wall_ns = self.own_ns = 0
+
+
+def table(every: int, rows) -> dict:
+    """``rows`` (dicts as ``LoopAccount.dump`` makes them) heaviest
+    first, with their own time summed by bucket.  A row's own time holds
+    the ``popleft`` that opened it: ``floor_ns``, the smallest mean own
+    time of a row of at least 100 handles, is the most the observer can
+    have added to any handle."""
+    rows = sorted((r for r in rows if r["handles"]),
+                  key=lambda r: -r["own_ns"])
+    own = dict.fromkeys(BUCKETS, 0)
+    for r in rows:
+        own[r["bucket"]] += r["own_ns"]
+    means = [r["own_ns"] // r["handles"] for r in rows
+             if r["handles"] >= 100 and r["bucket"] != "turn"]
+    return {"every": every, "own_ns": own,
+            "timed_busy_ns": sum(r["wall_ns"] for r in rows),
+            "floor_ns": min(means) if means else None, "rows": rows}
+
+
+def window(after: dict, before: dict) -> dict:
+    """What the table grew by between two dumps, as a dump."""
+    was = {(r["bucket"], r["name"], r["msg"]): r for r in before["rows"]}
+    rows = []
+    for r in after["rows"]:
+        b = was.get((r["bucket"], r["name"], r["msg"]))
+        rows.append({**r, **{f: r[f] - b[f] for f in (
+            "handles", "wall_ns", "own_ns")}} if b else r)
+    return table(after["every"], rows)
 
 
 def declare_counters(counters: PerfCounters) -> None:
@@ -206,6 +385,51 @@ class TimedSocket(BareSocket):
             a.recv_calls += 1
 
 
+class BareReady(collections.deque):
+    """``loop._ready`` of an accounted loop between timed turns: no
+    method of its own, so ``_run_once`` calls ``collections.deque``'s C
+    ``popleft``.  ``LoopAccount.set_timing`` makes it a ``TimedReady``
+    and back (``__class__``: same layout), with the sockets."""
+
+    __slots__ = ("acct",)
+
+
+class TimedReady(BareReady):
+    """The same deque in a timed turn: taking a handle off it closes
+    the span of the handle before and opens this one's."""
+
+    __slots__ = ()
+
+    def popleft(self):
+        handle = _popleft(self)
+        a = self.acct
+        callback = handle._callback
+        if callback.__class__ is functools.partial:
+            callback = callback.func
+        owner = getattr(callback, "__self__", None)
+        if isinstance(owner, asyncio.Task):
+            # a task keeps the row its steps begin under (and its tag)
+            # as attributes of its own: they go when it goes, and a
+            # lookup is no Python frame
+            row = getattr(owner, "loopacct_row", None)
+            if row is None or row.acct is not a:
+                row = a._task_row(owner)
+        else:
+            fn = getattr(callback, "__func__", callback)
+            key = getattr(fn, "__code__", None) or \
+                getattr(fn, "__qualname__", None) or type(fn).__qualname__
+            row = a._callback_rows.get(key)
+            if row is None:
+                row = a._callback_row(key, fn)
+        a.handles += 1
+        row.handles += 1
+        a._mark(row)
+        return handle
+
+
+_popleft = collections.deque.popleft
+
+
 class _TimedSelector:
     """The loop's selector with ``select`` timed.  Everything else
     (``register``, ``modify``, ``get_key``, ``close`` ...) is the
@@ -224,7 +448,12 @@ class _TimedSelector:
 
     def select(self, timeout=None):
         a = self._acct
-        t0 = _clock()
+        if a._spanning:
+            # the turn's last handle ends here; what follows is parked
+            a._mark(a._turn)
+            t0 = a._at
+        else:
+            t0 = _clock()
         a.busy_ns += t0 - a._edge
         a.callbacks += len(self._ready)
         if timeout == 0:
@@ -261,8 +490,10 @@ class LoopAccount:
                  "parked_ns", "parked_cpu_ns", "send_ns", "send_bytes",
                  "send_calls", "recv_ns", "recv_bytes", "recv_calls",
                  "store_ns", "store_calls", "codec_ns", "turns",
-                 "callbacks", "_socks", "_stride", "_every", "_turn_left",
-                 "_edge", "_cpu_at", "_fold_at")
+                 "callbacks", "timed_busy_ns", "handles", "own", "rows",
+                 "_callback_rows", "_ready", "_spanning",
+                 "_turn", "_row", "_at", "_inside", "_socks", "_stride",
+                 "_every", "_turn_left", "_edge", "_cpu_at", "_fold_at")
 
     def __init__(self, loop, counters: PerfCounters = KERNELS):
         self.loop = loop
@@ -270,6 +501,21 @@ class LoopAccount:
         self.counters = counters
         self.timing = False
         self._zero()
+        # the table behind the buckets, kept for the account's life:
+        # (bucket, root or callback, message class) -> its row, and the
+        # rows that callbacks already seen begin under (a task keeps
+        # its own)
+        self.rows: Dict[Tuple[str, str, str], _Row] = {}
+        self._callback_rows: Dict[object, _Row] = {}
+        # the loop's deque of ready handles, once ``install`` made it the
+        # account's, and whether this turn's handles are spanned (a
+        # timed turn of a loop that has such a deque); the row of the
+        # span that runs (between handles the turn's own), where it
+        # began and what the four stamps read there
+        self._ready: Optional[BareReady] = None
+        self._spanning = False
+        self._turn = self._row = self._row_of("turn", "_run_once", False)
+        self._at = 0
         self._socks: "weakref.WeakSet[BareSocket]" = weakref.WeakSet()
         self._every = _EVERY
         self._stride = functools.partial(
@@ -288,6 +534,16 @@ class LoopAccount:
             self._turn_left = self._stride()
             if not self.timing:
                 self.set_timing(True)
+            if self._spanning:
+                # the turn's wall begins here, not where its select
+                # returned: the switches of the sockets' and the deque's
+                # classes are paid twice in ``_EVERY`` turns and would
+                # be booked for every one
+                self._turn.handles += 1
+                self._row = self._turn
+                self._at = _clock()
+                self._inside = self.send_ns + self.recv_ns \
+                    + self.store_ns + self.codec_ns
         elif self.timing:
             self.set_timing(False)
 
@@ -296,6 +552,81 @@ class LoopAccount:
         kind = TimedSocket if on else BareSocket
         for sock in self._socks:
             sock.__class__ = kind
+        if self._ready is not None:
+            self._spanning = on
+            self._ready.__class__ = TimedReady if on else BareReady
+
+    # -- who ran: the spans of a timed turn's handles -----------------------
+
+    def _mark(self, row: _Row) -> None:
+        """The span that runs ends here, and one of ``row``'s begins
+        (``_turn``: what follows is no handle's).  Its wall goes to the
+        timed busy time, and its own time (the wall less what the four
+        stamps booked since it began) to its row and bucket."""
+        now = _clock()
+        inside = self.send_ns + self.recv_ns + self.store_ns + self.codec_ns
+        wall = now - self._at
+        own = wall - (inside - self._inside)
+        self.timed_busy_ns += wall
+        was = self._row
+        was.wall_ns += wall
+        was.own_ns += own
+        self.own[was.bucket] += own
+        self._row = row
+        self._at = now
+        self._inside = inside
+
+    def cut(self, msg: str) -> None:
+        """The handle that runs took a frame that carries a ``msg``:
+        what it does from here on is booked under that message class,
+        one row a frame."""
+        was = self._row
+        if was is not self._turn:
+            row = self._row_of(was.bucket, was.name, was.task, msg)
+            row.handles += 1
+            self._mark(row)
+
+    def _row_of(self, bucket: str, name: str, task: bool,
+                msg: str = "") -> _Row:
+        key = (bucket, name, msg)
+        row = self.rows.get(key)
+        if row is None:
+            row = self.rows[key] = _Row(self, bucket, name, msg, task)
+        return row
+
+    def _task_row(self, task: "asyncio.Task") -> _Row:
+        """The row a task's steps begin under, from now on found on the
+        task: its tag's bucket, or its root's."""
+        coro = task.get_coro()
+        code = getattr(coro, "cr_code", None) or getattr(coro, "gi_code",
+                                                         None)
+        bucket = getattr(task, "loopacct_bucket", None) or (
+            bucket_of_root(code) if code is not None else "other")
+        name = code.co_qualname if code is not None \
+            else type(coro).__qualname__
+        row = task.loopacct_row = self._row_of(bucket, name, True)
+        return row
+
+    def _callback_row(self, key, fn) -> _Row:
+        """The row of a handle that steps no task: asyncio's selector
+        transports' and the messenger's ``_FrameStream``'s are the
+        transport, whatever else is "other" (the loop's own
+        ``_read_from_self``, by which another thread wakes it, too)."""
+        name = getattr(fn, "__qualname__", None) or type(fn).__qualname__
+        bucket = "transport" if name.startswith(_TRANSPORT) else "other"
+        row = self._callback_rows[key] = self._row_of(bucket, name, False)
+        return row
+
+    def dump(self) -> dict:
+        """The table, for the operator: what the handles of the timed
+        turns cost since the account was made, unscaled (one turn in
+        ``every`` was timed), with the sums by bucket beside it
+        (``table``).  What it grew by between two moments:
+        ``window(after, before)``."""
+        return table(self._every, [
+            {"bucket": r.bucket, "name": r.name, "msg": r.msg,
+             "task": r.task, "handles": r.handles, "wall_ns": r.wall_ns,
+             "own_ns": r.own_ns} for r in self.rows.values()])
 
     def adopt(self, family, type_, proto, fileno=None) -> BareSocket:
         """A socket of the account's kind (of the kind of the turn that
@@ -321,12 +652,27 @@ class LoopAccount:
         self.recv_ns = self.recv_bytes = self.recv_calls = 0
         self.store_ns = self.store_calls = self.codec_ns = 0
         self.turns = self.callbacks = 0
+        self.timed_busy_ns = self.handles = 0
+        # own ns by bucket, and the four stamps' sum where the span that
+        # runs began (they are zero again)
+        self.own: Dict[str, int] = dict.fromkeys(BUCKETS, 0)
+        self._inside = 0
 
     def fold(self) -> None:
-        """What has gathered up to the last edge of ``select`` goes into
-        the counters, under one take of their lock.  On the loop thread,
-        whose CPU clock it reads."""
+        """What has gathered goes into the counters, under one take of
+        their lock.  On the loop thread, whose CPU clock it reads."""
         cpu = _cpu()
+        # the busy time and the span that runs are booked up to here
+        # and go on, so that what a fold takes adds up, and the CPU
+        # time lies inside the busy time, wherever it is called (the
+        # account's own fold is at a select's edge; a test's is not)
+        if self._spanning:
+            self._mark(self._row)
+            now = self._at
+        else:
+            now = _clock()
+        self.busy_ns += now - self._edge
+        self._edge = now
         busy_cpu = cpu - self._cpu_at - self.parked_cpu_ns
         every = self._every
         grown: Dict[str, int] = {
@@ -346,7 +692,11 @@ class LoopAccount:
             "loop_codec_ns": self.codec_ns * every,
             "loop_turns": self.turns,
             "loop_callbacks": self.callbacks,
+            "loop_timed_busy_ns": self.timed_busy_ns * every,
+            "loop_handles": self.handles * every,
         }
+        for bucket, own in self.own.items():
+            grown[f"loop_own_{bucket}_ns"] = own * every
         self._cpu_at = cpu
         self._zero()
         self._fold_at = self._edge + _FOLD_NS
@@ -406,6 +756,16 @@ def install(loop) -> Optional[LoopAccount]:
     if ready is None or not callable(getattr(selector, "select", None)):
         return None
     ACCOUNT = acct = LoopAccount(loop)
+    if type(ready) is collections.deque:
+        # the loop's own handles move over; ``_run_once`` looks
+        # ``_ready`` up for every handle it takes, and so does
+        # ``call_soon_threadsafe`` (no thread calls it this early)
+        acct._ready = BareReady()
+        acct._ready.acct = acct
+        loop._ready = acct._ready
+        while ready:
+            acct._ready.append(ready.popleft())
+        ready = acct._ready
     loop._selector = _TimedSelector(selector, acct, ready)
     return acct
 
