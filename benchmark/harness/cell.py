@@ -29,6 +29,11 @@ DRAIN_S = 60.0          # callers may finish their last op after the window
 AFTER_WINDOW_S = 200.0  # drain + verify + stop take ~10 s; stuck beyond this
 HOST_ENGINE_COUNTERS = ("ec_host_matmul_calls",
                         "ec_host_planar_matmul_calls")
+# a run has to meet its stores' logical limit (nearfull, the cluster's own
+# ratio) before the host's physical one: under this much MemAvailable at
+# the window's end the run is refused by name, before the kernel ends it
+HOST_MEM_MIN_GIB = 4.0
+ERRORS_KEPT = 5         # of CellRun.errors, in the result's line
 # end-to-end metric prefix -> op kind
 E2E_KINDS = {"write": "write_full", "read": "read"}
 
@@ -66,6 +71,23 @@ class CompileWatch:
                 self.n += 1
 
         jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def store_fill_peak(statfs) -> float:
+    """The fullest OSD's used / total, from each OSD's ``statfs``
+    ``(total_bytes, used_bytes)``; a store that states no size is not
+    counted."""
+    return max((used / total for total, used in statfs if total),
+               default=0.0)
+
+
+def host_mem_available_gib(path: str = "/proc/meminfo") -> float:
+    """``MemAvailable`` as the kernel reckons it now, in GiB."""
+    with open(path, encoding="ascii") as f:
+        for line in f:
+            if line.startswith("MemAvailable:"):
+                return int(line.split()[1]) * 1024 / 2**30
+    raise OSError(f"{path} has no MemAvailable line")
 
 
 async def _in_flight(n: int, jobs) -> list:
@@ -213,32 +235,35 @@ class CellRun:
                           f"{f.f_lineno} {f.f_code.co_name}"] += 1
             self.say(stuck_after_window_s=AFTER_WINDOW_S,
                      tasks=where.most_common(40),
-                     errors=self.errors[:5])
+                     errors=self.errors[:ERRORS_KEPT])
             os._exit(4)
 
         return loop.call_later(AFTER_WINDOW_S, stuck)
 
     # ------------------------------------------------------------ verify
 
-    async def _read_back(self, io, names: List[str], label: str) -> int:
+    async def _read_back(self, io, names: List[str], label: str
+                         ) -> Tuple[int, int]:
         """Read ``names`` and compare with the reference; returns how
-        many differ, are missing or fail."""
-        async def check(name: str) -> bool:
+        many differ, are missing or fail, and how many of those RAISED
+        (the rest came back and differed)."""
+        async def check(name: str) -> Optional[bool]:
             try:
                 return await io.read(name) == self.expected(name)
             except asyncio.CancelledError:
                 raise
             except Exception as exc:
                 self.errors.append(f"{label} read {name}: {exc!r}")
-                return False
+                return None
 
         t0 = time.monotonic()
         good = await _in_flight(self.plan.callers,
                                 [(lambda n=n: check(n)) for n in names])
         bad = sum(1 for g in good if not g)
-        self.say(step=label, objects=len(names), bad=bad,
+        raised = sum(1 for g in good if g is None)
+        self.say(step=label, objects=len(names), bad=bad, raised=raised,
                  seconds=time.monotonic() - t0)
-        return bad
+        return bad, raised
 
     async def _verify(self, cluster, io, window_names: List[str],
                       run_before: Dict[str, float],
@@ -259,9 +284,21 @@ class CellRun:
         # a timeout) makes the window's rates meaningless
         check("window_failed_ops",
               sum(1 for r in self.records if not r[4]), "max", 0)
+        # the window's objects went to new names and stayed: how near the
+        # fullest store came to the ratio at which the mon warns (ten
+        # points on it refuses writes), and how near the host to its end
+        statfs = [osd.store.statfs() for osd in cluster.osds.values()]
+        available = host_mem_available_gib()
+        self.say(step="room", host_mem_available_gib=available,
+                 stores_used_gib=[used / 2**30 for _total, used in statfs],
+                 store_gib=[total / 2**30 for total, _used in statfs])
+        check("store_fill_peak", store_fill_peak(statfs),
+              "max", cluster.config.mon_osd_nearfull_ratio)
+        check("host_mem_available_gib", available, "min", HOST_MEM_MIN_GIB)
         check("objects_to_verify", len(names), "min", 1)
-        check("healthy_mismatches",
-              await self._read_back(io, healthy, "verify_healthy"), "max", 0)
+        bad, raised = await self._read_back(io, healthy, "verify_healthy")
+        check("healthy_mismatches", bad, "max", 0)
+        check("healthy_read_errors", raised, "max", 0)
 
         before = kernel_counters()
         t0 = time.monotonic()
@@ -269,9 +306,9 @@ class CellRun:
         await cluster.wait_down(victim)
         self.say(step="kill_osd", victim=victim,
                  seconds=time.monotonic() - t0)
-        check("degraded_mismatches",
-              await self._read_back(io, degraded, "verify_degraded"),
-              "max", 0)
+        bad, raised = await self._read_back(io, degraded, "verify_degraded")
+        check("degraded_mismatches", bad, "max", 0)
+        check("degraded_read_errors", raised, "max", 0)
         decoded = grew(kernel_counters(), before)
         # without a decode the degraded sample proves nothing about parity
         check("degraded_decode_ticks",
@@ -487,7 +524,7 @@ class CellRun:
                  osdmap_epochs_in_window=epochs_in_window,
                  window_closed_late_s=late_s, drain_s=drain_s,
                  verify_s=verify_s, setup_s=setup_s,
-                 errors=self.errors[:5])
+                 errors=self.errors[:ERRORS_KEPT])
         return {
             "correct": all(c["ok"] for c in checks),
             "attempted": len(window) + verified,
@@ -495,4 +532,6 @@ class CellRun:
             "metrics": layer_values if self.trace else metrics,
             "end_to_end": metrics,
             "trace": summary,
+            "errors": self.errors[:ERRORS_KEPT],
+            "checks": checks,
         }

@@ -26,9 +26,11 @@ ROOT = os.path.dirname(HERE)
 
 
 def spread(values):
-    """Distance between the quartiles as a share of the median."""
+    """Distance between the quartiles as a share of the median (None for
+    a metric whose median is 0: a traced run's `hb_late_share.write`)."""
     q1, _q2, q3 = statistics.quantiles(values, n=4)
-    return (q3 - q1) / statistics.median(values)
+    median = statistics.median(values)
+    return (q3 - q1) / median if median else None
 
 
 def main(argv=None) -> int:
@@ -90,13 +92,28 @@ def main(argv=None) -> int:
             with open(os.path.join(out_dir, "results.jsonl"), "a",
                       encoding="utf-8") as f:
                 f.write(json.dumps(row) + "\n")
-            brief = {k: v["value"] for k, v in
-                     (result or {}).get("metrics", {}).items()}
+            res = result or {}
+            brief = {k: v["value"]
+                     for k, v in res.get("metrics", {}).items()}
+            if args.trace:
+                # a traced run's own end-to-end readings are on a line
+                # before the last: show those, not its per-layer metrics
+                traced = [json.loads(ln)["end_to_end_of_traced_run"]
+                          for ln in lines
+                          if ln.startswith('{"end_to_end_of_traced_run"')]
+                brief = {k: v["value"]
+                         for k, v in (traced[-1] if traced else {}).items()}
+            checks = res.get("checks", {})
+            room = {k: checks[k]["value"] for k in
+                    ("store_fill_peak", "host_mem_available_gib")
+                    if k in checks}
+            not_ok = {} if res.get("correct", True) else {
+                "checks": checks, "errors": res.get("errors")}
             print(json.dumps({"tag": tag, "rc": rc,
                               "wall_s": round(wall, 1),
-                              "correct": (result or {}).get("correct"),
-                              "failed": (result or {}).get("failed"),
-                              **brief}), flush=True)
+                              "correct": res.get("correct"),
+                              "failed": res.get("failed"),
+                              **brief, **room, **not_ok}), flush=True)
             if rc != 0:
                 with open(log, encoding="utf-8") as f:
                     print(f.read()[-3000:], flush=True)

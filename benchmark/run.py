@@ -6,9 +6,11 @@
 Runs on a TPU only: it exits non-zero, printing no result, unless JAX
 finds a TPU whose kind is in the peaks table and as many chips as the
 cell asks for.  The last line of standard output is the result (one JSON
-object); everything else (phase times, counters, each number compared
-beside its limit) is on earlier lines.  ``--trace 0`` reports the cell's
-end-to-end metrics, ``--trace 1`` its per-layer metrics.
+object; its last keys are ``errors`` and ``checks``, every number compared
+beside its limit), and the checks are also the last lines of standard
+error; everything else (phase times, counters) is on earlier lines.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics.
 """
 
 from __future__ import annotations
@@ -30,6 +32,25 @@ sys.path.insert(0, ROOT)
 
 def say(**row) -> None:
     print(json.dumps(row), flush=True)
+
+
+def result_line(out: dict, device: dict, trace: bool) -> dict:
+    """The last line of standard output, from what ``CellRun.run``
+    returned.  ``errors`` (the first few things that ops and reads said
+    when they failed) and ``checks`` (every number compared beside its
+    limit) come last, ``checks`` the very last."""
+    result = {"correct": out["correct"], "attempted": out["attempted"],
+              "failed": out["failed"], "metrics": out["metrics"],
+              "device": device}
+    if trace:
+        summary = out["trace"]
+        device["busy_s"] = summary.busy_s
+        device["window_s"] = summary.window_s
+        result["breakdown"] = summary.breakdown()
+    result["errors"] = out["errors"]
+    result["checks"] = {c["name"]: {"value": c["value"], "limit": c["limit"]}
+                        for c in out["checks"]}
+    return result
 
 
 def main(argv=None, before_run=None) -> int:
@@ -92,15 +113,17 @@ def main(argv=None, before_run=None) -> int:
               "memory_peak_bytes": max(
                   int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                   for d in devs[:cell.chips])}
-    result = {"correct": out["correct"], "attempted": out["attempted"],
-              "failed": out["failed"], "metrics": out["metrics"],
-              "device": device}
     if args.trace:
-        summary = out["trace"]
-        device["busy_s"] = summary.busy_s
-        device["window_s"] = summary.window_s
-        result["breakdown"] = summary.breakdown()
         say(end_to_end_of_traced_run=out["end_to_end"])
+    result = result_line(out, device, bool(args.trace))
+    # each number compared beside its limit: the last lines of standard
+    # error, and the last key of the result
+    for e in out["errors"]:
+        print(f"error {e}", file=sys.stderr)
+    for c in out["checks"]:
+        print(f"check {c['name']} {c['value']} {c['rule']} {c['limit']} "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -4,6 +4,7 @@ tests/test_batch_dataplane.py does), so the device branches serve."""
 
 import asyncio
 import os
+import time
 
 import pytest
 
@@ -77,6 +78,14 @@ def test_write_cell_serves_and_verifies(device_engine, make):
     assert checks["degraded_decode_ticks"]["value"] >= 1
     assert checks["host_engine_calls"]["value"] == 0
     assert out["attempted"] > cellmod.HEALTHY_SAMPLE
+    # the room it had: a tiny window in a deployment's device, this host
+    assert 0 < checks["store_fill_peak"]["value"] < 0.01
+    assert checks["store_fill_peak"]["limit"] == 0.85
+    assert checks["host_mem_available_gib"]["limit"] == 4.0
+    assert checks["healthy_read_errors"]["value"] == 0
+    assert checks["degraded_read_errors"]["value"] == 0
+    assert out["errors"] == []
+    assert [c["name"] for c in out["checks"]] == list(checks)
 
 
 def test_traced_run_reports_layer_metrics(device_engine):
@@ -114,6 +123,9 @@ def test_control_breaks_parity_and_the_degraded_sample_sees_it(
     assert checks["healthy_mismatches"]["value"] == 0
     assert checks["degraded_mismatches"]["value"] > 0
     assert out["failed"] == checks["degraded_mismatches"]["value"]
+    # they decoded and DIFFERED: no read raised, and nothing was said
+    assert checks["degraded_read_errors"]["value"] == 0
+    assert out["errors"] == []
 
 
 def test_one_flipped_stored_bit_makes_correct_false(device_engine,
@@ -146,6 +158,46 @@ def test_one_flipped_stored_bit_makes_correct_false(device_engine,
     assert checks["degraded_mismatches"]["value"] == 1
     assert checks["healthy_mismatches"]["value"] == 0
     assert not out["correct"] and out["failed"] == 1
+    # that read RAISED: counted in both checks, once in `failed`, and the
+    # result says what it said
+    assert checks["degraded_read_errors"]["value"] == 1
+    assert checks["healthy_read_errors"]["value"] == 0
+    assert len(out["errors"]) == 1
+    assert out["errors"][0].startswith("verify_degraded read obj_")
+
+
+def test_a_device_too_small_for_the_window_fails_by_its_fill_alone(
+        device_engine, monkeypatch):
+    """Objects go to new names and stay.  A fixed number of writes into
+    devices sized so that they end 90% full: over nearfull (0.85), where
+    the run is no longer ``correct``, under the full ratio (0.95), so no
+    write is refused and no other check fails."""
+    ops_a_caller, callers, size = 10, TINY["callers"], TINY["object_bytes"]
+
+    async def paced_caller(self, io, c, stop):
+        for i in range(ops_a_caller):
+            op = self.plan.op(c, i)
+            t0 = time.perf_counter()
+            await self._write(io, op)
+            self.records.append((t0, time.perf_counter(), op.kind, op.size,
+                                 True))
+            await asyncio.sleep(0.1)    # most of them inside the window
+        await stop.wait()
+
+    monkeypatch.setattr(cellmod.CellRun, "_caller", paced_caller)
+    cell = tiny_cell("k2m1_write_4m_t16")
+    # warm-up bursts of 1, 2, 4, then the callers': each object leaves a
+    # shard of half its size on every one of the three OSDs
+    objects = 1 + 2 + 4 + callers * ops_a_caller
+    used = objects * size // 2
+    cell.config = {**cell.config, "store_bytes_per_osd": int(used / 0.9)}
+    out = run_cell(cell, seconds=3.0)
+    checks = {r["check"]: r for r in out["lines"] if "check" in r}
+    assert checks["store_fill_peak"]["value"] == pytest.approx(0.9, abs=1e-3)
+    assert not checks["store_fill_peak"]["ok"]
+    assert [n for n, c in checks.items() if not c["ok"]] == \
+        ["store_fill_peak"]
+    assert not out["correct"] and out["failed"] == 0 and out["errors"] == []
 
 
 def test_a_host_engine_call_makes_correct_false():
