@@ -15,6 +15,7 @@ from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.cluster.pg import PGRB, PGState, _coll
 from ceph_tpu.cluster.store import Transaction
 from ceph_tpu.ec import planar_store
+from ceph_tpu.ec.interface import ECError
 from ceph_tpu.ops import crc32c as crcmod
 from ceph_tpu.osdmap.osdmap import PGid, PGPool
 from ceph_tpu.trace import loopacct
@@ -994,8 +995,9 @@ class ECBackendMixin:
         never be decode sources (scrub repair would otherwise reconstruct
         FROM the corruption and bless it).  ``fast_k``: degraded-mode
         client reads — contact only the first k shard holders, resolve
-        on the first k clean same-generation shards, and hedge/promote
-        stragglers instead of gathering the full group.
+        on the first k clean same-generation shards the code decodes
+        from, and hedge/promote stragglers instead of gathering the
+        full group.
 
         Round 19: the 4th return maps each CHOSEN shard id to the
         layout its payload arrived in (``"planar8"`` plane matrices
@@ -1112,11 +1114,29 @@ class ECBackendMixin:
                     (e.version[1] for e in reversed(st.log.entries)
                      if e.oid == oid), None)
 
+                codec = self._codec(pool)
+                data = set(range(codec.get_data_chunk_count()))
+
+                def _decodes(ss, _k=need_k) -> bool:
+                    """k shards or more that the code decodes the data
+                    chunks from: of an MDS code any k, of a locally
+                    repairable one not every k (``decode_sources``) —
+                    a hedged gather resolves on those that answer
+                    first, and (1, 2, 3, 5) of the k4m2l3 pool do not
+                    give 0 (PR 43: it then waits for the next reply)."""
+                    if len(ss) < _k:
+                        return False
+                    try:
+                        codec.decode_sources(data - ss, sorted(ss))
+                    except ECError:
+                        return False
+                    return True
+
                 def _viable(acc, _local=dict(got), _c=_committed,
-                            _k=need_k, _lv=logged_ver):
+                            _lv=logged_ver):
                     """k same-generation shards at/below the commit
-                    watermark — pinned to the logged generation when
-                    the log knows it."""
+                    watermark that decode — pinned to the logged
+                    generation when the log knows it."""
                     byver: Dict[int, set] = {}
                     for s, (_d, v, _sz, _ly) in _local.items():
                         byver.setdefault(v, set()).add(s)
@@ -1126,9 +1146,8 @@ class ECBackendMixin:
                                 reply.hinfo.get("version", 0),
                                 set()).add(reply.shard)
                     if _lv is not None and _c(_lv):
-                        ss = byver.get(_lv)
-                        return ss is not None and len(ss) >= _k
-                    return any(_c(v) and len(ss) >= _k
+                        return _decodes(byver.get(_lv, set()))
+                    return any(_c(v) and _decodes(ss)
                                for v, ss in byver.items())
 
                 acc = await self._subread_round(

@@ -78,6 +78,9 @@ def _damage_journal(path: str, torn_tail: bool, lose_frames: int) -> None:
 
 
 class FileStore(MemStore):
+    # the checkpoint pickles the objects: each is a ``bytearray``
+    populates = False
+
     def __init__(self, path: str, checkpoint_every: int = 2048,
                  fsync: bool = False, device_bytes: int = 1 << 30):
         super().__init__(device_bytes)

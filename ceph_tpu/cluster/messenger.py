@@ -33,9 +33,10 @@ references.  Receiving: ``_FrameStream`` has the transport
 unpickling and the message's out-of-band fields are all views of that
 buffer.  The ONE user-space copy a payload byte takes per hop is its
 consumer's, at its own door: the store's (``store.py``: it owns what
-it keeps, a ``bytearray`` of its own; ``Transaction.write`` takes its
-``bytes`` at once, ``write_planar`` keeps the view it is given and
-``MemStore`` copies it when the op is applied), ``MOSDOpReply.own_data``
+it keeps, a ``bytearray`` of its own or, of a full shard of 256 KiB or
+more since PR 43, a populated mapping of its own; ``Transaction.write``
+takes its ``bytes`` at once, ``write_planar`` keeps the view it is given
+and ``MemStore`` copies it when the op is applied), ``MOSDOpReply.own_data``
 (the client API returns ``bytes``), the encode tick's fill of its host
 batch.  For ``write_planar`` that holds since PR 32, and of a write
 that replaces the whole shard (``plane_off`` 0 and a window of

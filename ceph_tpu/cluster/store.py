@@ -9,11 +9,12 @@ and the dev cluster.
 
 from __future__ import annotations
 
+import mmap
 import pickle
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple, Union
 
 from ceph_tpu.cluster.optracker import mark_current
 from ceph_tpu.ec import planar_store
@@ -21,9 +22,27 @@ from ceph_tpu.trace import loopacct
 from ceph_tpu.utils.perf import KERNELS
 
 
+# Where ``MemStore`` lands a full shard of at least ``_MAP_MIN`` bytes
+# (PR 43): in a mapping of its own, mapped ``MAP_POPULATE``.  What a store
+# retains is new memory, and every page of it is touched for the first
+# time on the loop thread: a fault a page (~1.0-1.2 ms a MiB on the chip
+# host) where malloc hands it out, 0.73-0.80 ms a MiB where the kernel
+# populates a mapping in bulk (PERF.md section 5, "First touches").  One
+# mapping a shard: it goes when its object goes, so nothing is stranded
+# and the store's memory is what ``statfs`` says; malloc maps a block of
+# this size by itself too (``M_MMAP_THRESHOLD``), one fault a page.  A
+# smaller shard is a ``bytearray`` as before.
+_MAP_MIN = 256 << 10
+
+
 @dataclass
 class Obj:
-    data: bytearray = field(default_factory=bytearray)
+    # the store's own ``bytearray``, or the writable flat view of a
+    # populated mapping of its own (``MemStore._land``: a full shard of
+    # ``_MAP_MIN`` bytes or more), unmapped when the object lets it go.
+    # A view has a fixed length: whatever may resize an object goes
+    # through ``_own`` first.
+    data: Union[bytearray, memoryview] = field(default_factory=bytearray)
     xattrs: Dict[str, bytes] = field(default_factory=dict)
     omap: Dict[str, bytes] = field(default_factory=dict)
     version: int = 0
@@ -169,7 +188,21 @@ class ObjectStore:
         raise NotImplementedError
 
 
+def _own(o: Obj, keep: bool = True) -> bytearray:
+    """``o.data`` as a ``bytearray``, for whatever may RESIZE an object:
+    a view of a mapping (``Obj.data``) cannot, so it is copied out first
+    (``keep``), or let go where every byte is about to be replaced."""
+    if type(o.data) is not bytearray:
+        o.data = bytearray(o.data if keep else b"")
+    return o.data
+
+
 class MemStore(ObjectStore):
+    # full shards land in populated mappings (``_land``).  False where
+    # the platform has no ``MAP_POPULATE``, and on a store that
+    # serialises its objects (``FileStore`` pickles them)
+    populates = hasattr(mmap, "MAP_POPULATE")
+
     def __init__(self, device_bytes: int = 1 << 30):
         self._colls: Dict[str, Dict[str, Obj]] = {}
         self._lock = threading.RLock()
@@ -296,18 +329,20 @@ class MemStore(ObjectStore):
                 # a partial overlay must land on LOGICAL bytes, so
                 # materialize once (counted relayout) before splicing.
                 if not (offset == 0 and old <= end):
-                    o.data[:] = planar_store.planes_to_shard(
+                    logical = planar_store.planes_to_shard(
                         planar_store.blob_to_planes(bytes(o.data)),
                         seam="relayout")
+                    _own(o, keep=False)[:] = logical
                 o.layout = None
             if offset == 0 and len(o.data) <= end:
                 # full rewrite/extend from 0 (the EC full-shard write):
                 # one copy, no zero-fill of bytes about to be replaced
-                o.data[:] = data
+                _own(o, keep=False)[:] = data
             else:
-                if len(o.data) < end:
-                    o.data.extend(b"\0" * (end - len(o.data)))
-                o.data[offset:end] = data
+                own = _own(o)
+                if len(own) < end:
+                    own.extend(b"\0" * (end - len(own)))
+                own[offset:end] = data
             o.version += 1
             self._used += len(o.data) - old
         elif kind == "write_planar":
@@ -319,8 +354,13 @@ class MemStore(ObjectStore):
             if plane_off == 0 and blob.nbytes == 8 * total_cols:
                 # the window IS the new shard (every full-shard write):
                 # nothing of the old object survives it, so it is not
-                # read, and the store's own copy is the only one made
-                o.data[:] = blob
+                # read, and the store's own copy is the only one made:
+                # into a populated mapping, or as before
+                landed = self._land(blob) if self.populates else None
+                if landed is None:
+                    _own(o, keep=False)[:] = blob
+                else:
+                    o.data = landed
                 KERNELS.inc("store_planar_direct_bytes", blob.nbytes)
             else:
                 # a partial (or overshooting) window lands in the old
@@ -341,9 +381,10 @@ class MemStore(ObjectStore):
                                                        seam="relayout")
                 else:
                     cur = None
-                o.data[:] = planar_store.planes_to_blob(
+                merged = planar_store.planes_to_blob(
                     planar_store.splice_columns(
                         cur, plane_off, window, total_cols))
+                _own(o, keep=False)[:] = merged
             o.layout = planar_store.LAYOUT_PLANAR
             o.version += 1
             self._used += len(o.data) - old
@@ -354,14 +395,16 @@ class MemStore(ObjectStore):
             if o.layout == planar_store.LAYOUT_PLANAR and old != size:
                 # byte truncate of a planar object cuts PLANE ROWS, not
                 # logical bytes — leave planar first (counted relayout)
-                o.data[:] = planar_store.planes_to_shard(
+                logical = planar_store.planes_to_shard(
                     planar_store.blob_to_planes(bytes(o.data)),
                     seam="relayout")
+                _own(o, keep=False)[:] = logical
                 o.layout = None
-            if len(o.data) > size:
-                del o.data[size:]
+            own = _own(o)
+            if len(own) > size:
+                del own[size:]
             else:
-                o.data.extend(b"\0" * (size - len(o.data)))
+                own.extend(b"\0" * (size - len(own)))
             o.version += 1
             self._used += len(o.data) - old
         elif kind == "remove":
@@ -422,6 +465,28 @@ class MemStore(ObjectStore):
 
     def _coll(self, coll: str) -> Dict[str, Obj]:
         return self._colls.setdefault(coll, {})
+
+    @staticmethod
+    def _land(blob: memoryview) -> Optional[memoryview]:
+        """``blob`` copied into a mapping of its own that the kernel
+        populated, on the calling thread: the view that becomes the
+        object.  None (the caller copies into a ``bytearray`` as before)
+        for a blob under ``_MAP_MIN`` and when the kernel refuses the
+        mapping."""
+        n = blob.nbytes
+        if n < _MAP_MIN:
+            return None
+        t0 = time.perf_counter_ns()
+        try:
+            block = mmap.mmap(-1, n, flags=mmap.MAP_PRIVATE
+                              | mmap.MAP_ANONYMOUS | mmap.MAP_POPULATE)
+        except OSError:
+            return None
+        KERNELS.inc("store_populate_ns", time.perf_counter_ns() - t0)
+        view = memoryview(block)
+        view[:] = blob.cast("B")        # flat bytes, whatever carried them
+        KERNELS.inc("store_planar_populated_bytes", n)
+        return view
 
     # -- reads -------------------------------------------------------------
 

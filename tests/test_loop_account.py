@@ -40,6 +40,7 @@ from ceph_tpu.trace import loopacct
 from ceph_tpu.trace import tick as ticktrace
 from ceph_tpu.utils.config import OPTIONS
 from ceph_tpu.utils.perf import PerfCounters
+from tests._flaky import contention_retry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
@@ -333,9 +334,11 @@ def test_a_ticks_cpu_time_is_read_between_its_walls_stamps(burst):
     assert 0 < g["ec_tick_cpu_ns"] <= g["ec_tick_wall_ns"]
 
 
+@contention_retry()
 def test_a_tick_off_the_counters_reads_its_threads_cpu_time():
     """A tick's CPU time is its worker thread's: a sleep adds wall and
-    none of it, a spin adds both."""
+    none of it, a spin adds both (a spin of ~10 ms that six workers'
+    threads may preempt for longer: tried twice, PR 43)."""
     log = ticktrace.TickLog(counters=PerfCounters("t"))
     for work, busy in ((lambda: threading.Event().wait(0.05), False),
                        (lambda: sum(range(400_000)), True)):
@@ -864,11 +867,14 @@ def test_a_worker_threads_handles_survive_the_class_switches(monkeypatch):
     assert 0 < g["loop_handles"] / 2 < 4 * n
 
 
+@contention_retry()
 def test_the_tool_finds_the_harness_window_and_prints_its_table():
     """scripts/loop_table.py around the benchmark's own run of a cell
     (tiny, on the CPU host): of the harness's reads of ``KERNELS`` it
     finds the two at the window's edges by the growth the harness
-    prints, and the table between them is the window's."""
+    prints, and the table between them is the window's (a window of
+    1.5 s against a fold every 100 ms, under six workers: tried twice,
+    PR 43)."""
     from benchmark.harness import cell as cellmod
     from benchmark.harness.loader import load_cell
 
@@ -1011,9 +1017,11 @@ def test_a_metric_file_reads_the_hand_worked_value(name):
 
 
 def test_the_accounts_entries_come_last_and_every_cell_reports_them():
+    """Last of what stood when they came (28): a later PR's entries
+    follow them (PR 43's two of the store's populated mappings)."""
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         spec = json.load(f)
-    added = spec["per_layer"][-len(METRICS):]
+    added = spec["per_layer"][28:28 + len(METRICS)]
     assert [m["name"] for m in added] == list(METRICS)
     for metric in added:
         layer, unit = METRICS[metric["name"]][:2]
@@ -1021,7 +1029,8 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them():
                           "better": "lower", "source": "program_counter",
                           "layer": layer, "moves": "write_MBps",
                           "workloads": list(CELLS)}
-    assert len(spec["per_layer"]) == 28 + len(METRICS)
+    assert [m["name"] for m in spec["per_layer"][28 + len(METRICS):]] == \
+        ["store_populated_share.write", "store_populate_ms_per_op.write"]
 
 
 def test_the_burst_reads_sane_through_the_metric_files(burst):

@@ -6,6 +6,7 @@ toward idempotent handlers.
 """
 
 import asyncio
+import mmap
 import os
 
 from tests._flaky import contention_retry
@@ -1071,7 +1072,9 @@ def test_stored_object_does_not_alias_the_frame():
                     obj = objs.get("big")
                     if obj is not None:
                         held += 1
-                        assert type(obj.data) is bytearray
+                        # its own populated mapping (512 KiB: PR 43)
+                        assert type(obj.data.obj) is mmap.mmap
+                        assert not obj.data.readonly
                         assert len(obj.data) == 512 << 10
             assert held == 3
             got = await io.read("big")
