@@ -19,6 +19,7 @@ from ceph_tpu.ec.interface import ECError
 from ceph_tpu.ops import crc32c as crcmod
 from ceph_tpu.osdmap.osdmap import PGid, PGPool
 from ceph_tpu.trace import loopacct
+from ceph_tpu.utils.perf import KERNELS
 
 
 class ECUndersized(Exception):
@@ -126,6 +127,35 @@ def choose_decode_group(got: Dict[int, Tuple[bytes, int, int]],
             stale = {s for s, (_d, ver, _sz) in got.items()
                      if ver < version}
     return shards, size, version, stale
+
+
+def first_ask(codec, missing: Set[int], up: List[int],
+              peers: List[Tuple[int, int]], want: int) -> Tuple[List, List]:
+    """Whom a fast gather asks first, and who stays the hedge's spare.
+    ``up``: the shard ids whose holders are up, in the primary's order
+    of preference (its own, then shard order); ``missing``: the data
+    chunks not among them; ``peers``: the ``(shard, osd)`` of those that
+    are not its own; ``want``: how many of them the first k takes.
+    Where a data chunk is missing the gather ends in a decode, and the
+    code names the chunks that decode multiplies (``decode_sources``):
+    those and the data chunks that are up are asked, topped up in shard
+    order to ``want``.  The first k (``peers[:want]``) where nothing is
+    missing, where the code has no opinion (None: any k will do, an MDS
+    code's answer), and where it says the read cannot be served: the
+    gather finds that out as it did."""
+    chosen = None
+    if missing:
+        try:
+            chosen = codec.decode_sources(missing, up)
+        except ECError:
+            pass
+    if chosen is None:
+        return peers[:want], peers[want:]
+    k = codec.get_data_chunk_count()
+    first = [p for p in peers if p[0] < k or p[0] in chosen]
+    spare = [p for p in peers if p not in first]
+    short = max(0, want - len(first))
+    return first + spare[:short], spare[short:]
 
 
 class ECBackendMixin:
@@ -1098,9 +1128,22 @@ class ECBackendMixin:
                  and shard not in got and shard not in exclude_shards]
         if peers and len(got) < need_k:
             want = need_k - len(got)
-            fast = (fast_k and bool(self.config.osd_ec_hedge_reads)
-                    and len(peers) > want)
-            if fast:
+            codec = self._codec(pool)
+            data = set(range(codec.get_data_chunk_count()))
+            # the holders that are up: the primary's own shard, then
+            # shard order.  Where a data chunk's holder is not among
+            # them the gather ends in a decode, and the code says
+            # whose shards that decode multiplies
+            holders = list(got) + [s for s, _o in peers]
+            decodes = data - set(holders)
+            first, spare = peers, []
+            if fast_k and self.config.osd_ec_hedge_reads:
+                first, spare = first_ask(codec, decodes, holders, peers,
+                                         want)
+            if decodes:
+                KERNELS.inc("ec_gather_decodes")
+                KERNELS.inc("ec_gather_subreads", len(first))
+            if spare:
                 # the object's newest logged generation: when the pg
                 # log still covers the object, early-resolve ONLY on
                 # exactly that generation — k shards of an OLDER
@@ -1113,9 +1156,6 @@ class ECBackendMixin:
                 logged_ver = next(
                     (e.version[1] for e in reversed(st.log.entries)
                      if e.oid == oid), None)
-
-                codec = self._codec(pool)
-                data = set(range(codec.get_data_chunk_count()))
 
                 def _decodes(ss, _k=need_k) -> bool:
                     """k shards or more that the code decodes the data
@@ -1151,8 +1191,8 @@ class ECBackendMixin:
                                for v, ss in byver.items())
 
                 acc = await self._subread_round(
-                    st, oid, peers[:want], off, length,
-                    spare=peers[want:], check=_viable)
+                    st, oid, first, off, length,
+                    spare=spare, check=_viable)
                 if _viable(acc):
                     self.perf.inc("osd_ec_fastk_reads")
                 else:
@@ -1164,6 +1204,9 @@ class ECBackendMixin:
                              if res == 0 and r is not None}
                     rest = [(s, o) for s, o in peers if s not in heard]
                     if rest:
+                        if decodes:
+                            KERNELS.inc("ec_gather_second_rounds")
+                            KERNELS.inc("ec_gather_subreads", len(rest))
                         acc = acc + await self._subread_round(
                             st, oid, rest, off, length)
             else:

@@ -121,7 +121,10 @@ class ErasureCode(ErasureCodeInterface):
         is an MDS code's answer, and the caller takes the first k.  A
         code for which not every k will do answers with the chunks, and
         raises ECError(EIO) where ``available`` cannot produce ``want``
-        (``ErasureCodeLrc``)."""
+        (``ErasureCodeLrc``, ``ErasureCodeShec``).  Asked by the decode
+        (``stripe._decode_src``) and, over the holders that are up, by
+        the read gather that chooses whom to ask first
+        (``cluster/backend_ec.py::first_ask``)."""
         return None
 
     # -- encode / decode ----------------------------------------------------

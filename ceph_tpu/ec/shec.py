@@ -12,20 +12,28 @@ minimal-subset search over parity combinations + GF Gaussian elimination
 Tolerates up to ``c`` erasures while reading fewer chunks than a full-k MDS
 decode — the "shingle" rows overlap so each data chunk is covered by a
 cheap local-ish parity.  Encode is the standard bytewise GF(2^8) matrix
-multiply, so the TPU MXU bit-matrix path serves it unchanged; only
-decode-matrix *construction* differs from MDS codes and stays on the host
-(k x k bytes).
+multiply, so the TPU MXU bit-matrix path serves it unchanged.  Decode
+differs from an MDS code's in two ways, and both are this module's: the
+recovery matrix comes from the plan search (on the host, k x k bytes at
+most), and WHICH chunks it multiplies is the plan's choice, not "the
+first k that came": the first row of SHEC(6,4,3) covers chunks 0-2 only,
+so {0, 1, 2, 4, 5, 6} do not give chunk 3 and {4, 5, 7} do.  One plan
+(``_recovery``) says it to everyone who asks: ``decode_sources`` (the
+read gather of ``cluster/backend_ec.py`` and ``stripe._decode_src``),
+``_batch_plan`` / ``_planar_decode_plan`` (the device decode) and the
+engine's ``decode_matrix`` (the host GF engine and the plane entry
+points).
 """
 
 from __future__ import annotations
 
 import errno
-from typing import Dict, List, Mapping, Set, Tuple
+from typing import Dict, List, Mapping, Optional, Set, Tuple
 
 import numpy as np
 
 from ceph_tpu.ec import matrices
-from ceph_tpu.ec.codec import MatrixCodec
+from ceph_tpu.ec.codec import MatrixCodec, _DeviceMatrixEngine
 from ceph_tpu.ec.interface import ECError, ErasureCodeProfile
 from ceph_tpu.ops import gf8, gfw
 
@@ -127,6 +135,31 @@ def shec_coding_matrix(k: int, m: int, c: int, technique: int,
     return mat
 
 
+class _ShingledEngine(_DeviceMatrixEngine):
+    """The shingled matrix behind the one engine seam
+    (``codec.matrix_engine``).  Encode is the base class's one matmul.
+    Decode is not a survivor-submatrix inversion: not every k rows of
+    the punctured generator invert, and fewer than k rebuild a chunk
+    their shingle covers.  ``decode_matrix`` is the codec's plan over
+    exactly the chunks it is given (columns of chunks the plan leaves
+    out are zero) and RAISES where they cannot produce ``out_rows``."""
+
+    def __init__(self, codec: "ErasureCodeShec"):
+        super().__init__(codec.k, codec.m, codec.build_coding_matrix(),
+                         w=codec.w)
+        self._plan = codec._recovery
+
+    def decode_matrix(self, src_rows: Tuple[int, ...],
+                      out_rows: Tuple[int, ...]) -> np.ndarray:
+        src_rows = tuple(src_rows)
+        rmat, used = self._plan(
+            tuple(e for e in range(self.k + self.m) if e not in src_rows),
+            tuple(out_rows))
+        full = np.zeros((len(out_rows), len(src_rows)), dtype=rmat.dtype)
+        full[:, [src_rows.index(s) for s in used]] = rmat
+        return full
+
+
 class ErasureCodeShec(MatrixCodec):
     DEFAULT_K = 4
     DEFAULT_M = 3
@@ -139,7 +172,9 @@ class ErasureCodeShec(MatrixCodec):
         # decode-plan cache keyed by (want, avails) bit patterns
         # (ErasureCodeShecTableCache semantics)
         self._plan_cache: Dict[Tuple, Tuple] = {}
-        # batched recovery matrices per (erasures, want) pattern
+        # per (erasures, want) pattern: the recovery matrix and the
+        # chunks it multiplies, and its bit-matrix for the device
+        self._recovery_cache: Dict[Tuple, Tuple] = {}
         self._batch_cache: Dict[Tuple, Tuple] = {}
 
     # -- profile ------------------------------------------------------------
@@ -195,6 +230,9 @@ class ErasureCodeShec(MatrixCodec):
     def build_coding_matrix(self) -> np.ndarray:
         return shec_coding_matrix(self.k, self.m, self.c, self.technique,
                                   self.w)
+
+    def prepare(self) -> None:
+        self.engine = _ShingledEngine(self)
 
     # -- field-width helpers (gf8 fast path, gfw for w in {16, 32}) ---------
 
@@ -331,6 +369,19 @@ class ErasureCodeShec(MatrixCodec):
         _, _, _, minimum = self._make_decoding_plan(want, avails)
         return set(minimum)
 
+    def decode_sources(self, want, available) -> Optional[List[int]]:
+        """Which of the chunks ``available`` (those that came) a decode
+        of the chunks ``want`` multiplies: the sources of the plan
+        (``_recovery``), so fewer than k where a shingle covers what is
+        lost; ECError(EIO) where ``available`` cannot produce ``want``.
+        This code always has an answer (``ErasureCode.decode_sources``):
+        the first k of those that came need not decode."""
+        have = set(available)
+        _rmat, src = self._recovery(
+            tuple(e for e in range(self.k + self.m) if e not in have),
+            tuple(sorted(c for c in want if c not in have)))
+        return list(src)
+
     def decode_chunks(
         self,
         want_to_read: Set[int],
@@ -426,31 +477,43 @@ class ErasureCodeShec(MatrixCodec):
         cached like the reference decode tables."""
         cache_key = (erasures, want)
         cached = self._batch_cache.get(cache_key)
+        if cached is None:
+            import jax.numpy as jnp
+
+            rmat, src = self._recovery(erasures, want)
+            if self.w == 8:
+                bitmat = jnp.asarray(gf8.expand_bitmatrix(rmat))
+            else:
+                bitmat = jnp.asarray(gfw.expand_bitmatrix_w(rmat, self.w))
+            cached = self._batch_cache[cache_key] = (bitmat, src)
+        return cached
+
+    def _recovery(self, erasures: Tuple[int, ...],
+                  want: Tuple[int, ...]):
+        """THE plan for one erasure pattern: ``(rmat, src)`` with
+        chunk[want] = rmat @ chunk[src], ``src`` ascending and every one
+        of its chunks multiplied.  ``erasures`` = every chunk that is not
+        there, ``want`` = those of them to rebuild.  ECError(EIO) where
+        the chunks that are there cannot give ``want``."""
+        cache_key = (tuple(erasures), tuple(want))
+        cached = self._recovery_cache.get(cache_key)
         if cached is not None:
             return cached
-        import jax.numpy as jnp
-
+        word_dtype = np.uint8 if self.w == 8 else np.uint64
         n = self.k + self.m
         avails = [0 if i in erasures else 1 for i in range(n)]
         want_vec = [1 if i in want else 0 for i in range(n)]
         srcs, cols, inv, _ = self._make_decoding_plan(want_vec, avails)
-        src_list = list(srcs)
-        pos = {s: i for i, s in enumerate(src_list)}
         # available data chunks in an erased parity's support feed the
-        # composition directly; extend the source list with them
-        for e in want:
-            if e >= self.k:
-                for j in range(self.k):
-                    if self.engine.coding[e - self.k, j] and avails[j] \
-                            and j not in pos:
-                        pos[j] = len(src_list)
-                        src_list.append(j)
-        S = len(src_list)
-
-        word_dtype = np.uint8 if self.w == 8 else np.uint64
+        # composition directly; they are sources too
+        src = sorted(set(srcs) | {
+            j for e in want if e >= self.k for j in range(self.k)
+            if self.engine.coding[e - self.k, j] and avails[j]})
+        pos = {s: i for i, s in enumerate(src)}
+        S = len(src)
 
         def data_expr(j: int) -> np.ndarray:
-            """Row expressing data chunk j over src_list."""
+            """Row expressing data chunk j over src."""
             row = np.zeros(S, dtype=word_dtype)
             if avails[j]:
                 row[pos[j]] = 1
@@ -472,13 +535,14 @@ class ErasureCodeShec(MatrixCodec):
                     if cj:
                         acc ^= self._mul(cj, data_expr(j)).astype(word_dtype)
                 rows.append(acc)
-        rmat = np.stack(rows).astype(word_dtype)
-        if self.w == 8:
-            bitmat = jnp.asarray(gf8.expand_bitmatrix(rmat))
-        else:
-            bitmat = jnp.asarray(gfw.expand_bitmatrix_w(rmat, self.w))
-        self._batch_cache[cache_key] = (bitmat, tuple(src_list))
-        return bitmat, tuple(src_list)
+        rmat = np.stack(rows).astype(word_dtype) if rows \
+            else np.zeros((0, S), dtype=word_dtype)
+        # a row of the system that no wanted chunk reads is no source
+        used = np.flatnonzero(rmat.any(axis=0))
+        cached = self._recovery_cache[cache_key] = (
+            np.ascontiguousarray(rmat[:, used]),
+            tuple(src[int(i)] for i in used))
+        return cached
 
 
 def make_shec(profile: ErasureCodeProfile):

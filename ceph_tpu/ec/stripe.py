@@ -680,10 +680,11 @@ def planar_at_rest_ok(codec, unit: int) -> bool:
     bit-planes at rest?
 
     Requires the bitpack layout contract: a bytewise GF(2^8) matrix
-    engine (``codec.matrix_engine``: the Reed-Solomon families, SHEC,
-    and LRC, whose layers flatten to one generator and whose decode
-    composes the layer walk) and a stripe unit that is a multiple of
-    the 8-byte packing quantum.  Packet-interleaved codecs (the
+    engine (``codec.matrix_engine``: the Reed-Solomon families; SHEC at
+    w = 8, whose decode multiplies the chunks its plan names; and LRC,
+    whose layers flatten to one generator and whose decode composes the
+    layer walk) and a stripe unit that is a multiple of the 8-byte
+    packing quantum.  Packet-interleaved codecs (the
     BitmatrixCodec family — their planar form is the packet-row matrix,
     a different serialization), wider fields, an LRC stack with such a
     layer, and mesh adapters keep byte-at-rest; the gate falls back per

@@ -299,7 +299,8 @@ OPTIONS: List[Option] = [
     # bit-plane matrices — zero layout conversions on the steady-state
     # write/read/RMW/recovery/scrub paths (pinned by the
     # ec_planar_unseamed counter).  Needs a bytewise GF(2^8) matrix
-    # code (the Reed-Solomon families, SHEC, LRC over such layers) and
+    # code (the Reed-Solomon families, SHEC at w=8, LRC over such layers:
+    # ec.codec.matrix_engine is the one question) and
     # stripe_unit % 8 == 0; a pool without them (packet-interleaved
     # techniques, w=16/32) stays on byte-at-rest whatever this says
     # (ec.stripe.planar_at_rest_ok).

@@ -25,7 +25,12 @@ fault (non-zero exit, no result line):
    seeded 4 MiB objects against the literal layer walk, shard crcs
    included, then phase 3's drill on 8 OSDs under the same counter
    rules, with every shard landing as planes and the degraded read and
-   the recovery decoding in the plane domain.
+   the recovery decoding in the plane domain;
+5. the SHEC k=6 m=4 c=3 code (PR 44), whose k is not a power of two: a
+   tick's planar encode of seeded 4 MiB objects (171 stripes in a
+   bucket of 256, kw = 48: two stack groups) and a decode with chunk
+   3's holder gone, from the three chunks the code names, against a
+   scalar product with upstream's matrix.
 
 With ``--multichip`` it runs only the four-chip mesh engine against the
 single-device codec.  Wall times printed here are a smoke test's, not
@@ -49,6 +54,7 @@ DEFAULT_EC_PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
                       "k": "2", "m": "1"}
 ISA_K8M4 = {"plugin": "isa", "k": "8", "m": "4"}
 LRC_K4M2L3 = {"plugin": "lrc", "k": "4", "m": "2", "l": "3"}
+SHEC_K6M4C3 = {"plugin": "shec", "k": "6", "m": "4", "c": "3"}
 
 
 def say(**row) -> None:
@@ -424,6 +430,111 @@ def phase_lrc(seed: int, **size) -> None:
                              "one holder down")
 
 
+# --------------------------------------------------------------- phase 5
+
+def shec_reference_matrix() -> np.ndarray:
+    """SHEC(6,4,3), technique multiple, w = 8: the coding matrix as
+    upstream's plugin builds it (the golden vector of exactly this code,
+    ``tests/golden/ec_golden.jsonl``), written out."""
+    return np.array([[1, 1, 1, 0, 0, 0],
+                     [0, 0, 0, 172, 82, 200],
+                     [1, 166, 196, 238, 83, 146],
+                     [1, 123, 245, 143, 244, 142]], dtype=np.uint8)
+
+
+def _gf_products(factor: int) -> np.ndarray:
+    """``factor`` times every byte in GF(2^8) modulo 0x11d, by shift and
+    reduce."""
+    x = np.arange(256, dtype=np.uint16)
+    out = np.zeros(256, dtype=np.uint16)
+    while factor:
+        if factor & 1:
+            out ^= x
+        x <<= 1
+        x ^= np.where(x & 0x100, 0x11d, 0).astype(np.uint16)
+        factor >>= 1
+    return out.astype(np.uint8)
+
+
+def shec_tick_against_the_reference(seed: int, n_objects: int = 2,
+                                    object_size: int = 4 * MIB,
+                                    stack_groups: int = 2) -> dict:
+    """One tick of seeded objects through ``encode_planes_multi`` against
+    the scalar product with ``shec_reference_matrix``, shard crcs
+    included; then every object decoded with chunk 3 gone, from the
+    chunks the code names.  ``stack_groups``: what the Pallas kernel
+    stacks at kw = 48."""
+    from ceph_tpu.ec import factory, planar_store
+    from ceph_tpu.ec.stripe import (StripeInfo, decode_planes_multi,
+                                    encode_planes_multi)
+    from ceph_tpu.ops.crc32c import crc32c
+    from ceph_tpu.utils.perf import KERNELS
+
+    def counters():
+        c = KERNELS.dump()["device_kernels"]
+        return c.get("planar_matmul_calls", 0), \
+            c.get("planar_stack_groups", 0)
+
+    codec = factory(dict(SHEC_K6M4C3))
+    sinfo = StripeInfo(6, 4096)
+    matrix = shec_reference_matrix()
+    rng = np.random.default_rng(seed)
+    datas = [rng.integers(0, 256, object_size, dtype=np.uint8).tobytes()
+             for _ in range(n_objects)]
+    calls0, groups0 = counters()
+    out = encode_planes_multi(codec, sinfo, datas, [True] * n_objects)
+    calls, groups = counters()      # the encode's: the decode's kw is 24
+    calls, groups = calls - calls0, groups - groups0
+    compared = 0
+    for i, (data, (planes, crcs)) in enumerate(zip(datas, out)):
+        ns = sinfo.object_stripes(len(data))
+        rows = np.frombuffer(data.ljust(ns * sinfo.stripe_width, b"\0"),
+                             dtype=np.uint8).reshape(ns, 6, 4096) \
+            .transpose(1, 0, 2).reshape(6, -1)
+        parity = np.zeros((4, rows.shape[1]), dtype=np.uint8)
+        for r in range(4):
+            for c in range(6):
+                if matrix[r, c]:
+                    parity[r] ^= _gf_products(int(matrix[r, c]))[rows[c]]
+        for s, row in enumerate(np.vstack([rows, parity])):
+            want = row.tobytes()
+            if planar_store.planes_to_shard(planes[s], seam=None) != want:
+                raise AssertionError(f"shec object {i} shard {s}: the "
+                                     "planar tick differs from the scalar "
+                                     "product")
+            if int(crcs[s]) != crc32c(0xFFFFFFFF, want):
+                raise AssertionError(f"shec object {i} shard {s}: device "
+                                     "crc differs from crc32c of the "
+                                     "reference")
+            compared += 1
+    # chunk 3's holder gone: the first k that are left (0, 1, 2, 4, 5, 6)
+    # do not give it; the code names 4, 5 and parity 7
+    have = [s for s in range(10) if s != 3]
+    sources = codec.decode_sources({3}, have)
+    if sources != [4, 5, 7]:
+        raise AssertionError(f"shec: chunk 3 decoded from {sources}")
+    got = decode_planes_multi(codec, sinfo, [
+        ({s: planes[s] for s in have}, len(data))
+        for data, (planes, _crcs) in zip(datas, out)])
+    if got != datas:
+        raise AssertionError("shec: a decode of chunk 3 differs from the "
+                             "object written")
+    say(check="shec_tick_and_decode_vs_reference", objects=n_objects,
+        object_bytes=object_size, shards_compared=compared,
+        decode_sources=sources, planar_matmul_calls=calls,
+        planar_stack_groups=groups, ok=True)
+    if calls <= 0 or groups != stack_groups * calls:
+        raise AssertionError(
+            f"mean stack-group factor {groups}/{calls}, not "
+            f"{stack_groups}: at kw = 48 the Pallas kernel stacks two")
+    return {"shards_compared": compared, "decode_sources": sources,
+            "planar_matmul_calls": calls, "planar_stack_groups": groups}
+
+
+def phase_shec(seed: int) -> None:
+    shec_tick_against_the_reference(seed)
+
+
 # ------------------------------------------------------------- multichip
 
 def _show(name: str, arr) -> None:
@@ -501,7 +612,7 @@ def main(argv=None) -> int:
 
     phases = [("multichip", phase_multichip)] if args.multichip else \
         [("kernels", phase_kernels), ("cluster", phase_cluster),
-         ("lrc", phase_lrc)]
+         ("lrc", phase_lrc), ("shec", phase_shec)]
     for name, phase in phases:
         t0 = time.monotonic()
         phase(args.seed)

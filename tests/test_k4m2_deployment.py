@@ -45,7 +45,8 @@ DEPLOYMENT = DEPLOYMENTS["rados_k4m2_8osd"]     # the failure-detection cases
 WIDE = DEPLOYMENTS["rados_isa_k8m4_12osd"]      # the placement cases
 SIZES = {"4k": 4096, "64k+1": 65537, "1m": 1 << 20}
 CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
-         "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16")
+         "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16",
+         "shec_k6m4c3_write_4m_t16")
 
 
 def bounded(coro, seconds):

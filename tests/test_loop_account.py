@@ -44,7 +44,8 @@ from tests._flaky import contention_retry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
-         "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16")
+         "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16",
+         "shec_k6m4c3_write_4m_t16")
 K, M_ = 2, 1
 PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
            "k": str(K), "m": str(M_)}

@@ -7,8 +7,10 @@ seeded random data.  The code is bytewise, so the walk over whole shards
 stripe.  Under test: the flattened engine behind ``codec.matrix_engine``
 that the plane entry points of ``ec/stripe.py`` drive, the chunks a
 decode multiplies of those that came (``decode_sources`` ->
-``stripe._decode_src``), and the served pool on 8 OSDs.  The gathers of
-``backend_ec.py``, peering and recovery are the parent's.
+``stripe._decode_src``), and the served pool on 8 OSDs.  Whom the read
+gather of ``backend_ec.py`` asks first is the code's choice since PR 44
+(``tests/test_shec_deployment.py`` freezes it for this pool); peering
+and recovery are the parent's.
 """
 
 import asyncio
@@ -573,8 +575,10 @@ def test_two_holders_down_reads_right(pair):
     multiply: the parent's byte path raised there too).  It now asks the
     code (``decode_sources``) before it resolves, so a k that does not
     decode widens the gather to the holders not heard from, as a short
-    one does, and the read returns the bytes.  The code choosing whom to
-    ask FIRST, at all four call sites, is still ROADMAP B6's."""
+    one does, and the read returns the bytes.  Since PR 44 the gather
+    asks the code whom to ask FIRST (``backend_ec.first_ask``), so these
+    reads need no second round; recovery's and peering's choice are
+    still ROADMAP B6's."""
     payload = seeded(sum(pair), 1 << 20)
 
     async def scenario():

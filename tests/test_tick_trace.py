@@ -731,7 +731,8 @@ def test_the_nine_entries_agree_with_their_files():
                                       "k2m1_write_64k_t16",
                                       "k4m2_write_4m_t16",
                                       "k8m4_write_4m_t16",
-                                      "lrc_k4m2l3_write_4m_t16"]
+                                      "lrc_k4m2l3_write_4m_t16",
+                                      "shec_k6m4c3_write_4m_t16"]
         path = os.path.join(ROOT, "benchmark", "layer_metrics",
                             name + ".json")
         with open(path, encoding="utf-8") as f:

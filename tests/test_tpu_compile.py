@@ -62,13 +62,19 @@ def _shape(shape, dtype, sharding):
 
 # (rw, kw) of the bit-matrix: k8m4 encode, k2m1 encode, k8 two-erasure
 # decode (2 wanted chunks from 8 survivors), the flattened LRC k4m2l3
-# generator (4 coding rows) and its one-erasure decode (1 chunk from 4)
+# generator (4 coding rows) and its one-erasure decode (1 chunk from 4),
+# the SHEC k6m4c3 matrix (kw = 48: K = 96 of 128 at g = 2, the first
+# width that is not a power of two) and its one-erasure decode (1 chunk
+# from 6 that came; the plan's own 3 sources ride kw = 24 at g = 4)
 _PALLAS_WIDTHS = [
     pytest.param(32, 64, id="k8m4_encode"),
     pytest.param(8, 16, id="k2m1_encode"),
     pytest.param(16, 64, id="k8_decode_e2"),
     pytest.param(32, 32, id="lrc_k4m2l3_encode"),
     pytest.param(8, 32, id="lrc_k4m2l3_decode_e1"),
+    pytest.param(32, 48, id="shec_k6m4c3_encode"),
+    pytest.param(8, 48, id="shec_k6_decode_e1"),
+    pytest.param(8, 24, id="shec_k6_decode_e1_from_3"),
 ]
 
 
@@ -91,12 +97,14 @@ def test_planar_matmul_xla_compiles_for_v5e(one_chip):
 
 # one k8m4 object, and the largest bucket warmed ahead (ec/stripe.py:
 # 8 x 4 MiB objects, _WARM_MAX_BYTES)
-@pytest.mark.parametrize("bb", [128, 1024])
-def test_batch_to_planes_compiles_for_v5e(one_chip, bb):
+@pytest.mark.parametrize("bb,k", [(128, 8), (1024, 8),
+                                  # one SHEC k6m4c3 object: 171 stripes
+                                  pytest.param(256, 6, id="256-k6")])
+def test_batch_to_planes_compiles_for_v5e(one_chip, bb, k):
     from ceph_tpu.ec.planar import _batch_to_planes_bitpack
 
     _batch_to_planes_bitpack.lower(
-        _shape((bb, 8, 4096), jnp.uint8, one_chip), 8).compile()
+        _shape((bb, k, 4096), jnp.uint8, one_chip), 8).compile()
 
 
 def test_crc32c_batch_compiles_for_v5e(one_chip):
@@ -114,7 +122,9 @@ def test_crc32c_batch_compiles_for_v5e(one_chip):
                                     pytest.param(4, 2, 2048, id="k4m2"),
                                     pytest.param(8, 4, 1024, id="k8m4"),
                                     pytest.param(4, 4, 2048,
-                                                 id="lrc_k4m2l3")])
+                                                 id="lrc_k4m2l3"),
+                                    pytest.param(6, 4, 2048,
+                                                 id="shec_k6m4c3")])
 def test_chunk_crcs_program_compiles_for_v5e(one_chip, k, m, bb):
     from ceph_tpu.ops.crc32c import _chunk_crcs_jit
 
@@ -146,11 +156,12 @@ def test_crush_rule_compiles_for_v5e(one_chip):
              t_shapes).compile()
 
 
-# (k, m, packed columns): g = 2, 8 and 4 stack groups; the middle case
-# spans two grid steps
+# (k, m, packed columns): g = 2, 8, 4 and (kw = 48, K = 96 of 128) 2
+# stack groups; the second case spans two grid steps
 @pytest.mark.parametrize("k,m,npk", [(8, 4, gf8_pallas._TILE_P),
                                      (2, 1, 2 * gf8_pallas._TILE_P),
-                                     (4, 2, gf8_pallas._TILE_P)])
+                                     (4, 2, gf8_pallas._TILE_P),
+                                     (6, 4, gf8_pallas._TILE_P)])
 def test_planar_kernel_interpret_matches_xla(k, m, npk):
     """`_planar_kernel` (unpack, K-stacked dot, pack) in Pallas interpret
     mode, bit for bit against planar_matmul_xla.  Needs no topology."""
