@@ -9,10 +9,13 @@ and the dev cluster.
 
 from __future__ import annotations
 
+import ctypes
 import mmap
 import pickle
+import queue
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple, Union
 
@@ -33,6 +36,162 @@ from ceph_tpu.utils.perf import KERNELS
 # this size by itself too (``M_MMAP_THRESHOLD``), one fault a page.  A
 # smaller shard is a ``bytearray`` as before.
 _MAP_MIN = 256 << 10
+
+# The spares (PR 45).  Nothing in a mapping's first touch depends on the
+# shard that will land in it, so ONE thread a process maps and populates
+# mappings ahead of the writes, off the loop thread, and ``_land`` takes a
+# ready one of the blob's exact length and is left with the copy; it
+# maps inline, as above, when none is ready.  ``_POOL_MAX`` bounds the
+# spare bytes (mapped, populated, not yet an object's) of the whole
+# process: ten k2m1 ops' worth of shards, more than one tick of eight
+# objects commits at once.  ``statfs`` is logical and does not count
+# them.  ``_POOL_PIECE`` is how much the thread populates in one call:
+# everybody who maps or faults waits that long for the address space.
+_POOL_MAX = 64 << 20
+_POOL_PIECE = 256 << 10
+_MAP_FIXED = 0x10                       # Linux, every architecture
+
+_libc_mmap = ctypes.CDLL(None, use_errno=True).mmap
+_libc_mmap.restype = ctypes.c_void_p
+_libc_mmap.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
+                       ctypes.c_int, ctypes.c_int, ctypes.c_long]
+
+
+def _make_spare(n: int) -> memoryview:
+    """A private anonymous mapping of ``n`` bytes that the kernel
+    populated, for the refill thread: ``_POOL_PIECE`` at a time, each
+    piece one ``mmap`` call that lets the GIL go.  A populate holds the
+    address space's lock for as long as it lasts, and everybody who maps,
+    unmaps or faults waits for it, the tick threads first: 2 MiB in one
+    call on this thread cost k2m1 a fifth to a half of its p95 and
+    512 KiB a quarter, and pages faulted in one by one (a ``memset``, a
+    write a page) cost it a seventh and are three times as dear to copy
+    into afterwards (PERF.md section 5, "First touches off the loop").
+    A mapping of one piece or less is asked for populated; a longer one
+    is mapped lazily and populated in place, piece by piece
+    (``MAP_FIXED`` over its own range: the mapping stays ``block``'s,
+    which unmaps all of it when it goes)."""
+    flags = mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
+    if n <= _POOL_PIECE:
+        return memoryview(mmap.mmap(-1, n, flags=flags | mmap.MAP_POPULATE))
+    block = mmap.mmap(-1, n, flags=flags)
+    base = ctypes.addressof(ctypes.c_char.from_buffer(block))
+    flags |= mmap.MAP_POPULATE | _MAP_FIXED
+    for off in range(0, n, _POOL_PIECE):
+        if _libc_mmap(base + off, min(_POOL_PIECE, n - off),
+                      mmap.PROT_READ | mmap.PROT_WRITE, flags, -1,
+                      0) != base + off:
+            raise OSError(ctypes.get_errno(), "mmap")
+    return memoryview(block)
+
+
+class _Pool:
+    """Spare mappings for ``MemStore._land``: private, anonymous, one
+    shard long, every page populated, never an object's before.  The
+    loop thread only ever pops a deque and puts a token; everything
+    else here runs on the refill thread, which takes no store's lock
+    and never sees a byte of an object."""
+
+    def __init__(self, bound: int = _POOL_MAX):
+        self.bound = bound
+        # length -> its spares, the newest at the left: ``_land`` takes
+        # there (the pages populated last are the warmest) and the thread
+        # evicts at the right.  ``deque`` and ``dict`` calls are atomic
+        self.ready: Dict[int, deque] = {}
+        # what ``_land`` tells the thread, one token a shard: n = it took
+        # a spare of n bytes, -n = it found none of n bytes
+        self.taken: queue.SimpleQueue = queue.SimpleQueue()
+        self.thread: Optional[threading.Thread] = None
+        # the thread's own: the lengths it knows, least recently taken
+        # first, and the spare bytes as the tokens tell them (never under
+        # what is there: a take is known here only after it happened)
+        self._order: Dict[int, None] = {}
+        self._reckoned = 0
+        self._starting = threading.Lock()
+
+    def miss(self, n: int) -> None:
+        """``_land`` found no spare of ``n`` bytes: the thread learns the
+        length, or makes room for it; the first miss starts the thread.
+        A shard longer than the bound is nobody's to make ready: nothing
+        is learnt and nothing evicted for it."""
+        if n > self.bound:
+            return
+        if self.thread is None:
+            self.start()
+        self.taken.put(-n)
+
+    def start(self) -> None:
+        with self._starting:
+            if self.thread is None:
+                self.thread = threading.Thread(
+                    target=self._refill, daemon=True, name="store-pool")
+                self.thread.start()
+
+    def stop(self) -> None:
+        """Every token put so far is served, then the thread ends; the
+        spares stay (``start`` goes on from them)."""
+        if self.thread is not None:
+            self.taken.put(None)
+            self.thread.join()
+            self.thread = None
+
+    def spare_bytes(self) -> int:
+        return sum(n * len(spares) for n, spares in list(self.ready.items()))
+
+    def _drop(self, m: int, until: int) -> None:
+        """Unmap spares of length ``m``, the coldest first, while the
+        pool holds more than ``until`` bytes."""
+        spares = self.ready.get(m)
+        while spares and self._reckoned > until:
+            try:
+                spares.pop()            # the mapping goes with its view
+            except IndexError:          # ``_land`` took the last one
+                return
+            self._reckoned -= m
+
+    def _refill(self) -> None:
+        ready, order, get = self.ready, self._order, self.taken.get
+        clock = time.perf_counter_ns
+        while True:
+            n = get()                   # parked here while the pool is full
+            if n is None:
+                return
+            if n > 0:
+                self._reckoned -= n
+            else:
+                n = -n
+            if n not in order:
+                # learnt: a length that never comes again ends here,
+                # with one miss and no spare
+                order[n] = None
+                continue
+            del order[n]
+            order[n] = None             # the most recently taken
+            spares = ready.get(n)
+            if spares is None:          # before ``_land`` can see it: empty
+                spares = ready[n] = deque()
+            room = self.bound - n
+            if not spares and self._reckoned > room:
+                # a length with nothing ready needs room: spares of the
+                # length least recently taken go first
+                for m in order:
+                    if m != n:
+                        self._drop(m, room)
+                    if self._reckoned <= room:
+                        break
+            while self._reckoned <= room:
+                t0 = clock()
+                try:
+                    # no name of the thread's keeps the mapping: it goes
+                    # when the object it becomes lets it go
+                    spares.appendleft(_make_spare(n))
+                except OSError:
+                    break
+                KERNELS.inc("store_pool_touch_ns", clock() - t0)
+                self._reckoned += n
+
+
+_POOL = _Pool()
 
 
 @dataclass
@@ -468,14 +627,29 @@ class MemStore(ObjectStore):
 
     @staticmethod
     def _land(blob: memoryview) -> Optional[memoryview]:
-        """``blob`` copied into a mapping of its own that the kernel
-        populated, on the calling thread: the view that becomes the
-        object.  None (the caller copies into a ``bytearray`` as before)
-        for a blob under ``_MAP_MIN`` and when the kernel refuses the
-        mapping."""
+        """``blob`` copied into a mapping of its own whose pages are
+        there already: the view that becomes the object.  The mapping is
+        a spare of the pool's when one of the blob's length is ready (the
+        copy is all that runs here); else the kernel populates one on the
+        calling thread, as since PR 43, and the pool is told.  None (the
+        caller copies into a ``bytearray`` as before) for a blob under
+        ``_MAP_MIN`` and when the kernel refuses the mapping."""
         n = blob.nbytes
         if n < _MAP_MIN:
             return None
+        pool = _POOL
+        spares = pool.ready.get(n)
+        try:
+            view = spares.popleft() if spares else None
+        except IndexError:              # the thread dropped the last one
+            view = None
+        if view is not None:
+            pool.taken.put(n)
+            view[:] = blob.cast("B")
+            KERNELS.inc("store_planar_pooled_bytes", n)
+            KERNELS.inc("store_planar_populated_bytes", n)
+            return view
+        pool.miss(n)
         t0 = time.perf_counter_ns()
         try:
             block = mmap.mmap(-1, n, flags=mmap.MAP_PRIVATE

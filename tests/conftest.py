@@ -61,3 +61,19 @@ def _lockdep_reset():
     yield
     LockDep.instance().reset()
     DepLock._held.clear()
+
+
+@pytest.fixture(autouse=True, scope="session")
+def _idle_store_pool():
+    """The store's spare mappings (PR 45) are one pool and one refill
+    thread a PROCESS: left on, every test that lands a shard of 256 KiB
+    would share with the tests after it a thread that populates 64 MiB
+    beside them, and a test that asserts on the wall of a 30 ms op would
+    be timing that too.  The session's pool has a bound of nothing, so it
+    never starts its thread and every shard lands inline;
+    ``tests/test_store_populated.py`` gives each of its tests a pool of
+    its own."""
+    from ceph_tpu.cluster import store
+
+    store._POOL = store._Pool(bound=0)
+    yield

@@ -1019,7 +1019,8 @@ def test_a_metric_file_reads_the_hand_worked_value(name):
 
 def test_the_accounts_entries_come_last_and_every_cell_reports_them():
     """Last of what stood when they came (28): a later PR's entries
-    follow them (PR 43's two of the store's populated mappings)."""
+    follow them (PR 43's two of the store's populated mappings, PR 45's
+    two of the pool those mappings are taken from)."""
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         spec = json.load(f)
     added = spec["per_layer"][28:28 + len(METRICS)]
@@ -1031,7 +1032,8 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them():
                           "layer": layer, "moves": "write_MBps",
                           "workloads": list(CELLS)}
     assert [m["name"] for m in spec["per_layer"][28 + len(METRICS):]] == \
-        ["store_populated_share.write", "store_populate_ms_per_op.write"]
+        ["store_populated_share.write", "store_populate_ms_per_op.write",
+         "store_pooled_share.write", "store_pool_touch_ms_per_op.write"]
 
 
 def test_the_burst_reads_sane_through_the_metric_files(burst):

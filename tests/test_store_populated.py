@@ -12,12 +12,24 @@ a ``bytearray`` one; that the mapping goes when its object lets it go,
 so that nothing is stranded whatever the traffic; that a mapping the
 kernel refuses falls back to the copy; that the populate is timed; and,
 on tiny clusters, that every shard byte of ``write_full``s is landed so.
+
+Since PR 45 the mapping may be a spare that the process's pool
+(``store._POOL``) had ready: one refill thread maps and populates spares
+of the lengths ``_land`` asked for, off the calling thread, and ``_land``
+takes one and copies, or maps inline as before when none is ready.
+Pinned in "the spares": a hit lands what a miss and the ``bytearray``
+path land; the spare bytes stay under the bound and the length least
+recently taken pays for a new one; a spare goes with its object as an
+inline mapping does; who never starts the thread; and that no
+transaction needs the thread to run.
 """
 
 import asyncio
 import gc
 import mmap
 import os
+import sys
+import threading
 import weakref
 
 import numpy as np
@@ -47,6 +59,17 @@ def run(coro):
 @pytest.fixture
 def small_map_min(monkeypatch):
     monkeypatch.setattr(store_mod, "_MAP_MIN", MAP_MIN)
+
+
+@pytest.fixture(autouse=True)
+def pool(monkeypatch):
+    """Every test has a pool of its own, empty and with no thread yet, so
+    that its first ``_land`` of a length is a miss whatever ran before;
+    the thread, if the test started one, ends with the test."""
+    fresh = store_mod._Pool()
+    monkeypatch.setattr(store_mod, "_POOL", fresh)
+    yield fresh
+    fresh.stop()
 
 
 def _planes(cols: int, seed: int) -> np.ndarray:
@@ -262,6 +285,29 @@ def _land(s, coll, oid, seed=7, cols=COLS):
     return blob
 
 
+def _pooled() -> int:
+    return KERNELS.get("store_planar_pooled_bytes")
+
+
+def _settle(pool) -> int:
+    """Every token put so far is served, so the pool is as full as it
+    gets; its spare bytes, which are what the thread reckons."""
+    if pool.thread is not None:
+        pool.stop()
+        pool.start()
+    assert pool._reckoned == pool.spare_bytes() <= pool.bound
+    return pool.spare_bytes()
+
+
+def _prime(pool, s, nbytes, coll="c"):
+    """Two misses: the first teaches the pool the length, the second
+    makes the thread fill it."""
+    for i in range(2):
+        _land(s, coll, f"prime{nbytes}_{i}", seed=90 + i, cols=nbytes // 8)
+    _settle(pool)
+    assert pool.ready[nbytes]
+
+
 def _mappings(s):
     return [o.data.obj for objs in s._colls.values() for o in objs.values()
             if type(o.data) is memoryview]
@@ -306,15 +352,21 @@ def test_the_threshold_is_a_quarter_of_a_mebibyte():
     assert store_mod._MAP_MIN == 256 << 10
 
 
+@pytest.mark.parametrize("landed", ["inline", "pooled"])
 @pytest.mark.parametrize("how", ["remove", "remove_collection",
                                  "overwritten", "resized", "rewritten"])
-def test_a_mapping_goes_with_its_object(small_map_min, how):
+def test_a_mapping_goes_with_its_object(small_map_min, pool, how, landed):
     """Whatever takes the object's bytes away takes the mapping with
     them, at once (no collector has to run), and leaves the neighbours'
-    alone: ``_used`` is what is mapped."""
+    alone: ``_used`` is what is mapped.  A mapping that was a spare of
+    the pool's goes the same way, and never back to the pool."""
     s = MemStore()
     s.queue_transaction(Transaction().create_collection("c"))
+    if landed == "pooled":
+        _prime(pool, s, NBYTES)
+    pooled = _pooled()
     _land(s, "c", "o", seed=20)
+    assert _pooled() - pooled == (NBYTES if landed == "pooled" else 0)
     kept = _land(s, "c", "next", seed=21)
     gone = weakref.ref(s._colls["c"]["o"].data.obj)
     stays = weakref.ref(s._colls["c"]["next"].data.obj)
@@ -370,6 +422,404 @@ def test_nothing_is_stranded(small_map_min, traffic):
                    for o in s._colls["c"].values())
         assert sum(len(m) for m in alive) <= s.statfs()[1] == s._used
         del alive, now
+
+
+# ------------------------------------------------------ the spares (PR 45)
+
+
+@pytest.mark.parametrize("how", ["miss", "hit"])
+@pytest.mark.parametrize("nbytes", [256 << 10, 700416, 2 << 20])
+def test_a_hit_and_a_miss_land_what_the_bytearray_path_lands(pool, nbytes,
+                                                             how):
+    """At the cells' own lengths and the threshold as it ships: a shard
+    that takes a spare and one that maps inline leave the bytes, the
+    version, ``_used`` and every other answer of the store that the
+    ``populates = False`` twin leaves; the hit is booked pooled AND
+    populated and runs no ``mmap`` on the calling thread, the miss is
+    booked populated only and times its populate."""
+    blob = _planes(nbytes // 8, seed=3).tobytes()
+    s, twin = MemStore(1 << 26), MemStore(1 << 26)
+    twin.populates = False
+    for store in (s, twin):
+        store.queue_transaction(Transaction().create_collection("c"))
+    if how == "hit":
+        _prime(pool, s, nbytes)
+        _prime(pool, twin, nbytes)
+    before, pooled, ns = _counters(), _pooled(), _populate_ns()
+    s.queue_transaction(
+        Transaction().write_planar("c", "o", 0, blob, nbytes // 8))
+    assert _grew(before) == (nbytes, nbytes, nbytes)
+    assert _pooled() - pooled == (nbytes if how == "hit" else 0)
+    assert (_populate_ns() > ns) == (how == "miss")
+    twin.queue_transaction(
+        Transaction().write_planar("c", "o", 0, blob, nbytes // 8))
+    o = s._colls["c"]["o"]
+    assert _mapped(o) and (o.data.ndim, o.data.format) == (1, "B")
+    assert s._used == twin._used and o.version == 1
+    assert _state(s) == _state(twin)
+    assert s.read_planar("c", "o") == blob
+
+
+@pytest.mark.parametrize("lengths", [[4096], [1024, 1536, 2048, 4096, 8192]],
+                         ids=["one_length", "five_lengths"])
+def test_the_spares_stay_under_the_bound(small_map_min, monkeypatch,
+                                         lengths):
+    """Read while the thread fills and after it has: never more spare
+    bytes than the bound, whichever lengths came in whatever order; and
+    the thread's own reckoning is what is there."""
+    pool = store_mod._Pool(bound=20 << 10)
+    monkeypatch.setattr(store_mod, "_POOL", pool)
+    s = MemStore(1 << 24)
+    s.queue_transaction(Transaction().create_collection("c"))
+    rng = np.random.default_rng(5)
+    try:
+        for i in range(200):
+            n = int(rng.choice(lengths))
+            _land(s, "c", f"o{i}", seed=i, cols=n // 8)
+            assert pool.spare_bytes() <= pool.bound
+            if i % 50 == 49:
+                assert 0 < _settle(pool) <= pool.bound
+                assert pool.bound - pool.spare_bytes() < max(lengths)
+        assert set(pool.ready) == set(lengths)
+    finally:
+        pool.stop()
+    assert s._used == sum(len(o.data) for o in s._colls["c"].values())
+
+
+@pytest.mark.parametrize("stale", ["a", "b"])
+def test_the_length_least_recently_taken_makes_the_room(small_map_min,
+                                                        monkeypatch, stale):
+    """A full pool holds two lengths; a third that finds nothing ready
+    is given room by the one taken longest ago, and the other keeps its
+    spares."""
+    pool = store_mod._Pool(bound=16 << 10)
+    monkeypatch.setattr(store_mod, "_POOL", pool)
+    a, b, c = 4096, 2048, 3072
+    s = MemStore(1 << 24)
+    s.queue_transaction(Transaction().create_collection("c"))
+    try:
+        _prime(pool, s, a)
+        _prime(pool, s, b)
+        fresh = b if stale == "a" else a
+        _land(s, "c", "again", cols=fresh // 8)     # a hit: now the newest
+        had = {n: len(pool.ready[n]) for n in (a, b)}
+        assert _settle(pool) + min(a, b) > pool.bound and all(had.values())
+        _land(s, "c", "c0", cols=c // 8)            # learnt, nothing made
+        assert _settle(pool) and c not in pool.ready
+        _land(s, "c", "c1", cols=c // 8)
+        _settle(pool)
+        old = a if stale == "a" else b
+        assert len(pool.ready[c]) >= 1
+        assert len(pool.ready[old]) < had[old]
+        assert len(pool.ready[fresh]) >= had[fresh]
+    finally:
+        pool.stop()
+
+
+def test_a_length_that_never_comes_again_costs_one_miss_and_no_spare(
+        small_map_min, pool):
+    s = _store()
+    for i, cols in enumerate((COLS, COLS + 8, COLS + 16)):
+        _land(s, "c", f"o{i}", cols=cols)
+    assert _settle(pool) == 0 and not pool.ready
+    assert sorted(pool._order) == [NBYTES, NBYTES + 64, NBYTES + 128]
+
+
+@pytest.mark.parametrize("nbytes, served", [(16 << 10, True),
+                                            ((16 << 10) + 64, False)],
+                         ids=["at_the_bound", "over_the_bound"])
+def test_a_shard_over_the_bound_is_nobodys_to_make_ready(
+        small_map_min, monkeypatch, nbytes, served):
+    """A full pool of one length; a shard as long as the whole bound is
+    given all of it, a longer one lands inline every time and costs the
+    others nothing: no thread work, nothing learnt, nothing evicted."""
+    pool = store_mod._Pool(bound=16 << 10)
+    monkeypatch.setattr(store_mod, "_POOL", pool)
+    s, twin = _store(), _store(populates=False)
+    try:
+        _prime(pool, s, NBYTES)
+        had = len(pool.ready[NBYTES])
+        assert had == 4
+        pooled, ns = _pooled(), _populate_ns()
+        for i in range(3):
+            blob = _planes(nbytes // 8, seed=i).tobytes()
+            for store in (s, twin):
+                store.queue_transaction(Transaction().write_planar(
+                    "c", f"big{i}", 0, blob, nbytes // 8))
+            _settle(pool)
+            assert _mapped(s._colls["c"][f"big{i}"])
+        if served:
+            assert _pooled() == pooled + nbytes
+            assert not pool.ready[NBYTES] and len(pool.ready[nbytes]) == 1
+        else:
+            assert _pooled() == pooled and _populate_ns() > ns
+            assert len(pool.ready[NBYTES]) == had
+            assert nbytes not in pool.ready and nbytes not in pool._order
+            assert pool.taken.empty()
+        for i in range(3):
+            assert s.read_planar("c", f"big{i}") == \
+                twin.read_planar("c", f"big{i}")
+    finally:
+        pool.stop()
+
+
+def test_no_transaction_needs_the_thread(small_map_min, pool, monkeypatch):
+    """With a refill thread that is never scheduled every write is served
+    on the calling thread, by misses: no byte of an object and no op of a
+    transaction is the thread's to handle."""
+    monkeypatch.setattr(store_mod._Pool, "start", lambda self: setattr(
+        self, "thread", threading.Thread(target=lambda: None)))
+    s, twin = _store(), _store(populates=False)
+    before, pooled, ns = _counters(), _pooled(), _populate_ns()
+    for i in range(20):
+        blob = _planes(COLS, seed=i).tobytes()
+        for store in (s, twin):
+            store.queue_transaction(
+                Transaction().write_planar("c", f"o{i}", 0, blob, COLS))
+        assert _populate_ns() > ns
+        ns = _populate_ns()
+        assert _mapped(s._colls["c"][f"o{i}"])
+    assert _grew(before) == (40 * NBYTES, 40 * NBYTES, 20 * NBYTES)
+    assert _pooled() == pooled and not pool.ready
+    assert _state(s) == _state(twin)
+    pool.thread = None                  # it was never started: nothing to join
+
+
+@pytest.mark.parametrize("who", ["under_the_threshold", "partial_window",
+                                 "byte_write", "no_map_populate",
+                                 "filestore"])
+def test_who_never_starts_the_thread(small_map_min, pool, tmp_path, who):
+    """The 64 KiB cell's shards, windows that are spliced, bytes at rest,
+    a platform without ``MAP_POPULATE`` and a store that pickles its
+    objects: none of them asks the pool for anything."""
+    if who == "filestore":
+        from ceph_tpu.cluster.filestore import FileStore
+        s = FileStore(str(tmp_path / "fs"), checkpoint_every=1 << 20)
+        s.mount()
+        s.queue_transaction(Transaction().create_collection("c"))
+    else:
+        s = _store()
+    s.populates = s.populates and who != "no_map_populate"
+    for i in range(4):
+        if who == "under_the_threshold":
+            _land(s, "c", f"o{i}", cols=MAP_MIN // 8 - 1)
+        elif who == "partial_window":
+            s.queue_transaction(Transaction().write_planar(
+                "c", "o", COLS * (i + 1), _planes(COLS, i).tobytes(),
+                COLS * (i + 2)))
+        elif who == "byte_write":
+            s.queue_transaction(
+                Transaction().write("c", f"o{i}", 0, bytes(NBYTES)))
+        else:
+            _land(s, "c", f"o{i}")
+    assert pool.thread is None and pool.taken.empty() and not pool.ready
+    assert "store-pool" not in {t.name for t in threading.enumerate()}
+    if who == "filestore":
+        s.umount()
+
+
+def test_two_stores_share_the_pool_and_the_bound(small_map_min,
+                                                 monkeypatch):
+    """One pool a process: what one store's misses taught it serves the
+    other's first shard of that length, the spares of both are counted
+    against the one bound, neither ``statfs`` counts them, and no mapping
+    is handed out twice."""
+    pool = store_mod._Pool(bound=16 << 10)
+    monkeypatch.setattr(store_mod, "_POOL", pool)
+    one, two = MemStore(1 << 22), MemStore(1 << 22)
+    for store in (one, two):
+        store.queue_transaction(Transaction().create_collection("c"))
+    try:
+        _prime(pool, one, NBYTES)
+        assert one.statfs()[1] == 2 * NBYTES and two.statfs()[1] == 0
+        pooled, blobs = _pooled(), {}
+        for i in range(12):
+            store = (one, two)[i % 2]
+            blobs[store, f"o{i}"] = _land(store, "c", f"o{i}", seed=i)
+            assert pool.spare_bytes() <= pool.bound
+            if i == 0:
+                assert _pooled() - pooled == NBYTES     # ``two``'s first
+        _settle(pool)
+    finally:
+        pool.stop()
+    assert _pooled() - pooled >= 4 * NBYTES
+    maps = _mappings(one) + _mappings(two)
+    assert len(set(map(id, maps))) == len(maps) == 14
+    assert all(m is not spare.obj for m in maps
+               for spare in pool.ready[NBYTES])
+    for (store, oid), blob in blobs.items():
+        assert store.read_planar("c", oid) == blob
+    assert one.statfs()[1] == 8 * NBYTES and two.statfs()[1] == 6 * NBYTES
+
+
+def test_pooled_is_within_populated_is_within_written(small_map_min, pool):
+    """After every step of a traffic of hits, misses, small shards,
+    spliced windows and a store that does not populate."""
+    s, plain = _store(), _store(populates=False)
+    before, pooled = _counters(), _pooled()
+    _prime(pool, s, NBYTES)
+    steps = [lambda i: _land(s, "c", f"o{i}"),
+             lambda i: _land(s, "c", f"s{i}", cols=MAP_MIN // 8 - 1),
+             lambda i: _land(s, "c", f"n{i}", cols=COLS + 8 * (i + 1)),
+             lambda i: _land(plain, "c", f"o{i}"),
+             lambda i: s.queue_transaction(Transaction().write_planar(
+                 "c", "w", COLS * i, _planes(COLS, i).tobytes(),
+                 COLS * (i + 1)))]
+    for i in range(30):
+        steps[i % len(steps)](i)
+        written, _direct, populated = _grew(before)
+        assert 0 <= _pooled() - pooled <= populated <= written
+    _settle(pool)
+    assert _pooled() - pooled > 0
+
+
+def test_a_spare_is_zeros_and_an_objects_mapping_never_returns(
+        small_map_min, pool):
+    """What ``_land`` takes has held nothing; what an object lets go is
+    unmapped, it is not a spare again."""
+    s = _store()
+    _prime(pool, s, NBYTES)
+    assert all(bytes(v) == bytes(NBYTES) for v in pool.ready[NBYTES])
+    spares = _settle(pool)
+    _land(s, "c", "o", seed=31)
+    gone = weakref.ref(s._colls["c"]["o"].data.obj)
+    _settle(pool)
+    s.queue_transaction(Transaction().remove("c", "o"))
+    assert gone() is None
+    assert _settle(pool) == spares
+    assert all(bytes(v) == bytes(NBYTES) for v in pool.ready[NBYTES])
+
+
+def _resident(view) -> bool:
+    """Every page of ``view``'s mapping is there (``mincore``)."""
+    import ctypes
+
+    n = len(view)
+    pages = -(-n // mmap.PAGESIZE)
+    vec = (ctypes.c_ubyte * pages)()
+    held = ctypes.c_char.from_buffer(view.obj)
+    rc = ctypes.CDLL(None, use_errno=True).mincore(
+        ctypes.c_void_p(ctypes.addressof(held)), ctypes.c_size_t(n), vec)
+    del held
+    assert rc == 0, ctypes.get_errno()
+    return all(b & 1 for b in vec)
+
+
+@pytest.mark.parametrize("piece", [64 << 10, store_mod._POOL_PIECE],
+                         ids=["pieces_of_64k", "the_products_piece"])
+@pytest.mark.parametrize("nbytes", [256 << 10, (256 << 10) + 8, 700416,
+                                    1 << 20, 2 << 20, 8 << 20])
+def test_a_spare_is_populated_piece_by_piece(monkeypatch, nbytes, piece):
+    """Whatever the length every page of a spare is there when
+    ``_make_spare`` returns, the kernel was asked for at most a piece at
+    a time, the pieces tile the mapping's own range in order, and what
+    comes back is one writable mapping of zeros that unmaps as a whole."""
+    monkeypatch.setattr(store_mod, "_POOL_PIECE", piece)
+    asked, real = [], store_mod._libc_mmap
+
+    def libc_mmap(addr, n, prot, flags, fd, off):
+        asked.append((addr, n, flags))
+        return real(addr, n, prot, flags, fd, off)
+
+    monkeypatch.setattr(store_mod, "_libc_mmap", libc_mmap)
+    view = store_mod._make_spare(nbytes)
+    assert type(view.obj) is mmap.mmap and len(view) == nbytes
+    assert not view.readonly and _resident(view)
+    assert bytes(view) == bytes(nbytes)
+    if nbytes <= piece:
+        assert not asked                # asked for populated, in one call
+    else:
+        want = mmap.MAP_POPULATE | store_mod._MAP_FIXED | mmap.MAP_PRIVATE
+        assert all(n <= piece and flags & want == want
+                   for _, n, flags in asked)
+        base = asked[0][0]
+        assert [a - base for a, _, _ in asked] == list(
+            range(0, nbytes, piece))
+        assert sum(n for _, n, _ in asked) == nbytes
+    view[:] = b"\x5a" * nbytes          # the pieces are the block's own
+    assert view.obj[nbytes - 1] == 0x5a
+    block = view.obj
+    view.release()
+    block.close()                       # one unmap, nothing exported
+
+
+def test_a_piece_the_kernel_refuses_ends_the_refill(monkeypatch):
+    monkeypatch.setattr(store_mod, "_libc_mmap", lambda *a: None)
+    with pytest.raises(OSError):
+        store_mod._make_spare(store_mod._POOL_PIECE + 1)
+
+
+def test_the_populate_lets_the_gil_go():
+    """Held without a clock: with a switch interval of an hour nobody is
+    made to give the GIL up, so the main thread gets it back while the
+    helper is still inside ``_make_spare`` only if the calls in there let
+    it go.  64 MiB, so that the populates last; a loaded host may still
+    wake the main thread too late, so any of five goes proves it (were
+    the GIL held, none could)."""
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(3600)
+    try:
+        for _ in range(5):
+            entered, done = threading.Event(), threading.Event()
+
+            def helper():
+                entered.set()
+                store_mod._make_spare(64 << 20)
+                done.set()
+
+            thread = threading.Thread(target=helper)
+            thread.start()
+            entered.wait(60)            # back here once the helper lets go
+            inside = not done.is_set()
+            thread.join(60)
+            assert not thread.is_alive()
+            if inside:
+                break
+    finally:
+        sys.setswitchinterval(interval)
+    assert inside
+
+
+def test_takers_on_many_threads_never_share_a_spare(small_map_min,
+                                                    monkeypatch):
+    """More threads than cores, each with a store of its own, a switch
+    interval of 10 us: every shard reads back its own bytes, no mapping
+    is two objects', and the bound holds throughout."""
+    pool = store_mod._Pool(bound=64 << 10)
+    monkeypatch.setattr(store_mod, "_POOL", pool)
+    workers, each = 2 * (os.cpu_count() or 4), 60
+    stores = [MemStore(1 << 24) for _ in range(workers)]
+    over = []
+
+    def work(w):
+        s = stores[w]
+        s.queue_transaction(Transaction().create_collection("c"))
+        for i in range(each):
+            _land(s, "c", f"o{i}", seed=1000 * w + i)
+            if pool.spare_bytes() > pool.bound:
+                over.append((w, i))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(w,))
+                   for w in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        _settle(pool)
+    finally:
+        sys.setswitchinterval(interval)
+        pool.stop()
+    assert not over
+    maps = [m for s in stores for m in _mappings(s)]
+    assert len(set(map(id, maps))) == len(maps) == workers * each
+    for w, s in enumerate(stores):
+        for i in range(0, each, 7):
+            assert s.read_planar("c", f"o{i}") == \
+                _planes(COLS, 1000 * w + i).tobytes()
 
 
 def test_a_mapping_the_kernel_refuses_is_copied_as_before(small_map_min,
@@ -647,6 +1097,20 @@ _METRICS = {
         ("store_populate_ns", "ec_coalesced_ops", 1e-06),
         ({"ec_coalesced_ops": 3000, "store_populate_ns": 13_500_000_000},
          4.5),
+        {"ec_coalesced_ops": 3000}),
+    # PR 45: 6 GB landed of which 5.4 GB in spares of the pool's; 21 s
+    # inside the refill thread's map-and-touch calls over 3000 ops
+    "store_pooled_share.write": (
+        ("store_planar_pooled_bytes", "store_planar_write_bytes", 100),
+        ({"store_planar_write_bytes": 6_000_000_000,
+          "store_planar_populated_bytes": 6_000_000_000,
+          "store_planar_pooled_bytes": 5_400_000_000}, 90.0),
+        {"store_planar_write_bytes": 1_000_000,
+         "store_planar_populated_bytes": 1_000_000}),
+    "store_pool_touch_ms_per_op.write": (
+        ("store_pool_touch_ns", "ec_coalesced_ops", 1e-06),
+        ({"ec_coalesced_ops": 3000, "store_pool_touch_ns": 21_000_000_000},
+         7.0),
         {"ec_coalesced_ops": 3000}),
 }
 
