@@ -18,7 +18,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from . import layers, xplane
-from .loader import Cell
+from .loader import BenchmarkError, Cell
 from .plan import Op, Plan
 from .stats import percentile
 
@@ -34,6 +34,7 @@ HOST_ENGINE_COUNTERS = ("ec_host_matmul_calls",
 # the window's end the run is refused by name, before the kernel ends it
 HOST_MEM_MIN_GIB = 4.0
 ERRORS_KEPT = 5         # of CellRun.errors, in the result's line
+SETTLE_S = 30.0         # a set-up kill has to settle inside this, or the run ends
 # end-to-end metric prefix -> op kind
 E2E_KINDS = {"write": "write_full", "read": "read"}
 
@@ -135,6 +136,9 @@ class CellRun:
         self.reference: Dict[str, Tuple[int, int]] = {}
         self.records: List[Record] = []
         self.errors: List[str] = []
+        self.window: List[Record] = []  # the records completed in the window
+        self.read_mismatches = 0    # reads of the loop that came back wrong
+        self.victims: List[int] = []            # killed in set-up
         self.compiles = CompileWatch()
 
     def expected(self, name: str) -> bytes:
@@ -147,17 +151,19 @@ class CellRun:
         await io.write_full(op.name, self.pool[op.size][op.payload])
         self.reference[op.name] = (op.size, op.payload)
 
-    async def _warm_up(self, io) -> None:
-        """Meet the shape buckets the window will meet: bursts of 1, 2, 4
-        ... ``callers`` objects of each size in flight (a tick coalesces
-        what arrives together and pads to a power of two)."""
+    def _burst_widths(self) -> List[int]:
         widths, w = [], 1
         while w < self.plan.callers:
             widths.append(w)
             w *= 2
-        widths.append(self.plan.callers)
+        return widths + [self.plan.callers]
+
+    async def _warm_up(self, io) -> None:
+        """Meet the shape buckets the window will meet: bursts of 1, 2, 4
+        ... ``callers`` objects of each size in flight (a tick coalesces
+        what arrives together and pads to a power of two)."""
         for size in self.plan.sizes:
-            for w in widths:
+            for w in self._burst_widths():
                 t0, c0 = time.monotonic(), self.compiles.n
                 await _in_flight(w, [
                     (lambda i=i: io.write_full(f"warm_{size}_{w}_{i}",
@@ -179,6 +185,125 @@ class CellRun:
                  bytes=sum(op.size for op in populated),
                  seconds=time.monotonic() - t0)
 
+    # -------------------------------------------- a pool degraded in set-up
+
+    async def _kill_in_set_up(self, cluster, client, pool_id: int) -> None:
+        """Kill the traffic file's victims and wait until the pool serves
+        degraded: the mon marks them down, every surviving OSD and the
+        client hold that epoch, and every PG has a surviving primary that
+        has peered the new interval and whose commit watermark covers its
+        log (until then a read gathers a second round, or waits).  All of
+        it inside ``SETTLE_S``, or the run ends: never a hang."""
+        k = int(self.cell.config["k"])
+        up, _primary = cluster.mon.osdmap.pool_mapping(pool_id)
+        held = [int((np.asarray(up)[:, :k] == o).sum())
+                for o in range(len(cluster.osds))]
+        self.victims = self.plan.victims(held)
+        loop, t0 = asyncio.get_event_loop(), time.monotonic()
+        deadline = loop.time() + SETTLE_S
+        try:
+            for v in self.victims:
+                await cluster.kill_osd(v)
+            for v in self.victims:
+                await cluster.wait_down(v, timeout=deadline - loop.time())
+            t_down = time.monotonic()
+            epoch = cluster.mon.osdmap.epoch
+            await cluster.wait_for_epoch(epoch,
+                                         timeout=deadline - loop.time())
+            pg_num = int(self.cell.config["pg_num"])
+            while True:
+                pgs = [st for osd in cluster.osds.values()
+                       for st in osd.pgs.values()
+                       if st.pgid.pool == pool_id
+                       and st.primary == osd.osd_id]
+                unsettled = [str(st.pgid) for st in pgs
+                             if any(v in st.acting for v in self.victims)
+                             or st.last_complete < st.last_update]
+                if client.objecter.osdmap.epoch >= epoch \
+                        and len(pgs) == pg_num and not unsettled:
+                    break
+                if loop.time() > deadline:
+                    raise TimeoutError(
+                        f"client epoch {client.objecter.osdmap.epoch} of "
+                        f"{epoch}, {len(pgs)} of {pg_num} PGs have a "
+                        f"primary, unsettled {unsettled}")
+                await asyncio.sleep(0.02)
+        except TimeoutError as exc:
+            raise BenchmarkError(
+                f"set-up kill of osd {self.victims} did not settle in "
+                f"{SETTLE_S:g} s: {exc}") from None
+        self.say(step="kill_in_set_up", victims=self.victims,
+                 rule=self.plan.victim_rule, data_shard_pgs=held,
+                 epoch=epoch, down_s=t_down - t0,
+                 seconds=time.monotonic() - t0)
+
+    async def _warm_reads(self, io) -> None:
+        """Meet the decode's programs before the loop does.  Read bursts of
+        1, 2, 4 ... ``callers`` populated objects in flight warm the read
+        path as the program serves it.  A decode tick has no shape bucket
+        (``ec/stripe.py::decode_planes_multi`` multiplies as many columns
+        as its reads have), so a tick of every size from 1 to ``callers``
+        is a program of its own, and which sizes a burst makes is the
+        batcher's timing: the kernel's entry point is watched during the
+        bursts (passed through untouched), and every multiple of the
+        one-object call it saw is then run once on zeros, uncounted."""
+        from ceph_tpu.ops import gf8
+        from ceph_tpu.utils.perf import KERNELS
+
+        # (matrix shape, plane rows) -> (a matrix, the fewest columns seen:
+        # the first burst is one read, so one object's)
+        seen: Dict[tuple, tuple] = {}
+        sound = gf8.planar_matmul
+
+        def watched(bitmat, planes):
+            key = (tuple(bitmat.shape), int(planes.shape[0]))
+            cols = int(planes.shape[1])
+            if key not in seen or cols < seen[key][1]:
+                seen[key] = (bitmat, cols)
+            return sound(bitmat, planes)
+
+        names = [op.name for op in self.plan.populated]
+        at = 0
+        gf8.planar_matmul = watched
+        try:
+            for w in self._burst_widths():
+                t0, c0, k0 = time.monotonic(), self.compiles.n, len(seen)
+                burst = [names[(at + i) % len(names)] for i in range(w)]
+                at += w
+                bad, raised = await self._compare_reads(io, burst,
+                                                        "warm_read", w)
+                self.read_mismatches += bad
+                self.say(step="warm_read", in_flight=w, bad=bad,
+                         raised=raised, seconds=time.monotonic() - t0,
+                         compiles=self.compiles.n - c0,
+                         decode_shapes_seen=len(seen) - k0)
+        finally:
+            gf8.planar_matmul = sound
+        if not seen:
+            raise BenchmarkError("no warm-up read reached the device's "
+                                 "decode: the pool is not degraded")
+        t0, c0 = time.monotonic(), self.compiles.n
+        loop = asyncio.get_event_loop()
+
+        def every_tick_size() -> int:
+            import jax
+
+            n_programs = 0
+            with KERNELS.muted():
+                for (_shape, rows), (bitmat, cols) in seen.items():
+                    for n in range(1, self.plan.callers + 1):
+                        jax.block_until_ready(sound(
+                            bitmat, np.zeros((rows, n * cols), np.uint8)))
+                        n_programs += 1
+            return n_programs
+
+        n_programs = await loop.run_in_executor(None, every_tick_size)
+        self.say(step="warm_decode_ticks", programs=n_programs,
+                 one_object_columns={f"{m[0]}x{m[1]}": cols for (m, _r),
+                                     (_b, cols) in seen.items()},
+                 seconds=time.monotonic() - t0,
+                 compiles=self.compiles.n - c0)
+
     # ---------------------------------------------------------- the loop
 
     async def _caller(self, io, c: int, stop: asyncio.Event) -> None:
@@ -186,14 +311,15 @@ class CellRun:
         while not stop.is_set():
             op = self.plan.op(c, i)
             i += 1
+            data = None
             t0 = time.perf_counter()
             try:
                 if op.kind == "write_full":
                     await self._write(io, op)
                     ok = True
                 else:
-                    # no compare inside the window (rados bench rand)
-                    ok = len(await io.read(op.name)) == op.size
+                    data = await io.read(op.name)
+                    ok = len(data) == op.size
             except asyncio.CancelledError:
                 raise
             except Exception as exc:    # a failed op is a result, counted
@@ -201,6 +327,14 @@ class CellRun:
                 self.errors.append(f"{op.kind} {op.name}: {exc!r}")
             self.records.append((t0, time.perf_counter(), op.kind,
                                  op.size, ok))
+            # every read is compared with the reference once its latency
+            # is stamped (rados bench rand verifies what it reads unless
+            # --no-verify): the timed path's own output decides `correct`
+            if data is not None and data != self.expected(op.name):
+                self.read_mismatches += 1
+                if len(self.errors) < ERRORS_KEPT:
+                    self.errors.append(f"read {op.name}: came back and "
+                                       "differs from the reference")
 
     async def _profile_slice(self, loop) -> Tuple[float, Dict[str, float]]:
         """Profile a slice in the middle of the window, Python and host
@@ -242,8 +376,8 @@ class CellRun:
 
     # ------------------------------------------------------------ verify
 
-    async def _read_back(self, io, names: List[str], label: str
-                         ) -> Tuple[int, int]:
+    async def _compare_reads(self, io, names: List[str], label: str,
+                             in_flight: int) -> Tuple[int, int]:
         """Read ``names`` and compare with the reference; returns how
         many differ, are missing or fail, and how many of those RAISED
         (the rest came back and differed)."""
@@ -256,23 +390,39 @@ class CellRun:
                 self.errors.append(f"{label} read {name}: {exc!r}")
                 return None
 
-        t0 = time.monotonic()
-        good = await _in_flight(self.plan.callers,
+        good = await _in_flight(in_flight,
                                 [(lambda n=n: check(n)) for n in names])
-        bad = sum(1 for g in good if not g)
-        raised = sum(1 for g in good if g is None)
+        return (sum(1 for g in good if not g),
+                sum(1 for g in good if g is None))
+
+    async def _read_back(self, io, names: List[str], label: str
+                         ) -> Tuple[int, int]:
+        t0 = time.monotonic()
+        bad, raised = await self._compare_reads(io, names, label,
+                                                self.plan.callers)
         self.say(step=label, objects=len(names), bad=bad, raised=raised,
                  seconds=time.monotonic() - t0)
         return bad, raised
 
+    def _samples(self, window_names: List[str], n_osds: int):
+        """(names the reference holds, healthy sample, degraded sample, the
+        OSD the verification kills where set-up killed none)."""
+        names = window_names or [op.name for op in self.plan.populated]
+        return (names,) + verification_plan(self.seed, names, n_osds)
+
     async def _verify(self, cluster, io, window_names: List[str],
                       run_before: Dict[str, float],
-                      window_grew: Dict[str, float]) -> Tuple[list, int]:
+                      window_grew: Dict[str, float],
+                      healthy_in_set_up: Optional[Tuple[int, int]] = None
+                      ) -> Tuple[list, int]:
         """Returns (checks, objects read).  A check is
-        ``{"name", "value", "limit", "rule", "ok"}``."""
-        names = window_names or [op.name for op in self.plan.populated]
-        healthy, degraded, victim = verification_plan(
-            self.seed, names, len(cluster.osds))
+        ``{"name", "value", "limit", "rule", "ok"}``.  A cell whose set-up
+        killed its victims read the healthy sample then, while the pool
+        was whole (``healthy_in_set_up``: bad, raised), and kills no
+        second holder here: the degraded sample is read with the set-up's
+        victims down."""
+        names, healthy, degraded, victim = self._samples(
+            window_names, len(cluster.osds) + len(self.victims))
         checks = []
 
         def check(name, value, rule, limit):
@@ -296,16 +446,18 @@ class CellRun:
               "max", cluster.config.mon_osd_nearfull_ratio)
         check("host_mem_available_gib", available, "min", HOST_MEM_MIN_GIB)
         check("objects_to_verify", len(names), "min", 1)
-        bad, raised = await self._read_back(io, healthy, "verify_healthy")
+        bad, raised = healthy_in_set_up or \
+            await self._read_back(io, healthy, "verify_healthy")
         check("healthy_mismatches", bad, "max", 0)
         check("healthy_read_errors", raised, "max", 0)
 
         before = kernel_counters()
-        t0 = time.monotonic()
-        await cluster.kill_osd(victim)
-        await cluster.wait_down(victim)
-        self.say(step="kill_osd", victim=victim,
-                 seconds=time.monotonic() - t0)
+        if not self.victims:
+            t0 = time.monotonic()
+            await cluster.kill_osd(victim)
+            await cluster.wait_down(victim)
+            self.say(step="kill_osd", victim=victim,
+                     seconds=time.monotonic() - t0)
         bad, raised = await self._read_back(io, degraded, "verify_degraded")
         check("degraded_mismatches", bad, "max", 0)
         check("degraded_read_errors", raised, "max", 0)
@@ -330,6 +482,19 @@ class CellRun:
             check("window_matmul_bytes",
                   window_grew.get("planar_matmul_bytes", 0), "min",
                   written // 2)
+        if any(r[2] == "read" for r in self.records):
+            # every read of the warm-up bursts, the lead-in and the window
+            # was compared when it came back; a window that reads a
+            # degraded pool has to have decoded on the device
+            check("window_read_mismatches", self.read_mismatches, "max", 0)
+            if self.victims:
+                check("window_decode_ticks",
+                      window_grew.get("ec_coalesced_read_ticks", 0),
+                      "min", 1)
+                check("window_decoded_reads",
+                      window_grew.get("ec_coalesced_reads", 0), "min",
+                      sum(1 for r in self.window
+                          if r[2] == "read" and r[4]) // 4)
         return checks, len(healthy) + len(degraded)
 
     # -------------------------------------------------------- reductions
@@ -423,6 +588,15 @@ class CellRun:
                      seconds=time.monotonic() - t0)
             await self._warm_up(io)
             await self._populate(io)
+            healthy_in_set_up = None
+            if self.plan.kill_shard_holders:
+                # the healthy sample has to be read while the pool is whole
+                _names, healthy, _degraded, _victim = self._samples(
+                    [], len(cluster.osds))
+                healthy_in_set_up = await self._read_back(
+                    io, healthy, "verify_healthy")
+                await self._kill_in_set_up(cluster, client, pool_id)
+                await self._warm_reads(io)
 
             stop = asyncio.Event()
             callers = [asyncio.ensure_future(self._caller(io, c, stop))
@@ -459,14 +633,16 @@ class CellRun:
                     t.cancel()
             drain_s = time.perf_counter() - w1 - late_s
 
-            window = [r for r in self.records if w0 <= r[1] <= w1]
+            self.window = window = [r for r in self.records
+                                    if w0 <= r[1] <= w1]
             attribution = await self._attribution(cluster, client, window) \
                 if self.trace else {}
             window_names = [n for n in self.reference
                             if n.startswith("obj_")]
             t0 = time.monotonic()
             checks, verified = await self._verify(
-                cluster, io, window_names, run_before, window_grew)
+                cluster, io, window_names, run_before, window_grew,
+                healthy_in_set_up)
             verify_s = time.monotonic() - t0
         except BaseException as exc:
             # leave evidence before the traceback: which daemons the mon

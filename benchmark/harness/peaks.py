@@ -46,7 +46,28 @@ def planar_matmul_cost(config: dict, input_bytes: float
     return ops, moved
 
 
-COST_FUNCTIONS = {"planar_matmul_encode": planar_matmul_cost}
+def planar_matmul_decode_cost(config: dict, input_bytes: float
+                              ) -> Tuple[float, float]:
+    """(int8 operations, HBM bytes) the planar GF(2) DECODE matmul needs
+    to rebuild ONE lost chunk from ``input_bytes`` of source planes.
+
+    The input is a (kw, npk) array of the planes of the k chunks the
+    decode reads, kw = 8*k bit-rows; the recovery bit-matrix is (w, kw):
+    the w bit-rows of the one chunk rebuilt.  2*w*kw*8*npk =
+    2*w*8*input_bytes operations; read the input once and write 1/k of
+    it: input_bytes * (k + 1) / k bytes.  It counts what the algorithm
+    needs, not what a kernel does, so that a later kernel is read on the
+    same work.  (A code that rebuilds from fewer than k chunks needs a
+    cost function of its own: SHEC, LRC's local groups.)
+    """
+    w, k = int(config["gf_word_bits"]), int(config["k"])
+    ops = 2.0 * w * 8 * input_bytes
+    moved = input_bytes * (k + 1) / k
+    return ops, moved
+
+
+COST_FUNCTIONS = {"planar_matmul_encode": planar_matmul_cost,
+                  "planar_matmul_decode": planar_matmul_decode_cost}
 
 
 def roofline_share(device_kind: str, ops: float, moved_bytes: float,

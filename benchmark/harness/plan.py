@@ -20,6 +20,19 @@ A traffic file (``traffic/<name>.json``):
                       sends one of them (never a constant byte)
     lead_in_s         closed loop run before the window opens (set-up):
                       the window starts with every caller busy
+    set_up            events of set-up, after the populated set is written
+                      and its healthy sample compared, before the loop
+                      starts (a key the generator does not know is refused
+                      by name):
+                        kill_shard_holders  n: that many OSDs are killed and
+                                            stay down for the rest of the run
+                        victim              how they are chosen: "seed"
+                                            (drawn from ``--seed``, as the
+                                            verification draws its own) or
+                                            "most_data_shards" (the OSDs that
+                                            hold a data shard in most PGs of
+                                            the pool, lowest id first: the
+                                            same work on every seed)
 """
 
 from __future__ import annotations
@@ -32,6 +45,8 @@ import numpy as np
 from .loader import BenchmarkError
 
 OPS = ("write_full", "read")
+SET_UP_KEYS = ("kill_shard_holders", "victim")
+VICTIM_RULES = ("seed", "most_data_shards")
 _BLOCK = 1024       # ops generated at a time per caller
 
 
@@ -83,6 +98,22 @@ class Plan:
         else:
             raise BenchmarkError(f"traffic keys {keys!r}: \"uniform\" or "
                                  "\"zipf\"")
+        set_up = traffic.get("set_up", {})
+        unknown = set(set_up) - set(SET_UP_KEYS)
+        if unknown:
+            raise BenchmarkError(f"traffic set_up {sorted(unknown)}: the "
+                                 f"generator knows {list(SET_UP_KEYS)}")
+        self.kill_shard_holders = int(set_up.get("kill_shard_holders", 0))
+        self.victim_rule = set_up.get("victim", "seed")
+        if self.kill_shard_holders < 0 or \
+                self.victim_rule not in VICTIM_RULES:
+            raise BenchmarkError(
+                f"traffic set_up {set_up}: kill_shard_holders >= 0 and "
+                f"victim one of {list(VICTIM_RULES)}")
+        if self.kill_shard_holders and not self.n_populate:
+            raise BenchmarkError("a set_up that kills shard holders needs "
+                                 "populate_objects > 0: the degraded pool "
+                                 "has to hold something")
         self._blocks: Dict[Tuple[int, int], tuple] = {}
         self.populated = self._populate_ops()
 
@@ -107,6 +138,20 @@ class Plan:
         size_i, payload = self._draw(rng, self.n_populate)
         return [Op("write_full", f"pop_{i:06d}", self.sizes[size_i[i]],
                    int(payload[i])) for i in range(self.n_populate)]
+
+    def victims(self, data_shards: List[int]) -> List[int]:
+        """The OSDs set-up kills.  ``data_shards[o]`` is the number of the
+        pool's PGs in which OSD ``o`` holds a data shard (the cell reads it
+        from the map before the kill)."""
+        n_osds = len(data_shards)
+        if self.kill_shard_holders >= n_osds:
+            raise BenchmarkError(f"set_up kills {self.kill_shard_holders} "
+                                 f"of {n_osds} OSDs")
+        if self.victim_rule == "most_data_shards":
+            order = sorted(range(n_osds), key=lambda o: (-data_shards[o], o))
+        else:
+            order = np.random.default_rng([self.seed, 4]).permutation(n_osds)
+        return [int(o) for o in order[:self.kill_shard_holders]]
 
     # ------------------------------------------------------------- ops
 

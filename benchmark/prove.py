@@ -107,6 +107,15 @@ def main(argv=None) -> int:
             room = {k: checks[k]["value"] for k in
                     ("store_fill_peak", "host_mem_available_gib")
                     if k in checks}
+            # what the run said on earlier lines: whom set-up killed (a
+            # cell degraded in set-up), and whether its window compiled
+            for ln in lines:
+                if '"kill_in_set_up"' in ln:
+                    room["victims"] = json.loads(ln)["victims"]
+                elif '"compiles_in_window"' in ln:
+                    room["compiles_in_window"] = \
+                        json.loads(ln)["compiles_in_window"]
+            row["victims"] = room.get("victims")
             not_ok = {} if res.get("correct", True) else {
                 "checks": checks, "errors": res.get("errors")}
             print(json.dumps({"tag": tag, "rc": rc,
@@ -140,6 +149,19 @@ def main(argv=None) -> int:
                     "min": min(vals), "max": max(vals),
                     "iqr_share": spread(vals) if len(vals) >= 3 else None}),
                     flush=True)
+    # a cell degraded in set-up: its end-to-end metrics by victim
+    by_victim = {}
+    for r in rows:
+        if r.get("victims") and r["result"] and not args.trace:
+            by_victim.setdefault(str(r["victims"]), []).append(r)
+    for victims, runs in sorted(by_victim.items()):
+        for n in sorted({n for r in runs for n in r["result"]["metrics"]}):
+            vals = [r["result"]["metrics"][n]["value"] for r in runs]
+            print(json.dumps({"victims": victims, "metric": n,
+                              "n": len(vals),
+                              "median": statistics.median(vals),
+                              "min": min(vals), "max": max(vals)}),
+                  flush=True)
     want = not args.control
     bad = [r["tag"] for r in rows if r["rc"] != 0 or r["result"] is None
            or r["result"].get("correct") is not want]

@@ -17,7 +17,8 @@ GIB = 1 << 30
 # what each deployment's OSD held before PR 42, and the chip host the new
 # sizes were set against (MemTotal as benchmark/host_touch.py read it)
 DEVICE_GIB_BEFORE = {"rados_k2m1_3osd": 8, "rados_k4m2_8osd": 3,
-                     "rados_isa_k8m4_12osd": 2, "rados_lrc_k4m2l3_8osd": 4}
+                     "rados_isa_k8m4_12osd": 2, "rados_lrc_k4m2l3_8osd": 4,
+                     "rados_shec_k6m4c3_10osd": 3.5}    # PR 44's first size
 CHIP_HOST_MEMTOTAL_GIB = 45.0
 
 

@@ -30,6 +30,40 @@ def test_every_cell_of_the_benchmark_loads():
             assert reader["kind"] in layers.KINDS, name
 
 
+def test_the_pending_cell_becomes_a_cell_by_appending_its_entries(tmp_path):
+    """``pending/apply.py``: the accepted entries stay as they are, letter
+    for letter, the pending ones come after them, and the cell loads with
+    its traffic's set-up event, two read metrics and twelve readers.  As
+    committed, BENCHMARK.json lists the six cells tier-1 pins."""
+    from benchmark.pending import apply
+
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        spec = json.load(f)
+    assert len(spec["workloads"]) == 6
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    os.symlink(BENCH_DIR, tmp_path / "benchmark")
+    assert apply.main(["--root", str(tmp_path), "--keep"]) == 0
+    after = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    for key in apply.KEYS:
+        assert after[key][:len(spec[key])] == spec[key]
+    assert {k: v for k, v in after.items() if k not in apply.KEYS} == \
+        {k: v for k, v in spec.items() if k not in apply.KEYS}
+    cell = load_cell("k2m1_degraded_randread_4m_t16", root=str(tmp_path))
+    assert (cell.config_name, cell.traffic_name, cell.chips) == \
+        ("rados_k2m1_3osd", "degraded_randread_4m_t16", 1)
+    assert cell.traffic["set_up"] == {"kill_shard_holders": 1,
+                                      "victim": "most_data_shards"}
+    assert cell.end_to_end == ["setup_s", "read_MBps", "read_p95_ms"]
+    assert len(cell.per_layer) == 12 and \
+        all(n.endswith(".read") for n in cell.per_layer)
+    bounds = {m["name"]: m["bound"] for m in after["end_to_end"]}
+    assert bounds["read_MBps"] == bounds["read_p95_ms"] == 0.25
+    assert len(after["workloads"][-1]["why"]) <= 200
+    Plan(cell.traffic, 2**31 + 5)
+    with pytest.raises(ValueError, match="entries already"):
+        apply.main(["--root", str(tmp_path), "--keep"])
+
+
 def test_new_cell_config_traffic_and_metric_are_files_only(tmp_path):
     """A later PR adds a cell by adding files and entries: nothing under
     harness/ is edited (it is not even copied here)."""
@@ -146,6 +180,113 @@ def test_plan_refuses_open_loop_and_unknown_ops():
         Plan({**MIX, "populate_objects": 0}, 1)
 
 
+def test_plan_refuses_a_set_up_key_it_does_not_know_by_name():
+    with pytest.raises(BenchmarkError, match=r"\['mark_out'\].*knows"):
+        Plan({**MIX, "set_up": {"kill_shard_holders": 1, "mark_out": 1}}, 1)
+    with pytest.raises(BenchmarkError, match="victim one of"):
+        Plan({**MIX, "set_up": {"kill_shard_holders": 1,
+                                "victim": "the_slowest"}}, 1)
+    with pytest.raises(BenchmarkError, match="populate_objects"):
+        Plan({**MIX, "ops": {"write_full": 1}, "populate_objects": 0,
+              "set_up": {"kill_shard_holders": 1}}, 1)
+    assert Plan(MIX, 1).kill_shard_holders == 0     # no event, no kill
+
+
+def test_set_up_victims_by_the_seed_and_by_the_map():
+    held = [4, 5, 7]        # rados_k2m1_3osd: PGs an OSD holds data in
+    by_seed = {"kill_shard_holders": 1}
+    drawn = [Plan({**MIX, "set_up": by_seed}, s).victims(held)
+             for s in range(1, 12)]
+    assert drawn[:6] == [[0], [1], [0], [0], [1], [2]]
+    assert {v[0] for v in drawn} == {0, 1, 2}
+    big = 2**31 + 12345
+    assert Plan({**MIX, "set_up": by_seed}, big).victims(held) == \
+        Plan({**MIX, "set_up": by_seed}, big).victims(held)
+    by_map = {"kill_shard_holders": 2, "victim": "most_data_shards"}
+    for seed in (1, 2, big):
+        assert Plan({**MIX, "set_up": by_map}, seed).victims(held) == [2, 1]
+        assert Plan({**MIX, "set_up": by_map}, seed).victims([3, 3, 3]) \
+            == [0, 1]                               # a tie: lowest id
+    with pytest.raises(BenchmarkError, match="kills 3 of 3"):
+        Plan({**MIX, "set_up": {"kill_shard_holders": 3}}, 1).victims(held)
+    # the set-up's draw is a stream of its own: the ops do not move
+    assert _ops(Plan({**MIX, "set_up": by_seed}, 9), 50) == \
+        _ops(Plan(MIX, 9), 50)
+
+
+# what the parent's generator (PR 45's tree, before `set_up` existed) drew
+# for seed 2147520371: callers 0 and 11, ops 0..63 (payload indices as hex
+# digits, a digest of the names), the populated set's payloads, two buffers
+PINNED_SEED = 2147520371
+PINNED = {
+    "write_4m_t16": {
+        0: ("write_full", "obj_c00_0000000", 12, "obj_c00_0000063", 15,
+            "c40d5d4f1bf02d661933048266e29f591907fc1e61b98ce9b966a5046964b5ef",
+            "136849cde9ee4fc7"),
+        11: ("write_full", "obj_c11_0000000", 7, "obj_c11_0000063", 2,
+             "765afc5f6260ccbf7d4b21cf0a400dd35ef489ea7b9ff009d1f804f039f21c82",
+             "491625fd8378aec3"),
+        "populated": "", "pool": ("546fb7078b5b46c4", "e9b8c6a4bbc0d51a")},
+    "write_64k_t16": {
+        0: ("write_full", "obj_c00_0000000", 12, "obj_c00_0000063", 15,
+            "c40d5d4f1bf02d661933048266e29f591907fc1e61b98ce9b966a5046964b5ef",
+            "136849cde9ee4fc7"),
+        11: ("write_full", "obj_c11_0000000", 7, "obj_c11_0000063", 2,
+             "765afc5f6260ccbf7d4b21cf0a400dd35ef489ea7b9ff009d1f804f039f21c82",
+             "491625fd8378aec3"),
+        "populated": "", "pool": ("3f2bab8c8adf15a7", "d592de801ebcb5b5")},
+    "randread_4m_t16": {
+        0: ("read", "pop_000060", 0, "pop_000024", 1,
+            "0e4f3d5e7a6485da9a487e0ea46b454b663446f23336b699d982a35697b4ea71",
+            "8b74b4cc727d5779"),
+        11: ("read", "pop_000052", 10, "pop_000004", 13,
+             "ae49ad706a810f0100af70734ea45476b0f380a0e5154132c03472bdc362b03d",
+             "64b880bd4e26589d"),
+        "populated":
+            "3da5daa745d2744a6b5edb1413c57a9e8f6a46642b07246e60a3a69f07100ff8",
+        "pool": ("546fb7078b5b46c4", "e9b8c6a4bbc0d51a")},
+}
+
+
+def _digest(data: bytes) -> str:
+    import hashlib
+    return hashlib.sha256(data).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("traffic", sorted(PINNED))
+def test_the_accepted_traffic_files_draw_what_the_parent_drew(traffic):
+    with open(os.path.join(BENCH_DIR, "traffic", traffic + ".json"),
+              encoding="utf-8") as f:
+        plan = Plan(json.load(f), PINNED_SEED)
+    want = PINNED[traffic]
+    size = plan.sizes[0]
+    for caller in (0, 11):
+        kind, first, p_first, last, p_last, payloads, names = want[caller]
+        ops = [plan.op(caller, i) for i in range(64)]
+        assert {o.kind for o in ops} == {kind}
+        assert {o.size for o in ops} == {size}
+        assert (ops[0].name, ops[0].payload) == (first, p_first)
+        assert (ops[63].name, ops[63].payload) == (last, p_last)
+        assert "".join("%x" % o.payload for o in ops) == payloads
+        assert _digest(" ".join(o.name for o in ops).encode()) == names
+    assert "".join("%x" % o.payload for o in plan.populated[:64]) == \
+        want["populated"]
+    pool = plan.payload_pool()[size]
+    assert (_digest(pool[0]), _digest(pool[15])) == want["pool"]
+
+
+def test_the_verification_draws_what_the_parent_drew():
+    from benchmark.harness.cell import verification_plan
+
+    names = [f"obj_c{c:02d}_{i:07d}" for c in range(16) for i in range(40)]
+    healthy, degraded, victim = verification_plan(PINNED_SEED, names, 3)
+    assert (healthy[:3], degraded[:3], victim) == (
+        ["obj_c04_0000002", "obj_c08_0000016", "obj_c07_0000038"],
+        ["obj_c11_0000011", "obj_c09_0000023", "obj_c07_0000026"], 2)
+    assert _digest(" ".join(healthy + degraded).encode()) == \
+        "16bc398498ae4ee3"
+
+
 # -------------------------------------------------------------- percentile
 
 def test_percentile_on_known_samples():
@@ -180,6 +321,23 @@ def test_planar_cost_on_the_hand_worked_call():
     ops, moved = peaks.planar_matmul_cost(K4M2, 4 * mib)
     assert moved == 6 * mib
     assert ops / (4 * mib) == 256
+
+
+def test_planar_decode_cost_on_the_hand_worked_call():
+    # k2m1, one 4 MiB object rebuilt from 1 data + 1 parity shard: the
+    # call takes 4 MiB of source planes (kw 16), writes the 8 bit-rows of
+    # the one lost chunk (2 MiB): 6 MiB moved, 2*8*16*8*(4 MiB/16) ops
+    mib = 1 << 20
+    ops, moved = peaks.planar_matmul_decode_cost(K2M1, 4 * mib)
+    assert moved == 6 * mib
+    assert ops == 2 * 8 * 16 * 8 * (4 * mib // 16)
+    # k4m2: four chunks in, one out: 5/4 of the input moved, 8 output rows
+    # whatever m is (the encode writes m chunks: 256 ops a byte)
+    ops, moved = peaks.planar_matmul_decode_cost(K4M2, 4 * mib)
+    assert moved == 5 * mib
+    assert ops / (4 * mib) == 128
+    assert peaks.COST_FUNCTIONS["planar_matmul_decode"] is \
+        peaks.planar_matmul_decode_cost
 
 
 def test_roofline_share_and_its_bound():
@@ -249,6 +407,13 @@ def test_trace_readers_on_the_recorded_slice(recorded):
     # 36 * 6 MiB moved at 819 GB/s = 0.2765 ms, over 1.8 ms of kernels
     assert roof == pytest.approx(100 * 0.0002765 / 0.0018, rel=0.01)
     assert 0 < roof < 100
+    # the read cell's file on the same programs: at k2m1 a decode of one
+    # lost chunk needs what the encode needs (6 MiB moved a 4 MiB call)
+    with open(os.path.join(BENCH_DIR, "layer_metrics",
+                           "planar_roofline.read.json"),
+              encoding="utf-8") as f:
+        assert layers.read_metric("planar_roofline.read", json.load(f),
+                                  r) == pytest.approx(roof)
     per_call = layers.read_metric("p", {
         "kind": "trace_program_ms", "line": "XLA Modules",
         "patterns": ["jit__batch_to_planes_bitpack"]}, r)
