@@ -187,17 +187,21 @@ class ECBackendMixin:
             "stripe_unit", self.config.osd_ec_stripe_unit))
         return StripeInfo(codec.get_data_chunk_count(), unit)
 
-    def _planar_mode(self, codec, sinfo) -> bool:
-        """Bit-planar AT-REST gate (round 19): config on AND the codec/
-        stripe geometry supports conversion-free plane-domain compute
-        (w=8 matrix codec, unit % 8 == 0).  Unsupported geometries
-        quietly stay byte-at-rest — the gate never changes what bytes a
-        client sees, only how shards are laid out."""
+    def _planar_layout(self, codec, sinfo) -> Optional[str]:
+        """Bit-planar AT-REST gate (round 19), answered by name: the
+        tag of the serialization the pool's shards rest in
+        (``ec/planar_store.py``: bit-planes, or a packet-interleaved
+        code's packet rows) when the config is on AND the codec/stripe
+        geometry supports conversion-free plane-domain compute
+        (``ec.stripe.at_rest_layout``); None = byte-at-rest.
+        Unsupported geometries quietly stay byte-at-rest — the gate
+        never changes what bytes a client sees, only how shards are
+        laid out."""
         if not self.config.osd_ec_planar_at_rest:
-            return False
+            return None
         from ceph_tpu.ec import stripe as stripemod
 
-        return stripemod.planar_at_rest_ok(codec, sinfo.chunk_size)
+        return stripemod.at_rest_layout(codec, sinfo.chunk_size)
 
     # ----------------------------------------------------------- EC backend
     #
@@ -562,15 +566,16 @@ class ECBackendMixin:
         awaiting its tick) and ``batch_encode`` (its amortized share of
         the coalesced dispatch).
 
-        Planar at rest: when ``_planar_mode`` holds, the tick runs
+        Planar at rest: when ``_planar_layout`` names one, the tick runs
         ``encode_planes_multi`` and the returned shards are (n, 8,
-        cols) AT-REST plane matrices with plane-major crcs —
-        layout == "planar8" tells the commit path to land and ship
-        them as planes (store txn write_planar, wire layout field)."""
+        cols) AT-REST plane matrices with plane-major crcs — the
+        layout tag (the pool's serialization) tells the commit path to
+        land and ship them as planes (store txn write_planar, wire
+        layout field)."""
         from ceph_tpu.cluster.optracker import CURRENT_OP, mark_current
 
-        planar = self._planar_mode(codec, sinfo)
-        layout = planar_store.LAYOUT_PLANAR if planar else None
+        layout = self._planar_layout(codec, sinfo)
+        planar = layout is not None
         mark_current("batch_parked")
         shards, crcs, (t0, t1, batch_n) = \
             await self._ec_batcher.encode(codec, sinfo, data,
@@ -598,11 +603,11 @@ class ECBackendMixin:
         the crc is CUMULATIVE for appends/full rewrites — no whole-shard
         re-read on the hot path — and data+crc can never disagree).
 
-        ``layout`` == "planar8" routes to the planar-at-rest twin: the
+        A planar ``layout`` tag routes to the planar-at-rest twin: the
         payload is a plane window, not shard bytes (round 19)."""
-        if layout == planar_store.LAYOUT_PLANAR:
+        if planar_store.is_planar(layout):
             self._apply_shard_planar(pgid, oid, shard, data, chunk_off,
-                                     shard_size, hinfo, pre_ops)
+                                     shard_size, hinfo, pre_ops, layout)
             return
         coll = _coll(pgid)
         old_size = self.store.stat(coll, oid)
@@ -664,7 +669,9 @@ class ECBackendMixin:
     def _apply_shard_planar(self, pgid: PGid, oid: str, shard: int,
                             data: bytes, chunk_off: int, shard_size: int,
                             hinfo: Dict,
-                            pre_ops: Optional[List[Tuple]] = None) -> None:
+                            pre_ops: Optional[List[Tuple]] = None,
+                            layout: str = planar_store.LAYOUT_PLANAR
+                            ) -> None:
         """Planar-at-rest twin of ``_apply_shard`` (round 19): ``data``
         is an (8, cols) plane window serialized row-major — the SAME
         bytes the encode produced and the wire carried — and it lands
@@ -674,14 +681,17 @@ class ECBackendMixin:
         column-spread identity (ops/crc32c.crc32c_planar_rows), so
         verify-on-read and scrub agree across mixed-layout members."""
         coll = _coll(pgid)
-        Q = planar_store.QUANTUM
+        Q = planar_store.quantum(layout)
+        packetsize = planar_store.packetsize_of(layout)
         if chunk_off % Q or len(data) % Q:
             raise ValueError(f"{oid}: unaligned planar sub-write "
                              f"(off={chunk_off}, len={len(data)})")
         old_size = self.store.stat(coll, oid)
         old_layout = self.store.object_layout(coll, oid)
-        cols = shard_size // Q
-        col_off = chunk_off // Q
+        # columns are bytes / 8 in either serialization; Q is what a
+        # range has to keep to (8, or a packet shard's super-block)
+        cols = shard_size // 8
+        col_off = chunk_off // 8
         # a view of what came (bytes, a frame's memoryview, the tick's
         # planes): needed only to clip and, with no crc shipped, to
         # checksum; ``data`` itself goes to the store, which copies it
@@ -697,7 +707,8 @@ class ECBackendMixin:
             # crc when the primary shipped one; else one host pass here
             crc = hinfo.get("crc")
             if crc is None:
-                crc = crcmod.crc32c_planar_rows(window)[0]
+                crc = crcmod.crc32c_planar_rows(
+                    window, packetsize=packetsize)[0]
         elif old_size is not None and chunk_off == old_size and \
                 shard_size == chunk_off + len(data) and \
                 self.store.getattr(coll, oid, "hinfo_crc") is not None:
@@ -706,7 +717,8 @@ class ECBackendMixin:
             # and the delta crc comes straight off the planes
             stored = int(self.store.getattr(coll, oid, "hinfo_crc"))
             crc = crcmod.crc32c_combine(
-                stored, crcmod.crc32c_planar_rows(window, seed=0)[0],
+                stored, crcmod.crc32c_planar_rows(
+                    window, seed=0, packetsize=packetsize)[0],
                 len(data))
         else:
             # true mid-shard RMW (or no stored crc): splice the window
@@ -714,9 +726,11 @@ class ECBackendMixin:
             # throughout, zero byte-view materializations
             old = None
             if old_size is not None:
-                if old_layout == planar_store.LAYOUT_PLANAR:
-                    old = planar_store.blob_to_planes(
-                        self.store.read_planar(coll, oid))
+                if planar_store.is_planar(old_layout):
+                    # (the other serialization is refused by name)
+                    old = planar_store.planes_as(
+                        self.store.read_planar(coll, oid), old_layout,
+                        layout)
                 else:
                     # byte-at-rest pre-state meeting a planar write: the
                     # one legal relayout hop — the STORE books it when
@@ -724,10 +738,12 @@ class ECBackendMixin:
                     raw = bytes(self.store.read(coll, oid))
                     if len(raw) % Q:
                         raw += b"\0" * (Q - len(raw) % Q)
-                    old = planar_store.shard_to_planes(raw, seam=None)
+                    old = planar_store.shard_to_planes(raw, seam=None,
+                                                       layout=layout)
             merged = planar_store.splice_columns(old, col_off, window,
                                                  cols)
-            crc = crcmod.crc32c_planar_rows(merged)[0]
+            crc = crcmod.crc32c_planar_rows(
+                merged, packetsize=packetsize)[0]
         txn = Transaction()
         if pre_ops:
             txn.ops.extend(tuple(op) for op in pre_ops)
@@ -737,7 +753,7 @@ class ECBackendMixin:
         # restore it without any layout conversion — rec["layout"]
         # tells pg.rewind_divergent_log which restore op to emit
         existed = old_size is not None
-        if existed and old_layout == planar_store.LAYOUT_PLANAR:
+        if existed and planar_store.is_planar(old_layout):
             old_range = self.store.read_planar(coll, oid)
         elif existed:
             old_range = bytes(self.store.read(coll, oid))
@@ -756,7 +772,7 @@ class ECBackendMixin:
                      {self._rb_key(hinfo["version"]): pickle.dumps(rec)})
         # ONE op covers the byte path's write+truncate pair: total_cols
         # pins the final shard extent, so no separate truncate
-        txn.write_planar(coll, oid, col_off, data, cols) \
+        txn.write_planar(coll, oid, col_off, data, cols, layout) \
            .setattr(coll, oid, "shard", str(shard).encode()) \
            .setattr(coll, oid, "size", str(hinfo["size"]).encode()) \
            .setattr(coll, oid, "hinfo_crc", str(crc).encode()) \
@@ -848,9 +864,8 @@ class ECBackendMixin:
         # SHIPPED as its plane matrix — zero layout conversions on this
         # holder (whole-object pulls, shard == -1, stay on bytes: they
         # come from the replicated pull path, which stores bytes)
-        planar = (msg.shard != -1 and
-                  self.store.object_layout(coll, msg.oid)
-                  == planar_store.LAYOUT_PLANAR)
+        at_rest = self.store.object_layout(coll, msg.oid)
+        planar = msg.shard != -1 and planar_store.is_planar(at_rest)
         try:
             full = (self.store.read_planar(coll, msg.oid) if planar
                     else self.store.read(coll, msg.oid))
@@ -873,9 +888,8 @@ class ECBackendMixin:
         # shards verify over plane-major rows via the spread identity,
         # bit-identical to the byte anchor's cumulative crc
         if stored_crc is not None and self.config.osd_ec_verify_reads:
-            [ok] = await self._read_batcher.verify([full],
-                                                   [int(stored_crc)],
-                                                   planar=planar)
+            [ok] = await self._read_batcher.verify(
+                [full], [int(stored_crc)], planar=planar and at_rest)
             if not ok:
                 self.perf.inc("osd_read_shard_crc_errors")
                 await self._reply_osd(conn, msg, M.MOSDECSubOpReadReply(
@@ -883,18 +897,19 @@ class ECBackendMixin:
                 return
         out_layout = None
         if planar:
-            Q = planar_store.QUANTUM
+            Q = planar_store.quantum(at_rest)
             if msg.off % Q == 0 and (msg.length is None
                                      or msg.length % Q == 0):
                 # sub-range by COLUMN slice of the plane matrix — every
-                # chunk-aligned gather lands here (unit % 8 == 0 gates
-                # planar mode, so chunk offsets are always 8-aligned)
+                # chunk-aligned gather lands here (the gate holds the
+                # unit to the serialization's quantum, so chunk offsets
+                # always keep to it; a column is 8 bytes in either)
                 planes = planar_store.blob_to_planes(full)
-                hi = (msg.off + msg.length) // Q \
+                hi = (msg.off + msg.length) // 8 \
                     if msg.length is not None else None
                 data = planar_store.planes_to_blob(
-                    planes[:, msg.off // Q: hi])
-                out_layout = planar_store.LAYOUT_PLANAR
+                    planes[:, msg.off // 8: hi])
+                out_layout = at_rest
             else:
                 # unaligned range: correctness-only byte fallback (books
                 # the unseamed counter; never hit by aligned gathers)
@@ -1057,13 +1072,14 @@ class ECBackendMixin:
             shard_attr = self.store.getattr(coll, oid, "shard")
             local_shard = int(shard_attr) if shard_attr is not None \
                 else None
-            Q = planar_store.QUANTUM
             # planar-at-rest local shard with an aligned range: read
             # the plane blob, verify plane-major, slice COLUMNS — the
             # byte view is never materialized (round 19)
-            lp = (self.store.object_layout(coll, oid)
-                  == planar_store.LAYOUT_PLANAR and off % Q == 0
-                  and (length is None or length % Q == 0))
+            at_rest = self.store.object_layout(coll, oid)
+            lp = False
+            if planar_store.is_planar(at_rest):
+                Q = planar_store.quantum(at_rest)
+                lp = off % Q == 0 and (length is None or length % Q == 0)
             data = full = None
             try:
                 if lp:
@@ -1089,14 +1105,14 @@ class ECBackendMixin:
                 if stored is not None and \
                         self.config.osd_ec_verify_reads:
                     [ok] = await self._read_batcher.verify(
-                        [full], [int(stored)], planar=lp)
+                        [full], [int(stored)], planar=lp and at_rest)
                 if ok:
                     if lp:
                         planes = planar_store.blob_to_planes(full)
-                        hi = (off + length) // Q \
+                        hi = (off + length) // 8 \
                             if length is not None else None
                         data = planar_store.planes_to_blob(
-                            planes[:, off // Q: hi])
+                            planes[:, off // 8: hi])
                     else:
                         data = full[off:] if length is None \
                             else full[off: off + length]
@@ -1112,7 +1128,7 @@ class ECBackendMixin:
                     data,
                     self.store.get_version(coll, oid),
                     int(sa) if sa else 0,
-                    planar_store.LAYOUT_PLANAR if lp else None)
+                    at_rest if lp else None)
         committed_seq = st.last_complete[1]
 
         def _committed(v: int) -> bool:
@@ -1322,29 +1338,27 @@ class ECBackendMixin:
             fast_k=True)
         if expected_size is not None and shards and gsize != expected_size:
             raise ECSizeMismatch(gsize)
-        planar = self._planar_mode(codec, sinfo)
+        layout = self._planar_layout(codec, sinfo)
+        planar = layout is not None
         avail = {}
         for s, d in shards.items():
             if len(d) != chunk_len:
                 continue
-            shard_planar = layouts.get(s) == planar_store.LAYOUT_PLANAR
             if planar:
-                # steady state: the holder shipped planes and the
-                # decode consumes planes — blob_to_planes is a reshape,
-                # not a conversion.  A byte reply (mixed-generation
-                # member still byte-at-rest) takes the one legal
-                # relayout hop on the gather edge.
-                avail[s] = planar_store.blob_to_planes(d) \
-                    if shard_planar \
-                    else planar_store.shard_to_planes(d, seam="relayout")
+                # steady state: the holder shipped planes in the pool's
+                # serialization and the decode consumes planes — a
+                # reshape, not a conversion.  A byte reply
+                # (mixed-generation member still byte-at-rest) takes
+                # the one legal relayout hop on the gather edge.
+                avail[s] = planar_store.planes_as(d, layouts.get(s),
+                                                  layout)
             else:
-                if shard_planar:
-                    # byte-mode decode of a still-planar holder's reply
-                    # (gate just flipped off): normalize — legal, never
-                    # on the pinned steady-state path
-                    d = planar_store.planes_to_shard(
-                        planar_store.blob_to_planes(d), seam="relayout")
-                avail[s] = np.frombuffer(d, dtype=np.uint8)
+                # byte-mode decode; a still-planar holder's reply (gate
+                # just flipped off) is normalized — legal, never on the
+                # pinned steady-state path
+                avail[s] = np.frombuffer(
+                    planar_store.as_shard_bytes(d, layouts.get(s)),
+                    dtype=np.uint8)
         if len(avail) < k:
             raise IOError(
                 f"only {len(avail)} of {k} shard ranges for {oid}")
@@ -1420,25 +1434,23 @@ class ECBackendMixin:
         shards, size, group_version, layouts = await self._gather_shards(
             pool, st, oid, k, exclude_shards=exclude_sources)
         shard_len = sinfo.shard_size(size)
-        planar = self._planar_mode(codec, sinfo)
+        out_layout = self._planar_layout(codec, sinfo)
+        planar = out_layout is not None
         avail = {}
         for s, d in shards.items():
             if len(d) != shard_len:
                 continue
-            shard_planar = layouts.get(s) == planar_store.LAYOUT_PLANAR
             if planar:
                 # steady state: sources shipped planes, the rebuild
                 # decodes AND re-encodes in the plane domain, and the
                 # pushed shards land as planes — conversion-free end to
                 # end; byte replies (mixed members) relayout once here
-                avail[s] = planar_store.blob_to_planes(d) \
-                    if shard_planar \
-                    else planar_store.shard_to_planes(d, seam="relayout")
+                avail[s] = planar_store.planes_as(d, layouts.get(s),
+                                                  out_layout)
             else:
-                if shard_planar:
-                    d = planar_store.planes_to_shard(
-                        planar_store.blob_to_planes(d), seam="relayout")
-                avail[s] = np.frombuffer(d, dtype=np.uint8)
+                avail[s] = np.frombuffer(
+                    planar_store.as_shard_bytes(d, layouts.get(s)),
+                    dtype=np.uint8)
         if len(avail) < k:
             self.perf.inc("osd_unrecoverable")
             return False
@@ -1449,7 +1461,6 @@ class ECBackendMixin:
         # like the coalesced write path (engine-per-backend)
         chunks = await self._read_batcher.reencode(
             codec, sinfo, avail, size, planar=planar)
-        out_layout = planar_store.LAYOUT_PLANAR if planar else None
         # stamp the rebuilt shards with the DECODE GROUP's version, not
         # our local one: a primary whose own shard is newer (or staler)
         # than the group it decoded from would otherwise relabel old

@@ -375,29 +375,34 @@ class ReadBatcher:
 
         ``planar``: each row is an AT-REST plane blob; the crc runs on
         plane-major rows (``crc32c_planar_rows``) and stays bit-exact
-        with the byte-anchor hinfo crc — no layout conversion.
+        with the byte-anchor hinfo crc — no layout conversion.  True, or
+        the blobs' layout tag (``ec/planar_store.py``): a packet-row
+        blob's bytes are walked in another order than a bit-plane one's.
 
         Hardware-crc hosts short-circuit inline: the per-row C pass
         (5.6 GB/s, GIL-releasing) beats any batching scheme — exactly
         crc32c_rows' own rule — so the tick/executor round trip would
         only tax the read hot path for nothing.  Device backends keep
         the coalesced crc32c batch."""
+        from ceph_tpu.ec import planar_store
         from ceph_tpu.ops import crc32c as crcmod
 
+        packetsize = planar_store.packetsize_of(planar) \
+            if isinstance(planar, str) else 0
         if crcmod._gcrc is not None:
             if planar:
-                from ceph_tpu.ec import planar_store
-
                 return [crc is None or
                         int(crcmod.crc32c_planar_rows(
-                            planar_store.blob_to_planes(row))[0])
+                            planar_store.blob_to_planes(row),
+                            packetsize=packetsize)[0])
                         == int(crc)
                         for row, crc in zip(rows, crcs)]
             return [crc is None or
                     crcmod.crc32c(0xFFFFFFFF, row) == int(crc)
                     for row, crc in zip(rows, crcs)]
         oks, _tick = await self._submit(
-            ("verify_planar",) if planar else ("verify",), None, None,
+            ("verify_planar", packetsize) if planar else ("verify",),
+            None, None,
             (rows, crcs))
         return oks
 
@@ -439,7 +444,7 @@ class ReadBatcher:
         return out
 
     @staticmethod
-    def _verify_planar_multi(reqs):
+    def _verify_planar_multi(reqs, packetsize: int = 0):
         """One tick's PLANAR crc verifications: every at-rest plane
         blob of every request, batched per length group through
         ``crc32c_planar_rows`` (plane-major rows, bit-exact with the
@@ -460,7 +465,7 @@ class ReadBatcher:
         out = [[True] * len(rows) for rows, _c in reqs]
         for _cols, group in by_len.items():
             stacked = np.vstack([planes for _ri, _j, planes, _c in group])
-            got = crc32c_planar_rows(stacked)
+            got = crc32c_planar_rows(stacked, packetsize=packetsize)
             for (ri, j, _p, crc), g in zip(group, got):
                 out[ri][j] = (crc is None) or (int(g) == int(crc))
         return out
@@ -491,7 +496,7 @@ class ReadBatcher:
         elif mode == "verify_planar":
             def compute(reqs):
                 return osd._compute(self._verify_planar_multi,
-                                    [r.data for r in reqs])
+                                    [r.data for r in reqs], key[1])
         else:
             def compute(reqs):
                 return osd._compute(self._verify_multi,

@@ -316,7 +316,7 @@ class BlueStore(ObjectStore):
             elif op[0] == "write_planar":
                 # whole-matrix COW rewrite: blocks of the FINAL size
                 # (old blocks free only after the onode repoints)
-                _, _, _, _, _, total_cols = op
+                total_cols = op[5]
                 need += (8 * total_cols + BLOCK - 1) // BLOCK
             elif op[0] == "truncate":
                 need += 1                       # partial-tail rewrite
@@ -348,7 +348,7 @@ class BlueStore(ObjectStore):
             _, coll, oid, offset, data = op
             o = self._coll(coll).get(oid)
             if o is not None and \
-                    getattr(o, "layout", None) == planar_store.LAYOUT_PLANAR:
+                    planar_store.is_planar(getattr(o, "layout", None)):
                 # byte write onto a planar object: it leaves planar-at-
                 # rest.  A partial overlay must land on LOGICAL bytes,
                 # so materialize once (counted relayout) first.
@@ -356,25 +356,27 @@ class BlueStore(ObjectStore):
                 if not (offset == 0 and o.size <= end) and o.size:
                     raw = self._read_all_replay_ok(coll, oid, o, replay)
                     logical = planar_store.planes_to_shard(
-                        planar_store.blob_to_planes(raw), seam="relayout")
+                        planar_store.blob_to_planes(raw), seam="relayout",
+                        layout=o.layout)
                     self._do_truncate(coll, oid, 0, replay)
                     self._do_write(coll, oid, 0, logical, replay)
                 o.layout = None
             self._do_write(coll, oid, offset, data, replay)
         elif kind == "write_planar":
-            _, coll, oid, plane_off, data, total_cols = op
+            _, coll, oid, plane_off, data, total_cols = op[:6]
             self._do_write_planar(coll, oid, plane_off, data, total_cols,
-                                  replay)
+                                  replay, planar_store.op_layout(op))
         elif kind == "truncate":
             _, coll, oid, size = op
             o = self._coll(coll).get(oid)
             if o is not None and o.size != size and o.size and \
-                    getattr(o, "layout", None) == planar_store.LAYOUT_PLANAR:
+                    planar_store.is_planar(getattr(o, "layout", None)):
                 # byte truncate of a planar object cuts PLANE ROWS, not
                 # logical bytes — leave planar first (counted relayout)
                 raw = self._read_all_replay_ok(coll, oid, o, replay)
                 logical = planar_store.planes_to_shard(
-                    planar_store.blob_to_planes(raw), seam="relayout")
+                    planar_store.blob_to_planes(raw), seam="relayout",
+                    layout=o.layout)
                 self._do_truncate(coll, oid, 0, replay)
                 self._do_write(coll, oid, 0, logical, replay)
                 o.layout = None
@@ -483,7 +485,8 @@ class BlueStore(ObjectStore):
             return b"\0" * o.size
 
     def _do_write_planar(self, coll, oid, plane_off, data, total_cols,
-                         replay) -> None:
+                         replay,
+                         layout: str = planar_store.LAYOUT_PLANAR) -> None:
         """Planar-at-rest shard write: splice the (8, wc) plane-column
         window into the object's plane matrix and rewrite it whole —
         COW into fresh blocks like every other write.  A full rewrite
@@ -498,21 +501,22 @@ class BlueStore(ObjectStore):
         cur = None
         if o.size and not full_rewrite:
             raw = self._read_all_replay_ok(coll, oid, o, replay)
-            if len(raw) % 8:
-                raw += b"\0" * (8 - len(raw) % 8)
-            if getattr(o, "layout", None) == planar_store.LAYOUT_PLANAR:
-                cur = planar_store.blob_to_planes(raw)
-            else:
-                # planar write landing on a byte-at-rest object: the
-                # config gate flipped mid-life — convert once, counted
-                cur = planar_store.shard_to_planes(raw, seam="relayout")
+            q = planar_store.quantum(layout)
+            if len(raw) % q:
+                raw += b"\0" * (q - len(raw) % q)
+            # the object's own serialization: a reshape; a planar write
+            # landing on a byte-at-rest object (the config gate flipped
+            # mid-life): converted once, counted; the other planar
+            # serialization: refused by name
+            cur = planar_store.planes_as(raw, getattr(o, "layout", None),
+                                         layout)
         merged = planar_store.splice_columns(
             cur, plane_off, window, total_cols)
         self._do_truncate(coll, oid, 0, replay)
         self._do_write(coll, oid, 0, planar_store.planes_to_blob(merged),
                        replay)
         o.size = 8 * total_cols
-        o.layout = planar_store.LAYOUT_PLANAR
+        o.layout = layout
 
     def _do_truncate(self, coll, oid, size, replay) -> None:
         o = self._onode(coll, oid)
@@ -586,7 +590,7 @@ class BlueStore(ObjectStore):
             o = self._onodes.get(coll, {}).get(oid)
             if o is None:
                 raise FileNotFoundError(f"{coll}/{oid}")
-            if getattr(o, "layout", None) == planar_store.LAYOUT_PLANAR \
+            if planar_store.is_planar(getattr(o, "layout", None)) \
                     and o.size:
                 # byte view of a planar object OUTSIDE the sanctioned
                 # seams (egress of last resort): logical byte 8i+u needs
@@ -595,7 +599,7 @@ class BlueStore(ObjectStore):
                 # counter the steady-state contract pins to zero.
                 data = planar_store.planes_to_shard(  # graftlint: ignore[planar-conversion-hygiene]
                     planar_store.blob_to_planes(self._read_all(
-                        coll, oid, o)), seam="unseamed")
+                        coll, oid, o)), seam="unseamed", layout=o.layout)
                 if length is None:
                     return data[offset:]
                 return data[offset : offset + length]
@@ -622,7 +626,7 @@ class BlueStore(ObjectStore):
             o = self._onodes.get(coll, {}).get(oid)
             if o is None:
                 raise FileNotFoundError(f"{coll}/{oid}")
-            if getattr(o, "layout", None) != planar_store.LAYOUT_PLANAR:
+            if not planar_store.is_planar(getattr(o, "layout", None)):
                 raise ValueError(f"{coll}/{oid} is not planar-at-rest")
             return self._read_all(coll, oid, o)
 

@@ -19,6 +19,7 @@ from ceph_tpu.cluster import messages as M
 from ceph_tpu.cluster import pglog
 from ceph_tpu.cluster.pglog import LogEntry, PGInfo, PGLog
 from ceph_tpu.cluster.store import Transaction
+from ceph_tpu.ec import planar_store
 from ceph_tpu.osdmap.osdmap import PGid, ceph_stable_mod
 from ceph_tpu.analysis import racecheck
 from ceph_tpu.utils.lockdep import DepLock
@@ -437,7 +438,7 @@ class PGLogMixin:
                 if not rec["existed"]:
                     txn.remove(coll, rec["oid"])
                 else:
-                    if rec.get("layout") == "planar8":
+                    if planar_store.is_planar(rec.get("layout")):
                         # planar-at-rest object: old_range IS the
                         # captured plane blob — restore it AS planes (a
                         # byte write would land the blob as logical
@@ -446,7 +447,8 @@ class PGLogMixin:
                         txn.write_planar(coll, rec["oid"],
                                          rec["chunk_off"] // 8,
                                          rec["old_range"],
-                                         rec["old_total"] // 8)
+                                         rec["old_total"] // 8,
+                                         rec["layout"])
                     else:
                         txn.write(coll, rec["oid"], rec["chunk_off"],
                                   rec["old_range"])

@@ -4,7 +4,7 @@
 from __future__ import annotations
 
 import asyncio
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from ceph_tpu.cluster import messages as M
 from ceph_tpu.crush.types import CRUSH_ITEM_NONE
@@ -39,22 +39,23 @@ class ScrubMixin:
 
         coll = _coll(pgid)
         oids = self._list_pg_objects(pgid)
-        pset = {oid for oid in oids
-                if self.store.object_layout(coll, oid)
-                == planar_store.LAYOUT_PLANAR}
+        # oid -> its planar tag (either serialization), the others bytes
+        pset = {oid: tag for oid in oids if planar_store.is_planar(
+            tag := self.store.object_layout(coll, oid))}
         blobs = {oid: (self.store.read_planar(coll, oid)
                        if oid in pset else self.store.read(coll, oid))
                  for oid in oids}
-        by_len: Dict[Tuple[int, bool], List[str]] = {}
+        by_len: Dict[Tuple[int, Optional[str]], List[str]] = {}
         for oid, b in blobs.items():
-            by_len.setdefault((len(b), oid in pset), []).append(oid)
+            by_len.setdefault((len(b), pset.get(oid)), []).append(oid)
         crcs: Dict[str, int] = {}
         for (ln, planar), group in by_len.items():
             if planar and ln > 0:
                 planes = np.vstack([planar_store.blob_to_planes(blobs[o])
                                     for o in group])
-                for o, v in zip(group,
-                                crcmod.crc32c_planar_rows(planes)):
+                for o, v in zip(group, crcmod.crc32c_planar_rows(
+                        planes,
+                        packetsize=planar_store.packetsize_of(planar))):
                     crcs[o] = int(v)
             elif not planar and len(group) >= 2 and ln > 0:
                 arr = np.stack([

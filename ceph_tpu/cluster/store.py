@@ -205,10 +205,11 @@ class Obj:
     xattrs: Dict[str, bytes] = field(default_factory=dict)
     omap: Dict[str, bytes] = field(default_factory=dict)
     version: int = 0
-    # at-rest data layout: None = classic bytes; planar_store.LAYOUT_PLANAR
-    # means ``data`` holds the shard's (8, L/8) packed bit-plane matrix
-    # serialized row-major (round 19).  Same byte length either way, so
-    # _used/statfs/stat need no layout awareness.
+    # at-rest data layout: None = classic bytes; a planar tag
+    # (``planar_store.is_planar``: ``planar8``, ``packet8.<p>``) means
+    # ``data`` holds the shard's (8, L/8) matrix of packed GF(2) rows in
+    # that serialization, row-major (round 19).  Same byte length either
+    # way, so _used/statfs/stat need no layout awareness.
     layout: Optional[str] = None
 
 
@@ -231,20 +232,21 @@ class Transaction:
         return self
 
     def write_planar(self, coll: str, oid: str, plane_off: int,
-                     data: bytes, total_cols: int):
+                     data: bytes, total_cols: int,
+                     layout: str = planar_store.LAYOUT_PLANAR):
         """Planar-at-rest shard write (round 19): land ``data`` — an
         (8, wc) plane-column window serialized row-major — at plane
         column ``plane_off`` (= byte offset / 8) and size the object to
         exactly ``total_cols`` columns (= shard bytes / 8).  One op
         covers the byte path's write+truncate pair, and the object's
-        layout becomes planar.
+        layout becomes ``layout``, the tag of the window's serialization.
 
         ``data`` (``bytes``, a ``memoryview``, a contiguous array) is
         kept as it is given and must not change before the transaction
         is queued: the store takes its one copy of it when the op is
         applied, and a journal its ``bytes`` in ``encode``."""
         self.ops.append(("write_planar", coll, oid, plane_off,
-                         data, total_cols))
+                         data, total_cols, layout))
         return self
 
     def truncate(self, coll: str, oid: str, size: int):
@@ -299,7 +301,7 @@ class Transaction:
         # a planar window may still be a view of a frame or of a tick's
         # planes (write_planar), which no pickle takes
         return pickle.dumps([
-            (*op[:4], bytes(op[4]), op[5])
+            (*op[:4], bytes(op[4]), *op[5:])
             if op[0] == "write_planar" and type(op[4]) is not bytes
             else op for op in self.ops])
 
@@ -400,7 +402,7 @@ class MemStore(ObjectStore):
                 grow += new - sizes[(coll, oid)]
                 sizes[(coll, oid)] = new
             elif kind == "write_planar":
-                _, coll, oid, _plane_off, _data, total_cols = op
+                _, coll, oid, _plane_off, _data, total_cols = op[:6]
                 # one op fixes the final size exactly: 8 plane rows of
                 # total_cols packed bytes == the shard's byte length, so
                 # planar admission counts TRUE plane bytes (satellite:
@@ -482,7 +484,7 @@ class MemStore(ObjectStore):
             o = self._coll(coll).setdefault(oid, Obj())
             old = len(o.data)
             end = offset + len(data)
-            if o.layout == planar_store.LAYOUT_PLANAR:
+            if planar_store.is_planar(o.layout):
                 # byte write onto a planar object: the object leaves
                 # planar-at-rest.  A full rewrite just drops the layout;
                 # a partial overlay must land on LOGICAL bytes, so
@@ -490,7 +492,7 @@ class MemStore(ObjectStore):
                 if not (offset == 0 and old <= end):
                     logical = planar_store.planes_to_shard(
                         planar_store.blob_to_planes(bytes(o.data)),
-                        seam="relayout")
+                        seam="relayout", layout=o.layout)
                     _own(o, keep=False)[:] = logical
                 o.layout = None
             if offset == 0 and len(o.data) <= end:
@@ -505,7 +507,8 @@ class MemStore(ObjectStore):
             o.version += 1
             self._used += len(o.data) - old
         elif kind == "write_planar":
-            _, coll, oid, plane_off, data, total_cols = op
+            _, coll, oid, plane_off, data, total_cols = op[:6]
+            layout = planar_store.op_layout(op)
             o = self._coll(coll).setdefault(oid, Obj())
             old = len(o.data)
             blob = memoryview(data)
@@ -525,38 +528,38 @@ class MemStore(ObjectStore):
                 # a partial (or overshooting) window lands in the old
                 # plane matrix, zero-extended or cut to total_cols
                 window = planar_store.blob_to_planes(blob)
-                if o.data and o.layout == planar_store.LAYOUT_PLANAR:
-                    cur = planar_store.blob_to_planes(bytes(o.data))
-                elif o.data:
-                    # a planar write landing on a byte-at-rest object:
-                    # the config gate flipped mid-life — convert once,
-                    # counted (zero-pad to the 8-byte packing quantum;
-                    # EC shards are stripe-unit aligned so this is a
-                    # non-EC-object guard)
+                if o.data:
+                    # the object's own serialization is a reshape.  A
+                    # planar write landing on a byte-at-rest object: the
+                    # config gate flipped mid-life — convert once,
+                    # counted (zero-pad to the packing quantum; EC
+                    # shards are stripe-unit aligned so this is a
+                    # non-EC-object guard).  The other planar
+                    # serialization is refused by name.
                     raw = bytes(o.data)
-                    if len(raw) % 8:
-                        raw += b"\0" * (8 - len(raw) % 8)
-                    cur = planar_store.shard_to_planes(raw,
-                                                       seam="relayout")
+                    q = planar_store.quantum(layout)
+                    if len(raw) % q:
+                        raw += b"\0" * (q - len(raw) % q)
+                    cur = planar_store.planes_as(raw, o.layout, layout)
                 else:
                     cur = None
                 merged = planar_store.planes_to_blob(
                     planar_store.splice_columns(
                         cur, plane_off, window, total_cols))
                 _own(o, keep=False)[:] = merged
-            o.layout = planar_store.LAYOUT_PLANAR
+            o.layout = layout
             o.version += 1
             self._used += len(o.data) - old
         elif kind == "truncate":
             _, coll, oid, size = op
             o = self._coll(coll).setdefault(oid, Obj())
             old = len(o.data)
-            if o.layout == planar_store.LAYOUT_PLANAR and old != size:
+            if planar_store.is_planar(o.layout) and old != size:
                 # byte truncate of a planar object cuts PLANE ROWS, not
                 # logical bytes — leave planar first (counted relayout)
                 logical = planar_store.planes_to_shard(
                     planar_store.blob_to_planes(bytes(o.data)),
-                    seam="relayout")
+                    seam="relayout", layout=o.layout)
                 _own(o, keep=False)[:] = logical
                 o.layout = None
             own = _own(o)
@@ -672,14 +675,14 @@ class MemStore(ObjectStore):
             o = self._colls.get(coll, {}).get(oid)
             if o is None:
                 raise FileNotFoundError(f"{coll}/{oid}")
-            if o.layout == planar_store.LAYOUT_PLANAR and o.data:
+            if planar_store.is_planar(o.layout) and o.data:
                 # byte view of a planar object OUTSIDE the sanctioned
                 # seams (egress of last resort): correct, but it books
                 # the ``unseamed`` counter the steady-state contract
                 # pins to zero — EC hot paths must use read_planar.
                 data = planar_store.planes_to_shard(  # graftlint: ignore[planar-conversion-hygiene]
                     planar_store.blob_to_planes(bytes(o.data)),
-                    seam="unseamed")
+                    seam="unseamed", layout=o.layout)
                 if length is None:
                     return data[offset:]
                 return data[offset : offset + length]
@@ -698,7 +701,7 @@ class MemStore(ObjectStore):
             o = self._colls.get(coll, {}).get(oid)
             if o is None:
                 raise FileNotFoundError(f"{coll}/{oid}")
-            if o.layout != planar_store.LAYOUT_PLANAR:
+            if not planar_store.is_planar(o.layout):
                 raise ValueError(f"{coll}/{oid} is not planar-at-rest")
             return bytes(o.data)
 

@@ -253,16 +253,22 @@ class _DeviceMatrixEngine:
 
 
 def matrix_engine(codec):
-    """The codec's engine where it is a bytewise GF(2^8) matrix code the
-    host GF engine, the plane entry points of ``ec/stripe.py`` and the
-    mesh engine can drive: ``coding`` ((m, k) bytes), ``_enc_bitmat``,
-    ``decode_matrix(src, want)`` (chunk[want] = R @ chunk[src]; raises
-    where ``src`` cannot produce ``want``) and ``decode_bitmat``.  None
-    for every other code: a wider field, a packet-interleaved chunk
-    layout (the bytes differ from the bytewise product of the same
-    matrix), no matrix at all.  The ONE place that asks; whoever needs
-    to know calls this.  The answer is kept on the codec: a pool's
-    codec is asked at every op."""
+    """The codec's engine where it is a GF(2^8) matrix code that the
+    plane entry points of ``ec/stripe.py`` can drive: ``coding`` ((m, k)
+    bytes), ``_enc_bitmat`` (its (8m, 8k) bit-matrix), ``decode_matrix(
+    src, want)`` (chunk[want] = R @ chunk[src]; raises where ``src``
+    cannot produce ``want``) and ``decode_bitmat``.  None for every
+    other code: a wider field, a bit-matrix with no byte matrix behind
+    it (the liberation family), no matrix at all.  The ONE place that
+    asks; whoever needs to know calls this.  The answer is kept on the
+    codec: a pool's codec is asked at every op.
+
+    The same bit-matrix multiplies two chunk layouts, and
+    ``engine_layout`` says which one the codec's chunks have: the
+    engine's matrices are right for packed GF(2) rows of either, but a
+    BYTEWISE product (the host GF engine on byte batches, the mesh
+    engine, a flattened LRC stack) is right only for ``planar8``:
+    ``bytewise_engine`` is the question those ask."""
     try:
         return codec._matrix_engine
     except AttributeError:
@@ -272,11 +278,38 @@ def matrix_engine(codec):
         return None         # none (yet): nothing to remember
     if getattr(eng, "w", 0) != 8 or \
             getattr(eng, "coding", None) is None or \
-            not hasattr(eng, "decode_matrix") or \
-            getattr(codec, "packetsize", None) is not None:
+            not hasattr(eng, "decode_matrix"):
         eng = None
     codec._matrix_engine = eng
     return eng
+
+
+def engine_layout(codec):
+    """The at-rest serialization (``ec/planar_store.py``'s tag) of the
+    packed GF(2) rows ``matrix_engine(codec)`` multiplies: ``planar8``
+    for bytewise chunks, ``packet8.<packetsize>`` for the w = 8
+    packet-interleaved bit-matrix codes (a chunk's w packets a
+    super-block ARE packed rows); None where there is no such engine."""
+    from ceph_tpu.ec import planar_store
+
+    if matrix_engine(codec) is None:
+        return None
+    try:
+        return codec._engine_layout     # asked at every op, as the engine
+    except AttributeError:
+        pass
+    p = getattr(codec, "packetsize", None)
+    codec._engine_layout = planar_store.LAYOUT_PLANAR if p is None \
+        else planar_store.packet_layout(p)
+    return codec._engine_layout
+
+
+def bytewise_engine(codec):
+    """``matrix_engine(codec)`` where the codec's chunks are the BYTEWISE
+    product of its matrix, else None: for whoever multiplies bytes and
+    not packed rows."""
+    eng = matrix_engine(codec)
+    return None if getattr(codec, "packetsize", None) is not None else eng
 
 
 class _DeviceBitEngine:
@@ -488,8 +521,17 @@ class BitmatrixCodec(MatrixCodec):
     # -- packet layout ------------------------------------------------------
 
     def stripe_unit(self, default: int) -> int:
+        """The pool's chunk size by upstream's rule: what
+        ``get_chunk_size`` gives for one stripe of ``default``-byte
+        units (OSDMonitor::prepare_pool_stripe_width: stripe_width =
+        k * get_chunk_size(stripe_unit * k)), with the technique's
+        ``get_alignment`` inside it: 64 KiB at k = 4, w = 8, packetsize
+        2048, where the 4 KiB default of a bytewise code stays 4 KiB.
+        Always whole super-blocks (the alignment is k*w*packetsize
+        words)."""
+        unit = self.get_chunk_size(default * self.k)
         quantum = self.w * self.packetsize
-        return ((default + quantum - 1) // quantum) * quantum
+        return ((unit + quantum - 1) // quantum) * quantum
 
     def _check_layout(self, s: int) -> None:
         if s % (self.w * self.packetsize):
@@ -580,8 +622,8 @@ class BitmatrixCodec(MatrixCodec):
     # Packet-interleaved chunks are ALREADY bit-planar: jerasure's w packets
     # of p bytes per super-block are packed bit-planes of the w-bit symbols.
     # The planar form is therefore the packet-row matrix (c*w, B*ns*p) of
-    # raw bytes, and the matmul keeps the byte-lane Kronecker trick — no
-    # second-level packing conversion on top.
+    # raw bytes: no second-level packing conversion on top, and at w = 8
+    # the multiply is ``gf8.planar_matmul`` on those rows as they are.
 
     def planar_supported(self, chunk_size: int) -> bool:
         from ceph_tpu.ec.planar import PlanarBatch
@@ -598,11 +640,23 @@ class BitmatrixCodec(MatrixCodec):
         return PlanarBatch.from_batch(batch, w=self.w, layout="packet",
                                       packetsize=self.packetsize)
 
+    def _rows_matmul(self, m01, rows):
+        """The (r*w, c*w) GF(2) matrix times packet rows.  At w = 8 with
+        a byte matrix behind it (the cauchy techniques: ``matrix_engine``)
+        that is the product's own planar matmul: packet rows are packed
+        GF(2) rows.  Wider fields and the liberation family keep the
+        byte-operand kernel and its lane-expanded matrix."""
+        if matrix_engine(self) is not None:
+            return gf8.planar_matmul(m01, rows)
+        m01 = np.asarray(m01)
+        return _planar_rows_matmul(_lane_expand(m01.tobytes(), m01.shape),
+                                   rows)
+
     def encode_planar(self, pb):
-        m01 = self._encode_bits()
-        lane = _lane_expand(m01.tobytes(), m01.shape)
+        eng = matrix_engine(self)
+        m01 = self._encode_bits() if eng is None else eng._enc_bitmat
         ticktrace.device_calls()        # the packet-rows matmul program
-        return pb.with_planes(_planar_rows_matmul(lane, pb.planes), self.m)
+        return pb.with_planes(self._rows_matmul(m01, pb.planes), self.m)
 
     def decode_planar(self, erasures, pb, want=None):
         from ceph_tpu.ec.planar import _select_chunk_rows
@@ -611,8 +665,8 @@ class BitmatrixCodec(MatrixCodec):
             want = tuple(erasures)
         avail = tuple(i for i in range(self.k + self.m) if i not in erasures)
         src = avail[: self.k]
-        m01 = self._decode_bits(src, tuple(want))
-        lane = _lane_expand(m01.tobytes(), m01.shape)
+        eng = matrix_engine(self)
+        m01 = self._decode_bits(src, tuple(want)) if eng is None \
+            else eng.decode_bitmat(src, tuple(want))
         src_rows = _select_chunk_rows(pb.planes, self.w, src)
-        return pb.with_planes(_planar_rows_matmul(lane, src_rows),
-                              len(want))
+        return pb.with_planes(self._rows_matmul(m01, src_rows), len(want))

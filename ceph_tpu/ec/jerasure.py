@@ -22,7 +22,9 @@ for every w in {8, 16, 32}.  The packet-interleaved codes
 (cauchy/liberation) ARE bit-planar natively — jerasure's w packets of
 ``packetsize`` bytes per super-block are packed bit-planes — so their
 planar form is the packet-row matrix (``packet`` flavor) and no
-second-level packing is applied.
+second-level packing is applied.  Since PR 48 a w = 8 cauchy pool also
+RESTS in that form (``ec/planar_store.py``: ``packet8.<packetsize>``)
+and multiplies through the product's planar kernel.
 """
 
 from __future__ import annotations

@@ -24,7 +24,7 @@ from typing import Dict, List, Mapping, Optional, Set, Tuple
 
 import numpy as np
 
-from ceph_tpu.ec.codec import MatrixCodec, _DeviceMatrixEngine, matrix_engine
+from ceph_tpu.ec.codec import MatrixCodec, _DeviceMatrixEngine, bytewise_engine
 from ceph_tpu.ec.interface import ECError, ErasureCodeInterface, ErasureCodeProfile
 from ceph_tpu.ec.table_cache import DecodeTableCache
 
@@ -272,7 +272,7 @@ class ErasureCodeLrc(MatrixCodec):
         # the batch and plane paths multiply by the flattened layers,
         # which are matrices only where every layer is a bytewise
         # GF(2^8) matrix code; any other stack keeps the scalar walk
-        if all(matrix_engine(layer.erasure_code) is not None
+        if all(bytewise_engine(layer.erasure_code) is not None
                for layer in self.layers):
             self.engine = _FlatLayersEngine(self)
         # kml-generated parameters are internal; do not expose them

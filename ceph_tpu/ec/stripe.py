@@ -147,6 +147,16 @@ def _host_engine_ok(codec) -> bool:
     return matrix_engine(codec) is not None
 
 
+def _host_bytes_ok(codec) -> bool:
+    """The same for the entry points that hand the host engine BYTE
+    batches: its product is bytewise, which a packet-interleaved chunk
+    is not (``codec.bytewise_engine``); the plane entry points multiply
+    packed rows and take either layout."""
+    from ceph_tpu.ec.codec import bytewise_engine
+
+    return _host_engine_ok(codec) and bytewise_engine(codec) is not None
+
+
 def _gf_apply_host(mat: np.ndarray, batch: np.ndarray) -> np.ndarray:
     """(B, k, S) x (m, k) GF(2^8) matrix -> (B, m, S) via table-driven
     numpy: coefficient-1 terms are pure XOR (the whole of RS m=1),
@@ -230,7 +240,7 @@ def encode_stripes_multi(codec, sinfo: StripeInfo, datas,
         flat[: len(d)] = np.frombuffer(d, dtype=np.uint8)
         pad += ns * sinfo.stripe_width - len(d)
         ofs += ns
-    if _host_engine_ok(codec):
+    if _host_bytes_ok(codec):
         # CPU backend: no layout conversion, no bucket padding — the
         # host GF engine is shape-agnostic and bandwidth-bound
         KERNELS.inc("ec_stripe_pad_bytes", pad)
@@ -517,7 +527,7 @@ def decode_stripes_multi(codec, sinfo: StripeInfo, reqs):
     KERNELS.inc("ec_coalesced_read_ticks")
     KERNELS.inc("ec_coalesced_reads",
                 sum(len(g) for g in groups.values()))
-    host = _host_engine_ok(codec)
+    host = _host_bytes_ok(codec)
     for (erasures, want), items in groups.items():
         total = sum(ns for _i, _a, _d, ns, _ls in items)
         full = np.zeros((total, n, unit), dtype=np.uint8)
@@ -601,7 +611,7 @@ def reencode_stripes_multi(codec, sinfo: StripeInfo, reqs):
     KERNELS.inc("ec_coalesced_reencode_ticks")
     KERNELS.inc("ec_coalesced_reencodes",
                 sum(len(g) for g in groups.values()))
-    host = _host_engine_ok(codec)
+    host = _host_bytes_ok(codec)
     planar = _planar_ok(codec, unit)
     for (erasures, want), items in groups.items():
         total = sum(ns for _i, _a, ns, _ls in items)
@@ -677,24 +687,34 @@ def reencode_stripes_multi(codec, sinfo: StripeInfo, reqs):
 
 def planar_at_rest_ok(codec, unit: int) -> bool:
     """Can this (codec, stripe_unit) pool store EC shards as packed
-    bit-planes at rest?
+    GF(2) rows at rest?  ``at_rest_layout`` with a yes or no."""
+    return at_rest_layout(codec, unit) is not None
 
-    Requires the bitpack layout contract: a bytewise GF(2^8) matrix
-    engine (``codec.matrix_engine``: the Reed-Solomon families; SHEC at
-    w = 8, whose decode multiplies the chunks its plan names; and LRC,
-    whose layers flatten to one generator and whose decode composes the
-    layer walk) and a stripe unit that is a multiple of the 8-byte
-    packing quantum.  Packet-interleaved codecs (the
-    BitmatrixCodec family — their planar form is the packet-row matrix,
-    a different serialization), wider fields, an LRC stack with such a
-    layer, and mesh adapters keep byte-at-rest; the gate falls back per
-    pool, not per cluster.
+
+def at_rest_layout(codec, unit: int) -> Optional[str]:
+    """The serialization (``ec/planar_store.py``'s tag) a (codec,
+    stripe_unit) pool stores its EC shards in, or None: byte-at-rest.
+
+    Requires a GF(2^8) matrix engine (``codec.matrix_engine``: the
+    Reed-Solomon families; SHEC at w = 8, whose decode multiplies the
+    chunks its plan names; LRC, whose layers flatten to one generator
+    and whose decode composes the layer walk: all ``planar8``; and the
+    w = 8 cauchy techniques, whose packet-interleaved chunks rest as
+    their packet-row matrix, ``packet8.<packetsize>``) and a stripe
+    unit that is a multiple of that serialization's quantum (8 bytes; a
+    super-block).  Wider fields, the liberation family (bit-matrices
+    with no byte matrix behind them), an LRC stack with a packet layer,
+    and mesh adapters keep byte-at-rest; the gate falls back per pool,
+    not per cluster.
     """
-    from ceph_tpu.ec.codec import matrix_engine
+    from ceph_tpu.ec import planar_store as pstore
+    from ceph_tpu.ec.codec import engine_layout
 
-    if matrix_engine(codec) is None or unit <= 0 or unit % 8:
-        return False
-    return _planar_ok(codec, unit)
+    layout = engine_layout(codec)
+    if layout is None or unit <= 0 or unit % pstore.quantum(layout) \
+            or not _planar_ok(codec, unit):
+        return None
+    return layout
 
 
 def _planes_rows_for(codec, src: Tuple[int, ...],
@@ -798,7 +818,8 @@ def _warm_buckets(codec, sinfo: StripeInfo, shape, chain, crcs: bool) -> None:
                 parity_pb = codec.encode_planar(pb)
                 if crcs and _device_crcs_ok(pb):
                     np.asarray(crcmod.planar_chunk_crcs(
-                        (pb.planes, parity_pb.planes), sinfo.chunk_size))
+                        (pb.planes, parity_pb.planes), sinfo.chunk_size,
+                        pb.packetsize))
                 np.asarray(parity_pb.planes)
     except Exception:   # a warm that fails costs a later tick its compile
         logging.getLogger("ceph_tpu.ec").exception(
@@ -807,11 +828,12 @@ def _warm_buckets(codec, sinfo: StripeInfo, shape, chain, crcs: bool) -> None:
 
 def _device_crcs_ok(pb) -> bool:
     """Can the chunk-crc program take this batch's planes?  Chosen by
-    what the batch says of itself; any other layout crcs on the host."""
+    what the batch says of itself: its blob (a chunk's plane group, or a
+    packet) within the program's reach; anything else crcs on the host."""
     from ceph_tpu.ops import crc32c as crcmod
 
-    return pb.layout == "bitpack" and pb.w == 8 \
-        and pb.chunk_size <= crcmod._PLANAR_DEV_MAX
+    return pb.w == 8 and \
+        (pb.packetsize or pb.chunk_size) <= crcmod._PLANAR_DEV_MAX
 
 
 def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
@@ -833,6 +855,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
     buckets such ticks can meet ahead of them (``_warm_tick_buckets``).
     """
     from ceph_tpu.ec import planar_store as pstore
+    from ceph_tpu.ec.codec import engine_layout
     from ceph_tpu.ops import crc32c as crcmod
     from ceph_tpu.ops.profiling import record_planar_at_rest
     from ceph_tpu.trace import tick as ticktrace
@@ -841,6 +864,9 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
     k = sinfo.k
     unit = sinfo.chunk_size
     n = codec.get_chunk_count()
+    # the caller passed the gate, so the codec names a serialization
+    layout = engine_layout(codec)
+    packetsize = pstore.packetsize_of(layout)
     if want_crcs is None:
         want_crcs = [False] * len(datas)
     counts = [sinfo.object_stripes(len(d)) for d in datas]
@@ -859,7 +885,8 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
     bb = total if host else _bucket(total)
     # the phases below land on the tick open on this thread (the
     # batcher's; trace/tick.py) and are no-ops outside one
-    ticktrace.annotate(total, bb, sum(len(d) for d in datas))
+    ticktrace.annotate(total, bb, sum(len(d) for d in datas),
+                       "packet" if packetsize else "bitpack")
     with ticktrace.phase("fill"):
         batch = np.zeros((total, k, unit), dtype=np.uint8)
         pad = 0
@@ -877,11 +904,14 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
     KERNELS.inc("ec_stripe_pad_bytes", pad + (bb - total) * k * unit)
     # THE sanctioned ingest: client bytes -> planes, once per tick
     record_planar_at_rest("ingest", total * k * unit)
+    if packetsize:
+        # ... of them as packet rows: whole packets moved, no bit sliced
+        KERNELS.inc("ec_planar_packet_ingest_bytes", total * k * unit)
     op_crcs: Dict[int, List[int]] = {}
     if host:
         rows = np.ascontiguousarray(
             batch.transpose(1, 0, 2).reshape(k, total * unit))
-        data_planes = pstore.rows_to_planes(rows)
+        data_planes = pstore.rows_to_planes(rows, layout)
         all_planes = np.vstack(
             [data_planes, _parity_planes_for(codec, data_planes)])
     else:
@@ -898,7 +928,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
         if any(want_crcs) and _device_crcs_ok(pb):
             with ticktrace.phase("crc"):
                 chunk_crcs = crcmod.planar_chunk_crcs(
-                    (pb.planes, parity_pb.planes), unit)
+                    (pb.planes, parity_pb.planes), unit, packetsize)
         # each readback blocks until the device is done, then copies
         # device -> host; a device call apiece
         with ticktrace.phase("readback"):
@@ -912,7 +942,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
             with ticktrace.phase("crc"):
                 ticktrace.device_calls()
                 op_crcs = _fold_op_crcs(np.asarray(chunk_crcs), counts,
-                                        want_crcs, unit)
+                                        want_crcs, unit, packetsize)
         with ticktrace.phase("slice"):
             all_planes = np.vstack([data_planes, parity_planes])
         if max_ops:
@@ -938,22 +968,26 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
         with ticktrace.phase("crc"):
             stacked = np.concatenate(
                 [p.reshape(n * 8, -1) for _i, p in group], axis=0)
-            crcs = crcmod.crc32c_planar_rows(stacked)
+            crcs = crcmod.crc32c_planar_rows(stacked,
+                                             packetsize=packetsize)
         for gi, (i, p) in enumerate(group):
             out[i] = (p, crcs[gi * n:(gi + 1) * n])
     return out
 
 
 def _fold_op_crcs(chunk_crcs: np.ndarray, counts, want_crcs,
-                  unit: int) -> Dict[int, List[int]]:
+                  unit: int, packetsize: int = 0) -> Dict[int, List[int]]:
     """A tick's (n, bb) zero-seeded chunk crcs -> {op: its n shard
     ``ceph_crc32c(~0, byte_view)`` values}.  Op i owns the ``counts[i]``
     columns after those of the ops before it (the bucket's padding
     stripes come last and are nobody's); ops of one length fold
-    together."""
-    from ceph_tpu.ops.crc32c import fold_chunk_crcs
+    together.  With ``packetsize`` the words are packet crcs, (n*8,
+    bb*per): a stripe's chunk is ``per`` super-blocks, and an op's run
+    folds in the byte stream's order (``packet_stream``)."""
+    from ceph_tpu.ops.crc32c import fold_chunk_crcs, packet_stream
 
-    n = chunk_crcs.shape[0]
+    per = unit // (8 * packetsize) if packetsize else 1
+    n = chunk_crcs.shape[0] // (8 if packetsize else 1)
     groups: Dict[int, List[Tuple[int, int]]] = {}
     c0 = 0
     for i, ns in enumerate(counts):
@@ -962,9 +996,10 @@ def _fold_op_crcs(chunk_crcs: np.ndarray, counts, want_crcs,
         c0 += ns
     out: Dict[int, List[int]] = {}
     for ns, members in groups.items():
+        runs = [chunk_crcs[:, c * per:(c + ns) * per] for _i, c in members]
         crcs = fold_chunk_crcs(
-            np.concatenate([chunk_crcs[:, c:c + ns] for _i, c in members]),
-            unit)
+            np.concatenate([packet_stream(r) for r in runs]), packetsize) \
+            if packetsize else fold_chunk_crcs(np.concatenate(runs), unit)
         for gi, (i, _c) in enumerate(members):
             out[i] = [int(c) for c in crcs[gi * n:(gi + 1) * n]]
     return out
@@ -989,14 +1024,14 @@ def _normalize_planes(shards, cols: int) -> Dict[int, np.ndarray]:
 
 def _assemble_from_planes(data_planes: Dict[int, np.ndarray], k: int,
                           nstripes: int, unit: int,
-                          logical_size: int) -> bytes:
+                          logical_size: int, layout: str) -> bytes:
     """Planar shards -> logical client bytes: THE sanctioned egress."""
     from ceph_tpu.ec import planar_store as pstore
     from ceph_tpu.ops.profiling import record_planar_at_rest
 
     stacked = np.vstack([data_planes[s] for s in range(k)])
     record_planar_at_rest("egress", int(stacked.size))
-    rows = pstore.planes_to_rows(stacked)          # (k, shard_len)
+    rows = pstore.planes_to_rows(stacked, layout)   # (k, shard_len)
     return _assemble_logical({s: rows[s] for s in range(k)},
                              k, nstripes, unit, logical_size)
 
@@ -1015,11 +1050,13 @@ def decode_planes_multi(codec, sinfo: StripeInfo, reqs):
     a relayout conversion (legal, counted, never on the steady state).
     """
     from ceph_tpu.ec import planar_store as pstore
+    from ceph_tpu.ec.codec import engine_layout
     from ceph_tpu.utils.perf import KERNELS
 
     k = sinfo.k
     unit = sinfo.chunk_size
     n = codec.get_chunk_count()
+    layout = engine_layout(codec)
     out: List = [None] * len(reqs)
     groups: Dict[Tuple, List] = {}
     for i, (shards, logical_size) in enumerate(reqs):
@@ -1032,7 +1069,7 @@ def decode_planes_multi(codec, sinfo: StripeInfo, reqs):
         missing = tuple(s for s in range(k) if s not in arrs)
         if not missing:
             out[i] = _assemble_from_planes(arrs, k, nstripes, unit,
-                                           logical_size)
+                                           logical_size, layout)
             continue
         if len(arrs) < k:
             raise ValueError(f"only {len(arrs)} of {k} shards")
@@ -1060,7 +1097,8 @@ def decode_planes_multi(codec, sinfo: StripeInfo, reqs):
             for i, arrs, ns, logical_size in items:
                 byte_shards = {
                     s: np.frombuffer(
-                        pstore.planes_to_shard(a, seam="relayout"),
+                        pstore.planes_to_shard(a, seam="relayout",
+                                               layout=layout),
                         dtype=np.uint8)
                     for s, a in arrs.items()}
                 out[i] = decode_stripes_multi(
@@ -1074,7 +1112,7 @@ def decode_planes_multi(codec, sinfo: StripeInfo, reqs):
                 data_planes[e] = rec[idx * 8:idx * 8 + 8, c0:c0 + cw]
             c0 += cw
             out[i] = _assemble_from_planes(data_planes, k, ns, unit,
-                                           logical_size)
+                                           logical_size, layout)
     return out
 
 
@@ -1090,11 +1128,13 @@ def reencode_planes_multi(codec, sinfo: StripeInfo, reqs):
     through untouched.
     """
     from ceph_tpu.ec import planar_store as pstore
+    from ceph_tpu.ec.codec import engine_layout
     from ceph_tpu.utils.perf import KERNELS
 
     k = sinfo.k
     unit = sinfo.chunk_size
     n = codec.get_chunk_count()
+    layout = engine_layout(codec)
     out: List = [None] * len(reqs)
     groups: Dict[Tuple, List] = {}
     for i, (shards, logical_size) in enumerate(reqs):
@@ -1133,12 +1173,13 @@ def reencode_planes_multi(codec, sinfo: StripeInfo, reqs):
                 for i, arrs, ns, logical_size in items:
                     byte_shards = {
                         s: np.frombuffer(
-                            pstore.planes_to_shard(a, seam="relayout"),
+                            pstore.planes_to_shard(a, seam="relayout",
+                                                   layout=layout),
                             dtype=np.uint8)
                         for s, a in arrs.items()}
                     rows = reencode_stripes_multi(
                         codec, sinfo, [(byte_shards, logical_size)])[0]
-                    out[i] = pstore.rows_to_planes(rows).reshape(
+                    out[i] = pstore.rows_to_planes(rows, layout).reshape(
                         n, 8, rows.shape[1] // 8)
                     pstore.record_planar_at_rest(
                         "relayout", int(rows.size))

@@ -395,10 +395,16 @@ def _planar_spread(planes: np.ndarray) -> np.ndarray:
     return (bits << shifts).astype(np.uint8)
 
 
-def crc32c_planar_rows(planes, seed: int = 0xFFFFFFFF):
+def crc32c_planar_rows(planes, seed: int = 0xFFFFFFFF,
+                       packetsize: int = 0):
     """(G*8, cols) packed bit-planes -> list of G ``ceph_crc32c(seed,
     byte_view)`` values, one per 8-row plane group, WITHOUT building the
     byte view.
+
+    ``packetsize``: the rows are packet rows (``ec/planar_store.py``'s
+    ``packet8`` serialization) and the byte stream is their packets,
+    super-block major: whole packets change places and no bit moves, so
+    the rows' bytes are walked in that order through ``crc32c_rows``.
 
     Rows come in eights (group g = rows 8g..8g+7 = one shard's at-rest
     planes, ec/planar_store.py layout).  Device backends run ONE
@@ -421,6 +427,11 @@ def crc32c_planar_rows(planes, seed: int = 0xFFFFFFFF):
     KERNELS.inc("crc32c_planar_bytes", g * length)
     if length == 0:
         return [crc32c(seed, b"")] * g
+    if packetsize:
+        ns = cols // packetsize
+        return [int(c) for c in crc32c_rows(
+            arr.reshape(g, 8, ns, packetsize).transpose(0, 2, 1, 3)
+            .reshape(g, length), seed)]
     if _gcrc is None and length <= _PLANAR_DEV_MAX:
         import jax
 
@@ -467,14 +478,17 @@ _CHUNK_TEMP_BYTES = 32 << 20
 
 
 @functools.lru_cache(maxsize=16)
-def _chunk_bitmat_dev(unit: int):
+def _chunk_bitmat_dev(unit: int, rows: int = 8):
     """``_planar_message_bitmat(unit)`` as the (8*unit, 32) int8 right-hand
     side of the chunk program, rows bit-major (row u*unit + p is bit u of
     blob byte p): the program lays a blob's 8 bit positions side by side
-    and never interleaves them.  1 MiB at 4 KiB, cached on the device."""
+    and never interleaves them.  1 MiB at 4 KiB, cached on the device.
+    A one-row blob (``rows`` 1: a packet) is its bytes in order, and
+    takes the plain message matrix."""
     import jax.numpy as jnp
 
-    mat = _planar_message_bitmat(unit).reshape(32, unit, 8)
+    mat = (_planar_message_bitmat(unit) if rows == 8
+           else _message_bitmat(unit)).reshape(32, unit, 8)
     return jnp.asarray(mat.transpose(2, 1, 0).reshape(8 * unit, 32),
                        dtype=jnp.int8)
 
@@ -489,15 +503,20 @@ def _chunk_crcs_jit():
     # ``jit__chunk_crcs_planes``.  It must not contain ``jit__planar_tiled``
     # (planar_roofline.write sums the device time of the programs so named
     # against the encode matmul's bytes; pinned by tests/test_tick_trace.py).
-    @functools.partial(jax.jit, static_argnums=2)
-    def _chunk_crcs_planes(bitmat, planes, unit: int):
-        cols = unit // 8
+    @functools.partial(jax.jit, static_argnums=(2, 3))
+    def _chunk_crcs_planes(bitmat, planes, unit: int, rows: int = 8):
+        # a blob is ``unit`` bytes: ``cols`` consecutive columns of
+        # ``rows`` plane rows (8: a chunk's plane group; 1: a packet)
+        cols = unit // rows
         bb = planes[0].shape[1] // cols
-        n = sum(p.shape[0] for p in planes) // 8
+        n = sum(p.shape[0] for p in planes) // rows
         gs = max(1, min(bb, _CHUNK_TEMP_BYTES // (n * 8 * unit)))
-        gs = 1 << (gs.bit_length() - 1)         # bb is a power of two
+        gs = 1 << (gs.bit_length() - 1)
+        while bb % gs:              # bb is a power of two, or times ns
+            gs //= 2
         groups = jnp.concatenate(
-            [p.reshape(-1, 8, bb // gs, gs, cols).transpose(2, 0, 3, 1, 4)
+            [p.reshape(-1, rows, bb // gs, gs, cols)
+             .transpose(2, 0, 3, 1, 4)
              .reshape(bb // gs, -1, gs, unit) for p in planes],
             axis=1)                             # (G, n, gs, unit) blobs
         weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
@@ -519,13 +538,18 @@ def _chunk_crcs_jit():
     return _chunk_crcs_planes
 
 
-def planar_chunk_crcs(planes, unit: int):
+def planar_chunk_crcs(planes, unit: int, packetsize: int = 0):
     """Device plane matrices ``[(c_i*8, bb*unit/8), ...]`` over one column
     axis -> the DEVICE (sum c_i, bb) uint32 array whose [s, j] is
     ``ceph_crc32c(0, bytes of shard s in stripe j)``.  Launched, not
     waited for, and its words set off for the host as soon as the device
     has them: the caller's ``np.asarray`` finds them there, and folds
-    each op's columns with ``fold_chunk_crcs``."""
+    each op's columns with ``fold_chunk_crcs``.
+
+    ``packetsize``: the matrices are packet rows, and what comes back is
+    (sum c_i * 8, bb * ns) words, [s*8 + t, j*ns + b] the crc of packet t
+    of super-block b of shard s's chunk in stripe j: ``packet_stream``
+    puts an op's columns in the byte stream's order for the fold."""
     from ceph_tpu.trace import tick as ticktrace
     from ceph_tpu.utils.perf import KERNELS
 
@@ -534,6 +558,19 @@ def planar_chunk_crcs(planes, unit: int):
     KERNELS.inc("crc32c_planar_bytes",
                 sum(int(p.shape[0]) * int(p.shape[1]) for p in planes))
     ticktrace.device_calls()            # the chunk program
-    words = _chunk_crcs_jit()(_chunk_bitmat_dev(unit), planes, unit)
+    # a blob: a chunk's 8-row plane group, or one packet of one row
+    blob, rows = (packetsize, 1) if packetsize else (unit, 8)
+    words = _chunk_crcs_jit()(_chunk_bitmat_dev(blob, rows), planes, blob,
+                              rows)
     words.copy_to_host_async()
     return words
+
+
+def packet_stream(words: np.ndarray) -> np.ndarray:
+    """(n*8, c) packet crcs of a run of columns -> (n, c*8): each shard's
+    packets in the order its bytes have them (a column's 8 rows, then the
+    next column), which is what ``fold_chunk_crcs`` joins."""
+    n8, c = words.shape
+    return np.ascontiguousarray(
+        words.reshape(n8 // 8, 8, c).transpose(0, 2, 1)).reshape(n8 // 8,
+                                                                  c * 8)

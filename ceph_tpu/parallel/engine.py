@@ -275,9 +275,9 @@ def wrap_codec_for_mesh(codec, n_devices: int = 0):
     """Return a mesh-routed adapter for codecs with a GF(2^8) coding
     matrix, or the codec unchanged when it cannot ride the mesh engine
     (wide-w / bitmatrix families keep their single-device path)."""
-    from ceph_tpu.ec.codec import matrix_engine
+    from ceph_tpu.ec.codec import bytewise_engine
 
-    if matrix_engine(codec) is None:
+    if bytewise_engine(codec) is None:
         return codec
     return MeshCodecAdapter(codec, mesh_for_codec(codec, n_devices))
 

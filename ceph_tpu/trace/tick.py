@@ -133,8 +133,8 @@ class Tick:
     four numbers per phase: [phase index, start, end, device calls]."""
 
     __slots__ = ("log", "name", "daemon", "seq", "op_ids", "stripes",
-                 "bucket", "payload_bytes", "thread", "calls", "cpu_ns",
-                 "t", "_phase", "_phase_t0", "_phase_calls")
+                 "bucket", "payload_bytes", "layout", "thread", "calls",
+                 "cpu_ns", "t", "_phase", "_phase_t0", "_phase_calls")
 
     def __init__(self, log: "TickLog", name: str, daemon: str, seq: int,
                  op_ids: Sequence):
@@ -146,6 +146,7 @@ class Tick:
         self.stripes = 0
         self.bucket = 0
         self.payload_bytes = 0
+        self.layout = ""        # the planes' serialization, once annotated
         self.thread = 0
         self.calls = 0
         self.cpu_ns = 0         # the worker thread's CPU time inside run
@@ -273,6 +274,8 @@ class Tick:
             meta: Dict = {"device_calls": calls} if calls else {}
             if name == "crc":
                 meta["path"] = "device" if calls else "host"
+            if name in ("crc", "to_planar") and self.layout:
+                meta["layout"] = self.layout
             out.append(span(f"{trace_id}:s{i}", root_id, name, t0, t1,
                             meta))
         return out
@@ -361,11 +364,15 @@ def device_calls(n: int = 1) -> None:
         tick.calls += n
 
 
-def annotate(stripes: int, bucket: int, payload_bytes: int) -> None:
+def annotate(stripes: int, bucket: int, payload_bytes: int,
+             layout: str = "") -> None:
     """What the open tick (if any) encodes: stripes before padding, the
-    bucket after it, and the client bytes."""
+    bucket after it, the client bytes, and the serialization its planes
+    are in (``bitpack`` / ``packet``: the ``to_planar`` and ``crc`` spans
+    of a dump say it)."""
     tick = getattr(_CURRENT, "tick", None)
     if tick is not None:
         tick.stripes = stripes
         tick.bucket = bucket
         tick.payload_bytes = payload_bytes
+        tick.layout = layout

@@ -298,12 +298,14 @@ OPTIONS: List[Option] = [
     # (sub-writes/sub-reads/recovery push) and verified as packed
     # bit-plane matrices — zero layout conversions on the steady-state
     # write/read/RMW/recovery/scrub paths (pinned by the
-    # ec_planar_unseamed counter).  Needs a bytewise GF(2^8) matrix
-    # code (the Reed-Solomon families, SHEC at w=8, LRC over such layers:
-    # ec.codec.matrix_engine is the one question) and
-    # stripe_unit % 8 == 0; a pool without them (packet-interleaved
-    # techniques, w=16/32) stays on byte-at-rest whatever this says
-    # (ec.stripe.planar_at_rest_ok).
+    # ec_planar_unseamed counter).  Needs a GF(2^8) matrix code (the
+    # Reed-Solomon families, SHEC at w=8, LRC over such layers, and the
+    # w=8 cauchy techniques, whose shards rest as packet rows:
+    # ec.codec.matrix_engine is the one question, engine_layout names
+    # the serialization) and a stripe unit of whole quanta (8 bytes; a
+    # packet code's super-block); a pool without them (w=16/32, the
+    # liberation family) stays on byte-at-rest whatever this says
+    # (ec.stripe.at_rest_layout).
     # 0 = byte-at-rest for every pool.
     Option("osd_ec_planar_at_rest", int, 1, min=0, max=1),
     # route EC pool batch encode/decode through the sharded mesh engine

@@ -43,9 +43,11 @@ from ceph_tpu.utils.perf import PerfCounters
 from tests._flaky import contention_retry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# every cell of the benchmark: all of them write (the read cell, which
+# waits under benchmark/pending/, reports none of these)
 CELLS = ("k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
          "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16",
-         "shec_k6m4c3_write_4m_t16")
+         "shec_k6m4c3_write_4m_t16", "cauchy_k4m2_write_4m_t16")
 K, M_ = 2, 1
 PROFILE = {"plugin": "jerasure", "technique": "reed_sol_van",
            "k": str(K), "m": str(M_)}
@@ -1017,7 +1019,7 @@ def test_a_metric_file_reads_the_hand_worked_value(name):
         (None if name in READ_NOTHING else 0.0)
 
 
-def test_the_accounts_entries_come_last_and_every_cell_reports_them():
+def test_the_accounts_entries_come_last_and_every_cell_reports_them(tmp_path):
     """Last of what stood when they came (28): a later PR's entries
     follow them (PR 43's two of the store's populated mappings, PR 45's
     two of the pool those mappings are taken from)."""
@@ -1031,9 +1033,28 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them():
                           "better": "lower", "source": "program_counter",
                           "layer": layer, "moves": "write_MBps",
                           "workloads": list(CELLS)}
-    assert [m["name"] for m in spec["per_layer"][28 + len(METRICS):]] == \
+    later = [m["name"] for m in spec["per_layer"][28 + len(METRICS):]]
+    assert later[:4] == \
         ["store_populated_share.write", "store_populate_ms_per_op.write",
          "store_pooled_share.write", "store_pool_touch_ms_per_op.write"]
+    # PR 48: the serialization a window's bytes took
+    assert later[4] == "packet_ingest_share.write"
+    # every cell that writes reports the account; the read cell, with
+    # its pending entries appended (PR 46's twelve, behind all of
+    # these), reports its own loop_busy_share and none of these
+    cells = [w["name"] for w in spec["workloads"]]
+    assert [c for c in cells if "write" in c] == list(CELLS)
+    from tests._pending import root_of
+    root = root_of("k2m1_degraded_randread_4m_t16", tmp_path)
+    with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
+        after = [m["name"] for m in json.load(f)["per_layer"]]
+    assert after[:28 + len(METRICS) + 5] == \
+        [m["name"] for m in spec["per_layer"][:28 + len(METRICS) + 5]]
+    assert len(after) == 28 + len(METRICS) + 5 + 12
+    assert all(name.endswith(".read")
+               for name in after[28 + len(METRICS) + 5:])
+    assert not set(METRICS) & set(
+        load_cell("k2m1_degraded_randread_4m_t16", root=root).per_layer)
 
 
 def test_the_burst_reads_sane_through_the_metric_files(burst):

@@ -125,9 +125,12 @@ _LAYERS_WITH_A_PACKET_LAYER = {
                   "k": "4", "m": "2"}, 4096, True, id="rs_van"),
     pytest.param({"plugin": "isa", "k": "8", "m": "4"}, 4096, True,
                  id="isa"),
-    # every other code keeps byte-at-rest, by the same one question
+    # a w = 8 packet-interleaved bit-matrix code too, since PR 48: its
+    # chunks rest as their packet-row matrix (another serialization of
+    # the same plane; tests/test_cauchy_deployment.py)
     pytest.param({"plugin": "jerasure", "technique": "cauchy_good",
-                  "k": "4", "m": "2"}, 16384, False, id="cauchy_good"),
+                  "k": "4", "m": "2"}, 16384, True, id="cauchy_good"),
+    # every other code keeps byte-at-rest, by the same one question
     pytest.param({"plugin": "jerasure", "technique": "liberation",
                   "k": "4", "m": "2", "w": "7"}, 14336, False,
                  id="liberation"),

@@ -1084,7 +1084,7 @@ def test_a_sub_write_that_arrives_twice_lands_the_same_object(monkeypatch):
 
 CELLS = ["k2m1_write_4m_t16", "k2m1_write_64k_t16", "k4m2_write_4m_t16",
          "k8m4_write_4m_t16", "lrc_k4m2l3_write_4m_t16",
-         "shec_k6m4c3_write_4m_t16"]
+         "shec_k6m4c3_write_4m_t16", "cauchy_k4m2_write_4m_t16"]
 # name: (the reader's numerator, denominator, scale; a window's growth
 # and what it reads; the growth of a program without the numerator)
 _METRICS = {

@@ -107,6 +107,33 @@ def test_batch_to_planes_compiles_for_v5e(one_chip, bb, k):
         _shape((bb, k, 4096), jnp.uint8, one_chip), 8).compile()
 
 
+# a cauchy_good k4m2 tick (PR 48): one 4 MiB object is 16 stripes of
+# 64 KiB chunks; the largest bucket warmed ahead is 8 objects
+@pytest.mark.parametrize("bb", [16, 128])
+def test_batch_to_planes_packet_compiles_for_v5e(one_chip, bb):
+    from ceph_tpu.ec.planar import _batch_to_planes_packet
+
+    compiled = _batch_to_planes_packet.lower(
+        _shape((bb, 4, 65536), jnp.uint8, one_chip), 8, 2048).compile()
+    # whole packets change places: no bit is shifted out of a byte
+    assert "shift-right-logical" not in compiled.as_text()
+
+
+def test_chunk_crcs_program_compiles_for_v5e_on_packet_rows(one_chip):
+    """The same program on one-row blobs (a packet is 2048 consecutive
+    bytes of its row): 8 objects' planes, (48, 128 * 4) words back."""
+    from ceph_tpu.ops.crc32c import _chunk_crcs_jit
+
+    p, bb, ns = 2048, 128, 4
+    compiled = _chunk_crcs_jit().lower(
+        _shape((8 * p, 32), jnp.int8, one_chip),
+        (_shape((4 * 8, bb * ns * p), jnp.uint8, one_chip),
+         _shape((2 * 8, bb * ns * p), jnp.uint8, one_chip)),
+        p, 1).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "convolution(" in text
+
+
 def test_crc32c_batch_compiles_for_v5e(one_chip):
     from ceph_tpu.ops.crc32c import _crc32c_batch_jit
 
