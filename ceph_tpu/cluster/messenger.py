@@ -25,8 +25,17 @@ Copies (PR 29).  A frame is ``<u32 len><type><body>``.  Sending: the
 message is pickled with protocol 5; a field that carries object data
 (``messages.py`` says which, through ``oob``) and is read-only and at
 least ``_OOB_MIN`` long stays OUT of the pickle, and header, pickle,
-those buffers and the signature go to the transport as separate
-buffers (``writelines``: one ``sendmsg``).  A payload byte is not
+those buffers and the signature go out as separate buffers, one
+``sendmsg`` when the socket has room: through the transport
+(``writelines``) on the loop thread, or, a frame of ``_IO_MIN`` =
+256 KiB or more since PR 50, through one of the process's two sender
+threads, which writes the same buffers to the connection itself (the
+send is the kernel's copy into the socket, GIL released: the one
+thread every daemon waits for no longer stands in it).  A stream's
+bytes leave in the order ``write`` was called, whoever writes them,
+and ``drain`` returns once the frame has left user space, so
+``Connection.send`` and ``send_message`` hold their locks as before
+(``_FrameStream``).  A payload byte is not
 copied in user space on the sending side; the replay buffer keeps
 references.  Receiving: ``_FrameStream`` has the transport
 ``recv_into`` one ``bytearray`` per large frame, and verification,
@@ -107,8 +116,13 @@ import asyncio
 import collections
 import copyreg
 import itertools
+import os
 import pickle
+import queue
+import select
+import socket
 import struct
+import threading
 import hmac as _hmac
 import hashlib
 import time as _time
@@ -150,6 +164,51 @@ _SOCK_BUF = 2 << 20
 # next one's length prefix is moved once more
 _RECV_SCRATCH = 256 << 10
 _RECV_PEEK = 4 << 10
+# the same line on the sending side: a frame of at least _IO_MIN is
+# written to its socket by one of the process's _IO_THREADS sender
+# threads and not by the loop thread (_FrameStream.write, _Senders).
+# Every cell is bound by the one loop, and at 4 MiB a third of the
+# loop's op was the wall inside its own sendmsg calls, GIL released, in
+# the kernel (`loop_send_ms_per_op.write` 3.6-7.7 ms of 12.7-23.6:
+# ledger, PR 48).  What chose the numbers (the builder's chip runs of a
+# refused PR 49, scratch switches on `k4m2_write_4m_t16`, same-seed
+# pairs of 51 s; PERF.md section 5 (14)): the send half on two threads
+# +5.5 to +8.2% `write_MBps` in six pairs of six (`loop_send` 5.5-6.2 ->
+# 2.0-2.2 ms an op); ONE thread -9 to -11% (the loop 74-76% busy, waiting
+# for the one sender); the receive half on threads nothing (what left
+# the recv stamp came back as transport time), and both halves on four
+# threads -4 to +4%: beside them the store's refill thread starved and
+# the populate came back on the loop (`store_pooled_share` 100 -> 54%).
+# So two threads, sends only.  The length: a hand-over has a fixed price
+# (a wake of the loop through its self-pipe and a handle of its own for
+# the completion, ~1.2 ms of `io_wait` a frame on the loaded chip host),
+# which the 64 KiB cell's frames (ops of 64 KiB, sub-writes of 32 KiB),
+# commit replies, acks, pings and map traffic would pay for nothing: a
+# send of theirs is a copy of a few KiB.  At 4 MiB every sub-write
+# (2 MiB, 1 MiB, 684 KiB, 512 KiB) and the client's op is over it.
+# This tree's own same-seed pairs (PR 50's builder, PERF.md section 6):
+# `k4m2_write_4m_t16` +4.7 to +9.9% in six of six; and the line itself,
+# on `k8m4_write_4m_t16`, whose sub-writes are the smallest that engage:
+# +5.0% at 256 KiB against +1.3% at 1 MiB (only the client's op engages
+# then), so the 512 KiB frames pay and the line is where a frame gets a
+# buffer of its own; the 64 KiB cell is the same at either (7 frames of
+# a window's 57000 reach 256 KiB)
+_IO_MIN = 256 << 10
+_IO_THREADS = 2
+# how long a sender thread waits for a socket to take ANY more bytes
+# before it fails the stream (a peer that stopped reading: a wedged or
+# throttled read loop), and the slices it waits in, at whose edges it
+# sees that the stream was closed under it.  A thread that waits serves
+# no other stream, so the wait is bounded where the loop's own (drain:
+# flow control) is not: by the heartbeat grace of a vstart cluster
+# (10 s), the time after which a peer that answers nothing is taken for
+# dead anyway; the session then reconnects and replays, as after a reset.
+# The slice bounds how long a close waits for the thread to let go, far
+# under _CLOSE_WAIT_S
+_IO_STALL_S = 10.0
+_IO_POLL_S = 0.05
+# sendmsg takes at most IOV_MAX buffers a call
+_IOV_MAX = 1024
 # a length prefix over this is a corrupt or hostile one, not a frame: a
 # frame's buffer is allocated when its prefix is read, before any of it
 # has arrived (the largest frames are client batches of 16 x 4 MiB)
@@ -192,16 +251,64 @@ _READER_BUCKET = {"osd": "msgr", "client": "client"}
 
 
 def _tune_socket(stream: "_FrameStream") -> None:
-    import socket as _socket
-
     sock = stream.transport.get_extra_info("socket")
     if sock is None:
         return
     try:
-        sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_SNDBUF, _SOCK_BUF)
-        sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_RCVBUF, _SOCK_BUF)
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, _SOCK_BUF)
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, _SOCK_BUF)
     except OSError:  # pragma: no cover - exotic transports
         pass
+
+
+# the socket's own C method: a sender thread's call is nobody's to time
+# (loopacct.TimedSocket is the LOOP thread's account)
+_sendmsg = socket.socket.sendmsg
+
+
+def _rest(parts: list, n: int) -> list:
+    """``parts`` less their first ``n`` bytes: whole buffers dropped, the
+    one the count ends in sliced (a view: nothing is copied)."""
+    for i, part in enumerate(parts):
+        size = len(part)
+        if n < size:
+            rest = list(parts[i:])
+            if n:
+                rest[0] = memoryview(part)[n:]
+            return rest
+        n -= size
+    return []
+
+
+class _Senders:
+    """The process's sender threads: ``_IO_THREADS`` daemon threads,
+    started by the first large frame, that take ``(stream, parts, t0)``
+    off one queue and run ``stream._io_send``.  A stream has at most one
+    frame here (``_FrameStream._io_busy``), so the queue's order is no
+    stream's concern."""
+
+    def __init__(self):
+        self.jobs: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.threads: List[threading.Thread] = []
+
+    def submit(self, stream: "_FrameStream", parts: list, t0: int) -> None:
+        if not self.threads:
+            self.threads = [
+                threading.Thread(target=self._run, name=f"msgr-send-{i}",
+                                 daemon=True)
+                for i in range(_IO_THREADS)]
+            for thread in self.threads:
+                thread.start()
+        self.jobs.put((stream, parts, t0))
+
+    def _run(self) -> None:
+        take = self.jobs.get
+        while True:
+            stream, parts, t0 = take()
+            stream._io_send(parts, t0)
+
+
+_IO = _Senders()
 
 
 class _FrameStream(asyncio.BufferedProtocol):
@@ -223,9 +330,31 @@ class _FrameStream(asyncio.BufferedProtocol):
     some, so a reader that waits (for a ``Throttle``, in dispatch)
     stops the drain and TCP pushes back on the peer.
 
-    Send: ``write`` hands a frame's parts to the transport as they are
-    (``writelines``: one ``sendmsg``, no joined copy); ``drain`` waits
-    while the transport is over its high-water mark."""
+    Send: ``write`` hands a frame's parts on as they are, nothing
+    joined or copied.  A frame under ``_IO_MIN`` goes to the transport
+    (``writelines``: one ``sendmsg`` on the loop thread), as every frame
+    did before PR 50.  A frame of ``_IO_MIN`` or more is written by one
+    of the process's sender threads (``_Senders``), to a duplicate of the
+    connection's descriptor that is the stream's own (``_io_socket``: the
+    transport may close ITS descriptor whenever the peer resets, and the
+    number must not be another connection's while a thread still writes
+    to it), with ``socket.socket``'s C ``sendmsg`` until the parts are
+    gone, waiting for room itself (``poll``).  The bytes leave in the
+    order ``write`` was called: a stream has ONE frame with a thread at
+    a time, while it has, every later ``write`` (small ones too) queues
+    behind it in ``_pending``, and a large frame is handed over only
+    once the transport has flushed what it buffered of the small ones
+    before it, so two threads never write one socket at once.  The
+    thread's completion (``_io_done``, on the loop) sends what queued
+    up: small parts through the transport, the next large frame back to
+    a thread.  ``drain`` returns when nothing of the stream is queued,
+    with a thread, or over the transport's high-water mark: the frame
+    has left user space, as before.  A thread's ``OSError``, its wait
+    for room running to ``_IO_STALL_S``, or the stream closed under it
+    end the connection as a transport's write error does (``abort``:
+    the drains raise ``ConnectionResetError``, the session replays on a
+    new connection; the receiver drops the partial frame), and
+    ``wait_closed`` does not return while a thread holds the socket."""
 
     def __init__(self, on_connect=None):
         self._loop = asyncio.get_running_loop()
@@ -242,7 +371,20 @@ class _FrameStream(asyncio.BufferedProtocol):
         self._error: Optional[Exception] = None
         self._write_paused = False
         self._drainers: collections.deque = collections.deque()
-        # done once connection_lost ran
+        # the send side's order (class docstring): (large, parts) of the
+        # writes that wait behind a frame with a sender thread, or, a
+        # large one at their head, for the transport to flush
+        # (_flushing: its limits are at 0 until it has); whether a
+        # thread has a frame of this stream; the descriptor the threads
+        # write to; and what tells a thread to let go
+        self._pending: collections.deque = collections.deque()
+        self._flushing = False
+        self._io_busy = False
+        self._io_sock: Optional[socket.socket] = None
+        self._io_halt = False
+        self._lost = False
+        # done once connection_lost ran and no sender thread holds the
+        # socket
         self._closed = self._loop.create_future()
 
     # -- transport callbacks ------------------------------------------------
@@ -321,9 +463,20 @@ class _FrameStream(asyncio.BufferedProtocol):
         return False    # the transport closes itself
 
     def connection_lost(self, exc) -> None:
-        self._closed.set_result(None)
+        self._lost = self._io_halt = True
+        self._pending.clear()
         self._fail(exc if isinstance(exc, ConnectionError)
                    else ConnectionResetError("connection lost"))
+        if not self._io_busy:
+            self._finish_close()
+        # else the sender thread's completion does: it lets go at its
+        # next look (_io_send), within _IO_POLL_S
+
+    def _finish_close(self) -> None:
+        if self._io_sock is not None:
+            self._io_sock.close()
+            self._io_sock = None
+        self._closed.set_result(None)
         self._wake_drainers()
 
     def pause_writing(self) -> None:
@@ -331,6 +484,12 @@ class _FrameStream(asyncio.BufferedProtocol):
 
     def resume_writing(self) -> None:
         self._write_paused = False
+        if self._flushing:
+            # the transport's buffer is empty: the large frame that
+            # waited for it can go
+            self._flushing = False
+            self.transport.set_write_buffer_limits()
+            self._pump()
         self._wake_drainers()
 
     def _wake_drainers(self) -> None:
@@ -362,15 +521,143 @@ class _FrameStream(asyncio.BufferedProtocol):
     def write(self, parts: list) -> None:
         # a closing transport takes nothing more (as StreamWriter.write
         # drops it); drain() is where the sender learns of it
-        if not self.transport.is_closing():
+        if self.transport.is_closing():
+            return
+        large = sum(map(len, parts)) >= _IO_MIN
+        if not (large or self._io_busy or self._pending):
             self.transport.writelines(parts)
+            return
+        self._pending.append((large, parts))
+        self._pump()
+
+    def _pump(self) -> None:
+        """Send what is queued, in order, as far as it can go now: up to
+        a large frame, which goes to a sender thread if the transport
+        buffers nothing, and else waits for that (the transport tells
+        when its buffer falls to its low-water mark, so the mark is 0
+        for as long)."""
+        pending = self._pending
+        transport = self.transport
+        if transport.is_closing():
+            pending.clear()     # as write() drops it
+            return
+        while pending and not self._io_busy:
+            large, parts = pending[0]
+            if not large:
+                transport.writelines(parts)
+            elif transport.get_write_buffer_size():
+                if not self._flushing:
+                    self._flushing = True
+                    transport.set_write_buffer_limits(high=0)
+                return
+            else:
+                self._hand_over(parts)
+            pending.popleft()
+
+    def _io_socket(self) -> Optional[socket.socket]:
+        """The socket the sender threads write this stream's frames to:
+        a duplicate of the transport's descriptor (same connection, same
+        buffers, same non-blocking mode), closed by ``_finish_close``
+        alone, and a plain ``socket.socket``, not the loop account's."""
+        sock = self.transport.get_extra_info("socket")
+        if sock is None:
+            return None
+        try:
+            fd = os.dup(sock.fileno())
+        except OSError:
+            return None
+        dup = socket.socket(fileno=fd)
+        dup.setblocking(False)
+        return dup
+
+    def _hand_over(self, parts: list) -> None:
+        if self._io_sock is None:
+            self._io_sock = self._io_socket()
+        if self._io_sock is None:
+            # no descriptor to write to (none left to duplicate, or a
+            # transport that has no socket): the loop sends it after all
+            KERNELS.inc("msgr_io_fallback")
+            self.transport.writelines(parts)
+            return
+        self._io_busy = True
+        _IO.submit(self, parts, _time.perf_counter_ns())
+
+    def _io_send(self, parts: list, t0: int) -> None:
+        """ON A SENDER THREAD: write ``parts`` to the socket until they
+        are gone, then tell the loop.  No Python a byte: a call takes
+        what the socket's buffer has room for, and the wait for more
+        room is a ``poll`` of the one descriptor."""
+        sock = self._io_sock
+        sent = 0
+        error = None
+        began = _time.perf_counter_ns()
+        try:
+            poller = None
+            while parts:
+                if self._io_halt:
+                    raise ConnectionAbortedError(
+                        "stream closed under its frame")
+                try:
+                    n = _sendmsg(sock, parts[:_IOV_MAX])
+                except (BlockingIOError, InterruptedError):
+                    n = 0
+                if n:
+                    sent += n
+                    parts = _rest(parts, n)
+                    if not parts:
+                        break
+                # the socket's buffer is full: wait for room, in slices,
+                # at whose edges a closed stream is seen
+                if poller is None:
+                    poller = select.poll()
+                    poller.register(sock.fileno(), select.POLLOUT)
+                stalled = 0.0
+                while not (poller.poll(_IO_POLL_S * 1e3) or self._io_halt):
+                    stalled += _IO_POLL_S
+                    if stalled >= _IO_STALL_S:
+                        raise TimeoutError(
+                            f"peer took no byte for {_IO_STALL_S} s")
+        except Exception as exc:
+            error = exc
+        took = _time.perf_counter_ns() - began
+        try:
+            self._loop.call_soon_threadsafe(
+                self._io_done, error, sent, took, t0)
+        except RuntimeError:
+            pass    # the loop is closed: nobody is left to tell
+
+    def _io_done(self, error: Optional[Exception], sent: int, took: int,
+                 t0: int) -> None:
+        """A sender thread is through with the stream's frame (on the
+        loop): what queued up behind it goes on, or, after an error,
+        the connection ends."""
+        self._io_busy = False
+        KERNELS.inc_many({
+            "msgr_io_send_frames": 1, "msgr_io_send_bytes": sent,
+            "msgr_io_call_ns": took,
+            "msgr_io_wait_ns": _time.perf_counter_ns() - t0})
+        if error is not None and not isinstance(error, OSError):
+            # not the connection's fault: a bug in the sender's own code
+            import logging
+
+            logging.getLogger("ceph_tpu.msgr").error(
+                "sender thread failed on a frame", exc_info=error)
+        if self._lost:
+            self._finish_close()
+        elif error is not None:
+            self._pending.clear()
+            self.transport.abort()      # connection_lost wakes the drains
+        else:
+            self._pump()
+            self._wake_drainers()
 
     async def drain(self) -> None:
         if self.transport.is_closing():
             # let connection_lost run, so that a write into a transport
             # that is going away surfaces here and not a frame later
             await asyncio.sleep(0)
-        while self._write_paused and not self._closed.done():
+        while (self._write_paused or self._io_busy or self._pending) \
+                and not self._closed.done():
             waiter = self._loop.create_future()
             self._drainers.append(waiter)
             try:
@@ -383,6 +670,9 @@ class _FrameStream(asyncio.BufferedProtocol):
             raise ConnectionResetError("connection lost")
 
     def close(self) -> None:
+        # a frame with a sender thread is given up: whoever closes
+        # beside a send in flight voids it (its drain raises)
+        self._io_halt = True
         self.transport.close()
 
     async def wait_closed(self) -> None:
@@ -596,7 +886,10 @@ class Connection:
         never lets it finish — ``wait_closed`` then waits forever, and
         with it every ``shutdown`` up to ``Cluster.stop``.  After
         ``_CLOSE_WAIT_S`` the transport is aborted: the bytes a closing
-        endpoint still owed were void anyway."""
+        endpoint still owed were void anyway.  A large frame that a
+        sender thread is writing is given up at once, and the close
+        returns when the thread has let the socket go (within
+        ``_IO_POLL_S``: ``_FrameStream``)."""
         self.closed = True
         try:
             self.stream.close()

@@ -297,17 +297,39 @@ def test_the_four_stamps_stay_inside_busy_time(burst):
 def test_sent_bytes_are_the_bytes_framed_deferred_writes_included(burst):
     """Every byte the messengers framed (``msgr_frame_bytes``, pickle and
     out-of-band buffers) and its header (5 bytes in band, up to 19 out of
-    band) left through a timed send, and arrived through a timed read in
+    band) left through a timed send of the loop thread or, since PR 50,
+    through a sender thread's (``msgr_io_send_bytes``: the frames of
+    ``messenger._IO_MIN`` and more), and arrived through a timed read in
     the same process: within 2%, though the burst's 8 MiB and 4 MiB
     frames exceed every socket buffer (``_SOCK_BUF`` 2 MiB, doubled at
-    most), so that most of each went out from the transport's deferred
-    ``_write_ready``, in more sends than there were frames."""
+    most)."""
     g = burst["grew"]
     assert g["msgr_frame_bytes"] > LARGE[0] * LARGE[1] * (K + M_) // K
     low = g["msgr_frame_bytes"] + 5 * g["msgr_frames"]
-    assert low <= g["loop_sock_send_bytes"] <= 1.02 * low
-    assert g["loop_sock_recv_bytes"] == g["loop_sock_send_bytes"]
-    assert g["loop_sock_send_calls"] > g["msgr_frames"]
+    sent = g["loop_sock_send_bytes"] + g["msgr_io_send_bytes"]
+    assert low <= sent <= 1.02 * low
+    assert g["loop_sock_recv_bytes"] == sent
+    assert g["loop_sock_send_calls"] >= g["msgr_frames"] \
+        - g["msgr_io_send_frames"]
+
+
+def test_a_sender_threads_sendmsg_is_not_booked_as_the_loops(burst):
+    """``loop_sock_send_ns`` times what the LOOP thread sends.  The
+    large frames' bytes (the four 8 MiB ops, their two 4 MiB sub-writes
+    each to another OSD) left on sender threads, whose socket is a plain
+    ``socket.socket`` and not the account's: with every turn timed the
+    loop's own sends are the small frames' bytes and no more, and no
+    large frame fell back to the loop."""
+    g = burst["grew"]
+    large = LARGE[0] * LARGE[1] * (K + M_ - 1) // K + LARGE[0] * LARGE[1]
+    assert g["msgr_io_send_bytes"] >= large
+    # (the sub-write batcher may put two ops' shards into one frame)
+    assert g["msgr_io_send_frames"] >= 2 * LARGE[0]
+    assert g.get("msgr_io_fallback", 0) == 0
+    small = g["msgr_frame_bytes"] + 19 * g["msgr_frames"] \
+        - g["msgr_io_send_bytes"]
+    assert 0 < g["loop_sock_send_bytes"] <= small
+    assert g["msgr_io_call_ns"] > 0 and g["msgr_io_wait_ns"] > 0
 
 
 def test_a_timed_socket_is_a_tcp_socket_as_asyncios_own_are(burst):
@@ -623,7 +645,9 @@ def test_the_seven_buckets_and_the_four_stamps_are_the_timed_busy_time(
 # (bucket, root coroutine or callback) that a burst of EC writes must
 # show: every kind of root the modules register and the tags name
 SEEN = (("transport", "_SelectorSocketTransport._read_ready"),
-        ("transport", "_SelectorSocketTransport._write_sendmsg"),
+        # a sender thread's completion (PR 50; before it the burst's
+        # large frames left from the transport's deferred _write_sendmsg)
+        ("transport", "_FrameStream._io_done"),
         ("msgr", "Messenger._accept"),
         ("client", "Messenger._read_loop"),
         ("other", "Messenger._accept"),      # a mon's
@@ -1039,6 +1063,9 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them(tmp_path):
          "store_pooled_share.write", "store_pool_touch_ms_per_op.write"]
     # PR 48: the serialization a window's bytes took
     assert later[4] == "packet_ingest_share.write"
+    # PR 50: who sent a window's bytes (the sender threads' share, and
+    # what a hand-over waits)
+    assert later[5:] == ["io_send_share.write", "io_wait_ms_per_op.write"]
     # every cell that writes reports the account; the read cell, with
     # its pending entries appended (PR 46's twelve, behind all of
     # these), reports its own loop_busy_share and none of these
@@ -1048,11 +1075,11 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them(tmp_path):
     root = root_of("k2m1_degraded_randread_4m_t16", tmp_path)
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         after = [m["name"] for m in json.load(f)["per_layer"]]
-    assert after[:28 + len(METRICS) + 5] == \
-        [m["name"] for m in spec["per_layer"][:28 + len(METRICS) + 5]]
-    assert len(after) == 28 + len(METRICS) + 5 + 12
+    assert after[:28 + len(METRICS) + 7] == \
+        [m["name"] for m in spec["per_layer"]]
+    assert len(after) == 28 + len(METRICS) + 7 + 12
     assert all(name.endswith(".read")
-               for name in after[28 + len(METRICS) + 5:])
+               for name in after[28 + len(METRICS) + 7:])
     assert not set(METRICS) & set(
         load_cell("k2m1_degraded_randread_4m_t16", root=root).per_layer)
 
