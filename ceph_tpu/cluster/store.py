@@ -10,9 +10,15 @@ and the dev cluster.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import mmap
+import os
 import pickle
 import queue
+import shutil
+import subprocess
+import tempfile
 import threading
 import time
 from collections import deque
@@ -22,6 +28,7 @@ from typing import Dict, List, Optional, Tuple, Union
 from ceph_tpu.cluster.optracker import mark_current
 from ceph_tpu.ec import planar_store
 from ceph_tpu.trace import loopacct
+from ceph_tpu.utils import compile_cache
 from ceph_tpu.utils.perf import KERNELS
 
 
@@ -45,11 +52,43 @@ _MAP_MIN = 256 << 10
 # spare bytes (mapped, populated, not yet an object's) of the whole
 # process: ten k2m1 ops' worth of shards, more than one tick of eight
 # objects commits at once.  ``statfs`` is logical and does not count
-# them.  ``_POOL_PIECE`` is how much the thread populates in one call:
-# everybody who maps or faults waits that long for the address space.
+# them.  ``_POOL_PIECE`` is how much the kernel is asked to populate in
+# one call: everybody who maps or faults waits that long for the address
+# space.
+#
+# Who walks a spare's pieces (PR 51): ``populate_pieces`` of
+# ``native/store_pool/populate_pieces.c``, ONE foreign call a spare, so
+# that the thread lets the GIL go and asks for it back once a shard and
+# not once a piece (beside a saturated loop, a tick thread an OSD and two
+# senders each return cost it ~0.3 ms, and the one thread stopped keeping
+# up: PERF.md section 5, "First touches off the loop").  No binary is
+# committed: the refill thread, when it starts, builds the source with
+# the host's ``cc`` into ``compile_cache.native_dir()`` (in the checkout,
+# beside ``.jax_cache``; named by the source's hash, so built once a
+# checkout and loaded from then on) and, where there is no compiler or
+# the build or the load fails, walks the pieces in Python as before and
+# says so in the log when it starts.  ``store_pool_calls`` over
+# ``store_pool_spares`` (``KERNELS``) says which walk served: at most 2
+# foreign calls a spare with the C walk, 1 + a call a piece without.
+#
+# ``_POOL_WALK_MAX`` is the longest spare the C walk takes; a longer one
+# (k2m1's 2 MiB shards) is walked in Python as since PR 45.  The length
+# stands for what the store cannot see, whether the loop or the ticks set
+# a pool's pace.  Where the loop does (every pool measured with shards of
+# 1 MiB and less, three OSDs or twelve) the C walk bought 2-17% of rate
+# and no tail.  Where the ticks do (both pools measured with 2 MiB
+# shards) a faster loop moves the queue to the coalescer: k2m1's rate
+# rose 9% and its p95 22% in ten same-seed pairs, half of each with the
+# walk cut to 1 MiB a call, and a sleep or a ``sched_yield`` between the
+# pieces bought nothing back.  The line goes when a tick no longer
+# allocates its buffers afresh (ROADMAP A2 (a); PERF.md section 6,
+# PR 51).
 _POOL_MAX = 64 << 20
 _POOL_PIECE = 256 << 10
+_POOL_WALK_MAX = 1 << 20
 _MAP_FIXED = 0x10                       # Linux, every architecture
+_NATIVE_SRC = os.path.join(compile_cache.CHECKOUT, "native", "store_pool",
+                           "populate_pieces.c")
 
 _libc_mmap = ctypes.CDLL(None, use_errno=True).mmap
 _libc_mmap.restype = ctypes.c_void_p
@@ -57,31 +96,84 @@ _libc_mmap.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_int,
                        ctypes.c_int, ctypes.c_int, ctypes.c_long]
 
 
-def _make_spare(n: int) -> memoryview:
+def _native_walk():
+    """``populate_pieces(base, n, piece)`` -> 0 or an errno, the C walk
+    over a spare's pieces, built on first use; None where this host
+    cannot build or load it (said in the log: once a process, whose one
+    refill thread asks when it starts).  It forks a compiler, so it is
+    that thread's to call and never the loop's.  The library is written
+    under a temporary name and renamed, so a process that finds the name
+    finds all of it."""
+    try:
+        with open(_NATIVE_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        cache = compile_cache.native_dir()
+        lib = os.path.join(cache, f"populate_pieces-{digest}.so")
+        if not os.path.exists(lib):
+            cc = shutil.which("cc")
+            if cc is None:
+                raise OSError("no C compiler (cc) on PATH")
+            os.makedirs(cache, exist_ok=True)
+            fd, tmp = tempfile.mkstemp(dir=cache, suffix=".tmp")
+            os.close(fd)
+            try:
+                subprocess.run(
+                    [cc, "-O2", "-shared", "-fPIC", "-o", tmp, _NATIVE_SRC],
+                    check=True, capture_output=True, timeout=120)
+                os.replace(tmp, lib)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+        walk = ctypes.CDLL(lib, use_errno=True).populate_pieces
+    except (OSError, subprocess.SubprocessError, AttributeError) as exc:
+        logging.getLogger("ceph_tpu.store").warning(
+            "store pool: no native walk (%s): the refill thread walks a "
+            "spare's pieces in Python", exc)
+        return None
+    walk.restype = ctypes.c_int
+    walk.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t]
+    return walk
+
+
+def _make_spare(n: int, walk=None) -> memoryview:
     """A private anonymous mapping of ``n`` bytes that the kernel
     populated, for the refill thread: ``_POOL_PIECE`` at a time, each
-    piece one ``mmap`` call that lets the GIL go.  A populate holds the
-    address space's lock for as long as it lasts, and everybody who maps,
-    unmaps or faults waits for it, the tick threads first: 2 MiB in one
-    call on this thread cost k2m1 a fifth to a half of its p95 and
-    512 KiB a quarter, and pages faulted in one by one (a ``memset``, a
-    write a page) cost it a seventh and are three times as dear to copy
-    into afterwards (PERF.md section 5, "First touches off the loop").
-    A mapping of one piece or less is asked for populated; a longer one
-    is mapped lazily and populated in place, piece by piece
-    (``MAP_FIXED`` over its own range: the mapping stays ``block``'s,
-    which unmaps all of it when it goes)."""
+    piece one ``mmap`` call.  A populate holds the address space's lock
+    for as long as it lasts, and everybody who maps, unmaps or faults
+    waits for it, the tick threads first: 2 MiB in one call on this
+    thread cost k2m1 a fifth to a half of its p95 and 512 KiB a quarter,
+    and pages faulted in one by one (a ``memset``, a write a page) cost
+    it a seventh and are three times as dear to copy into afterwards
+    (PERF.md section 5, "First touches off the loop").  A mapping of one
+    piece or less is asked for populated; a longer one is mapped lazily
+    and populated in place, piece by piece (``MAP_FIXED`` over its own
+    range: the mapping stays ``block``'s, which unmaps all of it when it
+    goes): by ``walk`` (``_native_walk``) in one foreign call that lets
+    the GIL go once, or, with no ``walk`` and for a spare longer than
+    ``_POOL_WALK_MAX``, by a Python loop that lets it go once a piece.
+    The foreign calls made are counted."""
     flags = mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
     if n <= _POOL_PIECE:
-        return memoryview(mmap.mmap(-1, n, flags=flags | mmap.MAP_POPULATE))
-    block = mmap.mmap(-1, n, flags=flags)
-    base = ctypes.addressof(ctypes.c_char.from_buffer(block))
-    flags |= mmap.MAP_POPULATE | _MAP_FIXED
-    for off in range(0, n, _POOL_PIECE):
-        if _libc_mmap(base + off, min(_POOL_PIECE, n - off),
-                      mmap.PROT_READ | mmap.PROT_WRITE, flags, -1,
-                      0) != base + off:
-            raise OSError(ctypes.get_errno(), "mmap")
+        block = mmap.mmap(-1, n, flags=flags | mmap.MAP_POPULATE)
+        calls = 1
+    else:
+        block = mmap.mmap(-1, n, flags=flags)
+        base = ctypes.addressof(ctypes.c_char.from_buffer(block))
+        if walk is not None and n <= _POOL_WALK_MAX:
+            calls = 2
+            refused = walk(base, n, _POOL_PIECE)
+            if refused:
+                raise OSError(refused, "mmap")
+        else:
+            pieces = range(0, n, _POOL_PIECE)
+            calls = 1 + len(pieces)
+            flags |= mmap.MAP_POPULATE | _MAP_FIXED
+            for off in pieces:
+                if _libc_mmap(base + off, min(_POOL_PIECE, n - off),
+                              mmap.PROT_READ | mmap.PROT_WRITE, flags, -1,
+                              0) != base + off:
+                    raise OSError(ctypes.get_errno(), "mmap")
+    KERNELS.inc_many({"store_pool_calls": calls, "store_pool_spares": 1})
     return memoryview(block)
 
 
@@ -152,6 +244,7 @@ class _Pool:
     def _refill(self) -> None:
         ready, order, get = self.ready, self._order, self.taken.get
         clock = time.perf_counter_ns
+        walk = _native_walk()           # here: it may fork a compiler
         while True:
             n = get()                   # parked here while the pool is full
             if n is None:
@@ -184,7 +277,7 @@ class _Pool:
                 try:
                     # no name of the thread's keeps the mapping: it goes
                     # when the object it becomes lets it go
-                    spares.appendleft(_make_spare(n))
+                    spares.appendleft(_make_spare(n, walk))
                 except OSError:
                     break
                 KERNELS.inc("store_pool_touch_ns", clock() - t0)

@@ -888,7 +888,9 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
     ticktrace.annotate(total, bb, sum(len(d) for d in datas),
                        "packet" if packetsize else "bitpack")
     with ticktrace.phase("fill"):
-        batch = np.zeros((total, k, unit), dtype=np.uint8)
+        # at the bucket's size at once: the stripes past ``total`` are
+        # the pad, zeros as allocated
+        batch = np.zeros((bb, k, unit), dtype=np.uint8)
         pad = 0
         ofs = 0
         for d, ns in zip(datas, counts):
@@ -898,9 +900,6 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
             flat[: len(d)] = np.frombuffer(d, dtype=np.uint8)
             pad += ns * sinfo.stripe_width - len(d)
             ofs += ns
-        if bb != total:
-            batch = np.concatenate(
-                [batch, np.zeros((bb - total, k, unit), dtype=np.uint8)])
     KERNELS.inc("ec_stripe_pad_bytes", pad + (bb - total) * k * unit)
     # THE sanctioned ingest: client bytes -> planes, once per tick
     record_planar_at_rest("ingest", total * k * unit)
@@ -912,8 +911,7 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
         rows = np.ascontiguousarray(
             batch.transpose(1, 0, 2).reshape(k, total * unit))
         data_planes = pstore.rows_to_planes(rows, layout)
-        all_planes = np.vstack(
-            [data_planes, _parity_planes_for(codec, data_planes)])
+        parity_planes = _parity_planes_for(codec, data_planes)
     else:
         with ticktrace.phase("to_planar"):
             pb = codec.to_planar(batch)
@@ -943,21 +941,25 @@ def encode_planes_multi(codec, sinfo: StripeInfo, datas, want_crcs=None,
                 ticktrace.device_calls()
                 op_crcs = _fold_op_crcs(np.asarray(chunk_crcs), counts,
                                         want_crcs, unit, packetsize)
-        with ticktrace.phase("slice"):
-            all_planes = np.vstack([data_planes, parity_planes])
         if max_ops:
             _warm_tick_buckets(codec, sinfo, total, bb, len(datas), max_ops,
                                any(want_crcs))
-    # per-op at-rest planes slice straight out of the coalesced plane
-    # matrix: op columns are contiguous (unit % 8 == 0), shard s is
-    # plane rows s*8..s*8+8 — no conversion, no transpose of payload
+    # per-op at-rest planes slice straight out of the tick's data and
+    # parity plane matrices: op columns are contiguous (unit % 8 == 0),
+    # shard s is plane rows s*8..s*8+8, the data shards' rows first — no
+    # conversion, no transpose of payload, and ONE copy a byte: a stack
+    # of the whole tick would write its pad, and every op a second time,
+    # into new pages, which is what a tick's host time is made of
     crc_groups: Dict[int, List] = {}
     with ticktrace.phase("slice"):
         c0 = 0
+        kr = data_planes.shape[0]
         for i, ns in enumerate(counts):
             cw = ns * unit // 8
-            op_planes = np.ascontiguousarray(
-                all_planes[:, c0:c0 + cw]).reshape(n, 8, cw)
+            op_planes = np.empty((n * 8, cw), dtype=np.uint8)
+            op_planes[:kr] = data_planes[:, c0:c0 + cw]
+            op_planes[kr:] = parity_planes[:, c0:c0 + cw]
+            op_planes = op_planes.reshape(n, 8, cw)
             c0 += cw
             out[i] = (op_planes, op_crcs.get(i))
             if want_crcs[i] and i not in op_crcs:

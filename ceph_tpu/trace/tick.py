@@ -16,7 +16,7 @@ cut into host phases at the lines of ``ec/stripe.py`` where the work is:
     encode_dispatch  host time inside codec.encode_planar
     readback       each blocking np.asarray: waits for the device, then
                    device->host
-    slice          np.vstack + the contiguous copy per op
+    slice          the contiguous copy per op, out of the two readbacks
     crc            what exists only because of the shard crcs.  Device
                    path: the chunk-crc program's launch (behind the
                    encode, before the first readback), then the readback
@@ -69,7 +69,7 @@ _PHASE_COUNTERS = {
                         "codec.encode_planar"),
     "readback": ("ec_tick_readback_ns", "blocking readbacks (wait for "
                  "the device + device->host)"),
-    "slice": ("ec_tick_slice_ns", "vstack + per-op contiguous copies"),
+    "slice": ("ec_tick_slice_ns", "per-op contiguous copies"),
     "crc": ("ec_tick_crc_ns", "the shard crcs: device program launch, "
             "readback of its words and fold, or crc32c over the ops' "
             "plane groups on the host"),

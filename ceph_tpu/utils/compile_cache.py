@@ -17,14 +17,22 @@ from __future__ import annotations
 
 import os
 
-_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
 def cache_dir() -> str:
     """The directory the persistent compile cache uses."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
-        os.path.join(_CHECKOUT, ".jax_cache")
+        os.path.join(CHECKOUT, ".jax_cache")
+
+
+def native_dir() -> str:
+    """Where the program keeps what it builds with the host's C compiler
+    (``cluster/store.py``'s walk over a spare's pieces): in the checkout,
+    beside ``.jax_cache``, whatever ``JAX_COMPILATION_CACHE_DIR`` says:
+    the program writes nowhere around its checkout."""
+    return os.path.join(CHECKOUT, ".native_cache")
 
 
 def enable() -> str:

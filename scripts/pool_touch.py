@@ -15,7 +15,11 @@ against its speed alone, and its longest gap (a held GIL or a held
 address-space lock shows there).
 
     pieces    the product's way, ``store._make_spare``: the kernel's
-              populate, ``store._POOL_PIECE`` a call
+              populate, ``store._POOL_PIECE`` a call, the pieces walked by
+              the C function the refill thread builds (one foreign call a
+              mapping; the Python loop where the host cannot build it)
+    pieces_python  the same pieces from the Python loop (PR 45's walk:
+              back at the GIL once a piece)
     populate  mmap.mmap(MAP_POPULATE): the whole mapping in one call
     memset    mmap.mmap lazy + ctypes.memset over all of it
     stride    mmap.mmap lazy + one byte a page written by numpy
@@ -44,7 +48,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from ceph_tpu.cluster.store import _make_spare  # noqa: E402
+from ceph_tpu.cluster.store import _make_spare, _native_walk  # noqa: E402
 
 MIB = 1 << 20
 LAZY = mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
@@ -70,8 +74,9 @@ def stride(n):
     return memoryview(block)
 
 
-WAYS = {"pieces": _make_spare, "populate": populate, "memset": memset,
-        "stride": stride}
+# beside "pieces", which ``main`` adds: it builds the C walk first
+WAYS = {"pieces_python": _make_spare, "populate": populate,
+        "memset": memset, "stride": stride}
 
 
 def main_alone(seconds: float) -> float:
@@ -157,8 +162,10 @@ def main(argv=None) -> int:
     alone = main_alone(0.5)
     print(json.dumps({"mapping_bytes": n, "count": args.count,
                       "main_alone_iters_per_s": alone}), flush=True)
+    walk = _native_walk()
+    ways = {"pieces": lambda n: _make_spare(n, walk), **WAYS}
     for rnd in range(args.rounds):
-        for name, make in WAYS.items():
+        for name, make in ways.items():
             row = run_way(make, n, args.count, blob, alone)
             print(json.dumps({"round": rnd, "way": name, **{
                 k: round(v, 3) for k, v in row.items()}}), flush=True)

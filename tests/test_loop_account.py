@@ -1065,7 +1065,12 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them(tmp_path):
     assert later[4] == "packet_ingest_share.write"
     # PR 50: who sent a window's bytes (the sender threads' share, and
     # what a hand-over waits)
-    assert later[5:] == ["io_send_share.write", "io_wait_ms_per_op.write"]
+    assert later[5:7] == ["io_send_share.write", "io_wait_ms_per_op.write"]
+    # PR 51: how often a spare sent the pool's refill thread back to the
+    # GIL (the cells whose shards reach the pool: not the 64 KiB cell)
+    assert later[7:] == ["store_pool_calls_per_spare.write"]
+    assert spec["per_layer"][-1]["workloads"] == \
+        [c for c in CELLS if c != "k2m1_write_64k_t16"]
     # every cell that writes reports the account; the read cell, with
     # its pending entries appended (PR 46's twelve, behind all of
     # these), reports its own loop_busy_share and none of these
@@ -1075,11 +1080,11 @@ def test_the_accounts_entries_come_last_and_every_cell_reports_them(tmp_path):
     root = root_of("k2m1_degraded_randread_4m_t16", tmp_path)
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         after = [m["name"] for m in json.load(f)["per_layer"]]
-    assert after[:28 + len(METRICS) + 7] == \
+    assert after[:28 + len(METRICS) + 8] == \
         [m["name"] for m in spec["per_layer"]]
-    assert len(after) == 28 + len(METRICS) + 7 + 12
+    assert len(after) == 28 + len(METRICS) + 8 + 12
     assert all(name.endswith(".read")
-               for name in after[28 + len(METRICS) + 7:])
+               for name in after[28 + len(METRICS) + 8:])
     assert not set(METRICS) & set(
         load_cell("k2m1_degraded_randread_4m_t16", root=root).per_layer)
 

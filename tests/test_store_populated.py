@@ -22,12 +22,26 @@ path land; the spare bytes stay under the bound and the length least
 recently taken pays for a new one; a spare goes with its object as an
 inline mapping does; who never starts the thread; and that no
 transaction needs the thread to run.
+
+Since PR 51 the pieces of a spare are walked by a C function that the
+refill thread builds with the host's ``cc`` (``store._native_walk``), in
+one foreign call a spare; the Python walk serves where it cannot be
+built.  Pinned in "the native walk": it leaves what the Python walk
+leaves and asks the kernel for no more than a piece at a time; it costs
+the thread two returns to the GIL a spare whatever the length; who
+builds it, where the build is kept, and that a host without a compiler
+or a source that does not build is served by the Python walk.
 """
 
 import asyncio
+import ctypes
 import gc
+import glob
+import logging
 import mmap
 import os
+import shutil
+import subprocess
 import sys
 import threading
 import weakref
@@ -348,8 +362,10 @@ def test_a_shard_under_the_threshold_is_a_bytearray(small_map_min, nbytes,
 
 def test_the_threshold_is_a_quarter_of_a_mebibyte():
     """What the cells' shards are measured against: 0.5-2 MiB map, the
-    64 KiB cell's 32 KiB do not."""
+    64 KiB cell's 32 KiB do not; and of those that map, the C walk takes
+    0.5-1 MiB and leaves k2m1's 2 MiB to the Python walk (PR 51)."""
     assert store_mod._MAP_MIN == 256 << 10
+    assert store_mod._POOL_WALK_MAX == 1 << 20
 
 
 @pytest.mark.parametrize("landed", ["inline", "pooled"])
@@ -692,8 +708,6 @@ def test_a_spare_is_zeros_and_an_objects_mapping_never_returns(
 
 def _resident(view) -> bool:
     """Every page of ``view``'s mapping is there (``mincore``)."""
-    import ctypes
-
     n = len(view)
     pages = -(-n // mmap.PAGESIZE)
     vec = (ctypes.c_ubyte * pages)()
@@ -749,13 +763,20 @@ def test_a_piece_the_kernel_refuses_ends_the_refill(monkeypatch):
         store_mod._make_spare(store_mod._POOL_PIECE + 1)
 
 
-def test_the_populate_lets_the_gil_go():
+@pytest.mark.parametrize("how", ["python", "native"])
+def test_the_populate_lets_the_gil_go(how, request):
     """Held without a clock: with a switch interval of an hour nobody is
     made to give the GIL up, so the main thread gets it back while the
     helper is still inside ``_make_spare`` only if the calls in there let
     it go.  64 MiB, so that the populates last; a loaded host may still
     wake the main thread too late, so any of five goes proves it (were
-    the GIL held, none could)."""
+    the GIL held, none could).  Either walk: the Python loop's ``mmap``
+    calls, and the C walk's one call."""
+    walk = None
+    if how == "native":                 # 64 MiB in the C walk's one call
+        walk = request.getfixturevalue("native")
+        request.getfixturevalue("monkeypatch").setattr(
+            store_mod, "_POOL_WALK_MAX", 64 << 20)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(3600)
     try:
@@ -764,7 +785,7 @@ def test_the_populate_lets_the_gil_go():
 
             def helper():
                 entered.set()
-                store_mod._make_spare(64 << 20)
+                store_mod._make_spare(64 << 20, walk)
                 done.set()
 
             thread = threading.Thread(target=helper)
@@ -887,6 +908,255 @@ def test_filestore_keeps_bytearrays_and_round_trips(small_map_min, tmp_path):
     assert s3.object_layout("c", "o") == PLANAR
     assert s3.read_planar("c", "o") == blob
     s3.umount()
+
+
+# ------------------------------------------------ the native walk (PR 51)
+
+
+@pytest.fixture
+def native():
+    """The C walk, built as the refill thread builds it, in the checkout's
+    own cache.  Skips where the host has no compiler."""
+    if shutil.which("cc") is None:
+        pytest.skip("no C compiler (cc) on this host")
+    walk = store_mod._native_walk()
+    assert walk is not None
+    return walk
+
+
+_RECORDER = """
+#undef mmap
+#include <stddef.h>
+#include <sys/mman.h>
+#include <sys/types.h>
+size_t longest_asked = 0;
+void *recorded_mmap(void *at, size_t n, int prot, int flags, int fd,
+                    off_t off)
+{
+    if (n > longest_asked)
+        longest_asked = n;
+    return mmap(at, n, prot, flags, fd, off);
+}
+"""
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """(the product's source built with its ``mmap`` renamed to a recorder
+    of this file's, the longest length the kernel was asked for through
+    it): what the walk asks the kernel, seen from outside the product's
+    source, which has no hook for it."""
+    if shutil.which("cc") is None:
+        pytest.skip("no C compiler (cc) on this host")
+    where = tmp_path_factory.mktemp("recorded")
+    (where / "recorder.c").write_text(_RECORDER)
+    lib = str(where / "recorded.so")
+    subprocess.run(["cc", "-O2", "-shared", "-fPIC", "-Dmmap=recorded_mmap",
+                    "-o", lib, store_mod._NATIVE_SRC,
+                    str(where / "recorder.c")], check=True,
+                   capture_output=True)
+    dll = ctypes.CDLL(lib, use_errno=True)
+    walk = dll.populate_pieces
+    walk.restype = ctypes.c_int
+    walk.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t]
+    return walk, ctypes.c_size_t.in_dll(dll, "longest_asked")
+
+
+def _pool_counts():
+    return (KERNELS.get("store_pool_calls"), KERNELS.get("store_pool_spares"))
+
+
+@pytest.mark.parametrize("piece", [64 << 10, store_mod._POOL_PIECE],
+                         ids=["pieces_of_64k", "the_products_piece"])
+@pytest.mark.parametrize("nbytes", [256 << 10, (256 << 10) + 8, 700416,
+                                    1 << 20, 2 << 20, 8 << 20])
+def test_the_native_walk_leaves_what_the_python_walk_leaves(
+        monkeypatch, native, recorded, nbytes, piece):
+    """The twins of ``test_a_spare_is_populated_piece_by_piece``: every
+    page is there when ``_make_spare`` returns, the kernel was asked for
+    at most a piece at a time (the same source with its ``mmap``
+    recorded), Python asked it for nothing, and what comes back is one
+    writable mapping of zeros that unmaps as a whole.  A spare longer
+    than ``_POOL_WALK_MAX`` (the 2 MiB and 8 MiB cases) is the Python
+    walk's, a call a piece, though the C walk is there."""
+    walk = native
+    pieces = -(-nbytes // piece)
+    c_walk = pieces > 1 and nbytes <= store_mod._POOL_WALK_MAX
+    monkeypatch.setattr(store_mod, "_POOL_PIECE", piece)
+    if c_walk:
+        monkeypatch.setattr(store_mod, "_libc_mmap", lambda *a: pytest.fail(
+            "the Python walk ran"))
+    calls, spares = _pool_counts()
+    view = store_mod._make_spare(nbytes, walk)
+    assert type(view.obj) is mmap.mmap and len(view) == nbytes
+    assert not view.readonly and _resident(view)
+    assert bytes(view) == bytes(nbytes)
+    # what the walk asks the kernel for such a range: a piece at a time
+    recorded_walk, longest = recorded
+    lazy = mmap.mmap(-1, nbytes, flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+    longest.value = 0
+    assert recorded_walk(ctypes.addressof(ctypes.c_char.from_buffer(lazy)),
+                         nbytes, piece) == 0
+    assert longest.value == min(piece, nbytes) and _resident(memoryview(lazy))
+    assert _pool_counts() == (
+        calls + (1 if pieces == 1 else 2 if c_walk else 1 + pieces),
+        spares + 1)
+    view[:] = b"\x5a" * nbytes          # the pieces are the block's own
+    assert view.obj[nbytes - 1] == 0x5a
+    block = view.obj
+    view.release()
+    block.close()                       # one unmap, nothing exported
+
+
+@pytest.mark.parametrize("nbytes", [256 << 10, (256 << 10) + 8, 700416,
+                                    1 << 20, 2 << 20, 8 << 20])
+def test_the_python_walk_returns_to_the_gil_once_a_piece(nbytes):
+    """What ``store_pool_calls`` over ``store_pool_spares`` says of a
+    thread without the C walk: the mapping's own call and one a piece: 9 /
+    5 / 4 for the cells' shards of 2 MiB / 1 MiB / 684 KiB, where the C
+    walk makes 2 (the twin above)."""
+    calls, spares = _pool_counts()
+    store_mod._make_spare(nbytes)
+    pieces = -(-nbytes // store_mod._POOL_PIECE)
+    assert _pool_counts() == (calls + (1 if pieces == 1 else 1 + pieces),
+                              spares + 1)
+
+
+@pytest.mark.parametrize("where", ["through_make_spare", "by_itself"])
+def test_a_piece_the_kernel_refuses_raises_from_the_native_walk(
+        monkeypatch, native, where):
+    """A piece that is no multiple of a page puts the second ``MAP_FIXED``
+    on an address the kernel refuses: the walk says which errno, and
+    ``_make_spare`` raises it; no spare is counted."""
+    walk = native
+    spares = _pool_counts()[1]
+    if where == "through_make_spare":
+        monkeypatch.setattr(store_mod, "_POOL_PIECE", mmap.PAGESIZE + 8)
+        with pytest.raises(OSError) as refused:
+            store_mod._make_spare(4 * mmap.PAGESIZE, walk)
+        assert refused.value.errno == 22
+    else:
+        block = mmap.mmap(-1, 4 * mmap.PAGESIZE)
+        base = ctypes.addressof(ctypes.c_char.from_buffer(block))
+        assert walk(base, 4 * mmap.PAGESIZE, mmap.PAGESIZE + 8) == 22
+        assert walk(base, 4 * mmap.PAGESIZE, 0) == 22
+        assert walk(base, 4 * mmap.PAGESIZE, mmap.PAGESIZE) == 0
+    assert _pool_counts()[1] == spares
+
+
+@pytest.mark.parametrize("cols", [COLS, 4 * COLS, 4 * COLS + 8],
+                         ids=["one_piece", "four_pieces", "and_a_bit"])
+def test_the_refill_thread_makes_two_calls_a_spare_at_most(
+        small_map_min, monkeypatch, pool, native, cols):
+    """Through the pool as the product runs it, pieces of a page: the
+    thread's spares cost it one foreign call (a shard of one piece) or two
+    whatever the length, the counters say so, and a shard that takes one
+    reads back."""
+    monkeypatch.setattr(store_mod, "_POOL_PIECE", NBYTES)
+    s = MemStore(1 << 24)
+    s.queue_transaction(Transaction().create_collection("c"))
+    calls, spares = _pool_counts()
+    _prime(pool, s, 8 * cols)
+    pooled = _pooled()
+    blob = _land(s, "c", "o", seed=41, cols=cols)
+    assert _pooled() - pooled == 8 * cols
+    _settle(pool)
+    made = _pool_counts()[1] - spares
+    assert made >= 2
+    assert _pool_counts()[0] - calls == made * (1 if cols == COLS else 2)
+    assert s.read_planar("c", "o") == blob and _mapped(s._colls["c"]["o"])
+
+
+def _compiler_runs(monkeypatch):
+    """Every ``subprocess.run`` from ``store``, with the thread it ran on."""
+    ran, real = [], subprocess.run
+
+    def run_(cmd, **kwargs):
+        ran.append((threading.current_thread().name, list(cmd)))
+        return real(cmd, **kwargs)
+
+    monkeypatch.setattr(store_mod.subprocess, "run", run_)
+    return ran
+
+
+def test_the_build_is_the_refill_threads_and_is_kept(small_map_min,
+                                                     monkeypatch, tmp_path):
+    """The first start of a checkout's refill thread builds the library,
+    on that thread and no other, under a temporary name that is gone
+    afterwards; the second start, and another process's, find it by the
+    source's hash and run no compiler."""
+    if shutil.which("cc") is None:
+        pytest.skip("no C compiler (cc) on this host")
+    monkeypatch.setattr(store_mod.compile_cache, "native_dir",
+                        lambda: str(tmp_path / "native"))
+    ran = _compiler_runs(monkeypatch)
+    s = MemStore(1 << 24)
+    s.queue_transaction(Transaction().create_collection("c"))
+    for start in range(2):
+        pool = store_mod._Pool()
+        monkeypatch.setattr(store_mod, "_POOL", pool)
+        calls, spares = _pool_counts()
+        try:
+            _land(s, "c", f"first{start}")      # a miss: starts the thread
+            assert not [r for r in ran if r[0] != "store-pool"]
+            _prime(pool, s, NBYTES)
+        finally:
+            pool.stop()
+        assert len(ran) == 1 and ran[0][0] == "store-pool"
+        kept = os.listdir(tmp_path / "native")
+        assert len(kept) == 1 and kept[0].startswith("populate_pieces-") \
+            and kept[0].endswith(".so")
+        # shards of one piece: one call a spare, either walk
+        made = _pool_counts()[1] - spares
+        assert made >= 1 and _pool_counts()[0] - calls == made
+    assert ran[0][1][0] == shutil.which("cc")
+    assert ran[0][1][-1] == store_mod._NATIVE_SRC
+
+
+@pytest.mark.parametrize("why", ["no_compiler", "the_source_does_not_build",
+                                 "no_source"])
+def test_without_the_library_the_python_walk_serves(
+        small_map_min, monkeypatch, tmp_path, caplog, pool, why):
+    """A host without ``cc`` (an empty PATH), a source the compiler
+    refuses, a checkout without the source: the thread walks the pieces in
+    Python, says so in the log when it starts (once a process: the
+    product never stops its thread), leaves nothing behind in the cache,
+    and the counters read a call a piece."""
+    monkeypatch.setattr(store_mod.compile_cache, "native_dir",
+                        lambda: str(tmp_path / "native"))
+    monkeypatch.setattr(store_mod, "_POOL_PIECE", NBYTES)
+    ran = _compiler_runs(monkeypatch)
+    if why == "no_compiler":
+        monkeypatch.setenv("PATH", "")
+    elif why == "no_source":
+        monkeypatch.setattr(store_mod, "_NATIVE_SRC",
+                            str(tmp_path / "gone.c"))
+    else:
+        if shutil.which("cc") is None:
+            pytest.skip("no C compiler (cc) on this host")
+        broken = tmp_path / "broken.c"
+        broken.write_text("int populate_pieces(void *base { return 0; }\n")
+        monkeypatch.setattr(store_mod, "_NATIVE_SRC", str(broken))
+    s = MemStore(1 << 24)
+    s.queue_transaction(Transaction().create_collection("c"))
+    calls, spares = _pool_counts()
+    with caplog.at_level(logging.WARNING, logger="ceph_tpu.store"):
+        assert store_mod._native_walk() is None
+        _prime(pool, s, 4 * NBYTES)             # starts the thread, twice
+        pooled = _pooled()
+        blob = _land(s, "c", "o", seed=43, cols=4 * COLS)
+        _settle(pool)
+        pool.stop()                     # every start has asked by now
+    assert _pooled() - pooled == 4 * NBYTES
+    assert s.read_planar("c", "o") == blob
+    # asked here and by each of the thread's three starts
+    said = [r for r in caplog.records if "no native walk" in r.getMessage()]
+    assert len(said) == 4 and {r.name for r in said} == {"ceph_tpu.store"}
+    assert len(ran) == (4 if why == "the_source_does_not_build" else 0)
+    cache = tmp_path / "native"
+    assert not cache.exists() or not os.listdir(cache)
+    made = _pool_counts()[1] - spares
+    assert made >= 2 and _pool_counts()[0] - calls == 5 * made
 
 
 # ------------------------------------------------------ tiny clusters, CPU
@@ -1135,6 +1405,40 @@ def test_the_metric_files_read_the_hand_worked_values(name, cell_name):
          "write_MBps")
     for growth, want in ((grown, pytest.approx(reads)), (without, 0.0),
                          ({}, None)):
+        readings = layers.Readings(
+            config=cell.config, device_kind="TPU v5 lite", attribution={},
+            counters=growth, slice_counters={}, trace=None)
+        assert layers.read_metric(name, reader, readings) == want
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_calls_a_spare_reads_which_walk_served(cell_name):
+    """2500 spares in a window: 5000 foreign calls with the C walk read
+    2.0, 22500 with the Python walk over 2 MiB shards 9.0; a program
+    without the two counters (the parent) grows neither and the accepted
+    reader reports nothing, so the parent's line leaves the metric out;
+    and so it would where the thread never starts, which is why the
+    64 KiB cell does not list it."""
+    from benchmark.harness import layers
+    from benchmark.harness.loader import load_cell
+
+    name = "store_pool_calls_per_spare.write"
+    cell = load_cell(cell_name)
+    if cell_name == "k2m1_write_64k_t16":
+        assert name not in cell.per_layer
+        return
+    reader = cell.per_layer[name]
+    assert (reader["kind"], reader["numerator"], reader["denominator"],
+            reader["scale"], reader["layer"], reader["moves"],
+            reader["unit"]) == \
+        ("counter_ratio", "store_pool_calls", "store_pool_spares", 1,
+         "fan-out and store", "write_MBps", "calls")
+    for growth, want in (
+            ({"store_pool_calls": 5000, "store_pool_spares": 2500}, 2.0),
+            ({"store_pool_calls": 22500, "store_pool_spares": 2500}, 9.0),
+            ({"ec_coalesced_ops": 3000,
+              "store_planar_write_bytes": 1_000_000}, None),
+            ({}, None)):
         readings = layers.Readings(
             config=cell.config, device_kind="TPU v5 lite", attribution={},
             counters=growth, slice_counters={}, trace=None)

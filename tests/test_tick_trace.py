@@ -128,9 +128,10 @@ def test_tick_root_says_what_it_encoded(burst):
 
 
 def test_phases_in_order_and_tiling_the_root(burst):
+    # one "slice" since PR 51: an op's block is written once, out of the
+    # two readbacks, and the tick's planes are no longer stacked first
     want = ["executor_wait", "fill", "to_planar", "encode_dispatch",
-            "crc", "readback", "readback", "crc", "slice", "slice",
-            "wake"]
+            "crc", "readback", "readback", "crc", "slice", "wake"]
     for t in burst["ticks"]:
         segs = t.segments()
         assert [s[0] for s in segs if s[0] != "other"] == want
@@ -141,6 +142,47 @@ def test_phases_in_order_and_tiling_the_root(burst):
         assert all(b >= a for _n, a, b, _c in segs)
         assert sum(b - a for _n, a, b, _c in segs) \
             == t.closed_ns - t.opened_ns
+
+
+@pytest.mark.parametrize("branch", ["host", "device"])
+@pytest.mark.parametrize("sizes", [[65536], [65536, 65536],
+                                   [65536, 65536, 65536],
+                                   [65536, 3 * 8192 - 100, 0, 8192]],
+                         ids=["one", "two", "three_padded_to_four",
+                              "mixed_and_empty"])
+def test_each_op_of_a_tick_gets_a_block_of_its_own(monkeypatch, branch,
+                                                   sizes):
+    """Since PR 51 the slice writes every op's planes once, out of the
+    tick's data and parity planes, without stacking the tick first, and
+    the batch is allocated at its bucket's size: what an op is handed is
+    what a tick of that op alone hands it, contiguous, writable, and
+    nobody else's memory."""
+    from ceph_tpu.ec import factory, stripe
+
+    codec = factory({"plugin": "jerasure", "technique": "reed_sol_van",
+                     "k": "2", "m": "1"})
+    sinfo = stripe.StripeInfo(2, 4096)
+    if branch == "device":
+        monkeypatch.setattr(stripe, "_host_engine_ok", lambda codec: False)
+    rng = np.random.default_rng(51)
+    datas = [rng.integers(0, 256, sz, dtype=np.uint8).tobytes()
+             for sz in sizes]
+    want = [True] * len(datas)
+    pad0 = KERNELS.dump()["device_kernels"].get("ec_stripe_pad_bytes", 0)
+    together = stripe.encode_planes_multi(codec, sinfo, datas, want)
+    pad = KERNELS.dump()["device_kernels"]["ec_stripe_pad_bytes"] - pad0
+    stripes = [sinfo.object_stripes(sz) for sz in sizes]
+    bb = sum(stripes) if branch == "host" else stripe._bucket(sum(stripes))
+    assert pad == bb * sinfo.stripe_width - sum(sizes)
+    for i, d in enumerate(datas):
+        [(alone, crcs)] = stripe.encode_planes_multi(codec, sinfo, [d],
+                                                     [True])
+        planes, got = together[i]
+        assert planes.shape == (3, 8, stripes[i] * 4096 // 8)
+        assert np.array_equal(planes, alone) and list(got) == list(crcs)
+        assert planes.flags.c_contiguous and planes.flags.writeable
+        for j in range(i):
+            assert not np.shares_memory(planes, together[j][0])
 
 
 def test_device_calls_per_tick_equal_the_count_read_off_the_code(burst):
